@@ -69,7 +69,7 @@ def main() -> None:
             print(json.dumps({"metric": METRIC, "value": 0.0,
                               "unit": "images/sec",
                               "error": f"trainer hung past {timeout:.0f}s"}))
-            return
+            raise SystemExit(1)
         rows = []
         if os.path.exists(jsonl):
             with open(jsonl) as f:
@@ -83,7 +83,7 @@ def main() -> None:
                               "error": f"trainer rc={proc.returncode}: "
                                        + (tail[-1] if tail else "no output"),
                               }))
-            return
+            raise SystemExit(1)
         epoch_s = next(r["seconds"] for r in rows
                        if r.get("kind") == "epoch"
                        and r["epoch"] == last_epoch)

@@ -32,7 +32,7 @@ only WHERE within the launch each fault lands, never whether it fires):
 Emits one JSON row per seed (metric ``train_soak``) with the recovery
 counts, ``parity_ok``, ``accounted``, and ``device_kind`` — the
 ``train_soak`` stage registered in ``tools/bench_gaps.py`` /
-``tools/record_bench.py`` / ``tools/tpu_when_ready.sh``; CPU smoke rows
+``tools/record_bench.py``; CPU smoke rows
 are pinned by ``tests/test_bench_smoke.py``.
 
 ``--multihost`` runs the POD-SCALE variant instead (metric
@@ -944,11 +944,17 @@ def main() -> None:
         import tempfile
 
         workdir = tempfile.mkdtemp(prefix="tpudp_train_soak_")
+    # One mode per invocation, and that is load-bearing on a chip: the
+    # kill/resume soaks keep THIS process off JAX and launch workers that
+    # take the device one at a time, while --sdc runs JAX in this process
+    # and launches nothing — a parent that has touched JAX holds the chip
+    # and a child that needs it would fail or hang.
     runner = (run_sdc_soak if args.sdc
               else run_soak_multihost if args.multihost else run_soak)
     metric = ("sdc_soak" if args.sdc
               else "train_soak_multihost" if args.multihost
               else "train_soak")
+    failed = []
     for seed in seeds:
         try:
             row = runner(seed, workdir)
@@ -957,7 +963,10 @@ def main() -> None:
         if "error" in row:
             row.setdefault("metric", metric)
             row.setdefault("value", 0)
+            failed.append(seed)
         print(json.dumps(row), flush=True)
+    if failed:  # every seed still got its row; the run did not succeed
+        raise SystemExit(f"error: {metric} seeds failed: {failed}")
 
 
 if __name__ == "__main__":

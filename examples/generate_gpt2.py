@@ -104,9 +104,7 @@ def main() -> None:
 
         jax.config.update("jax_platforms", args.platform)
     from tpudp.utils.compile_cache import enable_persistent_cache
-    from tpudp.utils.device_lock import acquire_for_process
 
-    acquire_for_process()  # self-skips when cpu-pinned
     enable_persistent_cache()
     import jax
     import jax.numpy as jnp

@@ -1,6 +1,6 @@
-"""Smoke tests for the benchmark harnesses — the round's headline artifact
-must always emit its parseable JSON line, so its plumbing is CI-guarded on
-the simulated CPU mesh (tiny steps; real numbers come from the TPU runs).
+"""Smoke tests for the benchmark harnesses: their plumbing is CI-guarded on
+the simulated CPU mesh (tiny steps; real numbers come from chip runs), and
+bench.py — which measures an accelerator or nothing — must refuse the CPU.
 """
 
 import json
@@ -22,140 +22,24 @@ def _run(script, env_extra, timeout=900, args=()):
         capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO)
 
 
-@pytest.mark.slow  # ~27s subprocess VGG compile; the headline-line
-# CONTRACT this guards (parent always prints one parseable JSON row) is
-# pinned on the fast tier by
-# test_bench_headline_parses_even_when_child_crashes — same parent emit
-# path, crash branch included — and the success-path row fields ride
-# every real TPU capture; only the smoke-host success VALUES are extra.
-def test_bench_emits_headline_json():
-    # BENCH_COST/BENCH_COLLECTIVE off: each side-measurement recompiles a
-    # program and this smoke test guards the headline-line CONTRACT, not
-    # those measurements (they run on every real TPU capture and the
-    # collective path is smoke-covered by test_matrix_bench_rows_parse's
-    # dp_ring row); with them the test was the fast tier's slowest (r4 #8).
-    proc = _run("bench.py", {
-        "BENCH_PLATFORM": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-        "BENCH_BATCH": "32", "BENCH_STEPS": "2", "BENCH_WARMUP": "1",
-        "BENCH_TRIES": "1", "BENCH_COST": "0", "BENCH_COLLECTIVE": "0",
-    })
-    lines = [l for l in proc.stdout.strip().splitlines()
-             if l.startswith("{")]
-    assert lines, f"no JSON line; stderr tail: {proc.stderr[-800:]}"
-    head = json.loads(lines[-1])
-    assert head["metric"] == "vgg11_cifar10_images_per_sec_per_chip"
-    assert head["unit"] == "images/sec/chip"
-    assert head["value"] > 0
-    assert "vs_baseline" in head
-    assert head["devices"] == 4
+def test_bench_without_a_chip_exits_nonzero_and_prints_no_value():
+    """bench.py measures an accelerator or nothing: on a machine where JAX
+    finds only the CPU it must exit non-zero and print NO row — no CPU
+    number under a device metric's name, no stored number re-emitted."""
+    proc = _run("bench.py", {"JAX_PLATFORMS": "cpu"}, timeout=300)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == "", proc.stdout
+    assert "no value printed" in proc.stderr
 
 
-def test_bench_headline_parses_even_when_child_crashes():
-    """The round-1 failure mode: every attempt dies -> the parent must still
-    print one parseable JSON line recording the error (rc 0).  Smoke mode
-    (BENCH_PLATFORM) never consumes banked TPU evidence, so the error line
-    (not a last_known_good re-emission) is the required outcome here."""
-    proc = _run("bench.py", {
-        "BENCH_PLATFORM": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-        "BENCH_BATCH": "31",  # not divisible by 4 devices -> child crashes
-        "BENCH_STEPS": "1", "BENCH_WARMUP": "0", "BENCH_TRIES": "1",
-    })
-    assert proc.returncode == 0
-    head = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert head["metric"] == "vgg11_cifar10_images_per_sec_per_chip"
-    assert head["value"] == 0.0
-    assert "error" in head
-
-
-def test_banked_fallback_selection(tmp_path, monkeypatch):
-    """_banked_good: newest-by-timestamp real TPU row wins; re-emitted
-    last_known_good rows and CPU smoke rows never qualify (staleness must
-    not compound, smoke numbers are not evidence)."""
-    import bench
-
-    rows = [
-        {"metric": bench.METRIC, "value": 100.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T04:00:00Z"},
-        {"metric": bench.METRIC, "value": 200.0, "device_kind": "cpu",
-         "measured_at_utc": "2026-07-30T05:00:00Z"},
-        {"metric": bench.METRIC, "value": 300.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T03:00:00Z",
-         "source": "last_known_good"},
-        # a different sync rung's measurement must never stand in for the
-        # requested one.  This ring row predates the round-4 direction
-        # flip (no ring_direction stamp) — it measured the OLD
-        # bidirectional schedule and must not satisfy a 'ring' request
-        # under the new single-direction meaning (round-4 advisor).
-        {"metric": bench.METRIC, "value": 400.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T06:00:00Z", "sync": "ring"},
-        # a post-flip ring row carries the stamp and DOES qualify
-        {"metric": bench.METRIC, "value": 450.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T05:30:00Z", "sync": "ring",
-         "ring_direction": "uni"},
-        # ring_bidir's label never changed meaning, so its unstamped
-        # pre-stamp row stays valid evidence
-        {"metric": bench.METRIC, "value": 460.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T05:40:00Z", "sync": "ring_bidir"},
-        # nor may a different param dtype's (bf16-params vs fp32)
-        {"metric": bench.METRIC, "value": 500.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T07:00:00Z",
-         "param_dtype": "bfloat16"},
-    ]
-    hist = tmp_path / "bench.history.jsonl"
-    hist.write_text("".join(json.dumps(r) + "\n" for r in rows))
-    # newest TPU row lives in the history file, older one in bench.json —
-    # timestamp order must beat file order
-    (tmp_path / "bench.json").write_text(json.dumps(
-        {"metric": bench.METRIC, "value": 50.0, "device_kind": "TPU v5",
-         "measured_at_utc": "2026-07-30T01:00:00Z"}) + "\n")
-    monkeypatch.setattr(bench, "_bench_json_path",
-                        lambda: str(tmp_path / "bench.json"))
-    good = bench._banked_good("allreduce", "float32")
-    assert good is not None and good["value"] == 100.0
-    # newest UNSTAMPED ring row (400.0, pre-flip bidirectional capture)
-    # must lose to the older stamped single-direction row (450.0)
-    ring = bench._banked_good("ring", "float32")
-    assert ring is not None and ring["value"] == 450.0
-    # unstamped ring_bidir evidence stays valid (label never flipped)
-    bidir = bench._banked_good("ring_bidir", "float32")
-    assert bidir is not None and bidir["value"] == 460.0
-    bf16 = bench._banked_good("allreduce", "bfloat16")
-    assert bf16 is not None and bf16["value"] == 500.0
-
-
-def test_emit_banked_marks_replay_machine_distinguishable(capsys):
-    """Round-3 judge #1: a banked re-emission must be impossible to
-    mistake for a fresh measurement — fresh:false, the git_rev of the
-    code that PRODUCED the row (null for rows banked before the field
-    existed), and the re-emitting rev recorded separately."""
-    import pytest
-
-    import bench
-
-    banked = {"metric": bench.METRIC, "value": 92469.2,
-              "images_per_sec_total": 92469.2,
-              "device_kind": "TPU v5 lite",
-              "baseline_4node_gloo_images_per_sec":
-                  bench.BASELINE_4NODE_GLOO_IPS,
-              "measured_at_utc": "2026-07-30T04:36:00Z"}
-    with pytest.raises(SystemExit):
-        bench._emit_banked(banked, "relay wedged")
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["fresh"] is False
-    assert out["source"] == "last_known_good"
-    assert out["git_rev"] is None  # pre-field row: producing rev unknown
-    assert out["stale_reason"] == "relay wedged"
-    assert "reemitted_by_git_rev" in out
-    # Explicit staleness horizon, never silently re-dated: stale_since
-    # is the banked row's own capture timestamp.
-    assert out["stale_since"] == "2026-07-30T04:36:00Z"
-    # a banked row that DOES carry its producing rev keeps it
-    with pytest.raises(SystemExit):
-        bench._emit_banked({**banked, "git_rev": "abc1234"}, "wedged")
-    out2 = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out2["git_rev"] == "abc1234"
+def test_bench_rejects_bad_rung_before_touching_a_device():
+    """A typo'd BENCH_SYNC / BENCH_PARAM_DTYPE fails fast (non-zero, no
+    row) instead of measuring something other than what was asked."""
+    for env in ({"BENCH_SYNC": "rnig"}, {"BENCH_PARAM_DTYPE": "bf16"}):
+        proc = _run("bench.py", {"JAX_PLATFORMS": "cpu", **env}, timeout=300)
+        assert proc.returncode != 0
+        assert "{" not in proc.stdout
+        assert next(iter(env)) in proc.stderr
 
 
 def test_registry_configs_all_gated():
@@ -248,24 +132,10 @@ def test_stale_tpu_row_gap(tmp_path):
     assert stale_tpu_rows(d) == []  # fresh measurement, no gap
     stale = {**fresh, "source": "last_known_good", "fresh": False,
              "stale_since": "2026-07-30T04:36:00Z",
-             "stale_reason": "relay wedged"}
+             "stale_reason": "device unavailable"}
     with open(os.path.join(d, "bench.json"), "w") as f:
         f.write(json.dumps(stale) + "\n")
     assert stale_tpu_rows(d) == ["stale-tpu-row:bench.json"]
-
-
-def test_error_row_skeleton():
-    """Every error emitter shares _error_row: value 0, fresh false, the
-    current git_rev for traceability, plus any extra fields."""
-    import bench
-
-    row = json.loads(bench._error_row("boom", attempt_errors=["x"]))
-    assert row["metric"] == bench.METRIC
-    assert row["value"] == 0.0 and row["vs_baseline"] == 0.0
-    assert row["fresh"] is False
-    assert row["error"] == "boom"
-    assert row["attempt_errors"] == ["x"]
-    assert "git_rev" in row
 
 
 # Demoted to slow (PR 20 durations audit): the matrix row schema and
@@ -351,7 +221,7 @@ def test_serve_prefix_gap_gate(tmp_path):
          "value": 1.4, "prefix_hit_tokens": 640, "parity_ok": True,
          "device_kind": "cpu"},                       # smoke: no
         {"metric": "serve_prefix", "workload": "multiturn",
-         "error": "relay wedged"},                    # error: no
+         "error": "device unavailable"},                    # error: no
         {"metric": "serve_prefix", "workload": "multiturn",
          "value": 2.0, "prefix_hit_tokens": 0, "parity_ok": True,
          "device_kind": "TPU v5 lite"},               # no hits: no
@@ -467,7 +337,7 @@ def test_serve_paged_gap_gate(tmp_path):
          "value": 2.0, "capacity_ok": True, "prefix_hit_tokens": 320,
          "parity_ok": True, "device_kind": "cpu"},     # smoke: no
         {"metric": "serve_paged", "workload": "shared_prefix",
-         "error": "relay wedged"},                     # error: no
+         "error": "device unavailable"},                     # error: no
         {"metric": "serve_paged", "workload": "shared_prefix",
          "value": 1.2, "capacity_ok": False, "prefix_hit_tokens": 320,
          "parity_ok": True,
@@ -511,7 +381,7 @@ def test_serve_paged_kernel_gap_gate(tmp_path):
          "value": 1.1, "gather_free_ok": True, "parity_ok": True,
          "device_kind": "cpu"},                        # smoke: no
         {"metric": "serve_paged_kernel", "workload": "shared_prefix",
-         "error": "relay wedged"},                     # error: no
+         "error": "device unavailable"},                     # error: no
         {"metric": "serve_paged_kernel", "workload": "shared_prefix",
          "value": 0.8, "gather_free_ok": False, "parity_ok": True,
          "device_kind": "TPU v5 lite"},                # slower: no
@@ -605,7 +475,7 @@ def test_serve_paged_traffic_gap_gate(tmp_path):
          "traffic": "prefill", "value": None, "kernel_ok": True,
          "parity_ok": True, "device_kind": "cpu"},     # smoke: no
         {"metric": "serve_paged_kernel", "workload": "shared_prefix",
-         "traffic": "verify", "error": "relay wedged"},  # error: no
+         "traffic": "verify", "error": "device unavailable"},  # error: no
         {"metric": "serve_paged_kernel", "workload": "shared_prefix",
          "traffic": "fused", "value": 0.7, "kernel_ok": False,
          "parity_ok": True,
@@ -733,7 +603,7 @@ def test_serve_fused_gap_gate(tmp_path):
     rows = [
         {**ok, "decode_fuse": 1, "device_kind": "cpu"},   # smoke: no
         {"metric": "serve_fused", "decode_fuse": 4,
-         "error": "relay wedged"},                        # error: no
+         "error": "device unavailable"},                        # error: no
         {**ok, "decode_fuse": 4, "parity_ok": False,
          "device_kind": "TPU v5 lite"},                   # parity: no
         {**ok, "decode_fuse": 8, "dispatch_ok": False,
@@ -823,7 +693,7 @@ def test_serve_spec_fused_gap_gate(tmp_path):
     rows = [
         {**ok, "config": "k2n4", "device_kind": "cpu"},   # smoke: no
         {"metric": "serve_spec_fused", "config": "k2n4",
-         "error": "relay wedged"},                        # error: no
+         "error": "device unavailable"},                        # error: no
         {**ok, "config": "k2n4", "parity_ok": False,
          "device_kind": "TPU v5 lite"},                   # parity: no
         {**ok, "config": "k4n8", "spec_fused_ok": False,
@@ -901,7 +771,7 @@ def test_serve_tenancy_gap_gate(tmp_path):
     rows = [
         {**ok, "seed": 0, "device_kind": "cpu"},      # smoke: no
         {"metric": "serve_tenancy", "seed": 1,
-         "error": "relay wedged"},                    # error: no
+         "error": "device unavailable"},                    # error: no
         {**ok, "seed": 1, "p99_ok": False,
          "device_kind": "TPU v5 lite"},               # p99 blown: no
         {**ok, "seed": 2, "parity_ok": False,
@@ -1073,7 +943,7 @@ def test_train_soak_gap_gate(tmp_path):
          "parity_ok": True, "accounted": True,
          "device_kind": "cpu"},                       # smoke: no
         {"metric": "train_soak", "seed": 1,
-         "error": "relay wedged", "value": 0},        # error: no
+         "error": "device unavailable", "value": 0},        # error: no
         {"metric": "train_soak", "seed": 1, "value": 8,
          "parity_ok": False, "accounted": True,
          "device_kind": "TPU v5 lite"},               # diverged: no
@@ -1156,16 +1026,3 @@ def test_train_soak_multihost_gap_gate(tmp_path):
               "w") as f:
         f.write(json.dumps({**ok, "seed": 2}) + "\n")
     assert train_soak_multihost_missing(d) == [1]  # banked row counts
-
-
-def test_bad_param_dtype_fails_fast():
-    """BENCH_PARAM_DTYPE typos (e.g. 'bf16') must exit with an error before
-    any measurement — a silent fp32 run recorded as 'bf16' would be a false
-    evidence row (same contract as _requested_sync for BENCH_SYNC)."""
-    proc = _run("bench.py", {
-        "BENCH_PLATFORM": "cpu",
-        "BENCH_PARAM_DTYPE": "bf16",
-        "BENCH_PROBE": "0",
-    }, timeout=300)
-    assert proc.returncode != 0
-    assert "BENCH_PARAM_DTYPE" in (proc.stderr + proc.stdout)

@@ -21,7 +21,7 @@ def test_matrix_gaps_ignore_errors_and_merge_history(tmp_path):
     assert matrix_missing(d) == list(MATRIX_CONFIGS)  # nothing measured yet
     _write(os.path.join(d, "matrix.history.jsonl"), [
         {"config": "dp_psum", "value": 90000.0, "unit": "images/sec/chip"},
-        {"config": "dp_ring", "error": "RuntimeError: relay wedged"},
+        {"config": "dp_ring", "error": "RuntimeError: device unavailable"},
     ])
     _write(os.path.join(d, "matrix.jsonl"), [
         {"config": "part1_single", "value": 88000.0},
@@ -129,7 +129,7 @@ def test_record_bench_renders_freshest_rows(tmp_path):
          "sec_per_step": 0.00277, "device_kind": "TPU v5 lite",
          "dtype": "bfloat16", "global_batch": 256,
          "measured_at_utc": "2026-07-30T04:36:00Z",
-         "source": "last_known_good", "stale_reason": "relay wedged"},
+         "source": "last_known_good", "stale_reason": "device unavailable"},
     ])
     _write(os.path.join(d, "epoch.json"), [
         {"metric": "vgg11_epoch_images_per_sec", "value": 88000.0,
@@ -148,7 +148,7 @@ def test_record_bench_renders_freshest_rows(tmp_path):
          "p99_token_latency_ms": 11.0, "mean_slot_occupancy": 0.93,
          "device_kind": "TPU v5 lite"},
         {"metric": "serve_tokens_per_sec", "concurrency": 4,
-         "error": "relay wedged"},
+         "error": "device unavailable"},
     ])
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(

@@ -1,24 +1,24 @@
-"""Compiled-program caching (tpudp/utils/compile_cache.py).
+"""Compiled-program caching.
 
-Persistent-cache helper: must (a) no-op on the CPU backend — the
-suite's platform — so smoke runs never see XLA:CPU's per-hit AOT
-mismatch noise, (b) honor the TPUDP_COMPILE_CACHE=0 opt-out, and (c)
-when forced, actually point JAX's config at the cache dir with zeroed
-thresholds (a silently renamed config flag in a JAX upgrade would
-otherwise disable caching without any signal — the function is
-deliberately never fatal).
+Persistent-cache helper (tpudp/utils/compile_cache.py): must (a) no-op on
+the CPU backend — the suite's platform — so smoke runs never see XLA:CPU's
+per-hit AOT mismatch noise, (b) leave the directory to JAX whenever
+``JAX_COMPILATION_CACHE_DIR`` is set — no ``jax_compilation_cache_dir``
+update in code — and otherwise use the one fixed in-checkout path, and
+(c) actually zero the thresholds (a silently renamed config flag in a JAX
+upgrade would otherwise disable caching without any signal; failures
+propagate).
 
-ProgramCache: the serve engine's step-program LRU.  The trace-
-stability audit (tpudp.analysis) leans on its semantics, so they are
-pinned here: distinct-(cfg, params) keying, identity (not equality)
-hits, strong-ref id() safety, LRU-over-gets eviction under the bound,
-and cross-engine sharing of one weight tree's programs.
+Step-program sharing: the serve engine builds its programs once per model
+CONFIG (weights are arguments), so engines over one config share them.
 """
+
+import os
 
 import jax
 import pytest
 
-from tpudp.utils.compile_cache import ProgramCache, enable_persistent_cache
+from tpudp.utils.compile_cache import DEFAULT_DIR, enable_persistent_cache
 
 
 @pytest.fixture()
@@ -32,114 +32,52 @@ def _restore_cache_config():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", prev[2])
 
 
-def test_noop_on_cpu_backend(tmp_path):
+def test_noop_on_cpu_backend(monkeypatch):
     # conftest forces the CPU platform, so the resolved-backend gate trips.
-    assert enable_persistent_cache(str(tmp_path / "cache")) is None
-    assert not (tmp_path / "cache").exists()
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append(k))
+    assert enable_persistent_cache() is None
+    assert updates == []
 
 
-def test_opt_out_env(monkeypatch, tmp_path):
-    monkeypatch.setenv("TPUDP_COMPILE_CACHE", "0")
-    assert enable_persistent_cache(force=True) is None
+def test_env_dir_is_left_to_jax(monkeypatch, tmp_path,
+                                _restore_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set => JAX reads its own variable; the
+    helper sets the two thresholds and NEVER updates
+    jax_compilation_cache_dir (an in-code update would override where the
+    machine's owner placed the cache)."""
+    d = str(tmp_path / "placed_from_outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    updates = {}
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.__setitem__(k, v), real_update(k, v)))
+    assert enable_persistent_cache(force=True) == d
+    assert "jax_compilation_cache_dir" not in updates
+    assert updates == {"jax_persistent_cache_min_compile_time_secs": 0.0,
+                       "jax_persistent_cache_min_entry_size_bytes": 0}
+    assert not os.path.exists(d)  # nothing created on JAX's behalf
 
 
-def test_forced_enable_sets_config(tmp_path, _restore_cache_config):
-    d = str(tmp_path / "cache")
-    assert enable_persistent_cache(d, force=True) == d
-    import os
-
-    assert os.path.isdir(d)
-    assert jax.config.jax_compilation_cache_dir == d
+def test_unset_env_uses_the_fixed_checkout_path(monkeypatch,
+                                                _restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert enable_persistent_cache(force=True) == DEFAULT_DIR
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_DIR == os.path.join(repo, "bench_results", "xla_cache")
+    assert os.path.isdir(DEFAULT_DIR)
+    assert jax.config.jax_compilation_cache_dir == DEFAULT_DIR
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
 
 
-def test_env_path_default(monkeypatch, tmp_path, _restore_cache_config):
-    d = str(tmp_path / "env_cache")
-    monkeypatch.setenv("TPUDP_COMPILE_CACHE", d)
-    assert enable_persistent_cache(force=True) == d
-
-
-# -- ProgramCache ------------------------------------------------------
-
-
-def _counting_cache(max_entries=8):
-    built = []
-
-    def build(cfg, params):
-        built.append((cfg, id(params)))
-        return (cfg, id(params), len(built))  # distinct object per build
-
-    return ProgramCache(build, max_entries=max_entries), built
-
-
-def test_program_cache_hit_is_identity():
-    cache, built = _counting_cache()
-    params = {"w": [1.0]}
-    first = cache.get("cfgA", params)
-    assert cache.get("cfgA", params) is first
-    assert len(built) == 1
-    assert cache.hits == 1 and cache.builds == 1
-
-
-def test_program_cache_distinct_cfg_and_params_key():
-    cache, built = _counting_cache()
-    p1, p2 = {"w": [1.0]}, {"w": [1.0]}  # equal but not identical
-    a = cache.get("cfgA", p1)
-    b = cache.get("cfgB", p1)  # same params, different cfg
-    c = cache.get("cfgA", p2)  # same cfg, equal-but-distinct params
-    assert len({id(a), id(b), id(c)}) == 3
-    assert len(built) == 3 and cache.hits == 0
-    # identity, not equality: the frozen-weight programs close over ONE
-    # specific tree; an equal copy must not alias them
-    assert cache.get("cfgA", p1) is a
-    assert cache.get("cfgA", p2) is c
-
-
-def test_program_cache_lru_eviction_under_bound():
-    cache, built = _counting_cache(max_entries=2)
-    trees = [{"i": i} for i in range(3)]
-    a = cache.get("cfg", trees[0])
-    cache.get("cfg", trees[1])
-    assert cache.get("cfg", trees[0]) is a  # refresh 0 → 1 is now LRU
-    cache.get("cfg", trees[2])              # evicts 1, not 0
-    assert len(cache) == 2
-    assert cache.get("cfg", trees[0]) is a          # still cached
-    n = len(built)
-    cache.get("cfg", trees[1])                      # was evicted
-    assert len(built) == n + 1
-
-
-def test_program_cache_holds_params_ref():
-    """The entry must keep the weight tree alive: that is what makes the
-    id()-based key safe (a dead tree's id could be recycled)."""
-    import gc
-    import weakref
-
-    class Tree(dict):
-        pass
-
-    cache, _ = _counting_cache()
-    params = Tree(w=1)
-    ref = weakref.ref(params)
-    cache.get("cfg", params)
-    del params
-    gc.collect()
-    assert ref() is not None  # the cache's strong ref pins it
-    cache.clear()
-    gc.collect()
-    assert ref() is None
-
-
-def test_program_cache_rejects_bad_bound():
-    with pytest.raises(ValueError):
-        ProgramCache(lambda cfg, params: None, max_entries=0)
-
-
 def test_engines_share_step_programs():
-    """Two engines over one (model, params) tree reuse one set of
-    frozen-weight step programs — the multi-engine deployment pattern
-    and the reason a preemption/churn storm can never recompile."""
+    """Engines over one model CONFIG reuse one set of step programs
+    (the weights are arguments) — the multi-engine deployment pattern
+    and the reason a preemption/churn storm can never recompile — even
+    when they serve different weight trees."""
     import numpy as np
 
     from tpudp.models.gpt2 import GPT2, GPT2Config
@@ -150,8 +88,12 @@ def test_engines_share_step_programs():
     model = GPT2(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         np.zeros((1, 8), np.int32), train=False)["params"]
+    other = jax.tree.map(lambda a: a + 1, params)
     e1 = Engine(model, params, num_slots=2, prefill_chunk=8)
-    e2 = Engine(model, params, num_slots=4, prefill_chunk=8)
+    e2 = Engine(model, other, num_slots=4, prefill_chunk=8)
     ms1, ms2 = e1._mstates[None], e2._mstates[None]
-    assert ms1.decode_step is ms2.decode_step
-    assert ms1.prefill_step is ms2.prefill_step
+    assert ms1.decode_step.func is ms2.decode_step.func
+    assert ms1.prefill_step.func is ms2.prefill_step.func
+    # each engine's programs are bound to ITS weights
+    assert ms1.decode_step.args[0] is params
+    assert ms2.decode_step.args[0] is other

@@ -299,7 +299,7 @@ def test_serve_bench_gap_gate(tmp_path):
         {"metric": "serve_tokens_per_sec", "concurrency": 1,
          "value": 900.0, "device_kind": "cpu"},          # smoke: no
         {"metric": "serve_tokens_per_sec", "concurrency": 4,
-         "error": "relay wedged"},                       # error: no
+         "error": "device unavailable"},                       # error: no
         {"metric": "serve_tokens_per_sec", "concurrency": 8,
          "value": 9000.0, "device_kind": "TPU v5 lite"},  # real: yes
     ]

@@ -538,7 +538,7 @@ def test_serve_soak_bench_gap_gate(tmp_path):
          "parity_ok": True, "no_leak": True, "canary_ok": True,
          "device_kind": "cpu"},                        # smoke: no
         {"metric": "serve_soak", "seed": 1,
-         "error": "relay wedged"},                     # error: no
+         "error": "device unavailable"},                     # error: no
         {"metric": "serve_soak", "seed": 2, "value": 9,
          "parity_ok": False, "no_leak": True, "canary_ok": True,
          "device_kind": "TPU v5 lite"},                # failed soak: no

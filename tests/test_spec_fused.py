@@ -307,15 +307,23 @@ def test_fused_spec_preemption_resumes_bit_exactly(target, draft):
     assert (h_low.draft_proposed, h_low.draft_accepted) == \
         (g_low.draft_proposed, g_low.draft_accepted)
     # And the schedule-independent pin: GREEDY preempted == greedy solo.
+    # The budget exceeds what the first two steps can emit (1 prefill
+    # sample + one fused program of at most FUSE windows x K+1 tokens),
+    # so the request is still in flight when the high tier arrives
+    # whatever the draft acceptance is — under jax 0.9.0 this model's
+    # greedy stream repeats enough that a 12-token budget finished
+    # inside the first fused program and nothing was left to preempt.
+    n_greedy = 1 + FUSE * (K + 1) + 7
     eng = make(True, tenants())
-    h = eng.submit(p_low, 12, tenant="low")
+    h = eng.submit(p_low, n_greedy, tenant="low")
     eng.step()
     eng.step()
     eng.submit(p_hi, 3, tenant="high")
     eng.run_until_complete()
     assert h.preemptions == 1
     np.testing.assert_array_equal(
-        _reference(model, params, p_low, 12)[5:], np.asarray(h.tokens))
+        _reference(model, params, p_low, n_greedy)[5:],
+        np.asarray(h.tokens))
 
 
 def test_fused_spec_step_failure_contained(target, draft):

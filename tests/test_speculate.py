@@ -488,7 +488,7 @@ def test_serve_spec_bench_gap_gate(tmp_path):
         {"metric": "serve_spec_tokens_per_sec", "speculate_k": 2,
          "value": 900.0, "device_kind": "cpu"},           # smoke: no
         {"metric": "serve_spec_tokens_per_sec", "speculate_k": 4,
-         "error": "relay wedged"},                        # error: no
+         "error": "device unavailable"},                        # error: no
         {"metric": "serve_spec_tokens_per_sec", "speculate_k": 8,
          "value": 9000.0, "device_kind": "TPU v5 lite"},  # real: yes
     ]

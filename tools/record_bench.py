@@ -1,10 +1,9 @@
 """Format bench_results/ artifacts into BASELINE.md-ready markdown.
 
-The TPU watcher (tools/tpu_when_ready.sh) drops raw JSON into
+Chip runs drop raw JSON into
 bench_results/{bench.json, matrix.jsonl, flash.jsonl}; this prints the
 "Measured values (round N)" markdown table rows for BASELINE.md so
-recording results is one command even if the TPU window opens at the last
-minute:
+recording results is one command:
 
     python tools/record_bench.py [--dir bench_results]
 """

@@ -17,33 +17,6 @@ no process groups, no Gloo, no torch.distributed.
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # Compat shim: jax.shard_map graduated from jax.experimental in newer
-    # releases; on older jax the experimental entry point is the same
-    # transform with `check_rep` where the graduated API says `check_vma`.
-    # Installed once at package import so every tpudp module (and the
-    # benches) can use the modern spelling unconditionally.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs,
-                          check_vma: bool = True, **kwargs):
-        kwargs.setdefault("check_rep", check_vma)
-        return _experimental_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, **kwargs)
-
-    _jax.shard_map = _compat_shard_map
-
-if not hasattr(_jax.lax, "axis_size"):
-    # Same vintage gap: lax.axis_size (static size of a named mesh axis)
-    # graduated later; on this jax the equivalent is core.axis_frame,
-    # which returns the bound axis size directly.  The ring/pipeline/
-    # compress rungs use the result in static shape math (`range(n)`,
-    # padding arithmetic), so the shim must return a Python int — and it
-    # does (verified under shard_map).
-    _jax.lax.axis_size = _jax.core.axis_frame
-
 from tpudp.mesh import make_mesh, make_mesh_nd, initialize_distributed  # noqa: F401
 from tpudp.train import Trainer, TrainState, make_train_step, make_eval_step  # noqa: F401
 from tpudp.parallel.sync import SYNC_STRATEGIES  # noqa: F401

@@ -51,6 +51,18 @@ CALLBACK_PRIM_PARTS = ("callback",)
 TRANSFER_PRIM_NAMES = {"device_put", "copy"}
 
 _ADDR_RE = re.compile(r"0x[0-9a-fA-F]+")
+# jax 0.9.0 prints set-valued eqn params (shard_map's ``manual_axes=
+# frozenset({'pipe', 'data'})``) in hash order, which PYTHONHASHSEED
+# reshuffles per process — sort the elements so the fingerprint is a
+# function of the program alone.
+_SET_RE = re.compile(r"frozenset\(\{([^{}]*)\}\)")
+
+
+def _stable_text(closed_jaxpr) -> str:
+    text = _ADDR_RE.sub("0xX", str(closed_jaxpr))
+    return _SET_RE.sub(
+        lambda m: "frozenset({%s})" % ", ".join(
+            sorted(e.strip() for e in m.group(1).split(","))), text)
 
 
 def repo_root() -> str:
@@ -137,7 +149,7 @@ def force_smoke_backend():
 
 
 def _census(jaxpr, acc) -> None:
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
 
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in inner.eqns:
@@ -166,7 +178,7 @@ def fingerprint(fn, args, donate_argnums=()) -> dict:
     from . import budget as _budget
 
     closed = jax.make_jaxpr(fn)(*args)
-    text = _ADDR_RE.sub("0xX", str(closed))
+    text = _stable_text(closed)
     acc = {"eqns": 0, "collectives": [], "callbacks": 0, "transfers": 0}
     _census(closed, acc)
     return {

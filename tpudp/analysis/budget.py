@@ -12,7 +12,7 @@ rungs (paged attention, TP serving) must not silently regress:
     their definition and die after their last use; **donated** program
     inputs (the arena, the train state — the ``donate_argnums`` tables
     the use-after-donation rule mirrors) die at their last use too,
-    while non-donated inputs and the frozen-weight constants stay
+    while non-donated inputs (the weights among them) stay
     resident for the whole program, exactly as XLA's aliasing rules
     allow.  Equations carrying sub-jaxprs (scan/cond/pjit) contribute
     their own inner peak at their program point.
@@ -81,7 +81,7 @@ def _aval_bytes(v) -> int:
 
 
 def _sub_jaxprs(eqn):
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
 
     for v in eqn.params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
@@ -113,7 +113,7 @@ def _unwrap(jaxpr, donated):
 
 def _peak_live(jaxpr, donated=frozenset()) -> int:
     """Donation-aware liveness sweep over one (open) jaxpr level."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     eqns = list(jaxpr.eqns)
     n = len(eqns)
@@ -173,7 +173,7 @@ def ledger(closed_jaxpr, donated=frozenset()) -> dict:
     """The budget record for one traced program.  ``donated`` holds the
     FLAT invar indices (pytree arguments flattened, the same order
     ``jax.make_jaxpr`` binds them) that the runtime donates."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     inner, donated = _unwrap(closed_jaxpr, frozenset(donated))
     arg_bytes = sum(_aval_bytes(v) for v in inner.invars)

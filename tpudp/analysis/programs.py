@@ -79,46 +79,48 @@ TRACE_COUNTER_PROGRAMS = {
 }
 
 #: Donated ARGUMENT positions per program (name before the ``@``),
-#: mirroring the runtime ``donate_argnums`` at each build site (the
-#: same facts the use-after-donation rule tables in rules.py).  The
-#: budget pass (tpudp/analysis/budget.py) uses these for its
-#: donation-aware peak-live-bytes sweep: a donated buffer's storage is
-#: reusable after its last read, a non-donated one is resident for the
-#: whole call.
+#: mirroring the runtime ``donate_argnums`` at each build site.  The
+#: serve programs take the weights first (the fused speculative ones
+#: the draft weights second), never donated, so their positions sit
+#: one (two) past the bound call-site positions the use-after-donation
+#: rule tables in rules.py record.  The budget pass
+#: (tpudp/analysis/budget.py) uses these for its donation-aware
+#: peak-live-bytes sweep: a donated buffer's storage is reusable after
+#: its last read, a non-donated one is resident for the whole call.
 PROGRAM_DONATIONS = {
-    "serve.decode_step": (0, 8),
-    "serve.verify_step": (0, 9),
-    "serve.prefill_chunk": (0,),
-    "serve.fused_decode": (0, 11),
-    "serve.fused_decode_stream": (0, 11),
+    "serve.decode_step": (1, 9),
+    "serve.verify_step": (1, 10),
+    "serve.prefill_chunk": (1,),
+    "serve.fused_decode": (1, 12),
+    "serve.fused_decode_stream": (1, 12),
     # Paged twins (Engine(kv_pages=N)): the shared page POOL donates in
     # place of the dense arena; the block table is host-authoritative
     # and never donated.  The kernel twins (Engine(paged_attn='kernel')
     # — the TPU default) share their einsum twins' signatures and
     # donation facts program-for-program.
-    "serve.decode_paged": (0, 9),
-    "serve.decode_paged_kernel": (0, 9),
-    "serve.verify_paged": (0, 10),
-    "serve.verify_paged_kernel": (0, 10),
-    "serve.prefill_paged": (0,),
-    "serve.prefill_paged_kernel": (0,),
-    "serve.fused_decode_paged": (0, 12),
-    "serve.fused_decode_paged_stream": (0, 12),
-    "serve.fused_decode_paged_kernel": (0, 12),
+    "serve.decode_paged": (1, 10),
+    "serve.decode_paged_kernel": (1, 10),
+    "serve.verify_paged": (1, 11),
+    "serve.verify_paged_kernel": (1, 11),
+    "serve.prefill_paged": (1,),
+    "serve.prefill_paged_kernel": (1,),
+    "serve.fused_decode_paged": (1, 13),
+    "serve.fused_decode_paged_stream": (1, 13),
+    "serve.fused_decode_paged_kernel": (1, 13),
     # On-device speculation (Engine(speculate_k=k, decode_fuse=N,
     # drafter=DraftModelDrafter(...))): the fused draft→verify→accept
     # while_loop donates the target arena/pool and the counters — the
     # draft model's KV arena is carry-local scratch, never an argument.
     # The tree-verify window donates like verify_step (its paged twin's
     # accepted-path commit is what makes rejected branches zero-write).
-    "serve.fused_spec_decode": (0, 12),
-    "serve.fused_spec_decode_stream": (0, 12),
-    "serve.fused_spec_paged": (0, 13),
-    "serve.fused_spec_paged_stream": (0, 13),
-    "serve.fused_spec_paged_kernel": (0, 13),
-    "serve.tree_verify": (0, 9),
-    "serve.tree_verify_paged": (0, 10),
-    "serve.tree_verify_paged_kernel": (0, 10),
+    "serve.fused_spec_decode": (2, 14),
+    "serve.fused_spec_decode_stream": (2, 14),
+    "serve.fused_spec_paged": (2, 15),
+    "serve.fused_spec_paged_stream": (2, 15),
+    "serve.fused_spec_paged_kernel": (2, 15),
+    "serve.tree_verify": (1, 10),
+    "serve.tree_verify_paged": (1, 11),
+    "serve.tree_verify_paged_kernel": (1, 11),
     "serve.sample_row": (),
     "serve.draft_model": (),
     "prefix.copy_block_in": (0,),
@@ -156,7 +158,7 @@ SERVE = dict(vocab=64, seq=64, layers=2, heads=2, d_model=32,
 # Draft-model smoke geometry for the fused speculative programs: a
 # 1-layer model whose max_seq_len covers max_len + k (the Engine
 # eligibility bound `dcfg.max_seq_len >= max_len + speculate_k`), its
-# weights frozen into the fused program next to the target's.
+# weights the fused program's second argument, next to the target's.
 DRAFT = dict(vocab=64, seq=64, layers=1, heads=2, d_model=16)
 # Tree-verify smoke shape: fork2x2 (last token at node 0 → two branches
 # of depth 2) — the smallest registered shape whose attention mask
@@ -245,7 +247,7 @@ def build_programs() -> dict:
 
     programs: dict[str, tuple] = {}
 
-    # -- serve step programs (frozen-weight jits, engine.py) -----------
+    # -- serve step programs (weights-first jits, engine.py) -----------
     from tpudp.serve import engine as _engine
 
     cfg, params, cache, h = _serve_args()
@@ -253,24 +255,24 @@ def build_programs() -> dict:
     (decode, verify, prefill, fused, fused_spec, tree_verify,
      decode_paged, verify_paged, prefill_paged, fused_paged,
      fused_spec_paged, tree_paged) = _engine._build_steps(
-        cfg, params, draft=(dcfg, dparams))
+        cfg, "einsum", dcfg)
     geo = f"s{SERVE['slots']}m{SERVE['max_len']}"
     programs[f"serve.decode_step@{geo}"] = (
-        decode, (cache, h["last"], h["lens"], h["active"], h["temps"],
+        decode, (params, cache, h["last"], h["lens"], h["active"], h["temps"],
                  h["topk"], h["topp"], h["keys"], h["counts"]))
     programs[f"serve.verify_step@{geo}k{SERVE['k']}"] = (
-        verify, (cache, h["window"], h["lens"], h["active"], h["ndraft"],
+        verify, (params, cache, h["window"], h["lens"], h["active"], h["ndraft"],
                  h["temps"], h["topk"], h["topp"], h["keys"],
                  h["counts"]))
     programs[f"serve.prefill_chunk@{geo}c{SERVE['chunk']}"] = (
-        prefill, (cache, np.int32(0), h["chunk"], np.int32(0),
+        prefill, (params, cache, np.int32(0), h["chunk"], np.int32(0),
                   np.int32(SERVE["chunk"] - 1)))
     # Fused decode window, both variants: the stream twin pins the
     # ordered io_callback in its host-callback census, so ANY change to
     # the callback count inside the loop (a new host round trip — the
     # exact regression this program exists to prevent) fails the audit
     # naming the program.
-    fused_args = (cache, h["last"], h["lens"], h["active"], h["temps"],
+    fused_args = (params, cache, h["last"], h["lens"], h["active"], h["temps"],
                   h["topk"], h["topp"], h["keys"], h["budgets"], h["eos"],
                   np.int32(-1), h["counts"])
     import functools
@@ -282,13 +284,13 @@ def build_programs() -> dict:
         functools.partial(fused, n_steps=SERVE["fuse"], stream=True),
         fused_args)
     # On-device speculation (ISSUE 16): the fused draft→verify→accept
-    # while_loop — both drafters' weights frozen in, the slot histories
+    # while_loop — both models' weights riding in, the slot histories
     # in, k+1-wide verify windows and per-slot PRNG chains advanced
     # in-carry.  Pinned in BOTH stream variants like the plain fused
     # window: a new host callback inside the speculative loop (the
     # regression class this whole program deletes) fails the audit by
     # name.
-    spec_args = (cache, h["hist"], h["last"], h["lens"], h["active"],
+    spec_args = (params, dparams, cache, h["hist"], h["last"], h["lens"], h["active"],
                  h["temps"], h["topk"], h["topp"], h["keys"],
                  h["budgets"], h["eos"], np.int32(-1), h["counts"])
     sgeo = f"{geo}k{SERVE['k']}n{SERVE['fuse']}"
@@ -303,7 +305,7 @@ def build_programs() -> dict:
     # commit.  The parents tuple is static (part of the compile key and
     # the lock identity, like n_steps on the fused window).
     tgeo = f"{geo}t{len(TREE_PARENTS)}"
-    tree_args = (cache, h["tree"], h["lens"], h["active"], h["ndraft"],
+    tree_args = (params, cache, h["tree"], h["lens"], h["active"], h["ndraft"],
                  h["temps"], h["topk"], h["topp"], h["keys"], h["counts"])
     programs[f"serve.tree_verify@{tgeo}"] = (
         functools.partial(tree_verify, parents=TREE_PARENTS), tree_args)
@@ -323,22 +325,22 @@ def build_programs() -> dict:
                      np.int32)
     pgeo2 = f"{geo}p{n_pages}"
     programs[f"serve.decode_paged@{pgeo2}"] = (
-        decode_paged, (pool, table, h["last"], h["lens"], h["active"],
+        decode_paged, (params, pool, table, h["last"], h["lens"], h["active"],
                        h["temps"], h["topk"], h["topp"], h["keys"],
                        h["counts"]))
     programs[f"serve.verify_paged@{pgeo2}k{SERVE['k']}"] = (
-        verify_paged, (pool, table, h["window"], h["lens"], h["active"],
+        verify_paged, (params, pool, table, h["window"], h["lens"], h["active"],
                        h["ndraft"], h["temps"], h["topk"], h["topp"],
                        h["keys"], h["counts"]))
     programs[f"serve.prefill_paged@{pgeo2}c{SERVE['chunk']}"] = (
-        prefill_paged, (pool, table[0], h["chunk"], np.int32(0),
+        prefill_paged, (params, pool, table[0], h["chunk"], np.int32(0),
                         np.int32(SERVE["chunk"] - 1)))
     # Both stream variants, like the dense fused window: the stream
     # twin pins the ordered io_callback in its census, so a host
     # round-trip change inside the PAGED loop fails the audit by name
     # too (kv_pages + fuse_stream is a legal engine configuration).
     fused_paged_args = (
-        pool, table, h["last"], h["lens"], h["active"], h["temps"],
+        params, pool, table, h["last"], h["lens"], h["active"], h["temps"],
         h["topk"], h["topp"], h["keys"], h["budgets"], h["eos"],
         np.int32(-1), h["counts"])
     programs[f"serve.fused_decode_paged@{pgeo2}n{SERVE['fuse']}"] = (
@@ -353,7 +355,7 @@ def build_programs() -> dict:
     # byte-diff test pins, so its trace (and any new transfer in it) is
     # locked here.
     spec_paged_args = (
-        pool, table, h["hist"], h["last"], h["lens"], h["active"],
+        params, dparams, pool, table, h["hist"], h["last"], h["lens"], h["active"],
         h["temps"], h["topk"], h["topp"], h["keys"], h["budgets"],
         h["eos"], np.int32(-1), h["counts"])
     programs[f"serve.fused_spec_paged@{pgeo2}k{SERVE['k']}n{SERVE['fuse']}"] = (
@@ -366,7 +368,7 @@ def build_programs() -> dict:
         spec_paged_args)
     programs[f"serve.tree_verify_paged@{pgeo2}t{len(TREE_PARENTS)}"] = (
         functools.partial(tree_paged, parents=TREE_PARENTS),
-        (pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
+        (params, pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
          h["temps"], h["topk"], h["topp"], h["keys"], h["counts"]))
     # The Pallas kernel twins (Engine(paged_attn='kernel') — the TPU
     # default): same signatures/donations as their einsum twins
@@ -380,20 +382,18 @@ def build_programs() -> dict:
     # the kernels trace in interpret mode — host-independent like the
     # rest of the lock.
     (_, verify_k, prefill_k, fused_k, fused_spec_k,
-     tree_k) = _engine._build_steps(cfg, params, paged_attn="kernel",
-                                    draft=(dcfg, dparams))[6:]
-    decode_paged_kernel = _engine._build_steps(cfg, params,
-                                               paged_attn="kernel")[6]
+     tree_k) = _engine._build_steps(cfg, "kernel", dcfg)[6:]
+    decode_paged_kernel = _engine._build_steps(cfg, "kernel")[6]
     programs[f"serve.decode_paged_kernel@{pgeo2}"] = (
         decode_paged_kernel,
-        (pool, table, h["last"], h["lens"], h["active"], h["temps"],
+        (params, pool, table, h["last"], h["lens"], h["active"], h["temps"],
          h["topk"], h["topp"], h["keys"], h["counts"]))
     programs[f"serve.verify_paged_kernel@{pgeo2}k{SERVE['k']}"] = (
-        verify_k, (pool, table, h["window"], h["lens"], h["active"],
+        verify_k, (params, pool, table, h["window"], h["lens"], h["active"],
                    h["ndraft"], h["temps"], h["topk"], h["topp"],
                    h["keys"], h["counts"]))
     programs[f"serve.prefill_paged_kernel@{pgeo2}c{SERVE['chunk']}"] = (
-        prefill_k, (pool, table[0], h["chunk"], np.int32(0),
+        prefill_k, (params, pool, table[0], h["chunk"], np.int32(0),
                     np.int32(SERVE["chunk"] - 1)))
     programs[f"serve.fused_decode_paged_kernel@{pgeo2}n{SERVE['fuse']}"] = (
         functools.partial(fused_k, n_steps=SERVE["fuse"], stream=False),
@@ -405,7 +405,7 @@ def build_programs() -> dict:
          spec_paged_args)
     programs[f"serve.tree_verify_paged_kernel@{pgeo2}t{len(TREE_PARENTS)}"] = (
         functools.partial(tree_k, parents=TREE_PARENTS),
-        (pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
+        (params, pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
          h["temps"], h["topk"], h["topp"], h["keys"], h["counts"]))
 
     programs["serve.sample_row@v%d" % SERVE["vocab"]] = (

@@ -499,34 +499,32 @@ class UseAfterDonation(Rule):
                 local[fn.name] = donated
         return local
 
+    @staticmethod
+    def _donated(ref, local):
+        """(name, donated indices) of a callable reference.  A bare name
+        may be one of this module's own jit defs (``local``); an
+        attribute (``ms.decode_step``) never is — it may be the same def
+        bound to leading arguments, so only the call-site table counts."""
+        if isinstance(ref, ast.Attribute):
+            return ref.attr, DONATING.get(ref.attr)
+        if isinstance(ref, ast.Name):
+            return ref.id, local.get(ref.id, DONATING.get(ref.id))
+        return None, None
+
     def _call_donations(self, mod, node, local):
         """Yield (donated_arg_expr, label) for a donating call."""
-        func = node.func
-        name = None
-        if isinstance(func, ast.Attribute):
-            name = func.attr
-        elif isinstance(func, ast.Name):
-            name = func.id
+        name, donated = self._donated(node.func, local)
         if name in DEVICE_WRAPPERS and len(node.args) >= 2:
             fn_pos, arg_start = DEVICE_WRAPPERS[name]
-            inner = node.args[fn_pos]
-            iname = None
-            if isinstance(inner, ast.Attribute):
-                iname = inner.attr
-            elif isinstance(inner, ast.Name):
-                iname = inner.id
-            donated = local.get(iname, DONATING.get(iname))
-            if donated:
-                for idx in donated:
-                    pos = arg_start + idx
-                    if pos < len(node.args):
-                        yield node.args[pos], iname
+            iname, donated = self._donated(node.args[fn_pos], local)
+            for idx in donated or ():
+                pos = arg_start + idx
+                if pos < len(node.args):
+                    yield node.args[pos], iname
             return
-        donated = local.get(name, DONATING.get(name)) if name else None
-        if donated:
-            for idx in donated:
-                if idx < len(node.args):
-                    yield node.args[idx], name
+        for idx in donated or ():
+            if idx < len(node.args):
+                yield node.args[idx], name
 
     def check(self, mod: Module):
         local = self._donating_targets(mod)

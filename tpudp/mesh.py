@@ -51,17 +51,11 @@ def _enable_cpu_cross_process_collectives() -> None:
     machine auto-selects the cpu backend, and skipping it there would
     leave the silent half-mesh psum in place.  The option only
     configures the CPU backend, so setting it under a TPU auto-select
-    is inert.  Best-effort (older jax has no such option)."""
-    import os
-
-    platforms = str(getattr(jax.config, "jax_platforms", None)
-                    or os.environ.get("JAX_PLATFORMS", "") or "")
+    is inert."""
+    platforms = jax.config.jax_platforms or ""  # JAX_PLATFORMS lands here
     if platforms and "cpu" not in platforms:
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # option or backend absent
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def make_mesh(num_devices: int | None = None, axis_name: str = DATA_AXIS) -> Mesh:

@@ -21,7 +21,8 @@ einsum chain:
   * Causal masking skips the compute of fully-masked blocks via
     ``pl.when`` (their tiles still stream, the MXU work is elided), and
     masks the diagonal tile elementwise.
-  * Runs in interpret mode off-TPU, so the same code is unit-testable on the
+  * Runs in interpret mode on the CPU platform (and only there unless
+    ``interpret=True`` is passed), so the same code is unit-testable on the
     CPU simulator mesh (tests/test_flash_attention.py checks fwd and grads
     against a dense oracle).
 
@@ -47,7 +48,10 @@ _LANES = 128  # scalar-per-row scratch is stored broadcast over one lane tile
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode is the CPU platform's stand-in for Mosaic, and only
+    that: every other backend compiles the kernel (or fails loudly) unless
+    the caller passes ``interpret=True`` itself."""
+    return jax.default_backend() == "cpu"
 
 
 def _read_rows(ref) -> jnp.ndarray:
@@ -325,8 +329,9 @@ def flash_attention(
     """Blocked flash attention. ``q, k, v``: ``(batch, time, heads, head_dim)``.
 
     ``time`` must be divisible by the block sizes (blocks are clamped to
-    ``time`` when shorter). Differentiable (custom VJP); off-TPU the kernels
-    run in Pallas interpret mode so tests work on the CPU simulator.
+    ``time`` when shorter). Differentiable (custom VJP); ``interpret=None``
+    means Pallas interpret mode on the CPU platform (so tests work on the
+    CPU simulator) and a Mosaic-compiled kernel on every other backend.
 
     Compiled (TPU) mode requires lane-aligned blocks: ``block_q``/``block_k``
     must be multiples of 128 (Mosaic tiling: the log-sum-exp blocks put

@@ -34,8 +34,9 @@ Two backends behind one op:
     via ``pl.when``, and int8 pages dequantize in-kernel.  Tolerance-
     bounded like flash (online softmax rounds differently from the XLA
     chain), so the engine treats it as an explicit opt-in
-    (``Engine(paged_attn='kernel')``).  Runs in interpret mode off-TPU
-    so the same code is unit-testable on the CPU host.
+    (``Engine(paged_attn='kernel')``).  Runs in interpret mode on the
+    CPU platform (and only there unless ``interpret=True`` is passed) so
+    the same code is unit-testable on the CPU host.
 
 The op covers both attention families the decode twins use: the GPT-2
 MHA einsum forms and LLaMA's grouped (GQA) forms — selected by
@@ -61,7 +62,10 @@ _STAT_LANES = 8
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode is the CPU platform's stand-in for Mosaic, and only
+    that: every other backend compiles the kernel (or fails loudly) unless
+    the caller passes ``interpret=True`` itself."""
+    return jax.default_backend() == "cpu"
 
 
 def page_tiles(pages, table, dtype):

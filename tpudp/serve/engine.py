@@ -18,12 +18,14 @@ Design (static shapes everywhere — the TPU rule that shapes are compile
     never change, so the jitted decode step compiles exactly once per
     ``(config, num_slots, max_len)`` and admission/retirement churn never
     recompiles (``TRACE_COUNTS`` observes this; a test pins it).
-  * **Frozen weights** — the step programs close over the params as
-    compile-time constants (``_build_steps``): weights are immutable for
-    an engine's lifetime, and freezing them lets XLA pre-pack the weight
-    matrices once at compile instead of per call (the measured win on
-    the CPU host is ~1.3x per decode step and ~2.3x per verify window).
-    Engines sharing one params tree share one set of programs.
+  * **Weights are arguments** — every step program takes the params as
+    its first (traced, never donated) argument (``_build_steps``), so
+    the device holds ONE copy of the weights however many programs run
+    over them, and programs are built once per model CONFIG: engines
+    over the same config share one set of compiled programs whatever
+    weights they serve.  (Until PR 21 the programs closed over the
+    weights as compile-time constants; on the TPU v5e each program then
+    carried its own copy — see ``_build_steps``.)
   * **Slot-masked decode step** — all ``num_slots`` rows run every step
     with PER-ROW positions (``models.generate._forward_cached``'s vector
     -``pos`` path).  Inactive rows compute garbage that is never read:
@@ -191,8 +193,8 @@ included):
     keep their shapes, so no preemption storm can ever recompile.
   * **Co-resident models** — ``Engine(models={name: (model, params)})``
     registers additional model/params pairs behind the same scheduler:
-    each gets its own slot arena and frozen-weight step programs (the
-    per-(cfg, params) LRU already shares compiled programs), a
+    each gets its own slot arena and its config's step programs bound
+    to its weights (programs are memoized per config), a
     ``TenantClass(model=name)`` routes its class there, and one host
     loop batches each model's decoding slots through that model's own
     step — per-request math is exactly the single-model engine's, so
@@ -240,7 +242,6 @@ from tpudp.models.generate import (Int8Pages, KVCache, _forward_cached,
 from tpudp.obs import FlightRecorder, Recorder
 from tpudp.ops.sampling import (sample_tokens, split_keys, tree_depths,
                                 verify_tokens, verify_tree_tokens)
-from tpudp.utils.compile_cache import ProgramCache
 
 # Trace-time side-effect counters: each jitted step body bumps its entry
 # when (and only when) XLA traces it, so tests can assert the decode step
@@ -671,18 +672,23 @@ def _tree_verify_math(forward, commit, state, tokens, lengths, active,
     return state, out, n_emit, new_keys, new_counts
 
 
-def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
-    """Jitted step programs with the WEIGHTS CLOSED OVER as compile-time
-    constants rather than traced arguments.
+@functools.lru_cache(maxsize=16)
+def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
+    """Jitted step programs for one model CONFIG.  Every program takes
+    the weights as its first (traced, never donated) argument — the
+    fused speculative programs take the draft model's weights second —
+    so the device holds ONE copy of each weight tree however many
+    programs run over it, and builds are memoized per ``(cfg,
+    paged_attn, draft_cfg)``: engines over the same config share one
+    set of compiled programs whatever weights they serve.
 
-    ``draft`` — a ``(draft_cfg, draft_params)`` pair — additionally
-    builds the fused SPECULATIVE programs (``fused_spec_step`` and its
-    paged twin), which close over the draft model's weights the same
-    way: an ``Engine(speculate_k=k, decode_fuse=N,
+    ``draft_cfg`` — a draft model's config — additionally builds the
+    fused SPECULATIVE programs (``fused_spec_step`` and its paged
+    twin): an ``Engine(speculate_k=k, decode_fuse=N,
     drafter=DraftModelDrafter(...))`` runs draft→verify→accept as one
     ``lax.while_loop`` program (``_fused_spec_math``).  ``None`` (every
     other engine) builds no such program — the returned tuple carries
-    ``None`` in those positions and the step cache key never grows.
+    ``None`` in those positions.
 
     ``paged_attn`` selects the PAGED programs' KV indirection (the
     dense programs never change): ``'einsum'`` is the GATHER-FREE
@@ -701,30 +707,28 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
     Every kernel program is tolerance-bounded like flash, hence its
     own TRACE_COUNTS key, pinned trace, and budget-ledger row.
 
-    An engine's params are immutable for its lifetime, and freezing them
-    lets XLA pre-pack the weight matrices for the step gemms at compile
-    time; with weights as arguments, XLA:CPU re-packs them on every call
-    whose lhs has more than one row — measured ~1.3x on the batched
-    decode step and ~2.3x on the k+1-wide verify window on the 2-core
-    host, the difference between speculation paying off and losing.
-    The memory cost is one extra copy of the weights bound into the
-    programs (the standard serving trade).
+    Why arguments and not closed-over constants (PRs 2-20 froze the
+    weights into the programs, a win measured on XLA:CPU only): on the
+    TPU v5e at GPT-2-small every program embedded its own ~340 MB copy
+    of the bf16 weights — ``bytes_in_use`` grew by that much per
+    compiled program, backend compile took 29-41 s per program and each
+    persistent-cache entry was ~765 MB (chip run, PR 21, CHANGES.md).
 
-    Shapes stay traced, so one build serves every engine geometry over
-    these weights, compiling once per (num_slots, max_len[, k]) exactly
-    as before; :func:`_engine_steps` memoizes builds per (cfg, params
-    identity) so engines sharing a weight tree share compiled programs.
+    Shapes stay traced, so one build serves every engine geometry,
+    compiling once per (num_slots, max_len[, k]) exactly as before.
     """
 
-    def _dense_fwd(cache, tokens, lengths, active):
+    def _dense_fwd(params):
         """The dense indirection for the shared step bodies: plain
         arena-row reads/writes (masked rows land in their own rows —
         the overwrite-before-visible rule needs no ``active``)."""
-        del active
-        return _forward_cached(cfg, params, tokens, cache, lengths)
+        def fwd(cache, tokens, lengths, active):
+            del active
+            return _forward_cached(cfg, params, tokens, cache, lengths)
+        return fwd
 
-    @functools.partial(jax.jit, donate_argnums=(0, 8))
-    def decode_step(cache, last_tokens, lengths, active, temps,
+    @functools.partial(jax.jit, donate_argnums=(1, 9))
+    def decode_step(params, cache, last_tokens, lengths, active, temps,
                     top_k, top_p, keys, counts):
         """One token for every slot: feed each row's last token at its
         own depth, sample per-row (``_decode_math`` — the body shared
@@ -735,11 +739,11 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
         OBS_DEVICE_COUNTERS accumulator (donated too — a handful of
         float adds riding the step, fetched only by metrics())."""
         TRACE_COUNTS["decode_step"] += 1
-        return _decode_math(_dense_fwd, cache, last_tokens, lengths,
+        return _decode_math(_dense_fwd(params), cache, last_tokens, lengths,
                             active, temps, top_k, top_p, keys, counts)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 9))
-    def verify_step(cache, tokens, lengths, active, n_draft, temps,
+    @functools.partial(jax.jit, donate_argnums=(1, 10))
+    def verify_step(params, cache, tokens, lengths, active, n_draft, temps,
                     top_k, top_p, keys, counts):
         """One speculative window for every slot: feed each row's
         ``[last, d_0 .. d_{k-1}]`` window at its own depth, accept the
@@ -753,12 +757,12 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
         decode (the window's tail writes are overwritten before they
         become visible, like every other masked write in the arena)."""
         TRACE_COUNTS["verify_step"] += 1
-        return _verify_math(_dense_fwd, cache, tokens, lengths, active,
+        return _verify_math(_dense_fwd(params), cache, tokens, lengths, active,
                             n_draft, temps, top_k, top_p, keys, counts)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 11),
+    @functools.partial(jax.jit, donate_argnums=(1, 12),
                        static_argnames=("n_steps", "stream"))
-    def fused_decode_step(cache, last_tokens, lengths, active, temps,
+    def fused_decode_step(params, cache, last_tokens, lengths, active, temps,
                           top_k, top_p, keys, budgets, eos_ids, ring_id,
                           counts, *, n_steps, stream=False):
         """Up to ``n_steps`` decode iterations in ONE device program: a
@@ -788,12 +792,12 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
         twin."""
         TRACE_COUNTS["fused_decode"] += 1
         return _fused_decode_math(
-            _dense_fwd, cache, last_tokens, lengths, active, temps,
+            _dense_fwd(params), cache, last_tokens, lengths, active, temps,
             top_k, top_p, keys, budgets, eos_ids, ring_id, counts,
             n_steps=n_steps, stream=stream)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def prefill_step(cache, slot, tokens, pos, last):
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_step(params, cache, slot, tokens, pos, last):
         """One fixed-size prompt chunk for one slot: slice the slot's
         arena row, run the scalar-pos cached forward (batch 1), write
         the row back.  ``slot``/``pos``/``last`` are traced scalars —
@@ -812,23 +816,21 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             lax.dynamic_update_slice_in_dim(cache.k, row.k, slot, axis=1),
             lax.dynamic_update_slice_in_dim(cache.v, row.v, slot, axis=1))
 
-    if draft is None:
+    if draft_cfg is None:
         fused_spec_step = None
     else:
-        draft_cfg, draft_params = draft
-
-        @functools.partial(jax.jit, donate_argnums=(0, 12),
+        @functools.partial(jax.jit, donate_argnums=(2, 14),
                            static_argnames=("n_draft_k", "n_steps",
                                             "stream"))
-        def fused_spec_step(cache, hist, last_tokens, lengths, active,
-                            temps, top_k, top_p, keys, budgets, eos_ids,
-                            ring_id, counts, *, n_draft_k, n_steps,
-                            stream=False):
+        def fused_spec_step(params, draft_params, cache, hist,
+                            last_tokens, lengths, active, temps, top_k,
+                            top_p, keys, budgets, eos_ids, ring_id, counts,
+                            *, n_draft_k, n_steps, stream=False):
             """Up to ``n_steps`` SPECULATIVE windows in ONE device
             program: each ``lax.while_loop`` iteration drafts
             ``n_draft_k`` greedy tokens per running slot with the
-            draft model (whose weights are frozen into this program
-            exactly like the target's), scores the k+1 window with the
+            draft model (its weights the program's second argument),
+            scores the k+1 window with the
             verify forward, and commits the accepted prefix + bonus
             token in-carry — ``_fused_spec_math``, the one copy shared
             with the paged twin.  ``hist`` ``(num_slots, max_len)``
@@ -841,16 +843,18 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             verify fetch are gone."""
             TRACE_COUNTS["fused_spec_decode"] += 1
             return _fused_spec_math(
-                _dense_fwd, draft_cfg, draft_params, cache, hist,
+                _dense_fwd(params), draft_cfg, draft_params, cache, hist,
                 last_tokens, lengths, active, temps, top_k, top_p, keys,
                 budgets, eos_ids, ring_id, counts, n_draft_k=n_draft_k,
                 n_steps=n_steps, stream=stream)
 
-    def _tree_dense_fwd(cache, tokens, lengths, depths, anc):
+    def _tree_dense_fwd(params):
         """Dense tree-verify indirection: the no-write tree forward
         reads the arena directly and hands back the window K/V."""
-        return _forward_tree(cfg, params, tokens, cache, lengths,
-                             depths, anc)
+        def fwd(cache, tokens, lengths, depths, anc):
+            return _forward_tree(cfg, params, tokens, cache, lengths,
+                                 depths, anc)
+        return fwd
 
     def _tree_dense_commit(cache, wk, wv, lengths, path, n_emit, active):
         """Dense accepted-path commit: path node ``d``'s K/V lands at
@@ -870,9 +874,9 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
                 v_all, vsel, lengths + d)
         return KVCache(k_all, v_all)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 9),
+    @functools.partial(jax.jit, donate_argnums=(1, 10),
                        static_argnames=("parents",))
-    def tree_verify_step(cache, tokens, lengths, active, n_cand, temps,
+    def tree_verify_step(params, cache, tokens, lengths, active, n_cand, temps,
                          top_k, top_p, keys, counts, *, parents):
         """One speculative TREE window for every slot
         (``Engine(speculate_tree=shape)``): ``tokens`` ``(num_slots,
@@ -888,7 +892,8 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
         ``verify_step`` so the host replay seam is shared."""
         TRACE_COUNTS["tree_verify"] += 1
         return _tree_verify_math(
-            _tree_dense_fwd, _tree_dense_commit, cache, tokens, lengths,
+            _tree_dense_fwd(params), _tree_dense_commit, cache, tokens,
+            lengths,
             active, n_cand, temps, top_k, top_p, keys, counts,
             parents=parents)
 
@@ -906,7 +911,7 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
     win_impl = "gather" if paged_attn == "gather" else (
         "kernel" if kernel_build else "einsum")
 
-    def _paged_fwd(table, impl):
+    def _paged_fwd(params, table, impl):
         """The paged indirection for the shared step bodies —
         ``generate._forward_paged`` with the build's impl baked in
         (``active`` masks the write path to the scratch page for idle
@@ -917,9 +922,9 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
         return fwd
 
     if paged_attn == "kernel":
-        @functools.partial(jax.jit, donate_argnums=(0, 9))
-        def decode_step_paged(pool, table, last_tokens, lengths, active,
-                              temps, top_k, top_p, keys, counts):
+        @functools.partial(jax.jit, donate_argnums=(1, 10))
+        def decode_step_paged(params, pool, table, last_tokens, lengths,
+                              active, temps, top_k, top_p, keys, counts):
             """Paged decode through the PALLAS paged-decode kernel
             (``Engine(paged_attn='kernel')`` — the TPU default): same sampling/
             PRNG contract and shared ``_decode_math`` body as the
@@ -928,26 +933,26 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             prefetch — tolerance-bounded like flash, hence its own
             TRACE_COUNTS key and pinned trace."""
             TRACE_COUNTS["decode_paged_kernel"] += 1
-            return _decode_math(_paged_fwd(table, "kernel"), pool,
+            return _decode_math(_paged_fwd(params, table, "kernel"), pool,
                                 last_tokens, lengths, active, temps,
                                 top_k, top_p, keys, counts)
     else:
-        @functools.partial(jax.jit, donate_argnums=(0, 9))
-        def decode_step_paged(pool, table, last_tokens, lengths, active,
-                              temps, top_k, top_p, keys, counts):
+        @functools.partial(jax.jit, donate_argnums=(1, 10))
+        def decode_step_paged(params, pool, table, last_tokens, lengths,
+                              active, temps, top_k, top_p, keys, counts):
             """Paged decode: one token for every slot, KV read/written
             through ``table`` into ``pool``.  Same sampling/PRNG
             contract as ``decode_step`` — literally the same
             ``_decode_math`` body; compiles once per (num_slots,
             max_len, num_pages)."""
             TRACE_COUNTS["decode_paged"] += 1
-            return _decode_math(_paged_fwd(table, paged_attn), pool,
+            return _decode_math(_paged_fwd(params, table, paged_attn), pool,
                                 last_tokens, lengths, active, temps,
                                 top_k, top_p, keys, counts)
 
     if kernel_build:
-        @functools.partial(jax.jit, donate_argnums=(0, 10))
-        def verify_step_paged(pool, table, tokens, lengths, active,
+        @functools.partial(jax.jit, donate_argnums=(1, 11))
+        def verify_step_paged(params, pool, table, tokens, lengths, active,
                               n_draft, temps, top_k, top_p, keys, counts):
             """Paged speculative verify through the flash-window kernel:
             the k+1 window attends its own in-window prefix and the
@@ -957,12 +962,12 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             and commit contract as the einsum twin; tolerance-bounded,
             own TRACE_COUNTS key and pinned trace."""
             TRACE_COUNTS["verify_paged_kernel"] += 1
-            return _verify_math(_paged_fwd(table, "kernel"), pool,
+            return _verify_math(_paged_fwd(params, table, "kernel"), pool,
                                 tokens, lengths, active, n_draft, temps,
                                 top_k, top_p, keys, counts)
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def prefill_step_paged(pool, row_table, tokens, pos, last):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def prefill_step_paged(params, pool, row_table, tokens, pos, last):
             """Paged prompt chunk through the flash-prefill kernel
             (grid ``chunk_tiles × kv_pages``, causal in-chunk mask,
             online-softmax carry in VMEM): the chunk's KV commits as
@@ -977,9 +982,9 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
                 logits, last, axis=1, keepdims=False)  # (1, vocab)
             return last_logits, new_pool
 
-        @functools.partial(jax.jit, donate_argnums=(0, 12),
+        @functools.partial(jax.jit, donate_argnums=(1, 13),
                            static_argnames=("n_steps", "stream"))
-        def fused_decode_step_paged(pool, table, last_tokens, lengths,
+        def fused_decode_step_paged(params, pool, table, last_tokens, lengths,
                                     active, temps, top_k, top_p, keys,
                                     budgets, eos_ids, ring_id, counts, *,
                                     n_steps, stream=False):
@@ -991,24 +996,24 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             carry/predicate/PRNG/stream contract as the einsum twin."""
             TRACE_COUNTS["fused_decode_paged_kernel"] += 1
             return _fused_decode_math(
-                _paged_fwd(table, "kernel"), pool, last_tokens, lengths,
-                active, temps, top_k, top_p, keys, budgets, eos_ids,
+                _paged_fwd(params, table, "kernel"), pool, last_tokens,
+                lengths, active, temps, top_k, top_p, keys, budgets, eos_ids,
                 ring_id, counts, n_steps=n_steps, stream=stream)
     else:
-        @functools.partial(jax.jit, donate_argnums=(0, 10))
-        def verify_step_paged(pool, table, tokens, lengths, active,
+        @functools.partial(jax.jit, donate_argnums=(1, 11))
+        def verify_step_paged(params, pool, table, tokens, lengths, active,
                               n_draft, temps, top_k, top_p, keys, counts):
             """Paged speculative verify (the shared ``_verify_math``
             body): the k+1 window's writes may cross one page boundary
             — each window position commits into its own page-containing
             row (the host preallocates the table entries)."""
             TRACE_COUNTS["verify_paged"] += 1
-            return _verify_math(_paged_fwd(table, win_impl), pool,
+            return _verify_math(_paged_fwd(params, table, win_impl), pool,
                                 tokens, lengths, active, n_draft, temps,
                                 top_k, top_p, keys, counts)
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def prefill_step_paged(pool, row_table, tokens, pos, last):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def prefill_step_paged(params, pool, row_table, tokens, pos, last):
             """Paged prompt chunk for one slot: the same scalar-pos
             cached forward the dense prefill runs, read/written through
             the slot's table row.  Chunk starts are page-aligned (pages
@@ -1023,9 +1028,9 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
                 logits, last, axis=1, keepdims=False)  # (1, vocab)
             return last_logits, new_pool
 
-        @functools.partial(jax.jit, donate_argnums=(0, 12),
+        @functools.partial(jax.jit, donate_argnums=(1, 13),
                            static_argnames=("n_steps", "stream"))
-        def fused_decode_step_paged(pool, table, last_tokens, lengths,
+        def fused_decode_step_paged(params, pool, table, last_tokens, lengths,
                                     active, temps, top_k, top_p, keys,
                                     budgets, eos_ids, ring_id, counts, *,
                                     n_steps, stream=False):
@@ -1041,20 +1046,20 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             the per-step full-view gather/scatter stream is gone."""
             TRACE_COUNTS["fused_decode_paged"] += 1
             return _fused_decode_math(
-                _paged_fwd(table, win_impl), pool, last_tokens, lengths,
-                active, temps, top_k, top_p, keys, budgets, eos_ids,
+                _paged_fwd(params, table, win_impl), pool, last_tokens,
+                lengths, active, temps, top_k, top_p, keys, budgets, eos_ids,
                 ring_id, counts, n_steps=n_steps, stream=stream)
 
-    if draft is None:
+    if draft_cfg is None:
         fused_spec_paged = None
     elif kernel_build:
-        @functools.partial(jax.jit, donate_argnums=(0, 13),
+        @functools.partial(jax.jit, donate_argnums=(2, 15),
                            static_argnames=("n_draft_k", "n_steps",
                                             "stream"))
-        def fused_spec_paged(pool, table, hist, last_tokens, lengths,
-                             active, temps, top_k, top_p, keys, budgets,
-                             eos_ids, ring_id, counts, *, n_draft_k,
-                             n_steps, stream=False):
+        def fused_spec_paged(params, draft_params, pool, table, hist,
+                             last_tokens, lengths, active, temps, top_k,
+                             top_p, keys, budgets, eos_ids, ring_id,
+                             counts, *, n_draft_k, n_steps, stream=False):
             """Paged fused speculation with KERNELS inside the loop
             body: each iteration's k+1 verify window runs the
             flash-window kernel (per-row window visibility through the
@@ -1065,19 +1070,19 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             tiles live instead of the full history's)."""
             TRACE_COUNTS["fused_spec_paged_kernel"] += 1
             return _fused_spec_math(
-                _paged_fwd(table, "kernel"), draft_cfg, draft_params,
+                _paged_fwd(params, table, "kernel"), draft_cfg, draft_params,
                 pool, hist, last_tokens, lengths, active, temps, top_k,
                 top_p, keys, budgets, eos_ids, ring_id, counts,
                 n_draft_k=n_draft_k, n_steps=n_steps, stream=stream,
                 chunk_draft_prefill=True)
     else:
-        @functools.partial(jax.jit, donate_argnums=(0, 13),
+        @functools.partial(jax.jit, donate_argnums=(2, 15),
                            static_argnames=("n_draft_k", "n_steps",
                                             "stream"))
-        def fused_spec_paged(pool, table, hist, last_tokens, lengths,
-                             active, temps, top_k, top_p, keys, budgets,
-                             eos_ids, ring_id, counts, *, n_draft_k,
-                             n_steps, stream=False):
+        def fused_spec_paged(params, draft_params, pool, table, hist,
+                             last_tokens, lengths, active, temps, top_k,
+                             top_p, keys, budgets, eos_ids, ring_id,
+                             counts, *, n_draft_k, n_steps, stream=False):
             """Paged fused speculative window: ``_fused_spec_math`` —
             the one shared copy of draft/verify/accept carry — with the
             paged indirection inside the loop (the table is
@@ -1088,12 +1093,12 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             pooled state."""
             TRACE_COUNTS["fused_spec_paged"] += 1
             return _fused_spec_math(
-                _paged_fwd(table, win_impl), draft_cfg, draft_params,
+                _paged_fwd(params, table, win_impl), draft_cfg, draft_params,
                 pool, hist, last_tokens, lengths, active, temps, top_k,
                 top_p, keys, budgets, eos_ids, ring_id, counts,
                 n_draft_k=n_draft_k, n_steps=n_steps, stream=stream)
 
-    def _tree_paged_fwd(table):
+    def _tree_paged_fwd(params, table):
         """Paged tree-verify indirection: materialize the read-only
         dense view (gather — the tree step's documented read cost;
         nothing is scattered back) and run the no-write tree forward
@@ -1126,7 +1131,7 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             return _stack_pages(pool, layers)
         return commit
 
-    def _tree_kernel_fwd(table):
+    def _tree_kernel_fwd(params, table):
         """Kernelized paged tree-verify indirection: node queries read
         the cache THROUGH the table inside the tree kernel (strict
         ``< pos0`` visibility + in-window ancestor mask as a
@@ -1138,9 +1143,9 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
         return fwd
 
     if kernel_build:
-        @functools.partial(jax.jit, donate_argnums=(0, 10),
+        @functools.partial(jax.jit, donate_argnums=(1, 11),
                            static_argnames=("parents",))
-        def tree_verify_paged(pool, table, tokens, lengths, active,
+        def tree_verify_paged(params, pool, table, tokens, lengths, active,
                               n_cand, temps, top_k, top_p, keys, counts,
                               *, parents):
             """Paged tree window on the kernel build: fp pools run the
@@ -1153,18 +1158,18 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             if isinstance(pool, Int8Pages):
                 TRACE_COUNTS["tree_verify_paged"] += 1
                 return _tree_verify_math(
-                    _tree_paged_fwd(table), _tree_paged_commit(table),
+                    _tree_paged_fwd(params, table), _tree_paged_commit(table),
                     pool, tokens, lengths, active, n_cand, temps, top_k,
                     top_p, keys, counts, parents=parents)
             TRACE_COUNTS["tree_verify_paged_kernel"] += 1
             return _tree_verify_math(
-                _tree_kernel_fwd(table), _tree_paged_commit(table), pool,
-                tokens, lengths, active, n_cand, temps, top_k, top_p,
+                _tree_kernel_fwd(params, table), _tree_paged_commit(table),
+                pool, tokens, lengths, active, n_cand, temps, top_k, top_p,
                 keys, counts, parents=parents)
     else:
-        @functools.partial(jax.jit, donate_argnums=(0, 10),
+        @functools.partial(jax.jit, donate_argnums=(1, 11),
                            static_argnames=("parents",))
-        def tree_verify_paged(pool, table, tokens, lengths, active,
+        def tree_verify_paged(params, pool, table, tokens, lengths, active,
                               n_cand, temps, top_k, top_p, keys, counts,
                               *, parents):
             """Paged speculative tree window (the shared
@@ -1173,9 +1178,9 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             rejected branches write nothing into the pool."""
             TRACE_COUNTS["tree_verify_paged"] += 1
             return _tree_verify_math(
-                _tree_paged_fwd(table), _tree_paged_commit(table), pool,
-                tokens, lengths, active, n_cand, temps, top_k, top_p,
-                keys, counts, parents=parents)
+                _tree_paged_fwd(params, table), _tree_paged_commit(table),
+                pool, tokens, lengths, active, n_cand, temps, top_k,
+                top_p, keys, counts, parents=parents)
 
     return (decode_step, verify_step, prefill_step, fused_decode_step,
             fused_spec_step, tree_verify_step,
@@ -1183,58 +1188,11 @@ def _build_steps(cfg, params, paged_attn: str = "einsum", draft=None):
             fused_decode_step_paged, fused_spec_paged, tree_verify_paged)
 
 
-# LRU of built step programs keyed by ((cfg, paged_attn), id(params)):
-# engines over the same weights (the test/bench pattern — and any
-# multi-engine deployment of one model) share one set of compiled
-# programs instead of re-freezing the weights per Engine; the paged
-# KV-indirection choice rides the hashable key half because it is a
-# build-time static that changes the paged program bodies.  The cache
-# itself lives in tpudp.utils.compile_cache (ProgramCache documents the
-# id()-key safety argument); the trace-stability audit pins its reuse
-# semantics.
-class _DraftKey:
-    """Rides the hashable half of the step-cache key for engines whose
-    programs fuse in a DRAFT model (``_build_steps(draft=...)``):
-    hashes and compares the draft params by IDENTITY while holding them
-    STRONGLY — the same argument :class:`ProgramCache` makes for the
-    main params' ``id()`` key: the id can't be reused while this key
-    (inside a live cache entry) pins the object, and ``__eq__``'s
-    ``is`` check confirms it on every hit."""
-
-    __slots__ = ("cfg", "params")
-
-    def __init__(self, cfg, params):
-        self.cfg = cfg
-        self.params = params
-
-    def __hash__(self):
-        return hash((self.cfg, id(self.params)))
-
-    def __eq__(self, other):
-        return (isinstance(other, _DraftKey) and self.cfg == other.cfg
-                and self.params is other.params)
-
-
-def _build_steps_keyed(key, params):
-    cfg, paged_attn, draft = key
-    return _build_steps(cfg, params, paged_attn,
-                        draft=None if draft is None
-                        else (draft.cfg, draft.params))
-
-
-_STEP_CACHE = ProgramCache(_build_steps_keyed, max_entries=8)
-
-
-def _engine_steps(cfg, params, paged_attn: str = "einsum", draft=None):
-    dk = None if draft is None else _DraftKey(*draft)
-    return _STEP_CACHE.get((cfg, paged_attn, dk), params)
-
-
 class _ModelState:
     """Per-model serving state behind the one scheduler: a slot KV
-    arena, the frozen-weight step programs, and (optionally) a prefix
-    cache.  The default model is ``_mstates[None]``; co-resident models
-    registered via ``Engine(models={name: (model, params)})`` get their
+    arena, the config's step programs bound to this model's weights,
+    and (optionally) a prefix cache.  The default model is
+    ``_mstates[None]``; co-resident models registered via ``Engine(models={name: (model, params)})`` get their
     own instance each.  Every arena shares the engine's (num_slots,
     max_len) geometry — a request occupies the SAME slot index in every
     arena, but only its own model's rows ever hold its real KV; the
@@ -1242,24 +1200,30 @@ class _ModelState:
     overwrite-before-visible rule makes unreadable, exactly like an
     inactive slot's row."""
 
-    __slots__ = ("name", "model", "config", "params", "decode_step",
-                 "verify_step", "prefill_step", "fused_step",
-                 "fused_spec_step", "tree_step",
-                 "decode_paged", "verify_paged", "prefill_paged",
-                 "fused_paged", "fused_spec_paged", "tree_paged",
+    #: One attribute per ``_build_steps`` program, in its return order.
+    _STEPS = ("decode_step", "verify_step", "prefill_step", "fused_step",
+              "fused_spec_step", "tree_step",
+              "decode_paged", "verify_paged", "prefill_paged",
+              "fused_paged", "fused_spec_paged", "tree_paged")
+    __slots__ = ("name", "model", "config", "params", *_STEPS,
                  "cache", "prefix_cache", "pool", "index",
                  "table", "slot_nodes", "obs_counts")
 
-    def __init__(self, name, model, params, steps):
+    def __init__(self, name, model, params, steps, draft_params=None):
         self.name = name
         self.model = model
         self.config = model.config
         self.params = params
-        (self.decode_step, self.verify_step, self.prefill_step,
-         self.fused_step, self.fused_spec_step, self.tree_step,
-         self.decode_paged, self.verify_paged,
-         self.prefill_paged, self.fused_paged, self.fused_spec_paged,
-         self.tree_paged) = steps
+
+        # The programs take the weights as leading arguments
+        # (_build_steps: the fused speculative ones the draft weights
+        # second); binding them here keeps every call site a plain
+        # ``ms.decode_step(cache, ...)``.
+        for attr, step in zip(self._STEPS, steps):
+            weights = ((params, draft_params)
+                       if attr.startswith("fused_spec") else (params,))
+            setattr(self, attr, None if step is None
+                    else functools.partial(step, *weights))
         self.cache = None
         self.prefix_cache = None
         # Paged mode (Engine(kv_pages=N)): no dense arena — ``pool`` is
@@ -1441,18 +1405,19 @@ class Engine:
     ``generate()``; ``kv_dtype="int8"`` additionally quantizes page
     payloads (tolerance-bounded outputs, double capacity).
     ``paged_attn`` picks the attention backend.  ``None`` — the
-    default — resolves to ``'kernel'`` on TPU backends and
-    ``'einsum'`` everywhere else (the dispatch decision is recorded in
-    :meth:`metrics`).  ``'einsum'`` reads K/V through the table inside
+    default — resolves to ``'einsum'`` on the CPU platform and
+    ``'kernel'`` on every accelerator backend (the dispatch decision is
+    recorded in :meth:`metrics`).  ``'einsum'`` reads K/V through the table inside
     the contraction — gather-free, bit-exact; ``'gather'`` is the
     PR 13 gather→dense→scatter baseline; ``'kernel'`` runs the WHOLE
     hot path through the Pallas kernels — paged-decode, the k+1
     verify window and chunked prefill through the flash-window
     kernel, the fused ``lax.while_loop`` programs dispatching kernels
-    per iteration, and tree verify through the tree kernel — with the
-    einsum path auto-selected per-program wherever a feature lacks
-    kernel support (today: tree verify over an int8 pool; the
-    fallback is visible in ``metrics()["paged_attn"]``).  Kernel
+    per iteration, and tree verify through the tree kernel.  Where a
+    feature lacks kernel support (today: tree verify over an int8
+    pool) an auto-resolved engine selects the einsum path for that
+    program and shows it in ``metrics()["paged_attn"]``; an explicit
+    ``'kernel'`` raises instead.  Kernel
     programs are tolerance-bounded like flash.  Public handles:
     :attr:`page_pool` / :attr:`page_index`; mutually exclusive with
     ``prefix_cache_blocks`` (the dense COPY cache, which stays
@@ -1571,9 +1536,9 @@ class Engine:
                 "page-pool payloads behind the table indirection")
         if paged_attn not in (None, "einsum", "gather", "kernel"):
             raise ValueError(
-                f"paged_attn must be None (auto: 'kernel' on TPU, "
-                f"'einsum' elsewhere), 'einsum' (gather-free bit-exact "
-                f"blockwise attention), 'gather' (PR 13's "
+                f"paged_attn must be None (auto: 'einsum' on CPU, "
+                f"'kernel' on accelerators), 'einsum' (gather-free "
+                f"bit-exact blockwise attention), 'gather' (PR 13's "
                 f"gather→dense→scatter baseline), or 'kernel' (the "
                 f"Pallas hot-path kernels, tolerance-bounded); got "
                 f"{paged_attn!r}")
@@ -1583,15 +1548,16 @@ class Engine:
                 f"paged_attn={paged_attn!r} requires kv_pages > 0 — the "
                 f"paged-attention backend choice only exists behind the "
                 f"block-table indirection")
-        # The TPU-default resolution: unset paged_attn means "kernels
-        # where the hardware wants them".  On TPU the Pallas kernels ARE
-        # the paged hot path; CPU hosts (every tier-1 test) silently
-        # resolve to the bit-exact einsum path — an explicit 'kernel'
-        # still runs (interpret mode) for parity testing.
+        # The default resolution: unset paged_attn means "the Pallas
+        # kernels on every accelerator".  Only the CPU platform (every
+        # tier-1 test) resolves to the bit-exact einsum path — there an
+        # explicit 'kernel' still runs (interpret mode) for parity
+        # testing.  The test is "is this the CPU", not "is this named
+        # tpu": an accelerator backend must never land on einsum unasked.
         self.paged_attn_requested = paged_attn
         if paged_attn is None:
             paged_attn = ("kernel" if kv_pages
-                          and jax.default_backend() == "tpu" else "einsum")
+                          and jax.default_backend() != "cpu" else "einsum")
         if drafter is not None and speculate_k == 0:
             raise ValueError("drafter requires speculate_k >= 1 "
                              "(speculation is off at k=0)")
@@ -1652,7 +1618,7 @@ class Engine:
                     f"{type(drafter).__name__} has none")
             self.speculate_tree = shape
         # Fused speculation (the tentpole seam): with a MODEL drafter
-        # whose weights can be frozen into the device program, a
+        # whose weights can ride into the device program, a
         # fuse-eligible iteration runs draft→verify→accept as one
         # lax.while_loop program instead of host-drafted per-step
         # verify.  The draft model must cover max_len + k positions:
@@ -1704,10 +1670,12 @@ class Engine:
         self.paged_attn = paged_attn
         # Static per-program dispatch table: which impl each paged
         # program family actually traces with.  The decision is made
-        # here, once, at build time — a kernel engine falls back to the
-        # bit-exact einsum program wherever a feature lacks kernel
-        # support (today: tree verify over an int8 pool).  metrics()
-        # exposes this table so every fall-back dispatch is visible.
+        # here, once, at build time — an AUTO-resolved kernel engine
+        # falls back to the bit-exact einsum program wherever a feature
+        # lacks kernel support (today: tree verify over an int8 pool),
+        # and metrics() exposes this table so the fall-back is visible.
+        # An EXPLICIT paged_attn='kernel' that cannot be honoured for a
+        # program this engine will run is an error, never an einsum.
         self.paged_attn_dispatch: dict[str, str] = {}
         if self._paged:
             fams = ("decode_paged", "verify_paged", "prefill_paged",
@@ -1715,6 +1683,15 @@ class Engine:
                     "tree_verify_paged")
             self.paged_attn_dispatch = {f: paged_attn for f in fams}
             if paged_attn == "kernel" and kv_dtype == "int8":
+                if (self.paged_attn_requested == "kernel"
+                        and self.speculate_tree is not None):
+                    raise ValueError(
+                        "paged_attn='kernel' cannot be honoured for "
+                        "speculate_tree over kv_dtype='int8': the "
+                        "tree-verify kernel reads fp pages only.  Leave "
+                        "paged_attn unset (the tree program then runs "
+                        "einsum, recorded in metrics()['paged_attn']) "
+                        "or drop kv_dtype/speculate_tree")
                 self.paged_attn_dispatch["tree_verify_paged"] = "einsum"
         self._max_pages = self.max_len // prefill_chunk  # table width
         # Fused decode windows (module docstring "Fused decode windows"):
@@ -1732,7 +1709,7 @@ class Engine:
             self.fused_stream = _Ring(
                 maxlen=max(4 * num_slots * decode_fuse, 64))
             _STREAM_RINGS[self._ring_id] = self.fused_stream
-        # Per-model serving state (arena + frozen-weight programs +
+        # Per-model serving state (arena + weight-bound programs +
         # optional prefix cache), default model under key None.
         # Co-resident models (key = registered name) each add their own
         # _ModelState behind the same scheduler; with none registered
@@ -1848,8 +1825,8 @@ class Engine:
     def _add_model(self, name: str | None, model, params) -> None:
         """Register one model behind the scheduler: its own slot arena
         (same (num_slots, max_len) geometry as every other model's),
-        frozen-weight step programs (shared through the per-(cfg,
-        params) LRU — two engines or two tenants over one tree compile
+        its config's step programs bound to its weights (memoized per
+        config — two engines or two tenants over one config compile
         once), and its own prefix cache when caching is on (cached KV
         is a function of MODEL and tokens; blocks must never cross
         models)."""
@@ -1868,11 +1845,11 @@ class Engine:
                     f"drafter vocab_size ({dcfg.vocab_size}) must match "
                     f"co-resident model {name!r}'s ({cfg.vocab_size}) — "
                     f"speculation requires a shared tokenizer")
+        dcfg, dparams = self._draft_pair or (None, None)
         ms = _ModelState(name, model, params,
-                         _engine_steps(cfg, params,
-                                       self.paged_attn if self._paged
-                                       else "einsum",
-                                       draft=self._draft_pair))
+                         _build_steps(cfg, self.paged_attn if self._paged
+                                      else "einsum", dcfg),
+                         draft_params=dparams)
         # Prefix cache: blocks sized to prefill_chunk so a cached block
         # boundary is always a chunk boundary (imported lazily — the
         # module imports TRACE_COUNTS from here, and the cache is

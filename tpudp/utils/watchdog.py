@@ -68,8 +68,8 @@ class Watchdog:
 
     *Scoped* — arm a deadline around one specific blocking region::
 
-        with wd.step(name="fetch_fence"):
-            fetch_fence(state.params)  # tpudp.utils.profiler
+        with wd.step(name="window_barrier"):
+            jax.block_until_ready(state.params)
 
     A scope may carry its own deadline (``wd.step(timeout_s=5.0)``) so one
     watchdog can guard regions with very different legitimate durations —
