@@ -4,6 +4,7 @@ chip; nothing runs).  Exit 77 = this installation cannot describe a TPU
 topology (the test skips); exit 1 = a kernel failed to compile."""
 
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
@@ -71,13 +72,25 @@ CASES = {
          sds((SLOTS,), jnp.int32), w, w)),
 }
 
+# The name= on each pallas_call: it reaches the compiled program as a
+# component of the Mosaic custom call's op_name (inside a flax module the
+# HLO instruction itself is then ``flash_fwd.<n>``, which is what the
+# benchmark's kernel_ms.* readers match in a device trace).
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
+           "paged_prefill", "paged_tree")
+MOSAIC_OP = re.compile(
+    r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
+
 failed = []
 for name, (fn, args) in CASES.items():
     try:
         lowered = jax.jit(fn).lower(*args)
         assert "tpu_custom_call" in lowered.as_text(), "no Mosaic call"
-        lowered.compile()
+        ops = MOSAIC_OP.findall(lowered.compile().as_text())
+        named = sorted({k for k in KERNELS for op in ops
+                        if re.search(rf"[/(]{k}[/)]", op)})
         print(f"OK {name}")
+        print(f"KERNELS {name} {','.join(named)}")
     except Exception as exc:  # noqa: BLE001 — report every family
         failed.append(name)
         print(f"FAIL {name}: {type(exc).__name__}: {str(exc)[:800]}")

@@ -25,6 +25,7 @@ from tpudp.models.gpt2 import GPT2, GPT2Config
 from tpudp.serve import Engine
 from tpudp.serve.engine import OBS_DEVICE_COUNTERS
 from tpudp.serve.faults import FaultySteps
+from tpudp.serve.tenancy import TenantClass
 from tpudp.train import Trainer, init_state, make_optimizer
 from tpudp.utils.watchdog import StepHangError, Watchdog
 
@@ -102,6 +103,89 @@ def test_overhead_budget_for_hot_path_api():
     assert per_pair < 50e-6, f"begin/end pair cost {per_pair * 1e6:.1f}us"
 
 
+def test_totals_survive_a_lapped_ring_and_are_monotone():
+    """``summary()`` is a set of counters: every closed span counts,
+    lapped out of the ring or not, so a later snapshot is never below
+    an earlier one (a window delta of it means something); ``clear()``
+    is the one reset."""
+    rec = obs.Recorder(name="t", capacity=4)
+    seen = []
+    for i in range(25):
+        tok = rec.begin("a" if i % 5 else "b")
+        rec.end(tok)
+        seen.append(rec.summary())
+    assert len(rec.snapshot()) == 4  # the ring lapped six times over
+    assert seen[-1]["a"]["count"] == 20 and seen[-1]["b"]["count"] == 5
+    for earlier, later in zip(seen, seen[1:]):
+        for name, slot in earlier.items():
+            assert later[name]["count"] >= slot["count"]
+            assert later[name]["total_s"] >= slot["total_s"]
+    # the ring's survivors are a part of the total, never more than it
+    ring_a = sum(r["dur"] for r in rec.snapshot() if r["name"] == "a")
+    assert 0.0 <= ring_a <= seen[-1]["a"]["total_s"]
+    # an open span, a lapped token and an event add nothing
+    stale = rec.begin("open")
+    for _ in range(4):
+        rec.end(rec.begin("a"))
+    rec.end(stale)
+    rec.event("point")
+    assert set(rec.summary()) == {"a", "b"}
+    rec.clear()
+    assert rec.summary() == {}
+
+
+def test_obs_never_imports_jax_at_module_level():
+    """The recorder finds the profiler through an already-imported jax
+    (``sys.modules``) and no module of the package imports it at its
+    top level: stdlib-only tooling can load ``tpudp/obs`` files."""
+    import ast
+
+    for path in glob.glob(os.path.join(ROOT, "tpudp", "obs", "*.py")):
+        for node in ast.parse(open(path).read()).body:
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [node.module or ""]
+            else:
+                continue
+            assert not any(n == "jax" or n.startswith("jax.")
+                           for n in names), (path, names)
+
+
+def test_spans_reach_the_profilers_host_plane(tmp_path):
+    """While a JAX profiler session captures, a begin/end span is also
+    a ``tpudp.<recorder>.<span>`` TraceAnnotation on the trace's clock,
+    children inside their parent; outside a session nothing is
+    annotated."""
+    from jax.profiler import ProfileData
+
+    rec = obs.Recorder(name="serve")
+    rec.end(rec.begin("before"))          # no session: ring only
+    with jax.profiler.trace(str(tmp_path)):
+        outer = rec.begin("step")
+        inner = rec.begin("fetch")
+        time.sleep(0.002)
+        rec.end(inner)
+        rec.end(outer)
+    rec.end(rec.begin("after"))
+    files = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files
+    events = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("tpudp."):
+                        events[ev.name] = (ev.start_ns,
+                                           ev.start_ns + ev.duration_ns)
+    assert set(events) == {"tpudp.serve.step", "tpudp.serve.fetch"}
+    (s0, s1), (f0, f1) = events["tpudp.serve.step"], events["tpudp.serve.fetch"]
+    assert s0 <= f0 and f1 <= s1 and f1 - f0 >= 1e6  # ns: the 2 ms sleep
+    # the recorder's own view is unchanged by the session
+    assert {"before", "step", "fetch", "after"} == set(rec.summary())
+
+
 # -- exports -----------------------------------------------------------
 
 
@@ -127,19 +211,13 @@ def test_chrome_trace_schema_round_trip():
             else:
                 assert b["dur"] == pytest.approx(o["dur"], abs=1e-9)
         assert b.get("fields") == o.get("fields")
-    assert obs.counters_from_chrome_trace(trace) == {"tokens": 11}
+    assert [(ev["name"], ev["args"]["value"])
+            for ev in trace["traceEvents"] if ev["ph"] == "C"] == [
+        ("tokens", 11)]
     # every event is well-formed trace_event JSON
     for ev in trace["traceEvents"]:
         assert ev["ph"] in ("X", "i", "C") and "ts" in ev
     rec.end(open_tok)
-
-
-def test_snapshot_json_parses():
-    rec = obs.Recorder(name="s")
-    rec.event("e", x=1)
-    doc = json.loads(obs.snapshot_json(rec, extra_field=True))
-    assert doc["component"] == "s" and doc["extra_field"] is True
-    assert doc["spans"][0]["name"] == "e"
 
 
 def test_prometheus_text_flattens_numeric_leaves():
@@ -196,14 +274,19 @@ def test_reference_window_lines_are_byte_exact():
 
 
 def test_one_timing_api_reexports():
-    """The fold-under-obs satellite: the old import paths keep working
-    and resolve to the SAME objects as the obs package's."""
-    from tpudp.utils.profiler import step_annotation, trace
-    from tpudp.utils.timing import StepTimer
+    """The profiler capture keeps its old import path, resolving to the
+    SAME object as the obs package's; the helpers nothing read (PR 26)
+    are gone from both."""
+    import tpudp.utils
+    import tpudp.utils.profiler as profiler
 
-    assert trace is obs.trace
-    assert step_annotation is obs.step_annotation
-    assert StepTimer is obs.StepTimer
+    assert profiler.trace is obs.trace
+    for gone in ("StepTimer", "step_annotation", "snapshot_json",
+                 "counters_from_chrome_trace", "list_dumps"):
+        assert not hasattr(obs, gone) and gone not in obs.__all__
+        assert not hasattr(profiler, gone) and not hasattr(tpudp.utils, gone)
+    with pytest.raises(ImportError):
+        import tpudp.utils.timing  # noqa: F401
 
 
 # -- flight recorder ---------------------------------------------------
@@ -279,6 +362,136 @@ def test_engine_device_counters_match_host_stats(lm):
     assert names.count("admit") == 2 and names.count("finish") == 2
 
 
+def _children_inside(snapshot, parent="step"):
+    """Every span that is not ``parent`` lies inside one ``parent``."""
+    spans = [r for r in snapshot if r["kind"] == "span" and r["dur"] is not None]
+    outer = [(r["t0"], r["t0"] + r["dur"]) for r in spans if r["name"] == parent]
+    return all(any(a <= r["t0"] and r["t0"] + r["dur"] <= b for a, b in outer)
+               for r in spans if r["name"] != parent)
+
+
+def test_engine_step_phase_spans_and_seconds_counters(lm):
+    """One Engine.step leaves the phase spans, children enclosed by
+    ``step``; the stats' seconds counters are the spans' totals, and the
+    blocked time is a part of the step."""
+    model, params = lm
+    eng = Engine(model, params, num_slots=2, max_len=32, prefill_chunk=8)
+    h = eng.submit(PROMPTS[0], 4)
+    eng.step()   # admit + the whole 8-token prompt + its first token + decode
+    snap = eng.obs.snapshot()
+    names = [r["name"] for r in snap if r["kind"] == "span"]
+    assert names[0] == "step" and names.count("step") == 1
+    assert {"admit", "prefill", "sample", "first_token_wait", "decode",
+            "fetch", "commit"} <= set(names)
+    assert names.count("commit") == 2  # the first token's, the decode's
+    assert _children_inside(snap)
+    assert len(h.tokens) == 2
+    eng.run_until_complete()
+    m = eng.metrics()
+    st, spans = m["stats"], m["spans"]
+    assert spans["step"]["count"] == st["steps"]
+    assert st["step_s"] == spans["step"]["total_s"] > 0.0
+    assert st["fetch_wait_s"] == spans["fetch"]["total_s"]
+    assert st["dispatch_s"] == pytest.approx(sum(
+        spans[n]["total_s"] for n in ("prefill", "sample", "decode")))
+    assert st["step_s"] >= st["fetch_wait_s"] + st["first_token_wait_s"]
+    assert st["step_s"] >= (st["admit_s"] + st["dispatch_s"] + st["commit_s"]
+                            + st["fetch_wait_s"] + st["first_token_wait_s"]
+                            + st["pages_s"])
+    assert st["pages_s"] == 0.0  # an unpaged engine backs no pages
+    paged = Engine(model, params, num_slots=2, max_len=32, prefill_chunk=8,
+                   kv_pages=8)
+    paged.generate_many(PROMPTS, 4)
+    assert paged.metrics()["spans"]["pages"]["count"] >= 1
+    assert _children_inside(paged.obs.snapshot())
+
+
+def test_engine_span_totals_are_monotone_past_the_ring(lm):
+    """A run several times longer than the ring: every total in
+    ``metrics()["spans"]`` and every seconds counter only grows."""
+    model, params = lm
+    eng = Engine(model, params, num_slots=2, max_len=32, prefill_chunk=8)
+    eng.obs = obs.Recorder(name="serve", capacity=16)
+    handles = [eng.submit(p, 12) for p in PROMPTS * 2]
+    prev, laps = None, 0
+    while not all(h.done for h in handles):
+        eng.step()
+        m = eng.metrics()
+        if prev is not None:
+            for name, slot in prev["spans"].items():
+                assert m["spans"][name]["count"] >= slot["count"]
+                assert m["spans"][name]["total_s"] >= slot["total_s"]
+            for key in ("step_s", "fetch_wait_s", "dispatch_s", "commit_s"):
+                assert m["stats"][key] >= prev["stats"][key]
+        prev = m
+    assert eng.obs._seq > 5 * eng.obs.capacity  # it did lap
+    assert prev["spans"]["step"]["count"] == prev["stats"]["steps"]
+    assert prev["spans"]["fetch"]["count"] == prev["stats"]["decode_steps"]
+
+
+def test_ttft_is_split_at_the_request_stamps(lm):
+    """A request that holds a slot while another prompt prefills: its
+    time to first token is queue + prefill wait + own prefill, stamped
+    on the clock submit_time and token_times use; the engine's counters
+    add the same numbers up and the finish event carries them."""
+    model, params = lm
+    eng = Engine(model, params, num_slots=2, max_len=64, prefill_chunk=8)
+    long_prompt = np.arange(1, 25, dtype=np.int32)     # three chunks
+    first = eng.submit(long_prompt, 3)
+    second = eng.submit(PROMPTS[0], 3)                 # waits behind it
+    eng.run_until_complete()
+    st = eng.metrics()["stats"]
+    assert st["first_tokens"] == 2
+    total = queued = waited = 0.0
+    for r in (first, second):
+        ttft, q, w = r.ttft_split()
+        assert r.submit_time <= r.admit_time <= r.first_chunk_time \
+            <= r.token_times[0]
+        own = r.token_times[0] - r.first_chunk_time
+        assert ttft == pytest.approx(q + w + own, abs=1e-9)
+        total, queued, waited = total + ttft, queued + q, waited + w
+    assert st["ttft_s"] == pytest.approx(total)
+    assert st["ttft_queue_s"] == pytest.approx(queued)
+    assert st["ttft_prefill_wait_s"] == pytest.approx(waited)
+    # both were admitted in the same step; the second then held its slot
+    # for the first's three chunks
+    assert second.ttft_split()[2] > first.ttft_split()[2]
+    assert second.first_chunk_time > first.token_times[0]
+    done = {r["fields"]["rid"]: r["fields"] for r in eng.obs.snapshot()
+            if r["name"] == "finish"}
+    for r in (first, second):
+        ttft, q, w = r.ttft_split()
+        assert (done[r.id]["ttft_s"], done[r.id]["ttft_queue_s"],
+                done[r.id]["ttft_prefill_wait_s"]) == (ttft, q, w)
+    # a third request queues for a slot: its queue share is not zero
+    eng2 = Engine(model, params, num_slots=1, max_len=64, prefill_chunk=8)
+    a = eng2.submit(PROMPTS[0], 6)
+    b = eng2.submit(PROMPTS[1], 2)
+    eng2.run_until_complete()
+    assert b.ttft_split()[1] > a.ttft_split()[0] > a.ttft_split()[1]
+
+
+def test_a_preempted_request_counts_one_first_token(lm):
+    """A low-tier request evicted after its first token and resumed
+    (bit-exact re-prefill of prompt + tokens) commits no second "first"
+    token: ``first_tokens`` counts requests, not prefills."""
+    model, params = lm
+    eng = Engine(model, params, num_slots=1, max_len=64, prefill_chunk=8,
+                 tenants={"high": TenantClass(priority=1),
+                          "low": TenantClass(priority=0)})
+    low = eng.submit(PROMPTS[0], 8, tenant="low")
+    for _ in range(3):
+        eng.step()
+    assert len(low.tokens) >= 2 and not low.done
+    stamps = (low.admit_time, low.first_chunk_time)
+    high = eng.submit(PROMPTS[1], 3, tenant="high")
+    eng.run_until_complete()
+    assert low.preemptions == 1 and low.ok and high.ok
+    st = eng.metrics()["stats"]
+    assert st["first_tokens"] == 2 and st["admitted"] == 3
+    assert (low.admit_time, low.first_chunk_time) == stamps  # the first grant
+
+
 def test_engine_obs_off_is_inert_and_parity_neutral(lm):
     model, params = lm
     ref = [np.asarray(generate(model, params, jnp.asarray(p[None]), 8))[0]
@@ -288,7 +501,18 @@ def test_engine_obs_off_is_inert_and_parity_neutral(lm):
     outs = eng.generate_many(PROMPTS, 8)
     for o, r in zip(outs, ref):
         assert np.array_equal(o, r)
+    on = Engine(model, params, num_slots=2, max_len=32, prefill_chunk=8)
+    for o, r in zip(on.generate_many(PROMPTS, 8), outs):
+        assert np.array_equal(o, r)  # bit-identical with the recorder on
     assert eng.obs.snapshot() == []
+    # the seconds counters and the TTFT split come from the recorder:
+    # off, the keys are absent (a reader then returns nothing)
+    from tpudp.serve.engine import OBS_PHASE_SECONDS
+    new = {*OBS_PHASE_SECONDS, "first_tokens", "ttft_s", "ttft_queue_s",
+           "ttft_prefill_wait_s"}
+    assert new <= set(on.metrics()["stats"])
+    assert not new & set(eng.metrics()["stats"])
+    assert eng.metrics()["spans"] == {}
     # device counters still accumulate (they ride the programs, not the
     # host recorder) — metrics() stays truthful either way
     assert eng.metrics()["device_counters"]["tokens"] > 0

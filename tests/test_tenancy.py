@@ -658,6 +658,8 @@ PR5_BASE_STATS = {
     "submitted", "admitted", "steps", "prefill_chunks", "decode_steps",
     "active_slot_steps", "tokens", "completed", "cancelled",
     "deadline_expired", "shed", "step_failures", "requeued", "errors",
+    # the TTFT split at each request's first token (PR 26; obs on)
+    "first_tokens", "ttft_s", "ttft_queue_s", "ttft_prefill_wait_s",
 }
 PR5_SPEC_STATS = {"verify_steps", "draft_tokens", "draft_accepted"}
 PR5_PREFIX_STATS = {"prefix_lookups", "prefix_hit_tokens",

@@ -13,7 +13,10 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def test_kernel_families_compile_for_v5e_topology():
+@pytest.fixture(scope="module")
+def compiled():
+    """One child process compiles every family (the topology is described
+    there, never while this file is imported); its report, by line."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
@@ -21,9 +24,31 @@ def test_kernel_families_compile_for_v5e_topology():
         capture_output=True, text=True, timeout=600, env=env)
     if proc.returncode == 77:
         pytest.skip(proc.stdout.strip().splitlines()[-1])
+    return proc
+
+
+def test_kernel_families_compile_for_v5e_topology(compiled):
+    proc = compiled
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1500:]
     ok = {line.split()[1] for line in proc.stdout.splitlines()
           if line.startswith("OK ")}
     assert ok == {"flash_fwd", "flash_bwd", "paged_decode",
                   "paged_window_verify", "paged_window_prefill",
                   "paged_tree"}, proc.stdout
+
+
+@pytest.mark.parametrize("family, kernels", [
+    ("flash_fwd", {"flash_fwd"}),
+    ("flash_bwd", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    ("paged_decode", {"paged_decode"}),
+    ("paged_window_verify", {"paged_prefill"}),
+    ("paged_window_prefill", {"paged_prefill"}),
+    ("paged_tree", {"paged_tree"})])
+def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
+    """The stable names the device trace is read by (PR 26): the compiled
+    program's Mosaic custom calls have their ``pallas_call``'s ``name=``
+    in the op_name, and a family carries no other family's kernel."""
+    named = {line.split()[1]: set(line.split()[2].split(","))
+             for line in compiled.stdout.splitlines()
+             if line.startswith("KERNELS ") and len(line.split()) == 3}
+    assert named.get(family) == kernels, compiled.stdout[-3000:]

@@ -6,7 +6,10 @@ One subsystem, four layers (docs/OBSERVABILITY.md):
     monotonic-clock ring per engine/trainer.  ``begin``/``end`` is the
     allocation-free hot-path API (the only one the ``obs-in-hot-path``
     lint rule allows on the designated scheduler/step hot paths);
-    ``span``/``event`` are the convenient off-hot-path forms.
+    ``span``/``event`` are the convenient off-hot-path forms.  Closed
+    spans also add to per-name cumulative totals that never lap, and
+    under a JAX profiler session each is a ``tpudp.<recorder>.<span>``
+    annotation on the trace's clock.
   * **Zero-sync device counters**: per-step scalars accumulated INSIDE
     the existing step programs (``tpudp/serve/engine.py``
     ``OBS_DEVICE_COUNTERS``) and carried in the arrays the engine
@@ -18,34 +21,28 @@ One subsystem, four layers (docs/OBSERVABILITY.md):
     containment, and resilience rollbacks — enable by directory
     (``TPUDP_FLIGHT_DIR`` or the ``flight_dir`` knobs).
   * **Exposition** (:mod:`tpudp.obs.export` / :mod:`tpudp.obs.metrics`):
-    Chrome/Perfetto ``trace_event`` JSON, plain JSON snapshots, and a
-    Prometheus-style text endpoint (``tpudp.cli --metrics-port``).
+    Chrome/Perfetto ``trace_event`` JSON and a Prometheus-style text
+    endpoint (``tpudp.cli --metrics-port``).
 
-This package also absorbed the repo's older one-off timing APIs so
-there is ONE timing surface: :class:`StepTimer` (ex
-``tpudp/utils/timing.py``), the XLA :func:`trace` capture wrapper (ex
-``tpudp.utils.profiler.trace``), and the reference-parity window-line
-formatter (:func:`reference_window_lines`) the Trainer prints through.
-The old import paths re-export from here.  Importing ``tpudp.obs``
-never imports jax.
+This package also holds the XLA :func:`trace` capture wrapper (ex
+``tpudp.utils.profiler.trace``, which re-exports it) and the
+reference-parity window-line formatter
+(:func:`reference_window_lines`) the Trainer prints through.
+Importing ``tpudp.obs`` never imports jax.
 """
 
-from tpudp.obs.export import (counters_from_chrome_trace, snapshot_json,
-                              spans_from_chrome_trace, to_chrome_trace)
+from tpudp.obs.export import spans_from_chrome_trace, to_chrome_trace
 from tpudp.obs.flight import (FLIGHT_DIR_ENV, FlightRecorder,
-                              coordinated_merge, list_dumps, merge_dumps,
+                              coordinated_merge, merge_dumps,
                               resolve_flight_dir)
 from tpudp.obs.format import reference_window_lines
 from tpudp.obs.metrics import MetricsServer, prometheus_text
 from tpudp.obs.record import NO_SPAN, Recorder
-from tpudp.obs.timing import StepTimer
-from tpudp.obs.tracing import step_annotation, trace
+from tpudp.obs.tracing import trace
 
 __all__ = [
     "FLIGHT_DIR_ENV", "FlightRecorder", "MetricsServer", "NO_SPAN",
-    "Recorder", "StepTimer", "coordinated_merge",
-    "counters_from_chrome_trace", "list_dumps", "merge_dumps",
-    "prometheus_text", "reference_window_lines", "resolve_flight_dir",
-    "snapshot_json", "spans_from_chrome_trace", "step_annotation",
-    "to_chrome_trace", "trace",
+    "Recorder", "coordinated_merge", "merge_dumps", "prometheus_text",
+    "reference_window_lines", "resolve_flight_dir",
+    "spans_from_chrome_trace", "to_chrome_trace", "trace",
 ]

@@ -1,6 +1,6 @@
-"""Exporters: Chrome/Perfetto ``trace_event`` JSON and plain snapshots.
+"""Exporter: Chrome/Perfetto ``trace_event`` JSON and its inverse.
 
-A recorder ring is only useful if something can read it.  Two formats:
+A recorder ring is only useful if something can read it:
 
   * :func:`to_chrome_trace` — the Chrome ``trace_event`` JSON format
     (the ``traceEvents`` array), loadable by Perfetto
@@ -14,14 +14,9 @@ A recorder ring is only useful if something can read it.  Two formats:
     :func:`spans_from_chrome_trace` is the identity on (name, kind,
     t0, dur, fields), which the schema round-trip test pins so the
     export can never drift from what Perfetto parses.
-  * :func:`snapshot_json` — the raw ring + counters as one JSON
-    document (the flight recorder's payload shape, reusable for ad-hoc
-    ``Engine.metrics()``-style dumps).
 """
 
 from __future__ import annotations
-
-import json
 
 from tpudp.obs.record import Recorder
 
@@ -85,21 +80,3 @@ def spans_from_chrome_trace(trace: dict) -> list[dict]:
                 rec["fields"] = dict(ev["args"])
             out.append(rec)
     return out
-
-
-def counters_from_chrome_trace(trace: dict) -> dict:
-    """Counter ("C") samples of a :func:`to_chrome_trace` export."""
-    out = {}
-    for ev in trace.get("traceEvents", []):
-        if ev.get("ph") == "C":
-            out[ev["name"]] = ev.get("args", {}).get("value")
-    return out
-
-
-def snapshot_json(recorder: Recorder, **extra) -> str:
-    """The ring + counters as one pretty-printed JSON document."""
-    return json.dumps(
-        {"component": recorder.name, "anchor_wall": recorder.anchor_wall,
-         "counters": dict(recorder.counters),
-         "spans": recorder.snapshot(), **extra},
-        indent=1, sort_keys=True, default=str)

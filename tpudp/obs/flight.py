@@ -130,7 +130,7 @@ class FlightRecorder:
             return None  # best-effort: never mask the fault being recorded
 
 
-def list_dumps(directory: str) -> list[str]:
+def _dump_paths(directory: str) -> list[str]:
     """Sorted flight-record files under ``directory`` (sorted so every
     host walks the same order — the merge below is a coordination-
     adjacent path)."""
@@ -147,7 +147,7 @@ def merge_dumps(directory: str) -> str | None:
     """Merge every per-host flight record under ``directory`` into
     ``flightrec-merged.json`` (records sorted by host then sequence).
     Pure file I/O — callable post-mortem on a dead pod's shared dir."""
-    paths = list_dumps(directory)
+    paths = _dump_paths(directory)
     if not paths:
         return None
     records = []
@@ -186,7 +186,7 @@ def coordinated_merge(directory: str | None) -> str | None:
     if jax.process_count() > 1:
         from tpudp.utils.checkpoint import gather_host_values
 
-        gather_host_values(len(list_dumps(directory)))
+        gather_host_values(len(_dump_paths(directory)))
     if jax.process_index() == 0:
         return merge_dumps(directory)
     return None

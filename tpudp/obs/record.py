@@ -27,11 +27,21 @@ The ring holds the last ``capacity`` records per recorder — old
 telemetry is dropped, never compacted; that bounded-loss contract is
 what makes the recorder safe to leave on in production and is exactly
 what the flight recorder (``tpudp/obs/flight.py``) wants: the last N
-spans before a fault ARE the black box.
+spans before a fault ARE the black box.  What must NOT be lost when the
+ring laps are the totals: ``end`` also adds every closed span to a
+cumulative per-name ``(count, total_s)`` that :meth:`Recorder.summary`
+returns — monotone counters a window delta or a Prometheus ``rate()``
+can be taken of.
 
 Timestamps are ``time.monotonic()`` (immune to wall-clock steps); each
 recorder stamps a ``(monotonic, wall)`` anchor pair at construction so
-exports can place the timeline in wall time.
+exports can place the timeline in wall time.  While a JAX profiler
+session is capturing, every ``begin``/``end`` span is ALSO a
+``jax.profiler.TraceAnnotation`` named ``tpudp.<recorder>.<span>``, which
+puts the program's spans on the profiler's clock, on the host plane
+beside the device's operations.  The recorder asks the profiler itself
+(``TraceAnnotation.is_enabled()``, tens of nanoseconds) and only once
+jax is already imported by someone else: this module never imports it.
 """
 
 from __future__ import annotations
@@ -39,6 +49,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import itertools
+import sys
 import time
 
 #: Disabled-recorder token: ``end()`` treats it as a no-op.
@@ -51,7 +62,7 @@ class _Rec:
     """One preallocated ring slot, reused in place (never reallocated —
     the hot path only stores into existing attributes)."""
 
-    __slots__ = ("seq", "kind", "name", "t0", "t1", "fields")
+    __slots__ = ("seq", "kind", "name", "t0", "t1", "fields", "ann")
 
     def __init__(self):
         self.seq = -1       # ring generation; -1 = never written
@@ -60,6 +71,7 @@ class _Rec:
         self.t0 = 0.0
         self.t1 = -1.0      # -1.0 = span still open
         self.fields = None  # dict for events / tagged spans, else None
+        self.ann = None     # the open TraceAnnotation under a profiler
 
 
 class Recorder:
@@ -72,7 +84,8 @@ class Recorder:
 
     __slots__ = ("name", "enabled", "capacity", "counters",
                  "anchor_monotonic", "anchor_wall",
-                 "_ring", "_seq", "_last_done", "_id")
+                 "_ring", "_seq", "_last_done", "_id", "_totals",
+                 "_annotation", "_trace_prefix")
 
     def __init__(self, name: str = "", capacity: int = 4096,
                  enabled: bool = True):
@@ -88,6 +101,9 @@ class Recorder:
         self._seq = 0
         self._last_done = NO_SPAN
         self._id = next(_RECORDER_IDS)
+        self._totals: dict[str, list] = {}  # name -> [count, total_s]
+        self._annotation = None  # jax.profiler.TraceAnnotation, once seen
+        self._trace_prefix = f"tpudp.{name}." if name else "tpudp."
 
     # -- hot-path API (allocation-free; sanctioned by obs-in-hot-path) --
 
@@ -104,9 +120,26 @@ class Recorder:
         rec.name = name
         rec.fields = None
         rec.t1 = -1.0
+        ann = self._annotation or self._find_annotation()
+        if ann is not None and ann.is_enabled():
+            # a profiler session is capturing: the span also goes on the
+            # trace's clock (allocates; only while a trace is taken)
+            rec.ann = ann(self._trace_prefix + name)
+            rec.ann.__enter__()
+        else:
+            rec.ann = None
         rec.t0 = time.monotonic()
         self._seq = seq + 1
         return seq
+
+    def _find_annotation(self):
+        """``jax.profiler.TraceAnnotation`` if jax is ALREADY imported
+        (a dict lookup until then — a process that never imports jax
+        never pays for it, and this module never imports it)."""
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        self._annotation = getattr(profiler, "TraceAnnotation", None)
+        return self._annotation
 
     def end(self, token: int) -> None:
         """Close the span ``begin`` opened.  A token the ring has since
@@ -116,8 +149,16 @@ class Recorder:
             return
         rec = self._ring[token % self.capacity]
         if rec.seq == token:
-            rec.t1 = time.monotonic()
+            t1 = rec.t1 = time.monotonic()
             self._last_done = token
+            if rec.ann is not None:
+                rec.ann.__exit__(None, None, None)
+                rec.ann = None
+            total = self._totals.get(rec.name)
+            if total is None:
+                total = self._totals[rec.name] = [0, 0.0]
+            total[0] += 1
+            total[1] += t1 - rec.t0
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump a host-side named counter (Counter add — hot-path safe)."""
@@ -139,6 +180,7 @@ class Recorder:
         rec.kind = "event"
         rec.name = name
         rec.fields = fields or None
+        rec.ann = None
         rec.t0 = time.monotonic()
         rec.t1 = rec.t0
         self._seq = seq + 1
@@ -200,23 +242,20 @@ class Recorder:
         return self._record_dict(rec)
 
     def summary(self) -> dict:
-        """Per-span-name aggregates over the surviving ring:
-        ``{name: {"count": n, "total_s": s}}`` — the cheap rollup
-        ``metrics()`` snapshots embed."""
-        agg: dict[str, dict] = {}
-        for rec in self.snapshot():
-            if rec["kind"] != "span" or rec.get("dur") is None:
-                continue
-            slot = agg.setdefault(rec["name"], {"count": 0, "total_s": 0.0})
-            slot["count"] += 1
-            slot["total_s"] += rec["dur"]
-        for slot in agg.values():
-            slot["total_s"] = round(slot["total_s"], 6)
-        return agg
+        """Per-span-name cumulative totals of every span closed since
+        construction (or :meth:`clear`), lapped out of the ring or not:
+        ``{name: {"count": n, "total_s": s}}``.  Monotone, so two
+        snapshots' difference is a window's time and the Prometheus
+        exposition of it can be ``rate()``d — the rollup ``metrics()``
+        snapshots embed."""
+        return {name: {"count": n, "total_s": s}
+                for name, (n, s) in list(self._totals.items())}
 
     def clear(self) -> None:
         self._seq = 0
         self._last_done = NO_SPAN
         for rec in self._ring:
             rec.seq = -1
+            rec.ann = None
         self.counters.clear()
+        self._totals.clear()

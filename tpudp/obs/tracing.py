@@ -4,10 +4,12 @@ here, so existing imports keep working).
 
 The host-side recorder (``tpudp/obs/record.py``) answers "what was the
 scheduler doing"; THIS layer answers "what was the chip doing": a real
-XLA/TPU profile (TensorBoard trace-viewer format) around any region,
-with per-step boundaries marked so the viewer groups work by training
-step.  jax is imported lazily so ``tpudp.obs`` stays importable from
-stdlib-only tooling (the same discipline as ``tpudp.analysis``).
+XLA/TPU profile (TensorBoard trace-viewer format) around any region.
+While it captures, the recorder's ``begin``/``end`` spans appear in it
+as ``tpudp.<recorder>.<span>`` annotations on the host plane (the
+recorder finds the session itself; nothing is wired through here).  jax
+is imported lazily so ``tpudp.obs`` stays importable from stdlib-only
+tooling (the same discipline as ``tpudp.analysis``).
 """
 
 from __future__ import annotations
@@ -27,10 +29,3 @@ def trace(log_dir: str | None) -> Iterator[None]:
 
     with jax.profiler.trace(log_dir):
         yield
-
-
-def step_annotation(step: int):
-    """Mark a training step in an active trace."""
-    import jax
-
-    return jax.profiler.StepTraceAnnotation("train_step", step_num=step)

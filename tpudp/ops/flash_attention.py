@@ -146,6 +146,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse.reshape(bh, t)
 
@@ -263,6 +264,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((bh, t, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse3, delta)
 
     dk, dv = pl.pallas_call(
@@ -289,6 +291,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse3, delta)
     return dq, dk, dv
 

@@ -306,6 +306,7 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), dtype),
         interpret=interpret,
+        name="paged_decode",
     )(tbl, pos, q[:, 0], *ins)
     return out[:, None]
 
@@ -472,6 +473,7 @@ def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, cur, h, dh), dtype),
         interpret=interpret,
+        name="paged_prefill",
     )(tbl, pos, q, *ins)
 
 
@@ -625,6 +627,7 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t1, h, dh), dtype),
         interpret=interpret,
+        name="paged_tree",
     )(tbl, pos0, anc_flat, q, *pages, wk, wv)
 
 

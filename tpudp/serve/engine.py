@@ -267,6 +267,24 @@ def _zero_obs_counts():
     return jnp.zeros((len(OBS_DEVICE_COUNTERS),), jnp.float32)
 
 
+#: Span names of the ``_device`` seam: each times the DISPATCH of one
+#: step program (asynchronous — the device's own time is felt in the
+#: ``fetch`` / ``first_token_wait`` spans that block on its result).
+DEVICE_SPANS = ("prefill", "sample", "decode", "verify", "tree_verify",
+                "fused_decode", "fused_spec", "prefix_in", "prefix_out")
+
+#: ``Engine.metrics()["stats"]`` seconds counters and the spans each
+#: sums — the recorder's cumulative totals, so monotone like every other
+#: stat (the ``*_seconds_total`` family an operator rates against
+#: ``step_s``).  Absent with ``Engine(obs=False)``.
+OBS_PHASE_SECONDS = {
+    "step_s": ("step",), "admit_s": ("admit",),
+    "dispatch_s": DEVICE_SPANS,
+    "first_token_wait_s": ("first_token_wait",), "pages_s": ("pages",),
+    "fetch_wait_s": ("fetch",), "commit_s": ("commit",),
+}
+
+
 class FinishReason(str, enum.Enum):
     """Why a request stopped.  ``COMPLETE``/``EOS`` are success; the rest
     are failures and make :meth:`Request.result` raise
@@ -1263,7 +1281,11 @@ class Request:
     them (iteration drives the engine), or call :meth:`result` for the
     full prompt+completion sequence.  ``token_times`` records a
     ``time.perf_counter()`` stamp per emitted token (the serve bench's
-    per-token latency source).  With speculation on,
+    per-token latency source); on the same clock ``submit_time``,
+    ``admit_time`` (first slot granted) and ``first_chunk_time`` (first
+    prefill chunk dispatched) split the time to the first token into
+    queueing, holding a slot while other prompts prefill, and the
+    request's own prefill (:meth:`ttft_split`).  With speculation on,
     ``draft_proposed``/``draft_accepted`` count this request's drafted
     and accepted tokens (``acceptance_rate`` is their ratio).
     :meth:`cancel` retires the request immediately — a disconnected
@@ -1306,6 +1328,8 @@ class Request:
         self.tokens: list[int] = []
         self.token_times: list[float] = []
         self.submit_time = time.perf_counter()
+        self.admit_time: float | None = None
+        self.first_chunk_time: float | None = None
         self.done = False
         self.finish_reason: FinishReason | None = None
         self.error: BaseException | None = None
@@ -1326,6 +1350,20 @@ class Request:
         if not self.draft_proposed:
             return None
         return self.draft_accepted / self.draft_proposed
+
+    def ttft_split(self) -> tuple[float, float, float] | None:
+        """``(ttft_s, queue_s, prefill_wait_s)`` of the first token:
+        submit to first token, of which submit to slot grant and slot
+        grant to first prefill chunk; the remainder is the request's
+        own prefill (and any preemption before the token).  None until
+        the token exists, and for a request adopted from another host
+        (its first token was not made under this engine's clock)."""
+        if (self.migrations or not self.token_times
+                or self.admit_time is None or self.first_chunk_time is None):
+            return None
+        return (self.token_times[0] - self.submit_time,
+                self.admit_time - self.submit_time,
+                self.first_chunk_time - self.admit_time)
 
     @property
     def cancelled(self) -> bool:
@@ -2104,6 +2142,12 @@ class Engine:
         self._maybe_canary()
         if self._quarantined:
             return emitted  # the canary just condemned this engine
+        # Phase spans (tpudp.obs begin/end, children of ``step`` by
+        # enclosure on this one scheduler thread): ``step``'s time in no
+        # child is the scheduler's own.  A child an exception cuts short
+        # stays open in the ring — the flight recorder's "where it was".
+        obs = self.obs
+        in_step = obs.begin("step")
         try:
             # Deadline expiry and admission sit INSIDE the containment
             # region: with prefix caching on, a deadline retirement can
@@ -2113,11 +2157,13 @@ class Engine:
             # other step failure instead of escaping to the caller.
             # Cache off, neither touches device state and this changes
             # nothing.
+            span = obs.begin("admit")
             self._expire_deadlines()
             if self._sched is not None:
                 self._preempt_for_priority()
             self._admit()
             slot = self._next_prefill_slot()
+            obs.end(span)
             if slot is not None:
                 self._run_prefill_chunk(slot, emitted)
             # Fuse only on PURE-DECODE iterations: nothing queued (so
@@ -2146,7 +2192,9 @@ class Engine:
                     # most recent co-resident slot through the
                     # bit-exact resume path — so the device program
                     # only ever sees fully-backed tables.
+                    span = obs.begin("pages")
                     active = self._ensure_decode_pages(ms, active, fuse)
+                    obs.end(span)
                     if not active.any():
                         continue
                 if self.speculate_k and not self._drafter_quarantined:
@@ -2168,6 +2216,7 @@ class Engine:
             # live on the canary handle, never in the emitted pairs.
             emitted = [(r, t) for (r, t) in emitted
                        if not getattr(r, "_canary", False)]
+        obs.end(in_step)
         return emitted
 
     def cancel(self, request: Request) -> bool:
@@ -2454,14 +2503,20 @@ class Engine:
             device[name or "default"] = row
             for k, v in row.items():
                 totals[k] += v
+        spans = self.obs.summary()
+        stats = dict(self.stats)
+        if self.obs.enabled:
+            for key, names in OBS_PHASE_SECONDS.items():
+                stats[key] = sum(spans[n]["total_s"] for n in names
+                                 if n in spans)
         out = {
-            "stats": dict(self.stats),
+            "stats": stats,
             "queue_depth": self.queue_depth,
             "slots_in_use": self.slots_in_use,
             "num_slots": self.num_slots,
             "device_counters": totals,
             "device_counters_per_model": device,
-            "spans": self.obs.summary(),
+            "spans": spans,
             "obs_counters": dict(self.obs.counters),
             "flight_dumps": self.flight.dumps,
         }
@@ -2522,6 +2577,8 @@ class Engine:
             r._slot = s
             r._order = self._admitted
             self._admitted += 1
+            if r.admit_time is None:  # a resume keeps the first grant
+                r.admit_time = time.perf_counter()
             self._slots[s] = r
             self._len[s] = 0
             self._temps[s] = r.temperature
@@ -2903,9 +2960,12 @@ class Engine:
         r.finish_reason = reason
         r.error = error
         self.stats[_FINISH_COUNTER[reason]] += 1
+        ttft, queued, prefill_wait = r.ttft_split() or (None, None, None)
         self.obs.event("finish", rid=r.id, reason=reason.value,
                        tenant=r.tenant, tokens=len(r.tokens),
-                       preemptions=r.preemptions)
+                       preemptions=r.preemptions, ttft_s=ttft,
+                       ttft_queue_s=queued,
+                       ttft_prefill_wait_s=prefill_wait)
         if r.tenant is not None:
             self._sched.stats(r.tenant)[_FINISH_COUNTER[reason]] += 1
 
@@ -2960,8 +3020,10 @@ class Engine:
 
         Every call rides an allocation-free obs span named ``kind`` —
         the one instrumentation point covering the whole device-call
-        taxonomy (prefill/sample/decode/verify/fused_decode/prefix
-        copies), and the region name the watchdog reports on a hang."""
+        taxonomy (``DEVICE_SPANS``), and the region name the watchdog
+        reports on a hang.  The call is asynchronous, so the span times
+        the DISPATCH; the device's time shows where the host blocks on
+        the result (the ``fetch`` / ``first_token_wait`` spans)."""
         idx = self._device_calls
         self._device_calls += 1
         tok = self.obs.begin(kind)
@@ -3072,6 +3134,8 @@ class Engine:
 
     def _run_prefill_chunk(self, s: int, emitted) -> None:
         r = self._slots[s]
+        if r.first_chunk_time is None:
+            r.first_chunk_time = time.perf_counter()
         ms = r._ms
         fill = r._fill
         start = r._nfill
@@ -3119,11 +3183,19 @@ class Engine:
                 "sample", _sample_row, last_logits, self._temps[s],
                 self._topk[s], self._topp[s], self._keys[s])
             self._keys = self._keys.at[s].set(carry)
+            # The sync that keeps this step's decode from being queued
+            # behind the prompt's last chunk: the host waits here for
+            # the chunk and the sampler to finish.
+            span = self.obs.begin("first_token_wait")
             # tpudp: lint-ok(host-sync): the FIRST-token commit — one
             # fetch per completed prefill, not per decoded token; the
             # decoded tokens ride decode_fuse windows
             # (_run_decode_fused) when fusing is on.
-            self._commit(s, int(tok), emitted)
+            tok = int(tok)
+            self.obs.end(span)
+            span = self.obs.begin("commit")
+            self._commit(s, tok, emitted)
+            self.obs.end(span)
 
     def _run_decode(self, ms: _ModelState, active, emitted) -> None:
         if self._paged:
@@ -3137,17 +3209,21 @@ class Engine:
                 "decode", ms.decode_step,
                 ms.cache, self._last, self._len, active, self._temps,
                 self._topk, self._topp, self._keys, ms.obs_counts)
+        span = self.obs.begin("fetch")
         # tpudp: lint-ok(host-sync): the single-step path's per-token
         # fetch — Engine(decode_fuse=N) amortizes it to one fetch per
         # fused lax.while_loop window (_run_decode_fused); this path
         # remains for the host-intervention steps (admission, prefill,
         # speculation, preemption) the fused window falls back to.
         toks = np.asarray(toks)
+        self.obs.end(span)
         self.stats["decode_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
+        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             self._len[s] += 1  # the fed token's KV landed this step
             self._commit(int(s), int(toks[s]), emitted)
+        self.obs.end(span)
 
     def _run_decode_fused(self, ms: _ModelState, active, emitted) -> None:
         """One fused window: up to ``decode_fuse`` decode iterations in
@@ -3194,11 +3270,13 @@ class Engine:
                 np.int32(self._ring_id), ms.obs_counts,
                 guard_timeout_s=budget_s,
                 n_steps=self.decode_fuse, stream=self._fuse_stream)
+        span = self.obs.begin("fetch")
         # tpudp: lint-ok(host-sync): the per-WINDOW fetch — one round
         # trip per up-to-decode_fuse-token window, the amortized
         # replacement for the single-step path's per-token fetch.
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
+        self.obs.end(span)
         self.stats["fused_windows"] += 1
         self.stats["fused_steps"] += int(iters)  # tpudp: lint-ok(host-sync): same fetch
         # Each loop iteration is one batched decode over the arena, and
@@ -3207,6 +3285,7 @@ class Engine:
         # occupancy consumers keep working with fusing on
         # (active / (decode_steps + fused_steps) x num_slots).
         self.stats["active_slot_steps"] += int(n_emit.sum())
+        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             # Take the window-final key carry PER SLOT, just before that
@@ -3224,6 +3303,7 @@ class Engine:
                     break  # retired (EOS / budget / cancel) mid-replay
                 self._len[s] += 1
                 self._commit(int(s), int(out[s, j]), emitted)
+        self.obs.end(span)
 
     def _quarantine_drafter(self, reason: str, r: Request | None = None,
                             proposed: int = 0) -> None:
@@ -3417,14 +3497,17 @@ class Engine:
                 "verify", ms.verify_step,
                 ms.cache, tokens, self._len, active, n_draft, self._temps,
                 self._topk, self._topp, self._keys, ms.obs_counts)
+        span = self.obs.begin("fetch")
         # tpudp: lint-ok(host-sync): the per-window verify fetch (one
         # round trip per k+1-token window, amortized over accepts) —
         # fusing the drafter into the device program removes it.
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
+        self.obs.end(span)
         self.stats["verify_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
         self.stats["draft_tokens"] += int(n_draft.sum())
+        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             accepted = int(n_emit[s]) - 1
@@ -3438,6 +3521,7 @@ class Engine:
                 # per commit advances the row past exactly those writes.
                 self._len[s] += 1
                 self._commit(s, int(out[s, j]), emitted)
+        self.obs.end(span)
 
     def _run_spec_fused(self, ms: _ModelState, active, emitted) -> None:
         """One fused SPECULATIVE window: up to ``decode_fuse``
@@ -3486,6 +3570,7 @@ class Engine:
                 budgets, eos, np.int32(self._ring_id), ms.obs_counts,
                 guard_timeout_s=budget_s, n_draft_k=k,
                 n_steps=self.decode_fuse, stream=self._fuse_stream)
+        span = self.obs.begin("fetch")
         # tpudp: lint-ok(host-sync): the per-PROGRAM fetch — one round
         # trip per up-to-decode_fuse speculative windows, replacing the
         # host-drafted path's per-window draft gather + verify fetch.
@@ -3493,6 +3578,7 @@ class Engine:
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
         n_win = np.asarray(n_win)  # tpudp: lint-ok(host-sync): same fetch
         n_acc = np.asarray(n_acc)  # tpudp: lint-ok(host-sync): same fetch
+        self.obs.end(span)
         self.stats["fused_spec_windows"] += 1
         self.stats["fused_spec_steps"] += int(iters)  # tpudp: lint-ok(host-sync): same fetch
         # A row participates in one verify window per loop iteration it
@@ -3501,6 +3587,7 @@ class Engine:
         self.stats["active_slot_steps"] += int(n_win.sum())
         self.stats["draft_tokens"] += int(n_win.sum()) * k
         self.stats["draft_accepted"] += int(n_acc.sum())
+        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             r.draft_proposed += int(n_win[s]) * k
@@ -3513,6 +3600,7 @@ class Engine:
                     break  # retired (EOS / budget / cancel) mid-replay
                 self._len[s] += 1
                 self._commit(int(s), int(out[s, j]), emitted)
+        self.obs.end(span)
 
     def _gather_tree_drafts(self, ms, active, shape):
         """Host-side TREE proposals behind the same fault-isolation
@@ -3607,13 +3695,16 @@ class Engine:
                 ms.cache, tokens, self._len, active, n_cand,
                 self._temps, self._topk, self._topp, self._keys,
                 ms.obs_counts, parents=shape.parents)
+        span = self.obs.begin("fetch")
         # tpudp: lint-ok(host-sync): the per-window verify fetch — the
         # tree twin of _run_verify's, one round trip per tree window.
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
+        self.obs.end(span)
         self.stats["tree_verify_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
         self.stats["draft_tokens"] += int(n_cand.sum())
+        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             accepted = int(n_emit[s]) - 1
@@ -3624,6 +3715,7 @@ class Engine:
                     break  # retired (EOS / budget / cancel) mid-window
                 self._len[s] += 1
                 self._commit(s, int(out[s, j]), emitted)
+        self.obs.end(span)
 
     def _commit(self, s: int, tok: int, emitted) -> None:
         r = self._slots[s]
@@ -3638,6 +3730,16 @@ class Engine:
         self._last[s] = tok
         emitted.append((r, tok))
         self.stats["tokens"] += 1
+        if len(r.tokens) == 1 and self.obs.enabled:
+            # The request's first token ever (a requeue or a preemption
+            # resume re-prefills with its tokens kept, so it never comes
+            # back here): where its time to first token went.
+            split = r.ttft_split()
+            if split is not None:  # None: adopted from another host
+                self.stats["first_tokens"] += 1
+                self.stats["ttft_s"] += split[0]
+                self.stats["ttft_queue_s"] += split[1]
+                self.stats["ttft_prefill_wait_s"] += split[2]
         if r.tenant is not None:
             self._sched.stats(r.tenant)["tokens"] += 1
         if r.eos_id is not None and tok == r.eos_id:

@@ -2,13 +2,11 @@
 
 The reference's entire observability story is ``time.time()`` brackets and
 ``print`` (SURVEY.md §5: no profiler, no traces).  tpudp keeps those
-parity metrics (tpudp/utils/timing.py, Trainer's window prints) and adds
+parity metrics (the Trainer's window prints) and adds
 the TPU-native layer the reference never had:
 
   * :func:`trace` — capture a real XLA/TPU profile (TensorBoard `trace
-    viewer` format) around any region, with per-step boundaries marked via
-    :class:`jax.profiler.StepTraceAnnotation` so the trace viewer groups
-    work by training step.
+    viewer` format) around any region.
   * :func:`measure_collective` — the north-star "grad all-reduce wall-time"
     metric (BASELINE.json:2): times a jitted shard_map psum over a pytree
     shaped exactly like the model's gradients, with
@@ -26,10 +24,10 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpudp.mesh import DATA_AXIS
-# trace/step_annotation moved to tpudp.obs (PR 11 folded the one-off
-# timing/tracing APIs under the telemetry package); re-exported here so
-# existing `from tpudp.utils.profiler import trace` imports keep working.
-from tpudp.obs.tracing import step_annotation, trace  # noqa: F401
+# trace moved to tpudp.obs (PR 11 folded the one-off timing/tracing APIs
+# under the telemetry package); re-exported here so existing
+# `from tpudp.utils.profiler import trace` imports keep working.
+from tpudp.obs.tracing import trace  # noqa: F401
 
 
 def measure_collective(
