@@ -55,11 +55,14 @@ def flash(q, k, v):
 
 ANC = tuple(map(tuple, np.tril(np.ones((5, 5), np.int32))))
 x = sds((2, 1024, H, DH), BF16)
+x8k = sds((1, 8192, H, DH), BF16)  # the chooser's blocks at a long sequence
 w = sds((SLOTS, 5, H, DH), BF16)
 CASES = {
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
         jnp.float32).sum(), argnums=(0, 1, 2)), (x, x, x)),
+    "flash_bwd_8k": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2)), (x8k, x8k, x8k)),
     "paged_decode": paged(1, SLOTS, (SLOTS,)),
     "paged_window_verify": paged(5, SLOTS, (SLOTS,)),
     "paged_window_prefill": paged(T, 1, ()),

@@ -32,7 +32,7 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1500:]
     ok = {line.split()[1] for line in proc.stdout.splitlines()
           if line.startswith("OK ")}
-    assert ok == {"flash_fwd", "flash_bwd", "paged_decode",
+    assert ok == {"flash_fwd", "flash_bwd", "flash_bwd_8k", "paged_decode",
                   "paged_window_verify", "paged_window_prefill",
                   "paged_tree"}, proc.stdout
 
@@ -40,6 +40,7 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
 @pytest.mark.parametrize("family, kernels", [
     ("flash_fwd", {"flash_fwd"}),
     ("flash_bwd", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    ("flash_bwd_8k", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     ("paged_decode", {"paged_decode"}),
     ("paged_window_verify", {"paged_prefill"}),
     ("paged_window_prefill", {"paged_prefill"}),
