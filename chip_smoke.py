@@ -19,6 +19,14 @@ from a seed, data generated from a seed — no network, no files read):
                   step with ``attn_impl='dense'`` and one with ``'flash'``;
                   the flash program must contain Mosaic custom calls, and
                   the two losses / gradient norms must agree.
+  train.lfm2      one LFM2-MoE train step at the published widths and a
+                  depth of two (a conv block with the dense SwiGLU, an
+                  attention block holding experts 0-7 of 32; t=1024) with
+                  ``moe_impl='dense'`` and one with ``'gmm'``; the gmm
+                  program must contain Mosaic custom calls (attention is
+                  dense in both, so they are the grouped-matmul kernels'),
+                  the two losses / gradient norms must agree, and the
+                  ``obs_moe`` counters must have counted every assignment.
   kernels         each Pallas paged-attention family the engine dispatches
                   (decode, window: prefill + verify, tree), called directly
                   with ``interpret=False`` at the engine's own geometry,
@@ -64,6 +72,14 @@ class Sizes:
     lm: dict = dataclasses.field(default_factory=dict)  # gpt2_small overrides
     lm_seq: int = 1024
     lm_batch_per_device: int = 2
+    # Lfm2Config overrides: the published widths at a depth of two, one
+    # block of each kind (conv + dense SwiGLU, attention + 8 of 32 experts)
+    lfm2: dict = dataclasses.field(default_factory=lambda: dict(
+        vocab_size=16384, hidden_size=2048, intermediate_size=7168,
+        moe_intermediate_size=1792, num_hidden_layers=2, num_dense_layers=1,
+        layer_types=("conv", "full_attention"), num_attention_heads=32,
+        num_key_value_heads=8, num_experts=8, num_experts_routed=32,
+        num_experts_per_tok=4))
     serve_slots: int = 4
     serve_max_len: int = 1024
     serve_chunk: int = 16         # Engine's default prefill_chunk
@@ -254,19 +270,23 @@ def phase_train_ladder(sizes: Sizes, workdir: str, rehearse: bool) -> str:
             f"verify_replicas=ok bytes_in_use={in_use}")
 
 
-def phase_train_gpt2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
-    """A transformer at published width: dense vs flash train step."""
+def _plain_vs_kernel_step(sizes: Sizes, rehearse: bool, vocab: int,
+                          make_model, plain: str, kernel: str,
+                          **state_kw) -> tuple[str, dict]:
+    """One train step of ``make_model(impl)`` for the plain-XLA ``impl``
+    and for the Pallas one, from the same state and batch: the plain
+    program holds no Mosaic call, the kernel's does (asserted from the
+    program that runs, not inferred from the backend's name), and loss and
+    gradient norm agree to the bf16 bound.  Returns the phase's detail
+    line and each impl's final state."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from tpudp.mesh import batch_sharding, make_mesh, replicated_sharding
-    from tpudp.models.gpt2 import gpt2_small
     from tpudp.train import init_state, make_optimizer, make_train_step
 
     mesh = make_mesh()
     batch = sizes.lm_batch_per_device * mesh.size
-    vocab = gpt2_small(**sizes.lm).config.vocab_size
     rng = np.random.default_rng(1)
     tokens = rng.integers(0, vocab, size=(batch, sizes.lm_seq)).astype(
         np.int32)
@@ -274,38 +294,75 @@ def phase_train_gpt2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
     y = jax.device_put(np.roll(tokens, -1, axis=1), batch_sharding(mesh))
     tx = make_optimizer(learning_rate=0.01)
 
-    out = {}
-    for impl in ("dense", "flash"):
-        model = gpt2_small(dtype=jnp.bfloat16, attn_impl=impl, **sizes.lm)
+    out, states = {}, {}
+    for impl in (plain, kernel):
+        model = make_model(impl)
         state = jax.device_put(
-            init_state(model, tx, input_shape=(1, 8), track_grad_norm=True),
+            init_state(model, tx, input_shape=(1, 8), track_grad_norm=True,
+                       **state_kw),
             replicated_sharding(mesh))
         step = make_train_step(model, tx, mesh, donate=False)
         lowered = step.lower(state, x, y)
         mosaic = "tpu_custom_call" in lowered.as_text()
-        if impl == "dense":
-            _check(not mosaic, "dense step unexpectedly holds a Mosaic call")
+        if impl == plain:
+            _check(not mosaic,
+                   f"{plain} step unexpectedly holds a Mosaic call")
         elif not rehearse:
-            # Asserted from the program that runs, not inferred from the
-            # backend's name: the flash fwd+bwd kernels are Mosaic calls.
-            _check(mosaic, "flash step holds no Mosaic custom call — the "
-                           "kernel was interpreted or replaced by dense")
-        new_state, loss = lowered.compile()(state, x, y)
-        jax.block_until_ready(new_state)
+            _check(mosaic, f"{kernel} step holds no Mosaic custom call — "
+                           "the kernel was interpreted or replaced")
+        states[impl], loss = lowered.compile()(state, x, y)
+        jax.block_until_ready(states[impl])
         # obs_norms = [sum|g|, sum|g|^2] accumulated inside the step
-        gnorm = float(np.sqrt(np.asarray(new_state.obs_norms)[1]))
+        gnorm = float(np.sqrt(np.asarray(states[impl].obs_norms)[1]))
         out[impl] = (float(loss), gnorm)
         _check(np.isfinite(out[impl]).all(), f"{impl}: {out[impl]}")
-        del state, new_state, lowered
-    (ld, gd), (lf, gf) = out["dense"], out["flash"]
-    _check(abs(lf - ld) <= FLASH_RTOL_BF16 * abs(ld),
-           f"flash loss {lf} vs dense {ld}")
-    _check(abs(gf - gd) <= FLASH_RTOL_BF16 * abs(gd),
-           f"flash grad norm {gf} vs dense {gd}")
+        del state, lowered
+    (lp, gp), (lk, gk) = out[plain], out[kernel]
+    _check(abs(lk - lp) <= FLASH_RTOL_BF16 * abs(lp),
+           f"{kernel} loss {lk} vs {plain} {lp}")
+    _check(abs(gk - gp) <= FLASH_RTOL_BF16 * abs(gp),
+           f"{kernel} grad norm {gk} vs {plain} {gp}")
     return (f"mesh={mesh.size} batch={batch} t={sizes.lm_seq} "
-            f"loss dense={ld:.5f} flash={lf:.5f} "
-            f"grad_norm dense={gd:.5f} flash={gf:.5f} "
-            f"flash_mosaic={'asserted' if not rehearse else 'interpreted'}")
+            f"loss {plain}={lp:.5f} {kernel}={lk:.5f} "
+            f"grad_norm {plain}={gp:.5f} {kernel}={gk:.5f} "
+            f"{kernel}_mosaic={'asserted' if not rehearse else 'interpreted'}",
+            states)
+
+
+def phase_train_gpt2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
+    """A transformer at published width: dense vs flash train step."""
+    import jax.numpy as jnp
+
+    from tpudp.models.gpt2 import gpt2_small
+
+    detail, _ = _plain_vs_kernel_step(
+        sizes, rehearse, gpt2_small(**sizes.lm).config.vocab_size,
+        lambda impl: gpt2_small(dtype=jnp.bfloat16, attn_impl=impl,
+                                **sizes.lm), "dense", "flash")
+    return detail
+
+
+def phase_train_lfm2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
+    """Two kinds of block and a share of a routed expert layer: the
+    train step with the expert layer as a plain loop vs as grouped-matmul
+    kernels (attention dense in both, so the only Mosaic calls are theirs)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpudp.models.lfm2 import Lfm2, Lfm2Config
+
+    detail, states = _plain_vs_kernel_step(
+        sizes, rehearse, sizes.lfm2["vocab_size"],
+        lambda impl: Lfm2(Lfm2Config(dtype=jnp.bfloat16, moe_impl=impl,
+                                     **sizes.lfm2)),
+        "dense", "gmm", track_moe=True)
+    for impl, state in states.items():
+        total, held = (float(v) for v in np.asarray(state.obs_moe)[:2])
+        _check(total == state.loss_sum.sharding.mesh.size
+               * sizes.lm_batch_per_device * sizes.lm_seq
+               * sizes.lfm2["num_experts_per_tok"] and 0 < held < total,
+               f"{impl}: obs_moe {np.asarray(state.obs_moe)}")
+    return f"{detail} held_share={held / total:.4f}"
 
 
 # ---------------------------------------------------------------- kernels
@@ -532,6 +589,7 @@ def phase_serve(sizes: Sizes, workdir: str, rehearse: bool) -> str:
 PHASES = (("train.vgg", phase_train_vgg),
           ("train.ladder", phase_train_ladder),
           ("train.gpt2", phase_train_gpt2),
+          ("train.lfm2", phase_train_lfm2),
           ("kernels", phase_kernels),
           ("serve", phase_serve))
 

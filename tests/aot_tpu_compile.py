@@ -26,6 +26,7 @@ except Exception as exc:  # noqa: BLE001 — no libtpu / no topology support
     sys.exit(77)
 
 from tpudp.ops.flash_attention import flash_attention  # noqa: E402
+from tpudp.ops.grouped_matmul import gmm  # noqa: E402
 from tpudp.ops.paged_attention import (paged_attention,  # noqa: E402
                                        tree_paged_attention)
 
@@ -53,11 +54,69 @@ def flash(q, k, v):
     return flash_attention(q, k, v, causal=True, interpret=False)
 
 
+def gmm_grads(x, w, sizes):
+    """One grouped product and its VJP: ``moe_gmm`` forward and for the
+    data gradient (transposed rhs), ``moe_tgmm`` for the weights'."""
+    return jax.grad(lambda a, b: gmm(a, b, sizes, interpret=False).astype(
+        jnp.float32).sum(), argnums=(0, 1))(x, w)
+
+
+def lfm2_step():
+    """The LFM2-MoE train step (flash attention, grouped-matmul experts,
+    remat) at the depth and pattern of the benchmark's cell and small
+    widths; the program's own CPU-means-interpret default is steered from
+    here, as the on-chip-measurement guide asks of a test."""
+    from tpudp.models.lfm2 import Lfm2, Lfm2Config
+    from tpudp.train import TrainState, make_optimizer, make_train_step
+
+    # by sys.modules: tpudp.ops re-exports the FUNCTION flash_attention
+    # under the module's own name
+    for name in ("tpudp.ops.flash_attention", "tpudp.ops.grouped_matmul"):
+        sys.modules[name]._interpret_default = lambda: False
+    model = Lfm2(Lfm2Config(
+        vocab_size=256, hidden_size=128, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+        num_attention_heads=2, num_key_value_heads=1, num_experts=2,
+        num_experts_routed=8, num_experts_per_tok=4, attn_impl="flash",
+        moe_impl="gmm", remat=True, dtype=BF16))
+    tx = make_optimizer(learning_rate=3e-4, weight_decay=0.0,
+                        optimizer="adamw")
+
+    def make_state(key):
+        params = model.init(key, jnp.zeros((1, 16), jnp.int32))["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          batch_stats={}, opt_state=tx.init(params),
+                          loss_sum=jnp.zeros((), jnp.float32))
+
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:1]), ("data",))
+    spec = jax.sharding.PartitionSpec
+    rep = jax.sharding.NamedSharding(mesh, spec())
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(make_state, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct(
+        (2, 128), jnp.int32,
+        sharding=jax.sharding.NamedSharding(mesh, spec("data")))
+    return make_train_step(model, tx, mesh, "allreduce"), (state, tokens,
+                                                           tokens)
+
+
 ANC = tuple(map(tuple, np.tril(np.ones((5, 5), np.int32))))
 x = sds((2, 1024, H, DH), BF16)
 x8k = sds((1, 8192, H, DH), BF16)  # the chooser's blocks at a long sequence
 w = sds((SLOTS, 5, H, DH), BF16)
+# the LFM2 expert layer at the benchmark cell's shapes: 4 x 8,192 tokens x
+# top-4 rows, 8 held experts of 2,048 x 1,792 (up) and 1,792 x 2,048 (down)
+ROWS, D, F, G = 131072, 2048, 1792, 8
 CASES = {
+    "moe_gmm_up": (gmm_grads, (sds((ROWS, D), BF16),
+                               sds((G, D, F), jnp.float32),
+                               sds((G,), jnp.int32))),
+    "moe_gmm_down": (gmm_grads, (sds((ROWS, F), BF16),
+                                 sds((G, F, D), jnp.float32),
+                                 sds((G,), jnp.int32))),
+    "lfm2_train_step": lfm2_step(),
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
         jnp.float32).sum(), argnums=(0, 1, 2)), (x, x, x)),
@@ -80,14 +139,14 @@ CASES = {
 # HLO instruction itself is then ``flash_fwd.<n>``, which is what the
 # benchmark's kernel_ms.* readers match in a device trace).
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
-           "paged_prefill", "paged_tree")
+           "paged_prefill", "paged_tree", "moe_gmm", "moe_tgmm")
 MOSAIC_OP = re.compile(
     r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
 
 failed = []
 for name, (fn, args) in CASES.items():
     try:
-        lowered = jax.jit(fn).lower(*args)
+        lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
         assert "tpu_custom_call" in lowered.as_text(), "no Mosaic call"
         ops = MOSAIC_OP.findall(lowered.compile().as_text())
         named = sorted({k for k in KERNELS for op in ops

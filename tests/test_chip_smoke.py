@@ -26,6 +26,13 @@ def test_phases_rehearse_on_cpu(tmp_path, monkeypatch, capsys):
         lm=dict(vocab_size=64, max_seq_len=128, num_layers=1, num_heads=2,
                 d_model=32),
         lm_seq=128,  # a multiple of 128: the flash kernel really runs
+        lfm2=dict(vocab_size=64, hidden_size=128, intermediate_size=256,
+                  moe_intermediate_size=128, num_hidden_layers=2,
+                  num_dense_layers=1,
+                  layer_types=("conv", "full_attention"),
+                  num_attention_heads=2, num_key_value_heads=1,
+                  num_experts=2, num_experts_routed=8,
+                  num_experts_per_tok=4),
         lm_batch_per_device=1, serve_slots=2, serve_max_len=64,
         serve_chunk=8, prompt_lens=(5, 8, 19), max_new=6)
     results = chip_smoke.run_phases(tiny, str(tmp_path), rehearse=True)
@@ -39,6 +46,7 @@ def test_phases_rehearse_on_cpu(tmp_path, monkeypatch, capsys):
     by = {r["phase"]: r for r in results}
     assert "data_backend=" in by["train.vgg"]["detail"]
     assert "mesh=8" in by["train.ladder"]["detail"]
+    assert "gmm_mosaic=interpreted" in by["train.lfm2"]["detail"]
     assert "interpret=True" in by["kernels"]["detail"]
     assert "fallbacks=[]" in by["serve"]["detail"]
     for r in results:  # compile time is reported apart from run time

@@ -1,7 +1,9 @@
 """Every Pallas kernel family must compile under Mosaic for the v5e — checked
 WITHOUT a chip: libtpu can compile for a TPU topology description on a CPU
 host (tests/aot_tpu_compile.py).  This is a compile check only (lowering,
-Mosaic passes, VMEM fit at GPT-2-small geometry); that the compiled kernels
+Mosaic passes, VMEM fit at GPT-2-small geometry and, for the grouped
+matmuls, at the LFM2 expert layer's real shapes; one whole LFM2-MoE train
+step at small widths); that the compiled kernels
 compute the right numbers is `chip_smoke.py`'s job on the chip."""
 
 import os
@@ -34,7 +36,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
           if line.startswith("OK ")}
     assert ok == {"flash_fwd", "flash_bwd", "flash_bwd_8k", "paged_decode",
                   "paged_window_verify", "paged_window_prefill",
-                  "paged_tree"}, proc.stdout
+                  "paged_tree", "moe_gmm_up", "moe_gmm_down",
+                  "lfm2_train_step"}, proc.stdout
 
 
 @pytest.mark.parametrize("family, kernels", [
@@ -44,7 +47,11 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("paged_decode", {"paged_decode"}),
     ("paged_window_verify", {"paged_prefill"}),
     ("paged_window_prefill", {"paged_prefill"}),
-    ("paged_tree", {"paged_tree"})])
+    ("paged_tree", {"paged_tree"}),
+    ("moe_gmm_up", {"moe_gmm", "moe_tgmm"}),
+    ("moe_gmm_down", {"moe_gmm", "moe_tgmm"}),
+    ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                         "moe_gmm", "moe_tgmm"})])
 def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
     program's Mosaic custom calls have their ``pallas_call``'s ``name=``

@@ -1,4 +1,7 @@
-"""Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-Experts MLPs: :class:`MoeMlp` (capacity-based, with its
+all-to-all over an ``expert`` axis; described first) and
+:class:`DroplessMoe` (sort-based, no capacity, holds a stated share of the
+routed experts; at the end of the file).
 
 Beyond-parity capability (SURVEY.md §2.2 lists EP/MoE as absent from the
 reference).  Switch-Transformer-style top-1 routing by default, general
@@ -27,12 +30,14 @@ fraction and the Switch aux loss ``E * sum(f_e * P_e)``) are sown into the
 
 from __future__ import annotations
 
+import functools
 import math
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from tpudp.mesh import axis_is_bound as _axis_is_bound
 
@@ -161,3 +166,184 @@ class MoeMlp(nn.Module):
         y = jnp.einsum("ecd,tec->td", expert_outputs.astype(jnp.float32),
                        combine)
         return y.astype(self.dtype).reshape(orig_shape)
+
+
+# ------------------------------------------------------ dropless experts
+
+
+def collect_moe_counts(intermediates) -> tuple:
+    """Every ``moe_counts`` vector sown into an ``intermediates``
+    collection, in layer order (``()`` when none): per expert layer
+    ``[assignments, rows computed, load of held expert 0, 1, ...]``, what
+    ``TrainState.obs_moe`` is advanced from (tpudp.train)."""
+    return tuple(v for path, v in
+                 jax.tree_util.tree_flatten_with_path(intermediates)[0]
+                 if "moe_counts" in jax.tree_util.keystr(path))
+
+
+_SCORE_FNS = {"sigmoid": jax.nn.sigmoid}
+ROUTE_NAME = "moe_route"  # the expert choice, for jax.checkpoint policies
+
+
+def _sum_by_token(rows, slot_of, k):
+    """Token ``t``'s ``k`` assignments sit at rows ``slot_of[t*k:(t+1)*k]``:
+    gather them and add (float32), ``(M, d) -> (M // k, d)``."""
+    mine = rows[slot_of].reshape(-1, k, rows.shape[-1])
+    return mine.astype(jnp.float32).sum(axis=1).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_by_expert(x, token_of, slot_of, k):
+    """``x[token_of]``: each token's row once per assignment, in sorted
+    order.  Its transpose sums a token's ``k`` rows back, and is written
+    as the gather by ``slot_of`` that it is (:func:`_sum_by_token`), so
+    neither direction is a scatter-add."""
+    return x[token_of]
+
+
+def _rows_by_expert_fwd(x, token_of, slot_of, k):
+    return x[token_of], (token_of, slot_of)
+
+
+def _rows_by_expert_bwd(k, res, d_rows):
+    return _sum_by_token(d_rows, res[1], k), None, None
+
+
+_rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_tokens(rows, token_of, slot_of, k):
+    """The transpose of :func:`_rows_by_expert`."""
+    return _sum_by_token(rows, slot_of, k)
+
+
+def _rows_to_tokens_fwd(rows, token_of, slot_of, k):
+    return _sum_by_token(rows, slot_of, k), (token_of, slot_of)
+
+
+def _rows_to_tokens_bwd(k, res, d_tokens):
+    return d_tokens[res[0]], None, None
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+class DroplessMoe(nn.Module):
+    """One chip's share of a routed SwiGLU expert layer, no token dropped:
+    ``(..., d) -> (..., d)``.
+
+    The router scores all ``num_experts_routed`` experts (``score_fn`` of
+    its float32 logits), selects ``top_k`` by score plus an optional
+    ``expert_bias`` (a parameter that takes no gradient: load is balanced
+    by moving it, not by a loss, so nothing is sown as ``moe_aux``), and
+    weights the chosen experts by their unbiased scores, normalised over
+    the choice when ``normalize`` and times ``scaling``.  This module HOLDS
+    the ``num_experts`` experts from ``first_expert`` on and returns their
+    part of the result, ``sum_j w_j * expert_j(x)`` over the chosen experts
+    it holds; what the others would add is left out (they live on other
+    chips; summed over all the shares the parts give the whole layer,
+    tests/test_lfm2.py::test_expert_shares_sum_to_the_whole_layer).
+    ``num_experts_routed=None`` holds them all.  No exchange runs here and
+    no mesh axis is read: on one chip the share is the layer.
+
+    ``impl='gmm'`` is the sort-based dropless dispatch: the ``T x k``
+    assignments are ordered by held-expert id with every assignment to an
+    absent expert in one trailing group, the tokens' rows are gathered in
+    that order into a ``(T x k, d)`` buffer (the worst case, so nothing
+    can overflow), the three products run as grouped matmuls
+    (tpudp.ops.grouped_matmul: tiles past the held experts' rows are
+    skipped), and each token sums its assignments' weighted rows back.
+    ``impl='dense'`` is a masked loop over the held experts in plain XLA,
+    every expert over every token.  The layer takes it by itself, silently,
+    where Mosaic cannot tile the shape (``grouped_matmul.supported``: the
+    16-token init trace, tiny CPU sizes), as ops/attention.py falls back
+    from flash; the option exists only so that tests and ``chip_smoke.py``
+    can run the loop at a shape the kernels take, as their reference.
+    """
+
+    num_experts: int  # held here
+    hidden: int
+    top_k: int
+    num_experts_routed: int | None = None
+    first_expert: int = 0
+    score_fn: str = "sigmoid"
+    selection_bias: bool = False
+    normalize: bool = True
+    scaling: float = 1.0
+    impl: str = "gmm"  # 'gmm' | 'dense'
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        d, f, g, k = x.shape[-1], self.hidden, self.num_experts, self.top_k
+        e = self.num_experts_routed or g
+        if not (0 <= self.first_expert and self.first_expert + g <= e
+                and 1 <= k <= e):
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + g - 1} "
+                f"and top_k={k} do not fit {e} routed experts")
+        if self.impl not in ("gmm", "dense"):
+            raise ValueError(f"unknown moe impl {self.impl!r}; choose from "
+                             "'gmm', 'dense'")
+        xt = x.reshape(-1, d)
+        t = xt.shape[0]
+        stacked = nn.initializers.lecun_normal(batch_axis=(0,))
+        gate = self.param("gate", nn.initializers.lecun_normal(), (d, e),
+                          jnp.float32)
+        w1 = self.param("w1", stacked, (g, d, f), jnp.float32)
+        w3 = self.param("w3", stacked, (g, d, f), jnp.float32)
+        w2 = self.param("w2", stacked, (g, f, d), jnp.float32)
+
+        # --- route over all the experts, in float32 at full precision: a
+        # bf16 pass would move scores by more than neighbours differ
+        scores = _SCORE_FNS[self.score_fn](jnp.dot(
+            xt.astype(jnp.float32), gate, precision=lax.Precision.HIGHEST))
+        biased = scores
+        if self.selection_bias:
+            biased = scores + lax.stop_gradient(self.param(
+                "expert_bias", nn.initializers.zeros, (e,), jnp.float32))
+        _, chosen = lax.top_k(biased, k)  # (T, k) routed-expert ids
+        # A block under remat recomputes its forward pass inside another
+        # XLA program, whose rounding can break a near tie the other way:
+        # the backward pass would then run other experts than the forward
+        # pass did.  Named, so that a remat policy keeps the choice
+        # (models/lfm2.py: ROUTE_SAVED).
+        chosen = checkpoint_name(chosen, ROUTE_NAME)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.normalize:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+        weights = weights * self.scaling
+        local = chosen - self.first_expert
+        local = jnp.where((local >= 0) & (local < g), local, g)  # g: absent
+        loads = jnp.sum(local[..., None] == jnp.arange(g), axis=(0, 1))
+        self.sow("intermediates", "moe_chosen", chosen)
+
+        from tpudp.ops import grouped_matmul as gm
+
+        xb = xt.astype(self.dtype)
+        if self.impl == "gmm" and gm.supported(t * k, d, f):
+            # --- sort the assignments by held expert, absent ones last
+            flat = local.reshape(-1)
+            order = jnp.argsort(flat, stable=True)  # row -> assignment
+            slot_of = jnp.argsort(order)  # assignment -> row
+            token_of = order // k
+            rows = _rows_by_expert(xb, token_of, slot_of, k)
+            hdn = nn.silu(gm.gmm(rows, w1, loads)) * gm.gmm(rows, w3, loads)
+            out = gm.gmm(hdn, w2, loads)  # zeros from the absent group on
+            out = (out.astype(jnp.float32)
+                   * weights.reshape(-1)[order][:, None]).astype(self.dtype)
+            y = _rows_to_tokens(out, token_of, slot_of, k)
+            computed = gm.visited_rows(loads, t * k)
+        else:
+            y = jnp.zeros((t, d), jnp.float32)
+            for j in range(g):
+                w_j = jnp.sum(jnp.where(local == j, weights, 0.0), axis=-1)
+                hdn = nn.silu(xb @ w1[j].astype(self.dtype)) \
+                    * (xb @ w3[j].astype(self.dtype))
+                y = y + w_j[:, None] * (hdn @ w2[j].astype(self.dtype))
+            computed = g * t
+        self.sow("intermediates", "moe_counts", jnp.concatenate([
+            jnp.asarray([t * k, computed], jnp.float32),
+            loads.astype(jnp.float32)]))
+        return y.astype(self.dtype).reshape(x.shape)

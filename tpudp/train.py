@@ -81,7 +81,16 @@ class TrainState(struct.PyTreeNode):
     bit-identical bytes, so their fingerprints agree bit-for-bit; the
     resilience layer fetches it only at the window-edge seam where the
     host already synchronizes for ``loss_sum`` and majority-votes it
-    across replicas (``ResiliencePolicy(sdc_check_every=N)``)."""
+    across replicas (``ResiliencePolicy(sdc_check_every=N)``).
+
+    ``obs_moe`` is the expert layers' rider (``init_state(track_moe=True)``
+    / ``Trainer(track_moe=True)``): a ``(4,)`` float32 accumulator of
+    ``[assignments, assignments to held experts, largest held-expert
+    load x experts held, rows computed]``, each summed over the expert
+    layers and the steps, advanced inside the jitted step from the
+    ``moe_counts`` that ``tpudp.models.moe.DroplessMoe`` sows (loads are
+    summed over the data axis before the largest is taken) and read only
+    by ``Trainer.metrics()`` (:func:`moe_metrics`)."""
 
     step: jnp.ndarray
     params: Any
@@ -90,6 +99,22 @@ class TrainState(struct.PyTreeNode):
     loss_sum: jnp.ndarray
     obs_norms: Any = None
     sdc_fp: Any = None
+    obs_moe: Any = None
+
+
+def moe_metrics(obs_moe) -> dict:
+    """``TrainState.obs_moe`` as ratios: the share of routing assignments
+    that went to experts held here, the largest held expert's load over
+    the mean held load (1.0 = even), and the rows the grouped products
+    ran over for each assignment they had to (1.0 = no tile wasted on a
+    group boundary)."""
+    total, held, as_if_largest, rows = (float(x) for x in
+                                        np.asarray(obs_moe))
+    if not total or not held:
+        return {}
+    return {"moe_held_share": held / total,
+            "moe_load_max_over_mean": as_if_largest / held,
+            "moe_rows_over_held": rows / held}
 
 
 def make_optimizer(
@@ -221,12 +246,14 @@ def init_state(
     input_dtype=None,
     track_grad_norm: bool = False,
     track_sdc: bool = False,
+    track_moe: bool = False,
 ) -> TrainState:
     """Initialize params/batch_stats/optimizer state (reference seeds both
     RNGs with 0: ``src/Part 2a/main.py:20-21``).  ``input_dtype`` defaults to
     float32 for image-shaped (>2-D) inputs and int32 for 2-D token inputs.
     ``track_grad_norm`` allocates the ``obs_norms`` device accumulator
-    and ``track_sdc`` the ``sdc_fp`` in-step fingerprint slot (see
+    and ``track_sdc`` the ``sdc_fp`` in-step fingerprint slot,
+    ``track_moe`` the ``obs_moe`` expert-layer counters (see
     :class:`TrainState`); off — the default — adds no leaf."""
     if input_dtype is None:
         input_dtype = jnp.float32 if len(input_shape) > 2 else jnp.int32
@@ -243,6 +270,7 @@ def init_state(
         obs_norms=(jnp.zeros((2,), jnp.float32) if track_grad_norm
                    else None),
         sdc_fp=(jnp.zeros((2,), jnp.uint32) if track_sdc else None),
+        obs_moe=jnp.zeros((4,), jnp.float32) if track_moe else None,
     )
 
 
@@ -314,10 +342,16 @@ def _loss_and_updates(model, tx, state: TrainState, images, labels, sync_fn,
 
             loss = ce + aux_loss_coef * collect_moe_aux(
                 mutated.get("intermediates", {}))
-        return loss, (new_bs, ce)
+        moe_counts = ()
+        if state.obs_moe is not None:  # pytree structure: static
+            from tpudp.models.moe import collect_moe_counts
+
+            moe_counts = collect_moe_counts(mutated.get("intermediates", {}))
+        return loss, (new_bs, ce, moe_counts)
 
     if grad_accum == 1:
-        (_, (new_bs, loss)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (_, (new_bs, loss, moe_counts)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(
             state.params, state.batch_stats, images, labels)
     else:
         x_mb = images.reshape(grad_accum, -1, *images.shape[1:])
@@ -326,15 +360,16 @@ def _loss_and_updates(model, tx, state: TrainState, images, labels, sync_fn,
         def micro(carry, xy):
             g_acc, l_acc, bs = carry
             x, y = xy
-            (_, (bs, l)), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                state.params, bs, x, y)
+            (_, (bs, l, counts)), g = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params, bs, x, y)
             g_acc = jax.tree.map(lambda a, b: a + b, g_acc, g)
-            return (g_acc, l_acc + l, bs), None
+            return (g_acc, l_acc + l, bs), counts
 
         zeros = jax.tree.map(jnp.zeros_like, state.params)
-        (grads, loss, new_bs), _ = lax.scan(
+        (grads, loss, new_bs), moe_counts = lax.scan(
             micro, (zeros, jnp.zeros((), jnp.float32), state.batch_stats),
             (x_mb, y_mb))
+        moe_counts = jax.tree.map(lambda c: c.sum(axis=0), moe_counts)
         grads = jax.tree.map(lambda g: g / grad_accum, grads)
         loss = loss / grad_accum
     if axis_name is not None:
@@ -352,6 +387,18 @@ def _loss_and_updates(model, tx, state: TrainState, images, labels, sync_fn,
     if new_norms is not None:
         gn = optax.global_norm(grads)
         new_norms = new_norms + jnp.stack([gn, gn * gn])
+    # Expert-layer counters, the same piggyback: per layer [assignments,
+    # rows computed, load of each held expert]; loads add over the data
+    # axis before the largest is taken.
+    new_moe = state.obs_moe
+    if new_moe is not None:
+        for counts in moe_counts:
+            if axis_name is not None:
+                counts = lax.psum(counts, axis_name)
+            loads = counts[2:]
+            new_moe = new_moe + jnp.stack([
+                counts[0], loads.sum(), loads.max() * loads.shape[0],
+                counts[1]])
     updates, new_opt = tx.update(grads, state.opt_state, state.params)
     new_params = optax.apply_updates(state.params, updates)
     # In-step SDC fingerprint (tpudp.sdc): exact u32 checksum of the
@@ -375,6 +422,7 @@ def _loss_and_updates(model, tx, state: TrainState, images, labels, sync_fn,
             loss_sum=state.loss_sum + loss,
             obs_norms=new_norms,
             sdc_fp=new_fp,
+            obs_moe=new_moe,
         ),
         loss,
     )
@@ -818,6 +866,7 @@ class Trainer:
         step_fault_hook: Callable[[str, int], None] | None = None,
         track_grad_norm: bool = False,
         track_sdc_fingerprint: bool = False,
+        track_moe: bool = False,
         sdc_fault_hook: Callable[[TrainState], TrainState] | None = None,
         flight_dir: str | None = None,
     ):
@@ -885,7 +934,8 @@ class Trainer:
         self.state = init_state(model, self.tx, input_shape=input_shape,
                                 seed=seed,
                                 track_grad_norm=track_grad_norm,
-                                track_sdc=track_sdc_fingerprint)
+                                track_sdc=track_sdc_fingerprint,
+                                track_moe=track_moe)
         self.timing_mode = timing_mode
         self.log_every = log_every
         self.log = log_fn
@@ -1041,6 +1091,8 @@ class Trainer:
             if state.obs_norms is not None:
                 s, s2 = (float(x) for x in np.asarray(state.obs_norms))
                 snap["norms"] = (s, s2)
+            if state.obs_moe is not None:
+                snap["moe"] = np.asarray(state.obs_moe)
             self._metrics_snapshot = snap
         except Exception:  # donated mid-step; serve the last snapshot
             snap = self._metrics_snapshot
@@ -1061,6 +1113,8 @@ class Trainer:
             s, s2 = snap["norms"]
             out["grad_norm_mean"] = s / step
             out["grad_norm_rms"] = float(np.sqrt(max(s2 / step, 0.0)))
+        if "moe" in snap:
+            out.update(moe_metrics(snap["moe"]))
         return out
 
     def train_epoch(self, loader, epoch: int = 0, *,
