@@ -23,8 +23,9 @@ from a seed, data generated from a seed — no network, no files read):
                   depth of two (a conv block with the dense SwiGLU, an
                   attention block holding experts 0-7 of 32; t=1024) with
                   ``moe_impl='dense'`` and one with ``'gmm'``; the gmm
-                  program must contain Mosaic custom calls (attention is
-                  dense in both, so they are the grouped-matmul kernels'),
+                  program must contain a Mosaic custom call under each of
+                  the expert layer's kernel names (attention is dense in
+                  both, so all of them are the layer's),
                   the two losses / gradient norms must agree, and the
                   ``obs_moe`` counters must have counted every assignment.
   kernels         each Pallas paged-attention family the engine dispatches
@@ -272,13 +273,14 @@ def phase_train_ladder(sizes: Sizes, workdir: str, rehearse: bool) -> str:
 
 def _plain_vs_kernel_step(sizes: Sizes, rehearse: bool, vocab: int,
                           make_model, plain: str, kernel: str,
+                          kernels: tuple = (),
                           **state_kw) -> tuple[str, dict]:
     """One train step of ``make_model(impl)`` for the plain-XLA ``impl``
     and for the Pallas one, from the same state and batch: the plain
-    program holds no Mosaic call, the kernel's does (asserted from the
-    program that runs, not inferred from the backend's name), and loss and
-    gradient norm agree to the bf16 bound.  Returns the phase's detail
-    line and each impl's final state."""
+    program holds no Mosaic call, the kernel's does, one under each name
+    of ``kernels`` (asserted from the program that runs, not inferred from
+    the backend's name), and loss and gradient norm agree to the bf16
+    bound.  Returns the phase's detail line and each impl's final state."""
     import jax
     import numpy as np
 
@@ -303,20 +305,25 @@ def _plain_vs_kernel_step(sizes: Sizes, rehearse: bool, vocab: int,
             replicated_sharding(mesh))
         step = make_train_step(model, tx, mesh, donate=False)
         lowered = step.lower(state, x, y)
-        mosaic = "tpu_custom_call" in lowered.as_text()
+        text = lowered.as_text()
+        mosaic = "tpu_custom_call" in text
         if impl == plain:
             _check(not mosaic,
                    f"{plain} step unexpectedly holds a Mosaic call")
         elif not rehearse:
             _check(mosaic, f"{kernel} step holds no Mosaic custom call — "
                            "the kernel was interpreted or replaced")
+            missing = [k for k in kernels
+                       if f'kernel_name = "{k}"' not in text]
+            _check(not missing, f"{kernel} step: no Mosaic call named "
+                                f"{missing}")
         states[impl], loss = lowered.compile()(state, x, y)
         jax.block_until_ready(states[impl])
         # obs_norms = [sum|g|, sum|g|^2] accumulated inside the step
         gnorm = float(np.sqrt(np.asarray(states[impl].obs_norms)[1]))
         out[impl] = (float(loss), gnorm)
         _check(np.isfinite(out[impl]).all(), f"{impl}: {out[impl]}")
-        del state, lowered
+        del state, lowered, text
     (lp, gp), (lk, gk) = out[plain], out[kernel]
     _check(abs(lk - lp) <= FLASH_RTOL_BF16 * abs(lp),
            f"{kernel} loss {lk} vs {plain} {lp}")
@@ -342,6 +349,11 @@ def phase_train_gpt2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
     return detail
 
 
+# every Pallas kernel of the expert layer, forward and backward
+MOE_KERNELS = ("moe_gmm", "moe_tgmm", "moe_swiglu", "moe_swiglu_bwd",
+               "moe_combine")
+
+
 def phase_train_lfm2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
     """Two kinds of block and a share of a routed expert layer: the
     train step with the expert layer as a plain loop vs as grouped-matmul
@@ -355,7 +367,7 @@ def phase_train_lfm2(sizes: Sizes, workdir: str, rehearse: bool) -> str:
         sizes, rehearse, sizes.lfm2["vocab_size"],
         lambda impl: Lfm2(Lfm2Config(dtype=jnp.bfloat16, moe_impl=impl,
                                      **sizes.lfm2)),
-        "dense", "gmm", track_moe=True)
+        "dense", "gmm", kernels=MOE_KERNELS, track_moe=True)
     for impl, state in states.items():
         total, held = (float(v) for v in np.asarray(state.obs_moe)[:2])
         _check(total == state.loss_sum.sharding.mesh.size

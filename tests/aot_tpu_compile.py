@@ -61,6 +61,27 @@ def gmm_grads(x, w, sizes):
         jnp.float32).sum(), argnums=(0, 1))(x, w)
 
 
+def moe_layer():
+    """One chip's share of the LFM2 expert layer at the benchmark cell's
+    shapes, forward and backward: the grouped products and every row
+    kernel between the sort and the combine (tpudp/ops/expert_rows.py)."""
+    from tpudp.models.moe import DroplessMoe
+
+    sys.modules["tpudp.ops.grouped_matmul"]._interpret_default = (
+        lambda: False)
+    layer = DroplessMoe(num_experts=G, hidden=F, top_k=4,
+                        num_experts_routed=32, selection_bias=True,
+                        dtype=BF16)
+    params = {"gate": sds((D, 32), jnp.float32),
+              "expert_bias": sds((32,), jnp.float32),
+              "w1": sds((G, D, F), jnp.float32),
+              "w3": sds((G, D, F), jnp.float32),
+              "w2": sds((G, F, D), jnp.float32)}
+    return (jax.grad(lambda p, x: layer.apply({"params": p}, x).astype(
+        jnp.float32).sum(), argnums=(0, 1)),
+        (params, sds((ROWS // 4, D), BF16)))
+
+
 def lfm2_step():
     """The LFM2-MoE train step (flash attention, grouped-matmul experts,
     remat) at the depth and pattern of the benchmark's cell and small
@@ -116,6 +137,7 @@ CASES = {
     "moe_gmm_down": (gmm_grads, (sds((ROWS, F), BF16),
                                  sds((G, F, D), jnp.float32),
                                  sds((G,), jnp.int32))),
+    "moe_layer": moe_layer(),
     "lfm2_train_step": lfm2_step(),
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
@@ -139,7 +161,8 @@ CASES = {
 # HLO instruction itself is then ``flash_fwd.<n>``, which is what the
 # benchmark's kernel_ms.* readers match in a device trace).
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
-           "paged_prefill", "paged_tree", "moe_gmm", "moe_tgmm")
+           "paged_prefill", "paged_tree", "moe_gmm", "moe_tgmm",
+           "moe_swiglu", "moe_swiglu_bwd", "moe_combine", "moe_unwritten")
 MOSAIC_OP = re.compile(
     r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
 

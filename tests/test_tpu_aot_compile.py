@@ -2,8 +2,9 @@
 WITHOUT a chip: libtpu can compile for a TPU topology description on a CPU
 host (tests/aot_tpu_compile.py).  This is a compile check only (lowering,
 Mosaic passes, VMEM fit at GPT-2-small geometry and, for the grouped
-matmuls, at the LFM2 expert layer's real shapes; one whole LFM2-MoE train
-step at small widths); that the compiled kernels
+matmuls and the whole expert layer with its row kernels, at the LFM2
+layer's real shapes; one whole LFM2-MoE train step at small widths); that
+the compiled kernels
 compute the right numbers is `chip_smoke.py`'s job on the chip."""
 
 import os
@@ -13,6 +14,11 @@ import sys
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+# every Mosaic call of the expert layer, forward and backward
+MOE_LAYER = {"moe_gmm", "moe_tgmm", "moe_swiglu", "moe_swiglu_bwd",
+             "moe_combine", "moe_unwritten"}
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +42,7 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
           if line.startswith("OK ")}
     assert ok == {"flash_fwd", "flash_bwd", "flash_bwd_8k", "paged_decode",
                   "paged_window_verify", "paged_window_prefill",
-                  "paged_tree", "moe_gmm_up", "moe_gmm_down",
+                  "paged_tree", "moe_gmm_up", "moe_gmm_down", "moe_layer",
                   "lfm2_train_step"}, proc.stdout
 
 
@@ -50,8 +56,9 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("paged_tree", {"paged_tree"}),
     ("moe_gmm_up", {"moe_gmm", "moe_tgmm"}),
     ("moe_gmm_down", {"moe_gmm", "moe_tgmm"}),
-    ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                         "moe_gmm", "moe_tgmm"})])
+    ("moe_layer", MOE_LAYER),
+    ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+     | MOE_LAYER)])
 def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
     program's Mosaic custom calls have their ``pallas_call``'s ``name=``

@@ -30,7 +30,6 @@ fraction and the Switch aux loss ``E * sum(f_e * P_e)``) are sown into the
 
 from __future__ import annotations
 
-import functools
 import math
 
 import flax.linen as nn
@@ -185,48 +184,118 @@ _SCORE_FNS = {"sigmoid": jax.nn.sigmoid}
 ROUTE_NAME = "moe_route"  # the expert choice, for jax.checkpoint policies
 
 
-def _sum_by_token(rows, slot_of, k):
-    """Token ``t``'s ``k`` assignments sit at rows ``slot_of[t*k:(t+1)*k]``:
-    gather them and add (float32), ``(M, d) -> (M // k, d)``."""
-    mine = rows[slot_of].reshape(-1, k, rows.shape[-1])
-    return mine.astype(jnp.float32).sum(axis=1).astype(rows.dtype)
+# The pieces of the sort-based dispatch, each with its own VJP.  They pass
+# ``(M, n)`` row buffers whose rows from ``sum(loads)`` on are UNDEFINED
+# (tpudp/ops/expert_rows.py): nothing here, forward or backward, reads a
+# row tile past the walk or writes one.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_by_expert(x, token_of, slot_of, k):
+@jax.custom_vjp
+def _rows_by_expert(x, token_of, plan):
     """``x[token_of]``: each token's row once per assignment, in sorted
-    order.  Its transpose sums a token's ``k`` rows back, and is written
-    as the gather by ``slot_of`` that it is (:func:`_sum_by_token`), so
+    order, for the rows before the absent group.  Its transpose sums a
+    token's held rows back (``plan``: ``expert_rows.combine_plan``), so
     neither direction is a scatter-add."""
-    return x[token_of]
+    from tpudp.ops import expert_rows as er
+
+    return er.gather_rows(x, token_of, plan[2][-1])
 
 
-def _rows_by_expert_fwd(x, token_of, slot_of, k):
-    return x[token_of], (token_of, slot_of)
+def _rows_by_expert_fwd(x, token_of, plan):
+    return _rows_by_expert(x, token_of, plan), (token_of, plan)
 
 
-def _rows_by_expert_bwd(k, res, d_rows):
-    return _sum_by_token(d_rows, res[1], k), None, None
+def _rows_by_expert_bwd(res, d_rows):
+    return _rows_to_tokens(d_rows, *res), None, None
 
 
 _rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_to_tokens(rows, token_of, slot_of, k):
-    """The transpose of :func:`_rows_by_expert`."""
-    return _sum_by_token(rows, slot_of, k)
+@jax.custom_vjp
+def _rows_to_tokens(rows, token_of, plan):
+    """The transpose of :func:`_rows_by_expert`: ``(M, d) -> (T, d)``."""
+    from tpudp.ops import expert_rows as er
+
+    return er.combine_rows(rows, plan)
 
 
-def _rows_to_tokens_fwd(rows, token_of, slot_of, k):
-    return _sum_by_token(rows, slot_of, k), (token_of, slot_of)
+def _rows_to_tokens_fwd(rows, token_of, plan):
+    return _rows_to_tokens(rows, token_of, plan), (token_of, plan)
 
 
-def _rows_to_tokens_bwd(k, res, d_tokens):
-    return d_tokens[res[0]], None, None
+def _rows_to_tokens_bwd(res, d_tokens):
+    return _rows_by_expert(d_tokens, *res), None, None
 
 
 _rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+@jax.custom_vjp
+def _weights_by_row(weights, flat, order, slot_of, total):
+    """``weights.reshape(-1)[order]``, each row's combine weight, by the
+    sort that made ``order`` (a stable sort of ``flat``; a gather by index
+    over ``T x k`` scalars costs ten times the sort on the v5e).  A row from
+    ``total`` on hands back an undefined gradient, which its assignment
+    does not take."""
+    return lax.sort((flat, weights.reshape(-1)), num_keys=1,
+                    is_stable=True)[1]
+
+
+def _weights_by_row_fwd(weights, flat, order, slot_of, total):
+    return (_weights_by_row(weights, flat, order, slot_of, total),
+            (order, slot_of, total))
+
+
+def _weights_by_row_bwd(res, d_rows):
+    order, slot_of, total = res
+    back = lax.sort((order, d_rows), num_keys=1)[1].reshape(slot_of.shape)
+    return jnp.where(slot_of < total, back, 0.0), None, None, None, None
+
+
+_weights_by_row.defvjp(_weights_by_row_fwd, _weights_by_row_bwd)
+
+
+@jax.custom_vjp
+def _expert_ffn(rows, w_rows, w1, w3, w2, walk, walk_t):
+    """Every held expert's SwiGLU on its own rows, times the row's combine
+    weight: ``(w * silu(rows @ w1[g]) * (rows @ w3[g])) @ w2[g]``, three
+    grouped products and one row kernel under ``walk``; ``walk_t`` is the
+    weight gradients' (``tgmm``).  The weight goes in before the down
+    projection, where the hidden value is float32 and rounded once anyway:
+    the products' rows scale, so this is the weighted sum the layer
+    returns, and neither direction needs a pass that only weighs."""
+    return _expert_ffn_fwd(rows, w_rows, w1, w3, w2, walk, walk_t)[0]
+
+
+def _expert_ffn_fwd(rows, w_rows, w1, w3, w2, walk, walk_t):
+    from tpudp.ops import expert_rows as er
+    from tpudp.ops import grouped_matmul as gm
+
+    h1, h3 = gm.gmm_walk(rows, w1, walk), gm.gmm_walk(rows, w3, walk)
+    hdn = er.swiglu(h1, h3, w_rows, walk)
+    return gm.gmm_walk(hdn, w2, walk), (rows, w_rows, w1, w3, w2, h1, h3,
+                                        hdn, walk, walk_t)
+
+
+def _expert_ffn_bwd(res, d_out):
+    from tpudp.ops import expert_rows as er
+    from tpudp.ops import grouped_matmul as gm
+
+    rows, w_rows, w1, w3, w2, h1, h3, hdn, walk, walk_t = res
+    d_hdn = gm.gmm_walk(d_out, w2, walk, transpose_rhs=True)
+    # float32 parameters take the float32 accumulator, not a rounding of it
+    d_w2 = gm.tgmm_walk(hdn, d_out, walk_t, w2.dtype)
+    # the rows' weights take their gradient here: how the router learns
+    d_h1, d_h3, d_w = er.swiglu_bwd(h1, h3, d_hdn, w_rows, walk)
+    d_rows = gm.gmm_walk(
+        d_h3, w3, walk, transpose_rhs=True,
+        plus=gm.gmm_walk(d_h1, w1, walk, transpose_rhs=True))
+    return (d_rows, d_w[:, 0], gm.tgmm_walk(rows, d_h1, walk_t, w1.dtype),
+            gm.tgmm_walk(rows, d_h3, walk_t, w3.dtype), d_w2, None, None)
+
+
+_expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
 
 
 class DroplessMoe(nn.Module):
@@ -251,9 +320,19 @@ class DroplessMoe(nn.Module):
     assignments are ordered by held-expert id with every assignment to an
     absent expert in one trailing group, the tokens' rows are gathered in
     that order into a ``(T x k, d)`` buffer (the worst case, so nothing
-    can overflow), the three products run as grouped matmuls
-    (tpudp.ops.grouped_matmul: tiles past the held experts' rows are
-    skipped), and each token sums its assignments' weighted rows back.
+    can overflow), the three products run as grouped matmuls with the
+    rows' weights applied between them, and each token sums its
+    assignments' rows back.  THE INVARIANT of that path: **rows from
+    ``sum(loads)`` on are undefined and nobody reads them.**  The visit
+    tables (``grouped_matmul.visits``: the row tiles the held experts' rows
+    touch, a traced count) are computed once here from ``loads`` and every
+    operation between the sort and ``y`` walks under them, forward and
+    backward (tpudp/ops/grouped_matmul.py, tpudp/ops/expert_rows.py), so
+    the layer costs what the rows it owns cost: all ``T x k`` of them on
+    a chip that holds every expert or when every token picks held experts,
+    nothing when no row is owned.  The bound is what ``moe_counts`` sows as
+    its second entry.  Only the sort's own ``(T x k,)`` index vectors and
+    the ``(T, d)`` token-side tensors are touched whole.
     ``impl='dense'`` is a masked loop over the held experts in plain XLA,
     every expert over every token.  The layer takes it by itself, silently,
     where Mosaic cannot tile the shape (``grouped_matmul.supported``: the
@@ -310,7 +389,11 @@ class DroplessMoe(nn.Module):
         # pass did.  Named, so that a remat policy keeps the choice
         # (models/lfm2.py: ROUTE_SAVED).
         chosen = checkpoint_name(chosen, ROUTE_NAME)
-        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        # the chosen experts' own scores, by comparison (the same values:
+        # one term a sum): a gather by index over T x k scalars costs the
+        # v5e 1.3 ms, twice a layer under remat (PERF.md section 6, PR 30)
+        weights = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(e),
+                                    scores[:, None, :], 0.0), axis=-1)
         if self.normalize:
             weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
         weights = weights * self.scaling
@@ -319,6 +402,7 @@ class DroplessMoe(nn.Module):
         loads = jnp.sum(local[..., None] == jnp.arange(g), axis=(0, 1))
         self.sow("intermediates", "moe_chosen", chosen)
 
+        from tpudp.ops import expert_rows as er
         from tpudp.ops import grouped_matmul as gm
 
         xb = xt.astype(self.dtype)
@@ -326,15 +410,19 @@ class DroplessMoe(nn.Module):
             # --- sort the assignments by held expert, absent ones last
             flat = local.reshape(-1)
             order = jnp.argsort(flat, stable=True)  # row -> assignment
-            slot_of = jnp.argsort(order)  # assignment -> row
+            slot_of = jnp.argsort(order).reshape(t, k)  # assignment -> row
+            # --- the walk, once: every kernel from here to `y` runs over
+            # the row tiles it lists and no other, and it is what is sown
+            walk = gm.visits(loads, t * k)
+            total = walk[3][-1]
+            plan = er.combine_plan(slot_of, total)
             token_of = order // k
-            rows = _rows_by_expert(xb, token_of, slot_of, k)
-            hdn = nn.silu(gm.gmm(rows, w1, loads)) * gm.gmm(rows, w3, loads)
-            out = gm.gmm(hdn, w2, loads)  # zeros from the absent group on
-            out = (out.astype(jnp.float32)
-                   * weights.reshape(-1)[order][:, None]).astype(self.dtype)
-            y = _rows_to_tokens(out, token_of, slot_of, k)
-            computed = gm.visited_rows(loads, t * k)
+            w_rows = _weights_by_row(weights, flat, order, slot_of, total)
+            out = _expert_ffn(_rows_by_expert(xb, token_of, plan), w_rows,
+                              w1, w3, w2, walk,
+                              gm.visits(loads, t * k, visit_empty=True))
+            y = _rows_to_tokens(out, token_of, plan)
+            computed = walk[4] * gm.row_tile(t * k)
         else:
             y = jnp.zeros((t, d), jnp.float32)
             for j in range(g):

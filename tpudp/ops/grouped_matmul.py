@@ -3,14 +3,19 @@
 A dropless expert layer sorts its token rows by expert, so expert ``g``
 owns the contiguous rows ``[ends[g-1], ends[g])`` of one ``(M, K)`` buffer
 (``ends = cumsum(group_sizes)``) and the rows from ``sum(group_sizes)`` on
-belong to no expert held here.  Two kernels and a custom VJP cover the
-layer's nine products a step:
+belong to no expert held here.  THE INVARIANT the layer builds on: **those
+rows are undefined and nobody reads them**; neither kernel ever does.  Two
+kernels and a custom VJP cover the layer's nine products a step:
 
   * ``gmm(lhs (M, K), rhs (G, K, N), group_sizes) -> (M, N)``: each group's
     rows times its own matrix (HLO name ``moe_gmm``).  ``transpose_rhs``
     takes ``rhs`` as ``(G, N, K)``, which is the data gradient's product
     with the same weights.  Rows past the last group are never read and
-    come back as zeros.
+    come back as zeros: the public entry's contract, paid for by one pass
+    over all ``M`` rows after the kernel.  The expert layer calls
+    :func:`gmm_walk` instead, under visit tables it made once
+    (:func:`visits`), which leaves the tiles past the last visit UNWRITTEN
+    and can add the product to a buffer it is given (``plus``).
   * ``tgmm(lhs (M, K), rhs (M, N), group_sizes) -> (G, K, N)``: each
     group's ``lhs_gᵀ · rhs_g`` (``moe_tgmm``), the weight gradient.  An
     empty group's matrix is zeros; rows past the last group are never read.
@@ -96,7 +101,8 @@ def _sides(x: int, cap: int | None = None) -> list[int]:
             if x % b == 0]
 
 
-def _block_m(m: int) -> int:
+def row_tile(m: int) -> int:
+    """The row tile every kernel of a layer of ``m`` rows walks in."""
     return _sides(m, _MAX_BLOCK_M)[-1]
 
 
@@ -110,7 +116,7 @@ def choose_blocks(kernel: str, m: int, k: int, n: int, in_dtype,
     takes the ``(block_k, block_n)`` with the largest output block that
     fits, then the wider one (``lhs`` is read ``n / block_n`` times, ``rhs``
     ``k / block_k`` times)."""
-    bm = _block_m(m)
+    bm = row_tile(m)
     if kernel == "gmm":
         fits = [(bm, k, bn) for bn in _sides(n)
                 if vmem_bytes(kernel, bm, k, bn, in_dtype, out_dtype)
@@ -157,11 +163,20 @@ def _visits(group_sizes, m: int, block_m: int, visit_empty: bool):
     return gid, tile.astype(jnp.int32), starts, ends, cum[-1]
 
 
+def visits(group_sizes, m: int, visit_empty: bool = False):
+    """The visit tables of ``group_sizes`` over ``m`` rows at the default
+    row tile, for :func:`gmm_walk` (and the row kernels of
+    tpudp/ops/expert_rows.py), or with ``visit_empty`` for
+    :func:`tgmm_walk`: an expert layer computes them once and every kernel
+    of the layer walks under them.  The last entry is the visit count."""
+    return _visits(group_sizes, m, row_tile(m), visit_empty)
+
+
 def visited_rows(group_sizes, m: int, block_m: int | None = None):
     """Rows ``gmm`` runs over at these group sizes (its visits times the
     tile height): what a boundary-crossing tile and a ragged last tile add
     to ``sum(group_sizes)``.  A counter for ``TrainState.obs_moe``."""
-    bm = block_m or _block_m(m)
+    bm = block_m or row_tile(m)
     return _visits(group_sizes, m, bm, False)[4] * bm
 
 
@@ -178,7 +193,7 @@ def _params(interpret, semantics):
 # One trace and one lowering of each kernel for all the expert layers of a
 # model that call it at one shape (PR 27's set-up finding for flash).
 _gmm_jit = functools.partial(jax.jit, static_argnames=(
-    "transpose_rhs", "block_m", "block_n", "interpret"))
+    "transpose_rhs", "block_m", "block_n", "interpret", "zero_tail"))
 _tgmm_jit = functools.partial(jax.jit, static_argnames=(
     "out_dtype", "block_m", "block_k", "block_n", "interpret"))
 
@@ -187,7 +202,8 @@ _tgmm_jit = functools.partial(jax.jit, static_argnames=(
 
 
 def _gmm_kernel(gid_ref, tile_ref, start_ref, end_ref, lhs_ref, rhs_ref,
-                out_ref, *, block_m: int, transpose_rhs: bool):
+                *rest, block_m: int, transpose_rhs: bool):
+    out_ref = rest[-1]
     v = pl.program_id(1)
     g = gid_ref[v]
     row0 = tile_ref[v] * block_m
@@ -195,6 +211,10 @@ def _gmm_kernel(gid_ref, tile_ref, start_ref, end_ref, lhs_ref, rhs_ref,
     acc = lax.dot_general(lhs_ref[...], rhs_ref[0],
                           _NT if transpose_rhs else _NN,
                           preferred_element_type=jnp.float32)
+    if len(rest) == 2:  # `plus`: its block is fetched once for all the
+        # visits of a tile (the index does not change between them), so
+        # every group adds to what `plus` held before the call
+        acc = acc + rest[0][...].astype(jnp.float32)
     inside = (lo <= row0) & (row0 + block_m <= hi)
 
     @pl.when(inside)
@@ -215,17 +235,24 @@ def _gmm_kernel(gid_ref, tile_ref, start_ref, end_ref, lhs_ref, rhs_ref,
 
 
 @_gmm_jit
-def _gmm_impl(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n,
-              interpret):
+def _gmm_impl(lhs, rhs, walk, transpose_rhs, block_m, block_n, interpret,
+              zero_tail=True, plus=None):
+    """``walk``: :func:`visits` of the group sizes at this ``block_m``.
+    Without ``zero_tail`` the tiles past the last visit are left unwritten
+    (the expert layer's form: nobody reads them).  ``plus`` ``(M, N)`` is
+    added to the product in float32, and gives the result its buffer."""
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     bm, _, bn = choose_blocks("gmm", m, k, n, lhs.dtype, lhs.dtype)
     bm, bn = block_m or bm, block_n or bn
-    gid, tile, starts, ends, count = _visits(group_sizes, m, bm, False)
+    gid, tile, starts, ends, count = walk
     rhs_spec = pl.BlockSpec(
         (1, bn, k) if transpose_rhs else (1, k, bn),
         (lambda j, v, gid, *_: (gid[v], j, 0)) if transpose_rhs
         else (lambda j, v, gid, *_: (gid[v], 0, j)))
+    out_spec = pl.BlockSpec((bm, bn), lambda j, v, gid, tile, *_:
+                            (tile[v], j))
+    more = () if plus is None else (plus,)
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, block_m=bm,
                           transpose_rhs=transpose_rhs),
@@ -238,15 +265,17 @@ def _gmm_impl(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n,
                 pl.BlockSpec((bm, k), lambda j, v, gid, tile, *_:
                              (tile[v], 0)),
                 rhs_spec,
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda j, v, gid, tile, *_:
-                                   (tile[v], j)),
+            ] + [out_spec] * len(more),
+            out_specs=out_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        input_output_aliases={6: 0} if more else {},
         interpret=interpret,
         name="moe_gmm",
         **_params(interpret, ("parallel", "arbitrary")),
-    )(gid, tile, starts, ends, lhs, rhs.astype(lhs.dtype))
+    )(gid, tile, starts, ends, lhs, rhs.astype(lhs.dtype), *more)
+    if not zero_tail:
+        return out
     # tiles no group visits were never written
     live = jnp.arange(m)[:, None] < ends[-1]
     return jnp.where(live, out, jnp.zeros((), out.dtype))
@@ -291,14 +320,15 @@ def _tgmm_kernel(gid_ref, tile_ref, start_ref, end_ref, lhs_ref, rhs_ref,
 
 
 @_tgmm_jit
-def _tgmm_impl(lhs, rhs, group_sizes, out_dtype, block_m, block_k, block_n,
+def _tgmm_impl(lhs, rhs, walk, out_dtype, block_m, block_k, block_n,
                interpret):
+    """``walk``: :func:`visits` with ``visit_empty`` at this ``block_m``."""
     m, k = lhs.shape
     n = rhs.shape[1]
-    groups = group_sizes.shape[0]
     bm, bk, bn = choose_blocks("tgmm", m, k, n, lhs.dtype, out_dtype)
     bm, bk, bn = block_m or bm, block_k or bk, block_n or bn
-    gid, tile, starts, ends, count = _visits(group_sizes, m, bm, True)
+    gid, tile, starts, ends, count = walk
+    groups = starts.shape[0]
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, block_m=bm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -326,28 +356,31 @@ def _tgmm_impl(lhs, rhs, group_sizes, out_dtype, block_m, block_k, block_n,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _gmm(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n, interpret):
-    return _gmm_impl(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n,
-                     interpret)
+    return _gmm_fwd(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n,
+                    interpret)[0]
 
 
 def _gmm_fwd(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n,
              interpret):
-    out = _gmm_impl(lhs, rhs, group_sizes, transpose_rhs, block_m, block_n,
-                    interpret)
+    bm = block_m or row_tile(lhs.shape[0])
+    out = _gmm_impl(lhs, rhs, _visits(group_sizes, lhs.shape[0], bm, False),
+                    transpose_rhs, bm, block_n, interpret)
     return out, (lhs, rhs, group_sizes)
 
 
 def _gmm_bwd(transpose_rhs, block_m, block_n, interpret, res, dout):
     lhs, rhs, group_sizes = res
     dout = dout.astype(lhs.dtype)
+    m = lhs.shape[0]
+    bm = block_m or row_tile(m)
     # dlhs: the same weights, transposed, on the same groups of rows
-    dlhs = _gmm_impl(dout, rhs, group_sizes, not transpose_rhs, block_m,
-                     None, interpret)
+    dlhs = _gmm_impl(dout, rhs, _visits(group_sizes, m, bm, False),
+                     not transpose_rhs, bm, None, interpret)
     # drhs in rhs's own layout and dtype (float32 parameters get the
     # float32 accumulator, not a bf16 rounding of it)
     a, b = (dout, lhs) if transpose_rhs else (lhs, dout)
-    drhs = _tgmm_impl(a, b, group_sizes, jnp.dtype(rhs.dtype), block_m, None,
-                      None, interpret)
+    drhs = _tgmm_impl(a, b, _visits(group_sizes, m, bm, True),
+                      jnp.dtype(rhs.dtype), bm, None, None, interpret)
     return dlhs, drhs, None
 
 
@@ -395,6 +428,24 @@ def gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray, *,
                 interpret)
 
 
+def gmm_walk(lhs, rhs, walk, *, transpose_rhs: bool = False, plus=None):
+    """:func:`gmm` under tables made once (:func:`visits`), at the default
+    blocks, with the tiles past the last visit LEFT UNWRITTEN: the expert
+    layer's form, whose every consumer walks under the same tables.
+    ``plus`` ``(M, N)``: the product is added to it (float32, one rounding)
+    in its own buffer, which is how two data gradients into one buffer sum
+    without a pass of their own.  Not differentiable (the layer's VJP
+    composes it)."""
+    return _gmm_impl(lhs, rhs, walk, transpose_rhs, None, None,
+                     _interpret_default(), zero_tail=False, plus=plus)
+
+
+def tgmm_walk(lhs, rhs, walk, out_dtype):
+    """:func:`tgmm` under ``visits(..., visit_empty=True)``."""
+    return _tgmm_impl(lhs, rhs, walk, jnp.dtype(out_dtype), None, None, None,
+                      _interpret_default())
+
+
 def tgmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray, *,
          out_dtype=jnp.float32, block_m: int | None = None,
          block_k: int | None = None, block_n: int | None = None,
@@ -406,5 +457,6 @@ def tgmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray, *,
     if interpret is None:
         interpret = _interpret_default()
     _check(lhs, rhs, group_sizes, block_m, interpret)
-    return _tgmm_impl(lhs, rhs, group_sizes, jnp.dtype(out_dtype), block_m,
-                      block_k, block_n, interpret)
+    bm = block_m or row_tile(lhs.shape[0])
+    return _tgmm_impl(lhs, rhs, _visits(group_sizes, lhs.shape[0], bm, True),
+                      jnp.dtype(out_dtype), bm, block_k, block_n, interpret)
