@@ -18,8 +18,7 @@ without an accelerator, or when the step fails, the script exits non-zero
 and prints no value — a stored number is never re-emitted.
 
 Env knobs: BENCH_BATCH, BENCH_STEPS, BENCH_WARMUP, BENCH_DTYPE,
-BENCH_PARAM_DTYPE (bfloat16 casts params + momentum: the mfu_attribution
-'bf16_params' lever), BENCH_DONATE=0, BENCH_SYNC (gradient-sync rung,
+BENCH_PARAM_DTYPE (bfloat16 casts params + momentum), BENCH_DONATE=0, BENCH_SYNC (gradient-sync rung,
 validated against the ladder minus 'none').
 """
 
@@ -96,9 +95,8 @@ def main() -> None:
     tx = make_optimizer()
     state = init_state(model, tx)
     # BENCH_PARAM_DTYPE=bfloat16 casts params AND momentum to bf16 —
-    # halves weight-side HBM traffic (the benchmarks/mfu_attribution.py
-    # 'bf16_params' lever, selectable here so the headline number can
-    # adopt it once the attribution row proves the win on-chip).
+    # halves weight-side HBM traffic; its effect on the chip is not
+    # measured.
     if param_dtype == "bfloat16":
         state = state.replace(
             params=jax.tree.map(lambda a: a.astype(jnp.bfloat16),

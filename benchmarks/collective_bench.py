@@ -53,10 +53,9 @@ def main() -> None:
     if n == 1:
         # On one device every collective compiles to a no-op — a wall time
         # would measure dispatch overhead only (round-2 judge finding).
-        # Emit a labeled skip row so the watcher's gap gate (bench_gaps.py
-        # 'collective') knows the stage ran and found nothing measurable;
-        # the ring-default evidence on this host is HLO-level instead
-        # (tools/ring_hlo_evidence.py, BASELINE.md).
+        # Say so in one labeled row instead of printing timings of
+        # nothing; the ring-default evidence on such a host is HLO-level
+        # instead (tools/ring_hlo_evidence.py, BASELINE.md).
         print(json.dumps({
             "skipped": "1 device: every collective compiles to a no-op; "
                        "ring-vs-psum needs devices>1",

@@ -56,9 +56,7 @@ def attention_grad_flops(b, t, h, dh, causal=True):
 
 
 def main(*ts: int) -> None:
-    from tools.bench_gaps import FLASH_TS  # canonical sweep registry
-
-    ts = ts or FLASH_TS
+    ts = ts or (4096, 8192, 16384)
     b = int(os.environ.get("FLASH_B", 4))
     h = int(os.environ.get("FLASH_H", 12))
     dh = 64
@@ -157,10 +155,6 @@ def main(*ts: int) -> None:
         print(json.dumps({"t": t,
                           "error": f"{type(exc).__name__}: {exc}"[:500]}),
               flush=True)
-    # Informational completion marker ("all t values attempted", vs a run
-    # that wedged partway).  The watcher's resume gate does NOT read it —
-    # it diffs measured rows against tools.bench_gaps.FLASH_TS.
-    print(json.dumps({"flash_done": list(ts)}), flush=True)
 
 
 if __name__ == "__main__":

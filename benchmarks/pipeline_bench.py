@@ -1,7 +1,7 @@
 """Pipeline-parallel training rung (tpudp/parallel/schedule.py).
 
-One row per PP x DP geometry in ``tools/bench_gaps.PIPELINE_CONFIGS``
-(metric ``train_pipeline``), each closed only by a merciless three-part
+One row per PP x DP geometry (metric ``train_pipeline``; default sweep
+``PIPELINE_CONFIGS`` below), each held to a merciless three-part
 referee — the same bar the tier-1 tests hold, re-proven on the real
 device at bench scale:
 
@@ -30,12 +30,11 @@ device at bench scale:
     global-slice manifest).
 
 A row that is fast but diverged, or recovered but unaccounted, is a
-FAILURE to retry — same philosophy as ``resilience_bench.py``.  Resumes
-at config granularity via ``tools/bench_gaps.py train_pipeline`` (env
-``TRAIN_PIPELINE``); CPU smoke rows never close a config (the gate
-requires a TPU ``device_kind``).
+FAILURE — same philosophy as ``resilience_bench.py``.  A CPU smoke row
+shows the referees at work, never a speed (every row names its
+``device_kind``).
 
-Env knobs: TRAIN_PIPELINE (comma config names; default the registry),
+Env knobs: TRAIN_PIPELINE (comma geometry names; default the sweep),
 TRAIN_PIPELINE_PLATFORM (e.g. ``cpu``), TRAIN_PIPELINE_DEVICES (virtual
 CPU device count for smoke — also pins single-threaded Eigen so the
 parity referee measures the schedule, not Eigen's reduction order),
@@ -56,7 +55,11 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools.bench_gaps import PIPELINE_CONFIGS  # noqa: E402
+# The default sweep, ``pp{P}dp{D}[v{V}]``: P stages x D replicas, V virtual
+# stages a device.  All three need an 8-device slice (P*D = 8); the
+# interleaved v2 geometry additionally proves the virtual-stage ring wrap
+# at bench scale.
+PIPELINE_CONFIGS = ("pp2dp4", "pp4dp2", "pp2dp4v2")
 
 
 def _cfg() -> dict:
@@ -72,7 +75,7 @@ def _cfg() -> dict:
 
 def parse_config(name: str) -> tuple[int, int, int]:
     """``pp{P}dp{D}[v{V}]`` -> (stages, dp, interleave); ValueError on
-    anything else (the registry-guard test pins the format)."""
+    anything else (tests/test_hand_tools.py pins the format)."""
     m = re.fullmatch(r"pp(\d+)dp(\d+)(?:v(\d+))?", name)
     if not m:
         raise ValueError(f"bad pipeline config {name!r} "
@@ -244,19 +247,15 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--configs", type=str, default=None,
                     help="comma-separated geometry names (env: "
-                         "TRAIN_PIPELINE; default the registry)")
+                         "TRAIN_PIPELINE; default PIPELINE_CONFIGS)")
     ap.add_argument("--workdir", type=str, default=None,
                     help="checkpoint scratch root (default: a temp dir)")
     args = ap.parse_args()
     conf_env = args.configs or os.environ.get("TRAIN_PIPELINE")
-    if conf_env is not None and not conf_env.strip():
-        return  # the gap helper said: nothing missing
     names = ([c for c in conf_env.split(",") if c] if conf_env
              else list(PIPELINE_CONFIGS))
-    bad = [c for c in names if c not in PIPELINE_CONFIGS]
-    if bad:
-        raise SystemExit(f"error: unregistered pipeline configs {bad} "
-                         f"(registry: {list(PIPELINE_CONFIGS)})")
+    for name in names:
+        parse_config(name)  # a malformed name fails before any device work
 
     # Geometry env must land before the first backend touch (jax imports
     # happen inside the run functions, after this block).

@@ -30,9 +30,7 @@ only WHERE within the launch each fault lands, never whether it fires):
   launch 3: resumes, loss spike in the final epoch, runs to completion
 
 Emits one JSON row per seed (metric ``train_soak``) with the recovery
-counts, ``parity_ok``, ``accounted``, and ``device_kind`` — the
-``train_soak`` stage registered in ``tools/bench_gaps.py`` /
-``tools/record_bench.py``; CPU smoke rows
+counts, ``parity_ok``, ``accounted``, and ``device_kind``; CPU smoke rows
 are pinned by ``tests/test_bench_smoke.py``.
 
 ``--multihost`` runs the POD-SCALE variant instead (metric
@@ -48,7 +46,7 @@ recovery, and the final relaunch runs at a REDUCED host geometry
 (elastic verified restore).  Same merciless referee: final params
 bit-identical to an uninterrupted run, every fault accounted.
 
-Env knobs: TRAIN_SOAK (comma seeds; default the registry),
+Env knobs: TRAIN_SOAK (comma seeds; default the mode's seeds below),
 TRAIN_SOAK_PLATFORM (e.g. ``cpu``), TRAIN_SOAK_EPOCHS (3),
 TRAIN_SOAK_PER_EPOCH (6 batches), TRAIN_SOAK_BATCH (8),
 TRAIN_SOAK_KILLS (2), TRAIN_SOAK_WD_TIMEOUT (8s; the stall sleeps 1.75x
@@ -71,8 +69,11 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools.bench_gaps import (SDC_SOAK_SEEDS,  # noqa: E402
-                              TRAIN_SOAK_MULTIHOST_SEEDS, TRAIN_SOAK_SEEDS)
+# The seeds each mode runs by default; a seed outside its mode's tuple is
+# refused before anything runs.
+TRAIN_SOAK_SEEDS = (0, 1, 2)
+TRAIN_SOAK_MULTIHOST_SEEDS = (0, 1, 2)
+SDC_SOAK_SEEDS = (0, 1, 2)
 
 
 def _cfg() -> dict:
@@ -908,7 +909,7 @@ def main() -> None:
                     help="internal: run one trainer process (env-config)")
     ap.add_argument("--soak", type=str, default=None,
                     help="comma-separated seeds (env: TRAIN_SOAK; default "
-                         "the registry)")
+                         "the mode's seeds)")
     ap.add_argument("--multihost", action="store_true",
                     help="run the POD-SCALE soak instead: N worker "
                          "processes per launch, SIGKILL one of them "
@@ -931,8 +932,6 @@ def main() -> None:
                 else "TRAIN_SOAK_MULTIHOST" if args.multihost
                 else "TRAIN_SOAK")
     soak_env = args.soak or os.environ.get(env_name)
-    if soak_env is not None and not soak_env.strip():
-        return  # the gap helper said: nothing missing
     seeds = ([int(s) for s in soak_env.split(",") if s]
              if soak_env else list(registry))
     bad = [s for s in seeds if s not in registry]

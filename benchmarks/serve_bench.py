@@ -11,8 +11,8 @@ times the sequential service rate per slot — saturating by default, so
 the number measures the engine, not the arrival gaps), swept over
 several concurrency levels (``num_slots``).
 
-One JSON line per concurrency level (machine-readable, same style as
-matrix_bench) plus a final summary line:
+One JSON line per concurrency level (machine-readable) plus a final
+summary line:
 
   value                 aggregate NEW tokens/sec, first submit -> last token
   p50/p99_token_latency_ms   per-token latency (submit->first token, then
@@ -109,8 +109,7 @@ in-flight slots through the tenancy layer's carry-over path — against a
 small tenant-aware engine.  A seed PASSES only if the run never wedges
 (bounded step count), the engine ends empty (``no_leak`` — no slot or
 queue entry stranded), and every surviving completed request's greedy
-output is bit-identical to ``generate()`` (``parity_ok``).  The gap
-gate (tools/bench_gaps.serve_soak_missing) retries anything less.
+output is bit-identical to ``generate()`` (``parity_ok``).
 
 With ``--tenants SEED1,SEED2`` (or SERVE_TENANCY) the bench instead
 runs the MULTI-TENANT mixed workload (one ``serve_tenancy`` row per
@@ -153,8 +152,8 @@ row's gates.
 
 Runs on whatever device is attached; SERVE_PLATFORM=cpu pins the CPU
 smoke mode (tier-1 runs it at a trimmed geometry).  Knobs: SERVE_CONCURRENCY
-(comma-separated subset of the registered levels — the watcher's
-gap-resume path), SERVE_SPECULATE_K (same, for the spec rows),
+(comma-separated levels; default the sweep below), SERVE_SPECULATE_K
+(same, for the spec rows),
 SERVE_SOAK (same, for the soak rows),
 SERVE_DECODE_FUSE (same, for the fused-decode rows),
 SERVE_FUSED_CONCURRENCY,
@@ -170,7 +169,7 @@ TENANCY_P99_BOUND, TENANCY_LAYERS, TENANCY_DMODEL, TENANCY_VOCAB,
 SERVE_DISAGG (seed subset), DISAGG_REQUESTS, DISAGG_BURST,
 DISAGG_MAX_NEW, DISAGG_MEAN_GAP_S, DISAGG_LAYERS, DISAGG_DMODEL,
 DISAGG_VOCAB, DISAGG_TTFT_BOUND, DISAGG_P99_BOUND,
-SERVE_STRICT_LEVELS=1 (reject unregistered levels/seeds).
+SERVE_STRICT_LEVELS=1 (reject levels/seeds outside the default sweeps).
 """
 
 import argparse
@@ -182,13 +181,23 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools.bench_gaps import (SERVE_CONCURRENCIES,  # noqa: E402 (stdlib-only)
-                              SERVE_DISAGG_SEEDS, SERVE_FUSED_NS,
-                              SERVE_PAGED_TRAFFIC,
-                              SERVE_PAGED_WORKLOADS,
-                              SERVE_PREFIX_WORKLOADS, SERVE_SOAK_SEEDS,
-                              SERVE_SPEC_FUSED_CONFIGS, SERVE_SPEC_KS,
-                              SERVE_TENANCY_SEEDS)
+# The default sweep of each mode.  Workload and config NAMES outside
+# their tuple are always refused (a typo); numeric levels and seeds
+# outside theirs only under SERVE_STRICT_LEVELS=1.
+SERVE_CONCURRENCIES = (1, 4, 8)          # num_slots of the baseline sweep
+SERVE_SPEC_KS = (2, 4, 8)                # --speculate-k
+SERVE_PREFIX_WORKLOADS = ("shared_prefix", "multiturn")   # --prefix-cache
+SERVE_PAGED_WORKLOADS = ("shared_prefix",)                # --paged
+# --paged's kernel-vs-einsum rows, one per traffic kind: chunked prompt
+# ingestion, k=2 host speculation (the verify window), 4-token fused
+# decode windows.
+SERVE_PAGED_TRAFFIC = ("prefill", "verify", "fused")
+SERVE_FUSED_NS = (1, 4, 8)               # --decode-fuse; N=1 is the control
+SERVE_SPEC_FUSED_CONFIGS = ("k2n4", "k4n8")   # --spec-fused
+SPEC_FUSED_NAME = re.compile(r"k(\d+)n(\d+)")   # what a config name must be
+SERVE_SOAK_SEEDS = (0, 1, 2)             # --soak
+SERVE_TENANCY_SEEDS = (0, 1, 2)          # --tenants
+SERVE_DISAGG_SEEDS = (0, 1, 2)           # --disagg
 
 METRIC = "serve_tokens_per_sec"
 DISAGG_METRIC = "serve_disagg"
@@ -562,7 +571,7 @@ def main() -> None:
     # unknown "k{K}n{N}" is a typo, not an unregistered sweep point).
     sf_pairs = []  # (name, k, n)
     for name in sf_names:
-        m = re.fullmatch(r"k(\d+)n(\d+)", name)
+        m = SPEC_FUSED_NAME.fullmatch(name)
         if not m or name not in SERVE_SPEC_FUSED_CONFIGS:
             raise SystemExit(
                 f"error: unknown spec-fused config {name!r} "
@@ -775,8 +784,7 @@ def main() -> None:
     # the Engine.metrics() snapshots of the engines it measured —
     # device counters, span rollups, stats — into ONE JSON file next to
     # the row stream, so a bench row always ships with the structured
-    # telemetry that explains it (tools/bench_gaps.py's `obs` stage
-    # asserts the sidecar landed).
+    # telemetry that explains it.
     sidecar: dict = {"kind": "serve_bench_metrics", "stages": {}}
 
     def bank_metrics(stage: str, key, metrics: dict) -> None:
@@ -2042,8 +2050,8 @@ def main() -> None:
         lowers in interpret mode (timing the interpreter, not the
         kernel), so tokens/sec is only taken on a TPU or under
         ``SERVE_PAGED_KERNEL_TPS=1`` and the CPU smoke gate reads
-        parity alone (``value`` stays null, which keeps smoke rows
-        from ever closing the bench_gaps serve_paged_traffic stage)."""
+        parity alone (``value`` stays null: an interpreter's rate is
+        never written as a speed)."""
         deep_new = min(max_new, int(
             os.environ.get("SERVE_PAGED_TRAFFIC_NEW", "12")))
         kinds = {
@@ -2280,7 +2288,8 @@ def main() -> None:
             "rank1": {"stats": r1["stats"], "spans": r1["spans"]}})
 
     # One level crashing (OOM, transient backend fault) must not cost
-    # the remaining rows — same isolation contract as matrix_bench.
+    # the remaining rows: every level still prints its row, then the
+    # run exits non-zero.
     if disagg_seeds:
         for s in disagg_seeds:
             try:

@@ -264,7 +264,7 @@ def test_audit_stale_sources_reported(capture):
                for p in problems)
     # symmetric: a source REMOVED from AUDIT_SOURCES (file renamed/
     # dropped) without --update leaves a rotted lock entry the tier-1
-    # gate must reject too, matching sources_stale()'s poll-path verdict
+    # gate must reject too, matching sources_stale()'s jax-free verdict
     shrunk = json.loads(json.dumps(capture))
     del shrunk["sources"]["tpudp/parallel/ring.py"]
     problems = audit.compare(capture, shrunk)
@@ -304,7 +304,7 @@ def test_audit_registry_covers_trace_counters():
 
 
 def test_sources_stale_is_jax_free_and_detects(tmp_path):
-    """The bench_gaps poll path uses sources_stale without jax: prove
+    """sources_stale is the stale-lock check that needs no jax: prove
     it works in a jax-less subprocess (imports of the lint half must
     not drag jax in)."""
     code = (

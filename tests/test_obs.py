@@ -741,18 +741,3 @@ def test_obs_package_lints_clean():
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_bench_gaps_obs_stage(tmp_path):
-    """The obs sidecar gate: measured serve rows without the metrics
-    sidecar = gap; sidecar present (or nothing measured) = clean."""
-    from tools.bench_gaps import OBS_SIDECAR_NAME, obs_missing
-
-    d = str(tmp_path)
-    assert obs_missing(d) == []  # nothing measured, nothing owed
-    with open(os.path.join(d, "serve.jsonl"), "w") as f:
-        f.write(json.dumps({"metric": "serve_tokens_per_sec",
-                            "concurrency": 1, "value": 5.0,
-                            "device_kind": "cpu"}) + "\n")
-    assert obs_missing(d) == ["sidecar"]
-    with open(os.path.join(d, OBS_SIDECAR_NAME), "w") as f:
-        f.write("{}\n")
-    assert obs_missing(d) == []

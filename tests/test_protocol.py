@@ -273,9 +273,9 @@ def test_within_tolerance_budget_delta_names_the_lock_not_the_math():
 
 
 def test_lock_has_ledgers_is_the_shared_definition():
-    """`budget --table`, the bench_gaps poll gate, and the tier-1
-    presence test must agree on budget-completeness — one helper, not
-    three inline rules (review regression)."""
+    """`budget --table` and the tier-1 presence test must agree on
+    budget-completeness — one helper, not inline rules (review
+    regression)."""
     from tpudp.analysis.budget import lock_has_ledgers
 
     good = {"geometry": {"platform": "cpu", "devices": 8},
@@ -285,13 +285,10 @@ def test_lock_has_ledgers_is_the_shared_definition():
     assert not lock_has_ledgers(
         {**good, "programs": {"p": {}}})
     assert not lock_has_ledgers({**good, "programs": {}})
-    # the consumers actually call it
+    # the consumer actually calls it
     import inspect
 
-    from tools import bench_gaps
     from tpudp.analysis import cli as _cli
-    assert "lock_has_ledgers" in inspect.getsource(
-        bench_gaps.analysis_missing)
     assert "lock_has_ledgers" in inspect.getsource(_cli._cmd_budget)
 
 
@@ -751,8 +748,8 @@ def test_check_cli_nonzero_composes_with_pipefail(tmp_path):
 
 
 def test_verify_paths_is_jax_free():
-    """The protocol verifier must load and run on the watcher poll path
-    without jax (same file-path-load contract as the linter)."""
+    """The protocol verifier must load and run without jax (same
+    file-path-load contract as the linter)."""
     code = (
         "import importlib.util, sys, os\n"
         f"pkg = {os.path.join(ROOT, 'tpudp', 'analysis')!r}\n"

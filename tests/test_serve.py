@@ -284,31 +284,3 @@ def test_combined_top_k_top_p_composes_like_truncate_logits():
         assert tok[0] == 0, (seed, tok)
 
 
-def test_serve_bench_gap_gate(tmp_path):
-    """tools/bench_gaps serve stage: CPU smoke rows and error rows never
-    close a concurrency level; banked TPU rows do (the watcher's
-    window-accumulation contract, same rules as the mfu stage)."""
-    import json
-    import os
-
-    from tools.bench_gaps import SERVE_CONCURRENCIES, serve_missing
-
-    d = str(tmp_path)
-    assert serve_missing(d) == list(SERVE_CONCURRENCIES)
-    rows = [
-        {"metric": "serve_tokens_per_sec", "concurrency": 1,
-         "value": 900.0, "device_kind": "cpu"},          # smoke: no
-        {"metric": "serve_tokens_per_sec", "concurrency": 4,
-         "error": "device unavailable"},                       # error: no
-        {"metric": "serve_tokens_per_sec", "concurrency": 8,
-         "value": 9000.0, "device_kind": "TPU v5 lite"},  # real: yes
-    ]
-    with open(os.path.join(d, "serve.jsonl"), "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-    assert serve_missing(d) == [1, 4]
-    with open(os.path.join(d, "serve.history.jsonl"), "w") as f:
-        f.write(json.dumps(
-            {"metric": "serve_tokens_per_sec", "concurrency": 1,
-             "value": 7000.0, "device_kind": "TPU v5 lite"}) + "\n")
-    assert serve_missing(d) == [4]  # banked history row counts

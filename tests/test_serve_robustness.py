@@ -521,48 +521,6 @@ def test_finish_reason_success_paths(model_and_params):
 # -- tooling gate ------------------------------------------------------
 
 
-def test_serve_soak_bench_gap_gate(tmp_path):
-    """tools/bench_gaps serve_soak stage: CPU smoke rows, error rows,
-    and FAILED soaks (parity or leak) never close a seed; banked passing
-    TPU rows do (the watcher's window-accumulation contract, same rules
-    as the serve/serve_spec stages)."""
-    import json
-    import os
-
-    from tools.bench_gaps import SERVE_SOAK_SEEDS, serve_soak_missing
-
-    d = str(tmp_path)
-    assert serve_soak_missing(d) == list(SERVE_SOAK_SEEDS)
-    rows = [
-        {"metric": "serve_soak", "seed": 0, "value": 9,
-         "parity_ok": True, "no_leak": True, "canary_ok": True,
-         "device_kind": "cpu"},                        # smoke: no
-        {"metric": "serve_soak", "seed": 1,
-         "error": "device unavailable"},                     # error: no
-        {"metric": "serve_soak", "seed": 2, "value": 9,
-         "parity_ok": False, "no_leak": True, "canary_ok": True,
-         "device_kind": "TPU v5 lite"},                # failed soak: no
-    ]
-    with open(os.path.join(d, "serve_soak.jsonl"), "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-    assert serve_soak_missing(d) == list(SERVE_SOAK_SEEDS)
-    with open(os.path.join(d, "serve_soak.history.jsonl"), "w") as f:
-        f.write(json.dumps(
-            {"metric": "serve_soak", "seed": 1, "value": 11,
-             "parity_ok": True, "no_leak": True, "canary_ok": True,
-             "device_kind": "TPU v5 lite"}) + "\n")
-    assert serve_soak_missing(d) == [0, 2]  # banked passing row counts
-    # canary false-positive gate: a quarantine during the clean soak
-    # (canary_ok false) keeps the seed open even with parity + no_leak
-    with open(os.path.join(d, "serve_soak.jsonl"), "a") as f:
-        f.write(json.dumps(
-            {"metric": "serve_soak", "seed": 2, "value": 9,
-             "parity_ok": True, "no_leak": True, "canary_ok": False,
-             "device_kind": "TPU v5 lite"}) + "\n")
-    assert serve_soak_missing(d) == [0, 2]
-
-
 # -- SDC canaries (silent corruption on the serving path) --------------
 
 

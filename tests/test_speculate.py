@@ -473,31 +473,3 @@ def test_llama_family_speculative_greedy_parity():
 # -- tooling gate ------------------------------------------------------
 
 
-def test_serve_spec_bench_gap_gate(tmp_path):
-    """tools/bench_gaps serve_spec stage: CPU smoke rows and error rows
-    never close a k level; banked TPU rows do (the watcher's
-    window-accumulation contract, same rules as the serve stage)."""
-    import json
-    import os
-
-    from tools.bench_gaps import SERVE_SPEC_KS, serve_spec_missing
-
-    d = str(tmp_path)
-    assert serve_spec_missing(d) == list(SERVE_SPEC_KS)
-    rows = [
-        {"metric": "serve_spec_tokens_per_sec", "speculate_k": 2,
-         "value": 900.0, "device_kind": "cpu"},           # smoke: no
-        {"metric": "serve_spec_tokens_per_sec", "speculate_k": 4,
-         "error": "device unavailable"},                        # error: no
-        {"metric": "serve_spec_tokens_per_sec", "speculate_k": 8,
-         "value": 9000.0, "device_kind": "TPU v5 lite"},  # real: yes
-    ]
-    with open(os.path.join(d, "serve_spec.jsonl"), "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-    assert serve_spec_missing(d) == [2, 4]
-    with open(os.path.join(d, "serve_spec.history.jsonl"), "w") as f:
-        f.write(json.dumps(
-            {"metric": "serve_spec_tokens_per_sec", "speculate_k": 2,
-             "value": 7000.0, "device_kind": "TPU v5 lite"}) + "\n")
-    assert serve_spec_missing(d) == [4]  # banked history row counts

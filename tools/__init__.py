@@ -1,5 +1,4 @@
-"""Operational tooling (bench watcher helpers, result recorders).
-
-A package so the benchmarks can import the canonical measurement registry
-from tools.bench_gaps — single source for "what must be measured".
+"""What the package holds: ``ring_hlo_evidence.py`` (a hand tool: the
+collective ops each ring schedule compiles to) and ``trace_lock.json``
+(the pinned fingerprints and budgets of ``python -m tpudp.analysis audit``).
 """

@@ -18,14 +18,16 @@ Two surfaces (docs/ANALYSIS.md):
     benches.
 
 This ``__init__`` (and the lint half of the package) is import-light by
-design — stdlib only, jax loaded lazily inside the audit functions — so
-watcher tooling (tools/bench_gaps.py) can run the lint gate on its poll
-path without paying a jax import.
+design — stdlib only, jax loaded lazily inside the audit functions —
+because lint must run without jax: the lint and protocol gates take
+seconds on a host with no jax at all, and a broken jax install must not
+hide a lint finding.
 """
 
-# Relative imports throughout the package: tools/bench_gaps.py loads it
-# standalone (by file path, under a synthetic package name) to run the
-# lint gate without importing the jax-heavy `tpudp` parent package.
+# Relative imports throughout the package: it loads standalone (by file
+# path, under a synthetic package name), so the lint gate runs without
+# importing the jax-heavy `tpudp` parent package
+# (tests/test_analysis.py::test_sources_stale_is_jax_free_and_detects).
 from .core import (PROTOCOL_RULE_NAMES, Finding, Module,  # noqa: F401
                    Rule, lint_paths)
 from .protocol import (MigrationSpec, VoteSpec,  # noqa: F401

@@ -20,8 +20,8 @@ records:
 
 ``compare`` diffs a capture against the lockfile and names the
 offending program and WHAT changed.  Source digests (sha256 of
-AUDIT_SOURCES) also ride in the lock so stdlib-only tooling
-(tools/bench_gaps.py) can flag a stale lock without importing jax; the
+AUDIT_SOURCES) also ride in the lock so the stdlib half below
+(``sources_stale``) can flag a stale lock without importing jax; the
 tier-1 test keeps them fresh, so every hot-path edit forces an
 explicit ``audit --update`` + lockfile diff in review.
 
@@ -70,7 +70,7 @@ def repo_root() -> str:
         os.path.abspath(__file__))))
 
 
-# -- stdlib half (bench_gaps-safe) ------------------------------------
+# -- stdlib half (jax-free) --------------------------------------------
 
 def source_digests(root: str | None = None) -> dict[str, str]:
     from .programs import AUDIT_SOURCES
@@ -103,7 +103,7 @@ def write_lock(path: str, capture_result: dict) -> None:
 
 def sources_stale(lock_path: str, root: str | None = None) -> list[str]:
     """Pinned source files whose digest no longer matches the lock —
-    pure stdlib, usable from the watcher poll path.  A missing/
+    pure stdlib, usable where jax is not.  A missing/
     unreadable lock returns every pinned source."""
     try:
         lock = load_lock(lock_path)

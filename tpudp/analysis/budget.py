@@ -233,9 +233,8 @@ def compare_budgets(name: str, old: dict | None,
 def lock_has_ledgers(lock: dict) -> bool:
     """Is the committed lock budget-complete — capture geometry present
     and a ledger under every pinned program?  THE one definition,
-    shared by `budget --table`, the bench_gaps poll gate, and the
-    tier-1 presence test (three consumers that must never disagree
-    about the same artifact).  Stdlib-only."""
+    shared by `budget --table` and the tier-1 presence test (consumers
+    that must never disagree about the same artifact).  Stdlib-only."""
     programs = lock.get("programs")
     return bool(lock.get("geometry") and programs
                 and all("budget" in rec for rec in programs.values()))

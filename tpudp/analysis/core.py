@@ -1,9 +1,9 @@
 """Rule engine for the tpudp hazard linter.
 
-Pure stdlib (``ast`` + ``re``) by design: the linter must be loadable
-from the watcher's poll path (tools/bench_gaps.py) without importing
-jax, so this module and :mod:`tpudp.analysis.rules` never import
-anything heavier than the standard library.  The jaxpr auditor
+Pure stdlib (``ast`` + ``re``) by design: lint must run without jax
+(in seconds, and on a host whose jax is absent or broken), so this
+module and :mod:`tpudp.analysis.rules` never import anything heavier
+than the standard library.  The jaxpr auditor
 (:mod:`tpudp.analysis.audit`) is the only part of the package that
 touches jax, and it does so lazily inside functions.
 

@@ -25,10 +25,10 @@ half of the package stays stdlib-importable.
 from __future__ import annotations
 
 #: Files whose edits can change a registered trace.  Their sha256
-#: digests ride in the lockfile: tools/bench_gaps.py compares them on
-#: the watcher poll path (stdlib-only) to report a stale lock without
-#: paying a jax import, and the tier-1 audit test requires them fresh
-#: so `audit --update` provenance can't rot.
+#: digests ride in the lockfile: `audit.sources_stale` compares them
+#: with the standard library alone, so a stale lock is reported without
+#: a jax import, and the tier-1 audit test requires them fresh so
+#: `audit --update` provenance can't rot.
 AUDIT_SOURCES = (
     "tpudp/serve/engine.py",
     "tpudp/serve/prefix_cache.py",

@@ -44,7 +44,7 @@ suppressions apply; a suppression naming a protocol rule that matches
 nothing is reported by THIS pass (the lint pass defers those names
 here), so stale protocol exemptions cannot linger.
 
-Pure stdlib, importable from the watcher poll path like the linter.
+Pure stdlib, importable without jax like the linter.
 """
 
 from __future__ import annotations
@@ -244,9 +244,9 @@ class ModuleSet:
 
         Cached per function once the summary fixpoint settled (the
         taint depends on callee summaries, which only grow DURING
-        :meth:`_summarize`; afterwards the ASTs are immutable) — the
-        watcher polls verify_paths, so the repeated whole-AST fixpoints
-        are worth skipping."""
+        :meth:`_summarize`; afterwards the ASTs are immutable), so the
+        verify pass does not repeat the whole-AST fixpoint the summary
+        pass ended on."""
         if info.taint is not None:
             return info.taint
         tainted: dict[str, str] = {}

@@ -196,10 +196,10 @@ sync_auto = sync_allreduce
 
 # Wire-schedule provenance for evidence rows (round-4 advisor): the label
 # "ring" changed meaning in round 4 (bidirectional -> single-direction,
-# per the measured sweep in parallel/ring.py), so bench/matrix rows stamp
-# the direction the labeled rung actually ran, and banked-row matching
-# (tools/bench_gaps.py::matrix_missing) treats ring rows WITHOUT the
-# stamp — pre-flip captures — as measuring a different schedule.
+# per the measured sweep in parallel/ring.py), so bench.py and
+# benchmarks/collective_bench.py stamp their rows with the direction the
+# labeled rung actually ran; a ring row WITHOUT the stamp is a pre-flip
+# capture and measured a different schedule.
 RING_DIRECTION: dict[str, str] = {
     "ring": "uni",
     "ring_uni": "uni",

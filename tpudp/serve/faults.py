@@ -45,9 +45,8 @@ and finish bit-identically — so the storm's referee is the same as the
 soak's: no wedge, no slot leak, survivors bit-exact.
 
 Used by ``tests/test_serve_robustness.py``, ``tests/test_tenancy.py``,
-and the ``serve_soak``/``serve_tenancy`` stages
-(``benchmarks/serve_bench.py --soak`` / ``--tenants``, registered in
-``tools/bench_gaps.py``).
+and the soak referees ``benchmarks/serve_bench.py --soak`` /
+``--tenants``.
 """
 
 from __future__ import annotations

@@ -37,9 +37,8 @@ Plus :func:`corrupt_checkpoint`: deterministic on-disk corruption (byte
 flip / truncation / manifest tamper) driving the verified-restore
 fallback tests and the soak's corrupt-checkpoint phase.
 
-Used by ``tests/test_resilience.py`` and the ``train_soak`` stage
-(``benchmarks/resilience_bench.py``, registered in
-``tools/bench_gaps.py``).
+Used by ``tests/test_resilience.py`` and the kill/resume soak referee
+``benchmarks/resilience_bench.py``.
 """
 
 from __future__ import annotations
