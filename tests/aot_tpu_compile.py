@@ -172,10 +172,11 @@ for name, (fn, args) in CASES.items():
         lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
         assert "tpu_custom_call" in lowered.as_text(), "no Mosaic call"
         ops = MOSAIC_OP.findall(lowered.compile().as_text())
-        named = sorted({k for k in KERNELS for op in ops
-                        if re.search(rf"[/(]{k}[/)]", op)})
+        calls = {k: sum(bool(re.search(rf"[/(]{k}[/)]", op)) for op in ops)
+                 for k in KERNELS}
         print(f"OK {name}")
-        print(f"KERNELS {name} {','.join(named)}")
+        print(f"KERNELS {name} " + ",".join(
+            f"{k}={n}" for k, n in sorted(calls.items()) if n))
     except Exception as exc:  # noqa: BLE001 — report every family
         failed.append(name)
         print(f"FAIL {name}: {type(exc).__name__}: {str(exc)[:800]}")

@@ -28,6 +28,21 @@ def _dense(q, k, v, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
 
 
+def kernel_calls(jaxpr) -> dict:
+    """How many ``pallas_call`` equations a program holds under each
+    kernel ``name=``, sub-programs included (the printed jaxpr shows a
+    sub-program that several equations share once)."""
+    calls = {}
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            calls[name] = calls.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            for name, n in kernel_calls(sub).items():
+                calls[name] = calls.get(name, 0) + n
+    return calls
+
+
 def _rand_qkv(key, b=2, t=256, h=2, dh=32):
     ks = jax.random.split(key, 3)
     shape = (b, t, h, dh)
@@ -179,3 +194,30 @@ def test_explicit_blocks_override_and_are_checked():
         flash_attention(q, k, v, block_q=96)
     with pytest.raises(ValueError, match="multiples of 128"):
         flash_attention(q, k, v, block_q=64, interpret=False)
+
+
+@pytest.mark.parametrize("remat, forward_calls", [(False, 1), (True, 2)])
+def test_checkpoint_names_change_no_program_that_lists_none(remat,
+                                                            forward_calls):
+    """``_flash_fwd`` names its outputs for remat policies (OUT_NAME,
+    LSE_NAME; models/lfm2.py lists them).  A name outside a checkpoint is
+    an identity, and ``make_train_step(remat=True)`` is a whole-model
+    ``jax.checkpoint`` under the default policy, which keeps nothing: a
+    GPT-2 train step holds the calls it held before the names, one
+    forward kernel a layer without remat and two with it."""
+    from tpudp.models.gpt2 import GPT2, GPT2Config
+    from tpudp.train import init_state, make_optimizer, make_train_step
+
+    layers = 2
+    model = GPT2(GPT2Config(vocab_size=128, max_seq_len=128,
+                            num_layers=layers, num_heads=2, d_model=64,
+                            attn_impl="flash"))
+    tx = make_optimizer()
+    state = init_state(model, tx, input_shape=(1, 128))
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    program = jax.make_jaxpr(make_train_step(
+        model, tx, None, "none", donate=False, remat=remat))(
+            state, tokens, tokens)
+    assert kernel_calls(program) == {
+        "flash_fwd": forward_calls * layers, "flash_bwd_dq": layers,
+        "flash_bwd_dkv": layers}

@@ -12,6 +12,7 @@ import optax
 import pytest
 
 from perf.harness.cells import load_module
+from test_flash_attention import kernel_calls
 from tpudp.models.lfm2 import (Lfm2, Lfm2Config, QkNormAttention, ShortConv)
 from tpudp.models.moe import DroplessMoe
 
@@ -32,16 +33,18 @@ CONFIG = dict(
     compute_dtype="float32", train={"moe_impl": "gmm", "remat": True})
 
 
-def _setup(seed=0, **overrides):
+def _setup(seed=0, t=64, attn_impl="dense", **overrides):
     config = {**CONFIG, **overrides}
-    model = fam.build_model(config)
+    model = fam.build_model(config, attn_impl=attn_impl)
     tokens = jnp.asarray(np.random.default_rng(seed).integers(
-        0, config["vocab_size"], (2, 65)))
+        0, config["vocab_size"], (2, t + 1)))
     x, y = tokens[:, :-1], tokens[:, 1:]
     params = model.init(jax.random.PRNGKey(seed + 1), x)["params"]
     # a selection bias that is not zero, so that it is seen to act
-    params["h_2"]["moe"]["expert_bias"] = 0.1 * jax.random.normal(
-        jax.random.PRNGKey(seed + 2), (config["num_experts_routed"],))
+    last = params[f"h_{config['num_hidden_layers'] - 1}"]
+    if "moe" in last:
+        last["moe"]["expert_bias"] = 0.1 * jax.random.normal(
+            jax.random.PRNGKey(seed + 2), (config["num_experts_routed"],))
     return config, model, params, x, y
 
 
@@ -294,11 +297,104 @@ def test_remat_keeps_the_expert_choice_of_the_forward_pass():
     assert top_ks(Lfm2(plain)) == 2
     from tpudp.models import lfm2
 
-    kept, lfm2.ROUTE_SAVED = lfm2.ROUTE_SAVED, None  # nn.remat's default
+    kept, lfm2.REMAT_POLICY = lfm2.REMAT_POLICY, None  # nn.remat's default
     try:
         assert top_ks(Lfm2(model.config)) == 4
     finally:
-        lfm2.ROUTE_SAVED = kept
+        lfm2.REMAT_POLICY = kept
+
+
+def _mean_loss(model, x, y):
+    return lambda p: jnp.mean(fam.system_token_losses(model, p, x, y))
+
+
+def _kept_by_block(grad_jaxpr):
+    """What each block under remat hands its backward pass, read off the
+    gradient's program: for every backward ``remat2`` equation, in the
+    order of the backward pass, ``(name, shape)`` of each operand that is
+    no parameter; ``name`` is what ``checkpoint_name`` gave it
+    (``reduce_precision`` is what jax wraps a kept value in that the
+    forward pass reads again), None for an unnamed one."""
+    jaxpr = grad_jaxpr.jaxpr
+    leaves, names, blocks = set(jaxpr.invars), {}, []
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        if prim == "name":
+            names[eqn.outvars[0]] = eqn.params["name"]
+        elif prim == "reduce_precision" and eqn.invars[0] in names:
+            names[eqn.outvars[0]] = names[eqn.invars[0]]
+        elif prim == "remat2":
+            assert eqn.params["differentiated"]
+            blocks.append([(names.get(v), v.aval.shape)
+                           for v in eqn.invars if v not in leaves])
+    return blocks
+
+
+@pytest.mark.parametrize("layer_types, dense_layers", [
+    (("conv", "full_attention", "conv"), 1),
+    (("full_attention", "conv"), 0),
+    (("conv", "conv"), 1),
+    (("conv", "conv"), 0)])
+def test_a_block_under_remat_keeps_its_input_and_the_named_values(
+        layer_types, dense_layers, monkeypatch):
+    """Beside its input, a block keeps the expert choice, flash's output
+    and row statistics, the convolution's in-projection and what its
+    operator adds to the residual stream, and nothing else: no value of
+    the expert layer's ``T x k``-row buffers.  With ``o`` and ``lse``
+    kept, the backward pass has no use for a second forward flash call:
+    one ``flash_fwd`` an attention layer in the gradient's program, where
+    everything-recomputed holds two."""
+    from tpudp.models import lfm2
+    from tpudp.models.moe import ROUTE_NAME
+    from tpudp.ops.flash_attention import LSE_NAME, OUT_NAME
+
+    config, model, params, x, y = _setup(
+        t=128, attn_impl="flash", layer_types=list(layer_types),
+        num_hidden_layers=len(layer_types), num_dense_layers=dense_layers)
+    assert model.config.remat
+    b, t = x.shape
+    rows = b * t * config["num_experts_per_tok"]
+
+    def program():
+        return jax.make_jaxpr(jax.grad(_mean_loss(
+            Lfm2(model.config), x, y)))(params)
+
+    policy = program()
+    blocks = _kept_by_block(policy)[::-1]  # the backward pass runs last first
+    assert len(blocks) == len(layer_types)
+    for i, (kind, kept) in enumerate(zip(layer_types, blocks)):
+        want = [lfm2.OP_NAME] + (
+            [lfm2.IN_PROJ_NAME] if kind == "conv" else [OUT_NAME, LSE_NAME])
+        if i >= dense_layers:
+            want.append(ROUTE_NAME)
+        assert sorted(n for n, _ in kept if n) == sorted(want), (i, kept)
+        # unnamed: the block's input and the cotangent of its output;
+        # RoPE's positions
+        assert sorted(shape for n, shape in kept if not n) == sorted(
+            [(b, t, config["hidden_size"])] * 2
+            + [(t,)] * (kind == "full_attention")), (i, kept)
+        assert all(shape[:1] != (rows,) for _, shape in kept), (i, kept)
+    attention = layer_types.count("full_attention")
+    calls = kernel_calls(policy)
+    assert [calls.get(k, 0) for k in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [attention] * 3
+    monkeypatch.setattr(lfm2, "REMAT_POLICY", None)  # nn.remat's default
+    assert kernel_calls(program()).get("flash_fwd", 0) == 2 * attention
+
+
+@pytest.mark.parametrize("attn_impl, t", [("dense", 64), ("flash", 128)])
+def test_remat_changes_no_gradient(attn_impl, t):
+    """A kept value is the value its recomputation would have produced:
+    the loss and every parameter's gradient with ``remat`` are those
+    without it."""
+    _, model, params, x, y = _setup(t=t, attn_impl=attn_impl)
+    assert model.config.remat
+    plain = Lfm2(dataclasses.replace(model.config, remat=False))
+    loss, grads = jax.jit(jax.value_and_grad(_mean_loss(model, x, y)))(params)
+    want_loss, want = jax.jit(jax.value_and_grad(_mean_loss(plain, x, y)))(
+        params)
+    assert abs(float(loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
+    assert _rel(grads, want) <= 1e-6
 
 
 def test_unnormalised_scores_and_all_experts_held():

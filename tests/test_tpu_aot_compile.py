@@ -35,6 +35,15 @@ def compiled():
     return proc
 
 
+def _kernel_calls(proc) -> dict:
+    """``{family: {kernel name: Mosaic calls in the compiled program}}``
+    from the child's ``KERNELS <family> <name>=<n>,...`` lines."""
+    return {line.split()[1]: {k: int(n) for k, n in (
+        kv.split("=") for kv in line.split()[2].split(","))}
+        for line in proc.stdout.splitlines()
+        if line.startswith("KERNELS ") and len(line.split()) == 3}
+
+
 def test_kernel_families_compile_for_v5e_topology(compiled):
     proc = compiled
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1500:]
@@ -63,7 +72,18 @@ def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
     program's Mosaic custom calls have their ``pallas_call``'s ``name=``
     in the op_name, and a family carries no other family's kernel."""
-    named = {line.split()[1]: set(line.split()[2].split(","))
-             for line in compiled.stdout.splitlines()
-             if line.startswith("KERNELS ") and len(line.split()) == 3}
-    assert named.get(family) == kernels, compiled.stdout[-3000:]
+    assert set(_kernel_calls(compiled).get(family, ())) == kernels, \
+        compiled.stdout[-3000:]
+
+
+def test_the_lfm2_step_runs_flash_forward_once(compiled):
+    """Each block of the step is under remat, and the model's policy
+    (models/lfm2.py: REMAT_POLICY) keeps flash's output and row
+    statistics: the backward pass reads them, nothing it recomputes does,
+    and the program libtpu compiles for the v5e holds ONE forward flash
+    call for its one attention layer beside one of each backward kernel
+    (everything-recomputed holds two)."""
+    step = _kernel_calls(compiled).get("lfm2_train_step", {})
+    assert [step.get(k) for k in ("flash_fwd", "flash_bwd_dq",
+                                  "flash_bwd_dkv")] == [1, 1, 1], \
+        compiled.stdout[-3000:]

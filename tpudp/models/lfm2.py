@@ -23,11 +23,34 @@ every projection without bias:
     expert-parallel layer (docs/ARCHITECTURE.md, "The expert share").
 
 Reuses ``llama.apply_rope`` and ``ops.attention.multihead_attention`` (so
-``attn_impl='flash'`` is the owned flash kernel); ``remat`` wraps each block
-in ``nn.remat`` and keeps only its input and the experts its tokens chose
-(a recomputed choice can break a near tie the other way, and the backward
-pass would run other experts than the forward pass did).  ``train`` is
-accepted for Trainer compatibility.
+``attn_impl='flash'`` is the owned flash kernel).  ``train`` is accepted for
+Trainer compatibility.
+
+``remat`` wraps each block in ``nn.remat`` under the model's one policy,
+``REMAT_POLICY``.  Beside its input (``2d`` bytes a token a layer in bf16,
+``d = hidden_size``) a block keeps for its backward pass, each named with
+``checkpoint_name`` where it is produced:
+
+  * the experts its tokens chose (``moe.ROUTE_NAME``): a recomputed choice
+    can break a near tie the other way, and the backward pass would run
+    other experts than the forward pass did;
+  * an attention block: flash's output and log-sum-exp
+    (``flash_attention.OUT_NAME``, ``LSE_NAME``; ``2d + 4h`` bytes a token
+    for ``h`` heads).  They are all the backward kernels read of the
+    forward one, so the step runs one forward flash call, not two;
+  * a conv block: ``in_proj``'s output before the split (``IN_PROJ_NAME``;
+    ``6d`` bytes a token), the block's largest product;
+  * every block: what its operator adds to the residual stream, the output
+    of ``wo`` / ``out_proj`` (``OP_NAME``; ``2d`` bytes a token).
+
+Everything else is recomputed: q, k, v with their norm and RoPE, the
+convolution's elementwise tail, the norms, the dense SwiGLU
+(its ``gate`` and ``up`` are ``4 x intermediate_size`` bytes a token: at the
+release's widths they no longer fit a v5e beside the rest) and the whole
+expert layer, whose row buffers have ``T x k`` rows whatever the held
+experts' load (which is why the blocks are under remat at all): none of
+them is ever kept.  There is no knob: a step that cannot hold these bytes
+takes a smaller batch or ``grad_accum``.
 """
 
 from __future__ import annotations
@@ -39,11 +62,17 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from tpudp.models.llama import apply_rope
 from tpudp.models.moe import ROUTE_NAME, DroplessMoe
+from tpudp.ops.flash_attention import LSE_NAME, OUT_NAME
 
-ROUTE_SAVED = jax.checkpoint_policies.save_only_these_names(ROUTE_NAME)
+IN_PROJ_NAME = "conv_in_proj"  # ShortConv's in_proj output, before the split
+OP_NAME = "lfm2_op"  # what the block's operator adds to the residual stream
+# What a block under ``remat`` keeps beside its input (module docstring).
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    ROUTE_NAME, OUT_NAME, LSE_NAME, IN_PROJ_NAME, OP_NAME)
 
 
 @dataclass(frozen=True)
@@ -125,8 +154,8 @@ class ShortConv(nn.Module):
     def __call__(self, u: jnp.ndarray) -> jnp.ndarray:
         cfg = self.config
         d, taps = cfg.hidden_size, cfg.conv_L_cache
-        gate_b, gate_c, x = jnp.split(_dense(cfg, 3 * d, "in_proj")(u), 3,
-                                      axis=-1)
+        gate_b, gate_c, x = jnp.split(checkpoint_name(
+            _dense(cfg, 3 * d, "in_proj")(u), IN_PROJ_NAME), 3, axis=-1)
         w = self.param("conv_w", nn.initializers.variance_scaling(
             1.0, "fan_in", "uniform", in_axis=1, out_axis=0), (d, taps),
             jnp.float32)
@@ -178,9 +207,10 @@ class Lfm2Block(nn.Module):
         cfg, i = self.config, self.index
         u = _rms(cfg, "rms_op")(h)
         if cfg.layer_types[i] == "conv":
-            h = h + ShortConv(cfg, name="conv")(u)
+            op = ShortConv(cfg, name="conv")(u)
         else:
-            h = h + QkNormAttention(cfg, name="attn")(u, positions)
+            op = QkNormAttention(cfg, name="attn")(u, positions)
+        h = h + checkpoint_name(op, OP_NAME)
         u = _rms(cfg, "rms_ffn")(h)
         if i < cfg.num_dense_layers:
             gate = _dense(cfg, cfg.intermediate_size, "w1")(u)
@@ -211,8 +241,7 @@ class Lfm2(nn.Module):
         positions = jnp.arange(tokens.shape[1])
         wte = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                        name="wte")
-        # everything recomputed but the expert choice (moe.ROUTE_NAME)
-        block = nn.remat(Lfm2Block, policy=ROUTE_SAVED) if cfg.remat \
+        block = nn.remat(Lfm2Block, policy=REMAT_POLICY) if cfg.remat \
             else Lfm2Block
         h = wte(tokens)
         for i in range(cfg.num_hidden_layers):

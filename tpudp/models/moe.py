@@ -387,7 +387,7 @@ class DroplessMoe(nn.Module):
         # XLA program, whose rounding can break a near tie the other way:
         # the backward pass would then run other experts than the forward
         # pass did.  Named, so that a remat policy keeps the choice
-        # (models/lfm2.py: ROUTE_SAVED).
+        # (models/lfm2.py: REMAT_POLICY).
         chosen = checkpoint_name(chosen, ROUTE_NAME)
         # the chosen experts' own scores, by comparison (the same values:
         # one term a sum): a gather by index over T x k scalars costs the

@@ -57,6 +57,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -75,6 +76,12 @@ _MAX_BLOCK = 1024
 # 3/4 of the tile's elements where 128 leaves 9/16).
 _DIAG_CHUNK = 128
 _NT = (((1,), (1,)), ((), ()))  # a·bᵀ: contract the last dimension of both
+# The forward kernel's two outputs, for jax.checkpoint policies: a policy
+# that keeps both leaves a rematerialised backward pass no reason to run
+# the forward kernel again (models/lfm2.py: REMAT_POLICY).  Under any other
+# policy, or outside a checkpoint, a name lowers to nothing.
+OUT_NAME = "flash_out"
+LSE_NAME = "flash_lse"
 
 
 def _interpret_default() -> bool:
@@ -459,6 +466,7 @@ def _flash(q, k, v, causal, block_q, block_k, interpret):
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     o, lse = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret)
+    o, lse = checkpoint_name(o, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return o, (q, k, v, o, lse)
 
 
