@@ -17,13 +17,39 @@ from perf.harness.stats import percentile
 
 
 MAX_DRAIN_STEPS = 4096  # a stuck engine must not hang the run
+# What a window's tokens required of each of the engine's two programs,
+# whatever the model (``serve_mfu`` prices it with the family's
+# ``serve_costs``): program runs, token rows through the blocks, rows
+# through the output head, cached tokens read, query-key pairs attended.
+WORK = ("runs", "tokens", "logits", "cache_tokens", "attended")
+
+
+def _add_work(work: dict, prompt_len: int, indices: list[int]) -> None:
+    """Add what the tokens of one request with these indices required.
+    Token 0 is the prefill's: the whole prompt through the blocks, one row
+    of logits, the prompt's keys and values moved once, every causal
+    query-key pair.  Token ``j >= 1`` is a decode step's: one row, which
+    attends over the ``prompt_len + j`` tokens cached by then."""
+    for j in indices:
+        w = work["prefill" if j == 0 else "decode"]
+        w["logits"] += 1
+        if j == 0:
+            w["tokens"] += prompt_len
+            w["cache_tokens"] += prompt_len
+            w["attended"] += prompt_len * (prompt_len + 1) // 2
+        else:
+            w["tokens"] += 1
+            w["cache_tokens"] += prompt_len + j
+            w["attended"] += prompt_len + j
 
 
 class _Rec:
-    __slots__ = ("handle", "want", "submit", "times", "caller", "end")
+    __slots__ = ("handle", "want", "submit", "times", "caller", "end",
+                 "prompt_len")
 
-    def __init__(self, handle, want, submit, caller):
+    def __init__(self, handle, want, submit, caller, prompt_len):
         self.handle, self.want, self.submit = handle, want, submit
+        self.prompt_len = prompt_len
         self.times: list[float] = []   # one stamp per token
         self.caller = caller
         self.end = None                # when step() returned it finished
@@ -40,6 +66,7 @@ class Driver:
         self.traffic, self.config = cell.traffic, cell.config
         self.correct = True
         self.notes: list[str] = []
+        self.compared: list[tuple] = []  # (name, reading, its limit)
         self.live: dict[int, _Rec] = {}   # request id -> record, in flight
         self.done: list[_Rec] = []        # finished since the last harvest
         self.engine = None
@@ -106,6 +133,7 @@ class Driver:
             del ref
         ok = worst <= orc["logit_gap"]
         self.correct &= ok
+        self.compared.append(("oracle_logit_gap", worst, orc["logit_gap"]))
         pa = self.engine.metrics().get("paged_attn", {})
         self.notes.append(
             f"oracle: worst gap of a greedy token to the float32 "
@@ -128,7 +156,7 @@ class Driver:
         prompt, want = self.stream.next()
         now = time.perf_counter()
         handle = self.engine.submit(prompt, want)
-        self.live[handle.id] = _Rec(handle, want, now, caller)
+        self.live[handle.id] = _Rec(handle, want, now, caller, len(prompt))
 
     def _step(self) -> float:
         with self.run.spans.span("perf.engine_step"):
@@ -179,21 +207,28 @@ class Driver:
         in_window = [rec for rec in finished if rec.end <= t1]
         tokens = 0
         ttft, gaps = [], []
+        work = {"decode": dict.fromkeys(WORK, 0),
+                "prefill": dict.fromkeys(WORK, 0)}
         for rec in finished + list(self.live.values()):
             if rec.failed:
                 continue  # a failed request's tokens count for nothing
-            tokens += sum(1 for t in rec.times if t0 <= t <= t1)
+            inside = [j for j, t in enumerate(rec.times) if t0 <= t <= t1]
+            tokens += len(inside)
+            _add_work(work, rec.prompt_len, inside)
             if t0 <= rec.submit <= t1 and rec.times:
                 ttft.append(rec.times[0] - rec.submit)
             gaps.extend(b - a for a, b in zip(rec.times, rec.times[1:])
                         if t0 <= b <= t1)
         delta = {k: after.get(k, 0) - before.get(k, 0) for k in after
                  if isinstance(after.get(k), (int, float))}
+        work["decode"]["runs"] = delta.get("decode_steps", 0)
+        work["prefill"]["runs"] = delta.get("prefill_chunks", 0)
         return {"t0": t0, "t1": t1, "steps": steps, "tokens": tokens,
                 "steps_with_drain": steps + drain,
                 "attempted": len(in_window),
                 "failed": sum(rec.failed for rec in in_window), "ttft": ttft,
                 "gaps": gaps, "engine_stats": delta, "drain_steps": drain,
+                "work": work,
                 "num_slots": self.engine.num_slots,
                 "step_s": self.run.spans.durations("perf.engine_step",
                                                    t0, t1)}

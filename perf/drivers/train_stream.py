@@ -41,6 +41,7 @@ class Driver:
         self.loss_sum_seen = 0.0
         self.correct = True
         self.notes: list[str] = []
+        self.compared: list[tuple] = []  # (name, reading, its limit)
 
     # ---------------------------------------------------------- set-up
 
@@ -110,6 +111,11 @@ class Driver:
         ok = (token_gap <= tr["token_loss_atol"]  # a NaN fails both
               and abs(got - ref_mean) <= tr["loss_atol"])
         self.correct &= ok
+        self.compared += [
+            (name, value, tr[limit]) for name, value, limit in (
+                ("token_loss_gap", token_gap, "token_loss_atol"),
+                ("first_step_loss_gap", abs(got - ref_mean), "loss_atol"))
+            if math.isfinite(tr[limit])]  # a limit of inf: read, not held
         self.notes.append(
             f"one sequence's token losses vs the float32 reference: max "
             f"|diff| {token_gap:.3e} (bound {tr['token_loss_atol']}); "

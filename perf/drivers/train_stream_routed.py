@@ -51,6 +51,8 @@ class Driver(train_stream.Driver):
             r = self.routed_readings(self.state.params)
         ok = self.routed_ok(r)
         self.correct &= ok
+        self.compared += [(name, r[name], self.traffic[limit])
+                          for name, limit in CHECKS]
         tr = self.traffic
         self.notes.append(
             f"expert choices vs the reference's scores: largest shortfall "

@@ -42,6 +42,34 @@ def train_flops_per_sample(config: dict, traffic: dict) -> float:
     return flops.train_step_flops(fwd) / t
 
 
+def serve_costs(config: dict) -> dict:
+    """What serving one unit of the window's work (the serving driver's
+    ``WORK``) costs at the file's sizes, as the least a correct engine
+    can do; ``perf/metrics/serve_mfu.py`` prices a window with it.
+
+    Operations are the matmuls' (2 x multiply-accumulates): a token row
+    through the blocks meets ``12 x n_embd**2`` weights a layer (qkv,
+    projection, the two MLP products), a row of logits the tied embedding,
+    a query-key pair ``QK^T`` and ``AV`` over ``n_embd`` in every layer.
+    Bytes: one run of a program reads every weight it multiplies by once
+    (all but the position table, which is gathered by row), in the type
+    served; a cached token is its key and value in every layer, in the
+    compute type (the engine's pages: 98,304 B at these sizes)."""
+    import jax.numpy as jnp
+
+    d, layers, vocab = config["n_embd"], config["n_layer"], config["vocab_size"]
+    wsize = jnp.dtype(config["serve"]["weight_dtype"]).itemsize
+    csize = jnp.dtype(config["compute_dtype"]).itemsize
+    block = 12 * d * d + 13 * d  # kernels, their biases, the two norms
+    return {
+        "flops_per_token": 2 * layers * 12 * d * d,
+        "flops_per_logit": 2 * vocab * d,
+        "flops_per_attended": 2 * 2 * layers * d,
+        "bytes_per_run": wsize * (layers * block + 2 * d + vocab * d),
+        "bytes_per_cache_token": csize * 2 * layers * d,
+    }
+
+
 # ------------------------------------------------------------- reference
 
 

@@ -6,7 +6,9 @@ Runs one cell of ``BENCHMARK.json`` on the machine it is started on: set-up
 (weights on the device from the seed, compile or cache retrieval, warm-up of
 the cell's own shapes, the correctness check), then a measured window of
 ``--seconds`` in which nothing compiles, then one JSON object as the last
-line of standard output.  ``--trace 0`` reports the cell's end-to-end
+line of standard output.  Every number ``correct`` compared stands beside
+its limit in the last lines of standard error and under the line's last
+key, ``compared``.  ``--trace 0`` reports the cell's end-to-end
 metrics; ``--trace 1`` measures the same window with spans on, then a few
 more seconds under the profiler, and reports the cell's per-layer metrics,
 the device's busy seconds and a breakdown.
@@ -25,12 +27,16 @@ T_START = time.perf_counter()  # set-up counts from here
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRACE_SECONDS = 3.0
-TRACE_DIR = os.path.join(ROOT, "bench_results", "perf_trace")
+# a directory a process: two traced runs of one checkout at once (tests
+# under several workers) would otherwise remove each other's trace
+TRACE_DIR = os.path.join(ROOT, "bench_results", "perf_trace",
+                         str(os.getpid()))
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -135,6 +141,8 @@ def main(argv=None) -> int:
         driver.close()
     run.end_to_end = dict(out["end_to_end"], setup_s=setup_s)
     correct = bool(out["correct"]) and run.window_compiles == 0
+    compared = [*driver.compared, ("failed", out["failed"], 0),
+                ("window_compiles", run.window_compiles, 0)]
 
     for note in driver.notes:
         print(f"[perf] {note}", flush=True)
@@ -177,6 +185,13 @@ def main(argv=None) -> int:
         device["window_s"] = run.trace["window_s"]
         line["breakdown"] = {"device_ops": run.trace["device_ops"],
                              "idle_gaps": run.trace["idle_gaps"]}
+    line["compared"] = {}
+    for name, value, limit in compared:
+        print(f"[perf] compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr, flush=True)
+        line["compared"][name] = {
+            "value": value if math.isfinite(value) else repr(value),
+            "limit": limit}
     print(json.dumps(line), flush=True)
     return 0
 

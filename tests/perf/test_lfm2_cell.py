@@ -1,8 +1,8 @@
 """The ``lfm2moe.train_8k`` cell's own files (PR 29): its configuration
 keeps the published widths and states its cut, its operation counts equal
 a hand count, the four grouped-kernel readers are right on a hand-made
-trace and silent where there is nothing to read (they wait for their
-``BENCHMARK.json`` entries: PERF.md section 7), the cell rehearses on the
+trace and silent where there is nothing to read, the cell's entries are in
+``BENCHMARK.json`` (the readers' since PR 33), the cell rehearses on the
 CPU through ``perf/run.py`` with the routing-aware half of ``correct``,
 and that half comes out false on float8 matrices, a dropped expert layer
 and experts that compute a neighbour's function."""
@@ -16,8 +16,9 @@ from types import SimpleNamespace
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, ROOT)
+sys.path[:0] = [ROOT, os.path.dirname(os.path.abspath(__file__))]
 
+import benchmark_contracts as contracts  # noqa: E402
 from perf.harness import trace  # noqa: E402
 from perf.harness.cells import Cell, load_json, load_module  # noqa: E402
 
@@ -139,14 +140,24 @@ def test_a_grouped_kernel_reader_with_nothing_to_read_returns_nothing(metric):
         assert read(other) is None
 
 
-def test_the_cell_reports_what_benchmark_json_says():
-    cell = Cell(CELL, root=ROOT)
+# what the cell reports: the entries this file knows, each list in the
+# order BENCHMARK.json has them among themselves
+END_TO_END = ["train_throughput_per_chip", "setup_s"]
+PER_LAYER = [
+    "window_compiles", "data_wait_share", "reading_rate_median",
+    "step_device_ms", "mfu", "kernel_share.train", "kernel_ms.flash_fwd",
+    "kernel_ms.flash_bwd_dq", "kernel_ms.flash_bwd_dkv", "kernel_ms.moe_gmm",
+    "kernel_ms.moe_tgmm", "moe_gmm_roofline", "moe_tgmm_roofline"]
+
+
+@pytest.mark.parametrize("kind", contracts.CHECKOUTS)
+def test_the_cell_reports_what_benchmark_json_says(kind, tmp_path):
+    cell = Cell(CELL, root=contracts.checkout(kind, tmp_path))
     assert cell.chips == 1 and cell.traffic["driver"] == "train_stream_routed"
-    assert [m["name"] for m in cell.metrics("end_to_end")] == [
-        "train_throughput_per_chip", "setup_s"]
-    assert [m["name"] for m in cell.metrics("per_layer")] == [
-        "window_compiles", "data_wait_share", "reading_rate_median",
-        "step_device_ms", "mfu", "kernel_share.train"]
+    assert contracts.subsequence(
+        END_TO_END, [m["name"] for m in cell.metrics("end_to_end")])
+    assert contracts.subsequence(
+        PER_LAYER, [m["name"] for m in cell.metrics("per_layer")])
     # the limits of `correct` that this cell brings, each with its readings;
     # the base class's two free-routing numbers are read, not held (their
     # readings say why)
@@ -160,27 +171,38 @@ def test_the_cell_reports_what_benchmark_json_says():
 
 @pytest.fixture(scope="module")
 def rehearsal():
-    """``--trace 0``: every ``--trace 1`` run of one checkout writes the
-    profiler's trace to the same ``bench_results/perf_trace`` and removes
-    it afterwards, and test_layer_readers.py's traced rehearsals run
-    beside this file under xdist."""
+    """``--trace 1``: a traced run has a trace directory of its own (PR
+    33), so test_layer_readers.py's traced rehearsals may run beside this
+    one under xdist."""
     env = dict(os.environ)
     env.pop("BENCH_RUN", None)
     return subprocess.run(
         [sys.executable, os.path.join(ROOT, "perf", "run.py"), "--workload",
-         CELL, "--seed", "3000000019", "--seconds", "1", "--trace", "0",
+         CELL, "--seed", "3000000019", "--seconds", "1", "--trace", "1",
          "--rehearse"], capture_output=True, text=True, env=env, timeout=600)
 
 
 def test_the_cell_rehearses_on_the_cpu(rehearsal):
     assert rehearsal.returncode == 0, rehearsal.stderr[-2000:]
     line = json.loads(rehearsal.stdout.strip().splitlines()[-1])
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert {"correct", "attempted", "failed", "metrics", "device",
+            "compared"} <= set(line)
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 2
-    assert set(line["metrics"]) == {"train_throughput_per_chip", "setup_s"}
+    # what a CPU can read of the cell's per-layer metrics: no device plane,
+    # no kernel; no peaks, no share of them
+    assert {"window_compiles", "data_wait_share", "reading_rate_median"} \
+        <= set(line["metrics"]) <= set(PER_LAYER)
+    assert not any(name.startswith("kernel_") or name.endswith("_roofline")
+                   or name == "mfu" for name in line["metrics"])
     assert "compiles in window=0 " in rehearsal.stdout
+    assert '"train_throughput_per_chip": ' in rehearsal.stdout
+    # the three limits this cell brings, each beside its reading; the two
+    # the base class only reads are not among the numbers compared
+    assert list(line)[-1] == "compared" and set(line["compared"]) == {
+        "choice_gap", "routed_token_gap", "routed_grad_gap", "failed",
+        "window_compiles"}
 
 
 def test_the_rehearsal_makes_the_routing_aware_comparisons(rehearsal):
@@ -194,27 +216,34 @@ def test_the_rehearsal_makes_the_routing_aware_comparisons(rehearsal):
     assert len(free) == 1 and free[0].count("bound inf") == 2  # read, not held
 
 
-def test_benchmark_json_gained_only_appended_entries():
-    """Against the parent's file (the entries PR 27 left, by name): every
-    list keeps its old entries first and in order; the cell and its
-    configuration are at the end of theirs, the cell at the end of the
-    ``workloads`` of the six training metrics it reports.  No per-layer
-    entry is added and the three ``kernel_ms.flash_*`` keep their one
-    cell: PR 26's test pins the end of ``per_layer`` and those lists."""
-    b = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert [c["name"] for c in b["configs"]] == ["gpt2_medium", "lfm2_8b_a1b"]
-    assert [w["name"] for w in b["workloads"]] == [
-        "gpt2m.train", "gpt2m.serve_closed", CELL]
-    assert [m["name"] for m in b["per_layer"][-2:]] == [
-        "kernel_ms.paged_decode", "kernel_ms.paged_prefill"]
+@pytest.mark.parametrize("kind", contracts.CHECKOUTS)
+def test_benchmark_json_holds_the_cells_entries_in_order(kind, tmp_path):
+    """By name and by order among themselves, never a whole list nor its
+    end (benchmark_contracts.py): the configuration after ``gpt2_medium``,
+    the cell after the two that were there, and after ``gpt2m.train`` on
+    every ``workloads`` the two share; the four grouped-kernel entries (PR
+    33; PR 29 had to leave them out) list the cell."""
+    b = contracts.load(contracts.checkout(kind, tmp_path))
+    assert contracts.subsequence(["gpt2_medium", "lfm2_8b_a1b"],
+                                 [c["name"] for c in b["configs"]])
+    assert contracts.subsequence(["gpt2m.train", "gpt2m.serve_closed", CELL],
+                                 [w["name"] for w in b["workloads"]])
     reported = {m["name"]: m["workloads"] for m in
                 b["end_to_end"] + b["per_layer"] if CELL in m.get(
                     "workloads", [])}
-    assert set(reported) == {
-        "train_throughput_per_chip", "data_wait_share", "reading_rate_median",
-        "step_device_ms", "mfu", "kernel_share.train"}
+    assert set(END_TO_END + PER_LAYER) - {"setup_s", "window_compiles"} \
+        <= set(reported)
     for name, cells in reported.items():
-        assert cells == ["gpt2m.train", CELL], name
+        if "gpt2m.train" in cells:
+            assert contracts.subsequence(["gpt2m.train", CELL], cells), name
+    per_layer = {m["name"]: m for m in b["per_layer"]}
+    for name in PER_LAYER[-4:]:
+        m = per_layer[name]
+        assert (m["source"], m["layer"], m["moves"]) == (
+            "device_trace", "Kernels", "train_throughput_per_chip"), name
+        assert (m["unit"], m["better"]) == (
+            ("ms", "lower") if name.startswith("kernel_ms.")
+            else ("%", "higher")), name
     assert b["run_seconds"] == 20
 
 

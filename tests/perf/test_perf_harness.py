@@ -6,7 +6,6 @@ reproducible and never exceeds the model's positions."""
 import json
 import os
 import re
-import shutil
 import subprocess
 import sys
 from itertools import count
@@ -15,8 +14,9 @@ from statistics import median, quantiles
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, ROOT)
+sys.path[:0] = [ROOT, os.path.dirname(os.path.abspath(__file__))]
 
+import benchmark_contracts as contracts  # noqa: E402
 from perf.harness import loadgen, stats, trace  # noqa: E402
 from perf.harness.cells import Cell, load_json  # noqa: E402
 
@@ -35,17 +35,13 @@ def _run(root, *args, env=None):
 
 @pytest.fixture()
 def copy(tmp_path):
-    """A copy of the benchmark's files with the program linked beside it."""
-    root = tmp_path / "checkout"
-    shutil.copytree(os.path.join(ROOT, "perf"), root / "perf",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
-    os.symlink(os.path.join(ROOT, "tpudp"), root / "tpudp")
-    return root
+    return contracts.copy_checkout(tmp_path)
 
 
-def test_benchmark_json_keeps_the_contract():
-    b = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+@pytest.mark.parametrize("kind", contracts.CHECKOUTS)
+def test_benchmark_json_keeps_the_contract(kind, tmp_path):
+    root = contracts.checkout(kind, tmp_path)
+    b = contracts.load(root)
     assert set(b) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert 1 <= b["run_seconds"] <= 51 and isinstance(b["run_seconds"], int)
@@ -59,13 +55,13 @@ def test_benchmark_json_keeps_the_contract():
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-        assert os.path.isfile(os.path.join(ROOT, "perf", "traffic",
+        assert os.path.isfile(os.path.join(root, "perf", "traffic",
                                            w["traffic"] + ".json"))
     for c in b["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert 1 <= len(c["source"]) <= 200 and 1 <= len(c["why"]) <= 200
         assert any(c["file"].startswith(p + "/") for p in b["paths"])
-        assert load_json(os.path.join(ROOT, c["file"]))["reduced"] == c["reduced"]
+        assert load_json(os.path.join(root, c["file"]))["reduced"] == c["reduced"]
     names = [m["name"] for m in b["end_to_end"] + b["per_layer"]]
     assert len(names) == len(set(names))
     e2e = {m["name"]: m for m in b["end_to_end"]}
@@ -80,7 +76,7 @@ def test_benchmark_json_keeps_the_contract():
                                          "layer", "moves"}
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-        assert os.path.isfile(os.path.join(ROOT, "perf", "metrics",
+        assert os.path.isfile(os.path.join(root, "perf", "metrics",
                                            m["name"] + ".py"))
         # the metric it moves is reported in every cell where it is
         mine = set(m.get("workloads", cells))
@@ -97,37 +93,18 @@ def test_benchmark_json_keeps_the_contract():
         assert any(name in m.get("workloads", cells) for m in b["per_layer"])
 
 
-def test_files_added_to_a_copy_are_found_with_no_edit(copy):
+def test_files_added_to_a_copy_are_found_with_no_edit(tmp_path):
     """A configuration, a traffic mix, a cell and a per-layer metric come
-    in as new files and new entries; no file that was there changes."""
-    cfg = load_json(copy / "perf" / "configs" / "gpt2_medium.json")
-    cfg["rehearsal"]["n_layer"] = 1
-    (copy / "perf" / "configs" / "gpt2_other.json").write_text(json.dumps(cfg))
-    tr = load_json(copy / "perf" / "traffic" / "lm_tokens.json")
-    tr["rehearsal"]["per_chip_batch"] = 1
-    (copy / "perf" / "traffic" / "lm_short.json").write_text(json.dumps(tr))
-    (copy / "perf" / "metrics" / "steps_total.py").write_text(
-        "def read(run):\n    return float(run.window['steps'])\n")
-    b = load_json(copy / "BENCHMARK.json")
-    b["configs"].append({"name": "gpt2_other", "source": "test",
-                         "file": "perf/configs/gpt2_other.json",
-                         "reduced": [], "why": "test"})
-    b["workloads"].append({"name": "other.short", "config": "gpt2_other",
-                           "traffic": "lm_short", "chips": 1, "why": "test"})
-    for m in b["end_to_end"]:
-        if m["name"] == "train_throughput_per_chip":
-            m["workloads"].append("other.short")
-    b["per_layer"].append({"name": "steps_total", "unit": "count",
-                           "better": "higher", "source": "program_counter",
-                           "layer": "Trainer loop",
-                           "moves": "train_throughput_per_chip",
-                           "workloads": ["other.short"]})
-    (copy / "BENCHMARK.json").write_text(json.dumps(b))
+    in as new files and new entries; no file that was there changes.  The
+    new cell is on every metric's ``workloads`` (benchmark_contracts.py):
+    a reader with nothing to read in it returns nothing."""
+    copy = contracts.grown(tmp_path)
 
     cell = Cell("other.short", root=str(copy), rehearse=True)
     assert cell.config["n_layer"] == 1 and cell.traffic["per_chip_batch"] == 1
-    assert [m["name"] for m in cell.metrics("per_layer")] == [
-        "window_compiles", "steps_total"]
+    mine = [m["name"] for m in cell.metrics("per_layer")]
+    assert contracts.subsequence(["window_compiles", "steps_total"], mine)
+    assert set(mine) == {m["name"] for m in contracts.load(copy)["per_layer"]}
 
     # ... and the rehearsal runs the new cell; its last line has exactly
     # the contract's keys and says platform=cpu
@@ -135,15 +112,43 @@ def test_files_added_to_a_copy_are_found_with_no_edit(copy):
              "--seconds", "2", "--trace", "1", "--rehearse")
     assert p.returncode == 0, p.stderr[-2000:]
     line = json.loads(p.stdout.strip().splitlines()[-1])
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device",
+                         "compared"}
+    # every number ``correct`` compared, beside its limit: the line's last
+    # key and the last lines of standard error
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {"token_loss_gap", "first_step_loss_gap",
+                                     "failed", "window_compiles"}
+    assert all(c["value"] <= c["limit"] for c in line["compared"].values())
+    assert [ln.split()[2].rstrip(":") for ln in p.stderr.strip().splitlines()[
+        -len(line["compared"]):]] == list(line["compared"])
     assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 1
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(line["device"])
     assert line["correct"] is True and line["failed"] == 0
     assert line["metrics"]["steps_total"] == {
         "value": float(line["attempted"]), "unit": "count"}
     assert line["metrics"]["window_compiles"]["value"] == 0.0
-    # a device metric has no reading on the CPU: its reader returned nothing
-    assert "step_device_ms" not in line["metrics"]
+    # a device metric has no reading on the CPU, a serving metric none in a
+    # training run: their readers returned nothing
+    assert {"window_compiles", "steps_total", "data_wait_share",
+            "reading_rate_median"} <= set(line["metrics"])
+    assert not {"step_device_ms", "mfu", "kernel_ms.flash_fwd", "serve_mfu",
+                "engine_host_ms", "slot_occupancy"} & set(line["metrics"])
+
+
+def test_a_traced_run_has_a_trace_directory_of_its_own():
+    """Two ``--trace 1`` runs of one checkout at once (the tests' workers)
+    each write and remove a directory named by their process."""
+    from perf import run
+
+    assert os.path.split(run.TRACE_DIR) == (
+        os.path.join(ROOT, "bench_results", "perf_trace"), str(os.getpid()))
+    p = subprocess.run(
+        [sys.executable, "-c", "import sys; sys.path.insert(0, sys.argv[1]); "
+         "from perf import run; print(run.TRACE_DIR)", ROOT],
+        capture_output=True, text=True, timeout=60)
+    assert p.stdout.strip() != run.TRACE_DIR
+    assert os.path.dirname(p.stdout.strip()) == os.path.dirname(run.TRACE_DIR)
 
 
 def test_no_chip_means_a_nonzero_exit_and_no_metric():
