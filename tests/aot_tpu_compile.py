@@ -123,6 +123,49 @@ def lfm2_step():
                                                            tokens)
 
 
+def latent_steps():
+    """The serve engine's two programs for the latent-attention expert
+    family at the benchmark cell's real sizes (perf/configs/
+    pangu_ultra_moe_718b.json; 64 slots, 8,192 positions, 512-token pages,
+    1,024 of them): the grouped kernels at a decode step's 512 rows and a
+    chunk's 4,096, the whole program within the chip's memory, and the
+    page pool in ONE layout (PR 34 found XLA transposing all 3.4 GB of it
+    into a token-minor layout and back around a page write)."""
+    import json
+
+    from tpudp.models.generate import LatentPages
+    from tpudp.models.pangu import Pangu, PanguConfig
+    from tpudp.serve import engine
+
+    sys.modules["tpudp.ops.grouped_matmul"]._interpret_default = (
+        lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "pangu_ultra_moe_718b.json")) as f:
+        config = {k: v for k, v in json.load(f).items() if k != "rehearsal"}
+    cfg = PanguConfig.from_dict(config, dtype=BF16, param_dtype=BF16)
+    shapes = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = shapes(jax.eval_shape(
+        lambda k: Pangu(cfg).init(k, jnp.zeros((1, 16), jnp.int32))["params"],
+        jax.random.PRNGKey(0)))
+    slots, chunk, width = 64, 512, 8192 // 512
+    pool = shapes(jax.eval_shape(
+        lambda: LatentPages.zeros(cfg, LATENT_PAGES + 1, chunk)))
+    steps = engine._build_steps(cfg, "einsum")
+    i32, f32 = jnp.int32, jnp.float32
+    return {
+        "latent_decode": (steps[6], (
+            params, pool, sds((slots, width), i32), sds((slots,), i32),
+            sds((slots,), i32), sds((slots,), jnp.bool_), sds((slots,), f32),
+            sds((slots,), i32), sds((slots,), f32),
+            sds((slots, 2), jnp.uint32), sds((5,), f32))),
+        "latent_prefill": (steps[8], (
+            params, pool, sds((width,), i32), sds((1, chunk), i32),
+            sds((), i32), sds((), i32)))}
+
+
+LATENT_PAGES = 1024
 ANC = tuple(map(tuple, np.tril(np.ones((5, 5), np.int32))))
 x = sds((2, 1024, H, DH), BF16)
 x8k = sds((1, 8192, H, DH), BF16)  # the chooser's blocks at a long sequence
@@ -139,6 +182,7 @@ CASES = {
                                  sds((G,), jnp.int32))),
     "moe_layer": moe_layer(),
     "lfm2_train_step": lfm2_step(),
+    **latent_steps(),
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
         jnp.float32).sum(), argnums=(0, 1, 2)), (x, x, x)),
@@ -171,12 +215,21 @@ for name, (fn, args) in CASES.items():
     try:
         lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
         assert "tpu_custom_call" in lowered.as_text(), "no Mosaic call"
-        ops = MOSAIC_OP.findall(lowered.compile().as_text())
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        ops = MOSAIC_OP.findall(text)
         calls = {k: sum(bool(re.search(rf"[/(]{k}[/)]", op)) for op in ops)
                  for k in KERNELS}
         print(f"OK {name}")
         print(f"KERNELS {name} " + ",".join(
             f"{k}={n}" for k, n in sorted(calls.items()) if n))
+        if name.startswith("latent_"):
+            mem = compiled.memory_analysis()
+            layouts = sorted(set(re.findall(
+                rf"bf16\[5,{LATENT_PAGES + 1},512,\d+\]{{([\d,]+)", text)))
+            print(f"POOL {name} layouts={'|'.join(layouts)} bytes="
+                  f"{mem.argument_size_in_bytes + mem.output_size_in_bytes
+                     + mem.temp_size_in_bytes - mem.alias_size_in_bytes}")
     except Exception as exc:  # noqa: BLE001 — report every family
         failed.append(name)
         print(f"FAIL {name}: {type(exc).__name__}: {str(exc)[:800]}")

@@ -329,6 +329,10 @@ def test_program_donations_mirror_rules_tables():
         # the Pallas kernel twin dispatches through the same
         # _ModelState.decode_paged attribute (same signature/donations)
         "serve.decode_paged_kernel": "decode_paged",
+        # the latent-attention expert family's two programs (PR 34)
+        # dispatch through the same two attributes, same donations
+        "serve.decode_paged_latent": "decode_paged",
+        "serve.prefill_paged_latent": "prefill_paged",
         "serve.verify_paged": "verify_paged",
         # ... and likewise for the remaining ISSUE 17 kernel twins:
         # each dispatches through the same engine attribute as its

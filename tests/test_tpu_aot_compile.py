@@ -19,6 +19,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 # every Mosaic call of the expert layer, forward and backward
 MOE_LAYER = {"moe_gmm", "moe_tgmm", "moe_swiglu", "moe_swiglu_bwd",
              "moe_combine", "moe_unwritten"}
+MOE_FORWARD = {"moe_gmm", "moe_swiglu", "moe_combine", "moe_unwritten"}
 
 
 @pytest.fixture(scope="module")
@@ -52,7 +53,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     assert ok == {"flash_fwd", "flash_bwd", "flash_bwd_8k", "paged_decode",
                   "paged_window_verify", "paged_window_prefill",
                   "paged_tree", "moe_gmm_up", "moe_gmm_down", "moe_layer",
-                  "lfm2_train_step"}, proc.stdout
+                  "lfm2_train_step", "latent_decode", "latent_prefill"}, \
+        proc.stdout
 
 
 @pytest.mark.parametrize("family, kernels", [
@@ -67,7 +69,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("moe_gmm_down", {"moe_gmm", "moe_tgmm"}),
     ("moe_layer", MOE_LAYER),
     ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
-     | MOE_LAYER)])
+     | MOE_LAYER),
+    ("latent_decode", MOE_FORWARD), ("latent_prefill", MOE_FORWARD)])
 def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
     program's Mosaic custom calls have their ``pallas_call``'s ``name=``
@@ -87,3 +90,19 @@ def test_the_lfm2_step_runs_flash_forward_once(compiled):
     assert [step.get(k) for k in ("flash_fwd", "flash_bwd_dq",
                                   "flash_bwd_dkv")] == [1, 1, 1], \
         compiled.stdout[-3000:]
+
+
+@pytest.mark.parametrize("program", ["latent_decode", "latent_prefill"])
+def test_a_latent_step_program_fits_and_keeps_the_pool_in_place(compiled,
+                                                                program):
+    """The serve engine's programs for the latent-attention expert family
+    at the benchmark cell's sizes (`pangu_moe.serve_closed_2k`: 9.84 GB of
+    weights, 1,024 pages of 512 tokens): within the v5e's 15.75 GB, and
+    every operation on the page pool in its one row-major layout (a second
+    layout means XLA copies the whole 3.4 GB pool, twice a program)."""
+    lines = [ln.split() for ln in compiled.stdout.splitlines()
+             if ln.startswith(f"POOL {program} ")]
+    assert len(lines) == 1, compiled.stdout[-3000:]
+    fields = dict(kv.split("=") for kv in lines[0][2:])
+    assert fields["layouts"] == "3,2,1,0", fields
+    assert 13.0e9 < int(fields["bytes"]) < 15.75e9, fields

@@ -36,6 +36,8 @@ AUDIT_SOURCES = (
     "tpudp/models/generate.py",
     "tpudp/models/gpt2.py",
     "tpudp/models/llama.py",
+    "tpudp/models/pangu.py",
+    "tpudp/models/moe.py",
     "tpudp/ops/sampling.py",
     "tpudp/ops/attention.py",
     "tpudp/ops/paged_attention.py",
@@ -61,10 +63,12 @@ TRACE_COUNTER_PROGRAMS = {
     "fused_decode": "serve.fused_decode",
     "decode_paged": "serve.decode_paged",
     "decode_paged_kernel": "serve.decode_paged_kernel",
+    "decode_paged_latent": "serve.decode_paged_latent",
     "verify_paged": "serve.verify_paged",
     "verify_paged_kernel": "serve.verify_paged_kernel",
     "prefill_paged": "serve.prefill_paged",
     "prefill_paged_kernel": "serve.prefill_paged_kernel",
+    "prefill_paged_latent": "serve.prefill_paged_latent",
     "fused_decode_paged": "serve.fused_decode_paged",
     "fused_decode_paged_kernel": "serve.fused_decode_paged_kernel",
     "fused_spec_decode": "serve.fused_spec_decode",
@@ -100,6 +104,9 @@ PROGRAM_DONATIONS = {
     # donation facts program-for-program.
     "serve.decode_paged": (1, 10),
     "serve.decode_paged_kernel": (1, 10),
+    # the latent-attention expert family's two programs (LatentPages pool)
+    "serve.decode_paged_latent": (1, 10),
+    "serve.prefill_paged_latent": (1,),
     "serve.verify_paged": (1, 11),
     "serve.verify_paged_kernel": (1, 11),
     "serve.prefill_paged": (1,),
@@ -407,6 +414,31 @@ def build_programs() -> dict:
         functools.partial(tree_k, parents=TREE_PARENTS),
         (params, pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
          h["temps"], h["topk"], h["topp"], h["keys"], h["counts"]))
+
+    # The latent-attention expert family (tpudp/models/pangu.py): its two
+    # programs over a LatentPages pool, at the same smoke geometry.  The
+    # widths are no multiples of 128, so the expert layer traces its
+    # plain loop (the kernels have lowering tests of their own).
+    import jax
+    import jax.numpy as jnp
+
+    from tpudp.models.generate import LatentPages
+    from tpudp.models.pangu import Pangu, PanguConfig
+
+    lcfg = PanguConfig(vocab_size=SERVE["vocab"],
+                       max_position_embeddings=SERVE["seq"],
+                       num_experts_routed=8)
+    lparams = Pangu(lcfg).init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"]
+    lsteps = _engine._build_steps(lcfg, "einsum")
+    lpool = LatentPages.zeros(lcfg, n_pages + 1, SERVE["chunk"])
+    programs[f"serve.decode_paged_latent@{pgeo2}"] = (
+        lsteps[6], (lparams, lpool, table, h["last"], h["lens"], h["active"],
+                    h["temps"], h["topk"], h["topp"], h["keys"],
+                    h["counts"]))
+    programs[f"serve.prefill_paged_latent@{pgeo2}c{SERVE['chunk']}"] = (
+        lsteps[8], (lparams, lpool, table[0], h["chunk"], np.int32(0),
+                    np.int32(SERVE["chunk"] - 1)))
 
     programs["serve.sample_row@v%d" % SERVE["vocab"]] = (
         _engine._sample_row,

@@ -26,6 +26,11 @@ Cache memory is ``2 * L * B * max_len * d_model * kv_heads / num_heads``
 in ``llama.block_decode`` never widens it back; for generation lengths
 where the cache is the constraint, raise ``max_len`` only as far as
 needed (static shape).
+
+A third family is served through the PAGED forward only
+(:func:`_forward_paged`): latent attention with routed experts
+(``tpudp.models.pangu``), whose cache is :class:`LatentPages`.  It has no
+dense cache twin, so ``generate()`` and ``beam_search()`` refuse it.
 """
 
 from __future__ import annotations
@@ -45,13 +50,18 @@ class KVCache(NamedTuple):
     v: jnp.ndarray
 
     @classmethod
-    def zeros(cls, cfg, batch: int, max_len: int) -> "KVCache":
+    def geometry(cls, cfg) -> tuple:
+        """What two models must share to share one page pool."""
         # GQA configs (LlamaConfig.kv_heads < num_heads) allocate the
         # cache at KV width — the group factor is exactly the decode
         # memory GQA exists to save; MHA configs (GPT-2) are unchanged.
-        kv_heads = getattr(cfg, "kv_heads", cfg.num_heads)
-        shape = (cfg.num_layers, batch, max_len, kv_heads,
-                 cfg.d_model // cfg.num_heads)
+        return (cfg.num_layers, getattr(cfg, "kv_heads", cfg.num_heads),
+                cfg.d_model // cfg.num_heads, str(cfg.dtype))
+
+    @classmethod
+    def zeros(cls, cfg, batch: int, max_len: int) -> "KVCache":
+        layers, kv_heads, dh, _ = cls.geometry(cfg)
+        shape = (layers, batch, max_len, kv_heads, dh)
         return cls(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
@@ -71,14 +81,64 @@ class Int8Pages(NamedTuple):
     k_scale: jnp.ndarray  # (layers, pages, page_tokens, kv_heads) fp32
     v_scale: jnp.ndarray
 
+    geometry = KVCache.geometry
+
     @classmethod
     def zeros(cls, cfg, num_pages: int, page_tokens: int) -> "Int8Pages":
-        kv_heads = getattr(cfg, "kv_heads", cfg.num_heads)
-        shape = (cfg.num_layers, num_pages, page_tokens, kv_heads,
-                 cfg.d_model // cfg.num_heads)
+        layers, kv_heads, dh, _ = cls.geometry(cfg)
+        shape = (layers, num_pages, page_tokens, kv_heads, dh)
         return cls(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
                    jnp.ones(shape[:-1], jnp.float32),
                    jnp.ones(shape[:-1], jnp.float32))
+
+
+class LatentPages(NamedTuple):
+    """Page-pool buffers of a latent-attention (MLA) family
+    (``tpudp.models.pangu``): a token leaves ONE row a layer, its
+    normalised latent ``c_kv`` and its rotated shared key ``k_rope``, in
+    the compute dtype; nothing per head.  The two parts live in two
+    buffers, each with a minor dimension that is a multiple of the 128
+    lanes (``k_rope`` zero-padded up to one: ``pangu.latent_pad``; 512 + 64
+    would be 4.5 lane tiles in one buffer), so that XLA has no reason to
+    pad or relayout either: the step programs touch the pool only through
+    row writes into the donated buffers and page gathers out of them.
+    Block ids, the table, the radix tree and the trailing scratch page are
+    the other page types' (:class:`KVCache`, :class:`Int8Pages`)."""
+
+    c: jnp.ndarray  # (layers, pages, page_tokens, kv_lora_rank)
+    r: jnp.ndarray  # (layers, pages, page_tokens, latent_pad)
+
+    @classmethod
+    def geometry(cls, cfg) -> tuple:
+        from tpudp.models.pangu import latent_pad
+
+        return ("latent", cfg.num_layers, cfg.kv_lora_rank, latent_pad(cfg),
+                str(jnp.dtype(cfg.dtype)))
+
+    @classmethod
+    def zeros(cls, cfg, num_pages: int, page_tokens: int) -> "LatentPages":
+        _, layers, c, r, _ = cls.geometry(cfg)
+        return cls(jnp.zeros((layers, num_pages, page_tokens, c), cfg.dtype),
+                   jnp.zeros((layers, num_pages, page_tokens, r), cfg.dtype))
+
+
+def page_layout(cfg) -> str:
+    """``'heads'`` (K and V per KV head: GPT-2, LLaMA) or ``'latent'``
+    (:class:`LatentPages`), from the model config."""
+    return getattr(cfg, "page_layout", "heads")
+
+
+def page_type(cfg, kv_dtype: str | None = None):
+    """The page-pool pytree class a config's cache lives in.  Each has
+    ``zeros(cfg, num_pages, page_tokens)`` and ``geometry(cfg)``, the
+    tuple two models must share to share one pool."""
+    if page_layout(cfg) == "latent":
+        if kv_dtype is not None:
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} is not implemented for latent "
+                "(MLA) pages: they are kept in the compute dtype")
+        return LatentPages
+    return Int8Pages if kv_dtype == "int8" else KVCache
 
 
 def _quantize_kv(x: jnp.ndarray):
@@ -182,7 +242,10 @@ def write_token_pages(pages, k_new: jnp.ndarray, v_new: jnp.ndarray,
     decode step's write traffic is one token's worth of KV, not a
     whole-page (let alone whole-view) rewrite.
 
-    ``pages`` is one LAYER's page buffers — ``(k, v)`` fp or
+    ``pages`` is one LAYER's page buffers — ``(k, v)`` fp (the two may
+    differ in everything behind the token axis: :class:`LatentPages`'
+    ``(c, r)`` with ``k_new`` the latents and ``v_new`` the rotary keys
+    commit through this same function) or
     ``(k, v, k_scale, v_scale)`` int8 (new vectors quantize with the
     same symmetric-absmax math as :func:`scatter_pages`; since that
     quantization is idempotent on already-quantized vectors, the pool
@@ -321,6 +384,49 @@ class _PagedKV:
                                impl=self.impl, layer=self.layer)
 
 
+def _row_major(x: jnp.ndarray) -> jnp.ndarray:
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+class _LatentKV:
+    """The latent family's page store (``pangu.block_paged``): like
+    :class:`_PagedKV` in whole-pool mode, over :class:`LatentPages`'
+    ``(c, r)`` buffers.  ``layer`` is set by the forward before each
+    block; ``write`` commits the window's latents as single-page token
+    writes (:func:`write_token_pages`), ``attend`` runs the absorbed
+    attention through the block table."""
+
+    __slots__ = ("cfg", "pages", "table", "pos", "active", "layer")
+
+    def __init__(self, cfg, pages, table, pos, active):
+        self.cfg, self.pages, self.table = cfg, pages, table
+        self.pos, self.active, self.layer = pos, active, None
+
+    def write(self, c_kv: jnp.ndarray, k_rope: jnp.ndarray) -> None:
+        # a whole page-aligned chunk (b == 1) commits as ONE page write,
+        # which write_token_pages takes from a scalar position
+        pos = self.pos[0] if c_kv.shape[0] == 1 else self.pos
+        # Row-major rows, said out loud.  The 576-wide projection they
+        # come from is no multiple of the 128 lanes, XLA lays its output
+        # out token-minor, a page write takes its update's layout to its
+        # operand, and the WHOLE pool was transposed into that layout and
+        # back: 3.4 GB each way a prefill chunk (AOT compile for the v5e).
+        c_kv, k_rope = _row_major(c_kv), _row_major(k_rope)
+        self.pages = write_token_pages(self.pages, c_kv, k_rope, self.table,
+                                       pos, self.active, layer=self.layer)
+
+    def attend(self, q_lat: jnp.ndarray, q_rope: jnp.ndarray) -> jnp.ndarray:
+        from tpudp.ops.paged_attention import latent_paged_attention
+
+        return latent_paged_attention(
+            q_lat, q_rope, self.pages, self.table, self.pos,
+            scale=self.cfg.score_scale, dtype=self.cfg.dtype,
+            layer=self.layer)
+
+
 class _TreePagedKV:
     """One layer's READ-ONLY paged store for the tree-verify forward:
     ``attend`` runs the tree kernel over the slot's cache pages (strict
@@ -384,9 +490,17 @@ def _forward_tree_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
 
 def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
                    table: jnp.ndarray, pos: jnp.ndarray,
-                   active: jnp.ndarray, impl: str = "einsum"):
+                   active: jnp.ndarray, impl: str = "einsum", *,
+                   last=None, routed: list | None = None):
     """Page-table-indirected twin of :func:`_forward_cached` for the
     serve engine's paged arena.  Returns ``(logits, pool)``.
+
+    Three families dispatch here: GPT-2, LLaMA, and the latent-attention
+    expert family (``tpudp.models.pangu.forward_paged``: absorbed MLA over
+    :class:`LatentPages`, the dropless expert layer; it has the one
+    path and ``impl`` does not reach it (the engine refuses
+    ``paged_attn`` other than einsum); the one family that takes ``last``
+    and ``routed``, which see there).
 
     ``impl='einsum'`` (the engine default) and ``'kernel'`` are
     GATHER-FREE: each layer's block twin writes the window's new K/V
@@ -405,6 +519,11 @@ def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
     ``impl='gather'`` is PR 13's original path — gather the dense view,
     run the exact dense forward, scatter written pages back — kept as
     the bench comparison baseline and the kernel tests' oracle."""
+    if page_layout(cfg) == "latent":
+        from tpudp.models import pangu as _pangu
+
+        return _pangu.forward_paged(cfg, params, tokens, pool, table, pos,
+                                    active, last=last, routed=routed)
     if impl == "gather":
         view = gather_pages(cfg, pool, table)
         logits, view = _forward_cached(cfg, params, tokens, view, pos)
@@ -722,6 +841,7 @@ def validate_decode_config(cfg, fn_name: str) -> None:
     config with ``attn_impl='dense'`` to decode them.  Shared by the
     generate()/beam_search() entry points and tpudp.serve.Engine."""
     mlp_impl = getattr(cfg, "mlp_impl", "dense")  # LlamaConfig: dense only
+    # (the latent family's expert layer IS served: pangu.block_paged)
     if cfg.attn_impl != "dense" or mlp_impl != "dense":
         raise ValueError(
             f"{fn_name} supports dense-attention/dense-MLP configs "
@@ -734,6 +854,11 @@ def validate_decode_config(cfg, fn_name: str) -> None:
 
 def _validate_decode(cfg, prompt, max_new_tokens: int, fn_name: str) -> int:
     """Shared decode-entry checks; returns the total sequence length."""
+    if page_layout(cfg) == "latent":
+        raise ValueError(
+            f"{fn_name} has no dense-cache twin for a latent-attention "
+            f"config ({type(cfg).__name__}): its cache exists only as "
+            f"pages; serve it through tpudp.serve.Engine(kv_pages=N)")
     validate_decode_config(cfg, fn_name)
     prompt_len = prompt.shape[1]
     total = prompt_len + max_new_tokens

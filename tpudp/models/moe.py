@@ -298,6 +298,104 @@ def _expert_ffn_bwd(res, d_out):
 _expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
 
 
+def dropless_moe(x, gate, w1, w3, w2, *, top_k: int, first_expert: int = 0,
+                 expert_bias=None, score_fn: str = "sigmoid",
+                 normalize: bool = True, scaling: float = 1.0,
+                 impl: str = "gmm", dtype=jnp.float32, live=None):
+    """:class:`DroplessMoe`'s layer as a function of its raw parameters:
+    the module and the serve engine's raw-param twin
+    (``tpudp.models.pangu.block_paged``) both call it, so there is one
+    expert layer.  ``x`` ``(..., d)``; ``gate`` ``(d, routed)``; ``w1``,
+    ``w3`` ``(held, d, f)`` and ``w2`` ``(held, f, d)`` in any float type
+    (they reach the MXU in ``dtype``; the router runs in float32).
+
+    ``live`` ``(rows,)`` bool marks the rows that are real tokens: a
+    serving step also carries the rows of inactive slots and of a chunk's
+    padding, and those are sent to the ABSENT group (they cost no expert
+    a row, return zeros) and are counted nowhere.  ``None``: every row.
+
+    Returns ``(y, chosen, counts)``: the held experts' part of the layer
+    in ``dtype`` and ``x``'s shape, the routed-expert ids ``(rows, k)``
+    every row chose, and ``[assignments of live rows, rows computed, load
+    of held expert 0, 1, ...]`` as float32 (what the module sows as
+    ``moe_counts``)."""
+    d, k = x.shape[-1], top_k
+    g, f, e = w1.shape[0], w1.shape[2], gate.shape[1]
+    if not (0 <= first_expert and first_expert + g <= e and 1 <= k <= e):
+        raise ValueError(
+            f"experts {first_expert}..{first_expert + g - 1} "
+            f"and top_k={k} do not fit {e} routed experts")
+    if impl not in ("gmm", "dense"):
+        raise ValueError(f"unknown moe impl {impl!r}; choose from "
+                         "'gmm', 'dense'")
+    xt = x.reshape(-1, d)
+    t = xt.shape[0]
+    # --- route over all the experts, in float32 at full precision: a
+    # bf16 pass would move scores by more than neighbours differ
+    scores = _SCORE_FNS[score_fn](jnp.dot(
+        xt.astype(jnp.float32), gate.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    biased = scores if expert_bias is None \
+        else scores + lax.stop_gradient(expert_bias)
+    _, chosen = lax.top_k(biased, k)  # (T, k) routed-expert ids
+    # A block under remat recomputes its forward pass inside another
+    # XLA program, whose rounding can break a near tie the other way:
+    # the backward pass would then run other experts than the forward
+    # pass did.  Named, so that a remat policy keeps the choice
+    # (models/lfm2.py: REMAT_POLICY).
+    chosen = checkpoint_name(chosen, ROUTE_NAME)
+    # the chosen experts' own scores, by comparison (the same values:
+    # one term a sum): a gather by index over T x k scalars costs the
+    # v5e 1.3 ms, twice a layer under remat (PERF.md section 6, PR 30)
+    weights = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(e),
+                                scores[:, None, :], 0.0), axis=-1)
+    if normalize:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+    weights = weights * scaling
+    local = chosen - first_expert
+    local = jnp.where((local >= 0) & (local < g), local, g)  # g: absent
+    assignments = t * k
+    if live is not None:
+        local = jnp.where(live.reshape(t, 1), local, g)
+        assignments = jnp.sum(live) * k
+    loads = jnp.sum(local[..., None] == jnp.arange(g), axis=(0, 1))
+
+    from tpudp.ops import expert_rows as er
+    from tpudp.ops import grouped_matmul as gm
+
+    xb = xt.astype(dtype)
+    if impl == "gmm" and gm.supported(t * k, d, f):
+        # --- sort the assignments by held expert, absent ones last
+        flat = local.reshape(-1)
+        order = jnp.argsort(flat, stable=True)  # row -> assignment
+        slot_of = jnp.argsort(order).reshape(t, k)  # assignment -> row
+        # --- the walk, once: every kernel from here to `y` runs over
+        # the row tiles it lists and no other, and it is what is sown
+        walk = gm.visits(loads, t * k)
+        total = walk[3][-1]
+        plan = er.combine_plan(slot_of, total)
+        token_of = order // k
+        w_rows = _weights_by_row(weights, flat, order, slot_of, total)
+        out = _expert_ffn(_rows_by_expert(xb, token_of, plan), w_rows,
+                          w1, w3, w2, walk,
+                          gm.visits(loads, t * k, visit_empty=True))
+        y = _rows_to_tokens(out, token_of, plan)
+        computed = walk[4] * gm.row_tile(t * k)
+    else:
+        y = jnp.zeros((t, d), jnp.float32)
+        for j in range(g):
+            w_j = jnp.sum(jnp.where(local == j, weights, 0.0), axis=-1)
+            hdn = nn.silu(xb @ w1[j].astype(dtype)) \
+                * (xb @ w3[j].astype(dtype))
+            y = y + w_j[:, None] * (hdn @ w2[j].astype(dtype))
+        computed = g * t
+    counts = jnp.concatenate([
+        jnp.stack([jnp.asarray(assignments, jnp.float32),
+                   jnp.asarray(computed, jnp.float32)]),
+        loads.astype(jnp.float32)])
+    return y.astype(dtype).reshape(x.shape), chosen, counts
+
+
 class DroplessMoe(nn.Module):
     """One chip's share of a routed SwiGLU expert layer, no token dropped:
     ``(..., d) -> (..., d)``.
@@ -339,6 +437,10 @@ class DroplessMoe(nn.Module):
     16-token init trace, tiny CPU sizes), as ops/attention.py falls back
     from flash; the option exists only so that tests and ``chip_smoke.py``
     can run the loop at a shape the kernels take, as their reference.
+
+    The layer itself is :func:`dropless_moe`, a function of the raw
+    parameters; this module declares them (in ``param_dtype``; the
+    selection bias always float32) and sows what the function returns.
     """
 
     num_experts: int  # held here
@@ -352,86 +454,25 @@ class DroplessMoe(nn.Module):
     scaling: float = 1.0
     impl: str = "gmm"  # 'gmm' | 'dense'
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32  # what `init` makes the leaves in
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        d, f, g, k = x.shape[-1], self.hidden, self.num_experts, self.top_k
+        d, f, g = x.shape[-1], self.hidden, self.num_experts
         e = self.num_experts_routed or g
-        if not (0 <= self.first_expert and self.first_expert + g <= e
-                and 1 <= k <= e):
-            raise ValueError(
-                f"experts {self.first_expert}..{self.first_expert + g - 1} "
-                f"and top_k={k} do not fit {e} routed experts")
-        if self.impl not in ("gmm", "dense"):
-            raise ValueError(f"unknown moe impl {self.impl!r}; choose from "
-                             "'gmm', 'dense'")
-        xt = x.reshape(-1, d)
-        t = xt.shape[0]
         stacked = nn.initializers.lecun_normal(batch_axis=(0,))
-        gate = self.param("gate", nn.initializers.lecun_normal(), (d, e),
-                          jnp.float32)
-        w1 = self.param("w1", stacked, (g, d, f), jnp.float32)
-        w3 = self.param("w3", stacked, (g, d, f), jnp.float32)
-        w2 = self.param("w2", stacked, (g, f, d), jnp.float32)
-
-        # --- route over all the experts, in float32 at full precision: a
-        # bf16 pass would move scores by more than neighbours differ
-        scores = _SCORE_FNS[self.score_fn](jnp.dot(
-            xt.astype(jnp.float32), gate, precision=lax.Precision.HIGHEST))
-        biased = scores
-        if self.selection_bias:
-            biased = scores + lax.stop_gradient(self.param(
-                "expert_bias", nn.initializers.zeros, (e,), jnp.float32))
-        _, chosen = lax.top_k(biased, k)  # (T, k) routed-expert ids
-        # A block under remat recomputes its forward pass inside another
-        # XLA program, whose rounding can break a near tie the other way:
-        # the backward pass would then run other experts than the forward
-        # pass did.  Named, so that a remat policy keeps the choice
-        # (models/lfm2.py: REMAT_POLICY).
-        chosen = checkpoint_name(chosen, ROUTE_NAME)
-        # the chosen experts' own scores, by comparison (the same values:
-        # one term a sum): a gather by index over T x k scalars costs the
-        # v5e 1.3 ms, twice a layer under remat (PERF.md section 6, PR 30)
-        weights = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(e),
-                                    scores[:, None, :], 0.0), axis=-1)
-        if self.normalize:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
-        weights = weights * self.scaling
-        local = chosen - self.first_expert
-        local = jnp.where((local >= 0) & (local < g), local, g)  # g: absent
-        loads = jnp.sum(local[..., None] == jnp.arange(g), axis=(0, 1))
+        pd = self.param_dtype
+        gate = self.param("gate", nn.initializers.lecun_normal(), (d, e), pd)
+        w1 = self.param("w1", stacked, (g, d, f), pd)
+        w3 = self.param("w3", stacked, (g, d, f), pd)
+        w2 = self.param("w2", stacked, (g, f, d), pd)
+        bias = self.param("expert_bias", nn.initializers.zeros, (e,),
+                          jnp.float32) if self.selection_bias else None
+        y, chosen, counts = dropless_moe(
+            x, gate, w1, w3, w2, top_k=self.top_k,
+            first_expert=self.first_expert, expert_bias=bias,
+            score_fn=self.score_fn, normalize=self.normalize,
+            scaling=self.scaling, impl=self.impl, dtype=self.dtype)
         self.sow("intermediates", "moe_chosen", chosen)
-
-        from tpudp.ops import expert_rows as er
-        from tpudp.ops import grouped_matmul as gm
-
-        xb = xt.astype(self.dtype)
-        if self.impl == "gmm" and gm.supported(t * k, d, f):
-            # --- sort the assignments by held expert, absent ones last
-            flat = local.reshape(-1)
-            order = jnp.argsort(flat, stable=True)  # row -> assignment
-            slot_of = jnp.argsort(order).reshape(t, k)  # assignment -> row
-            # --- the walk, once: every kernel from here to `y` runs over
-            # the row tiles it lists and no other, and it is what is sown
-            walk = gm.visits(loads, t * k)
-            total = walk[3][-1]
-            plan = er.combine_plan(slot_of, total)
-            token_of = order // k
-            w_rows = _weights_by_row(weights, flat, order, slot_of, total)
-            out = _expert_ffn(_rows_by_expert(xb, token_of, plan), w_rows,
-                              w1, w3, w2, walk,
-                              gm.visits(loads, t * k, visit_empty=True))
-            y = _rows_to_tokens(out, token_of, plan)
-            computed = walk[4] * gm.row_tile(t * k)
-        else:
-            y = jnp.zeros((t, d), jnp.float32)
-            for j in range(g):
-                w_j = jnp.sum(jnp.where(local == j, weights, 0.0), axis=-1)
-                hdn = nn.silu(xb @ w1[j].astype(self.dtype)) \
-                    * (xb @ w3[j].astype(self.dtype))
-                y = y + w_j[:, None] * (hdn @ w2[j].astype(self.dtype))
-            computed = g * t
-        self.sow("intermediates", "moe_counts", jnp.concatenate([
-            jnp.asarray([t * k, computed], jnp.float32),
-            loads.astype(jnp.float32)]))
-        return y.astype(self.dtype).reshape(x.shape)
+        self.sow("intermediates", "moe_counts", counts)
+        return y

@@ -42,6 +42,11 @@ The op covers both attention families the decode twins use: the GPT-2
 MHA einsum forms and LLaMA's grouped (GQA) forms — selected by
 ``grouped`` so each family's paged math mirrors ITS dense twin
 op-for-op (the bitwise contract is per-family).
+
+:func:`latent_paged_attention` is the third family's: absorbed latent
+(MLA) attention over ``generate.LatentPages``, XLA contractions a page
+tile at a time under an online softmax (no kernel, no dense twin to be
+bitwise with; tolerance-bounded against the expanded form).
 """
 
 from __future__ import annotations
@@ -157,6 +162,73 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
     pr = jax.nn.softmax(lg.astype(jnp.float32), axis=-1).astype(dtype)
     return jnp.einsum("bhqpt,bpthd->bqhd",
                       pr.reshape(b, h, cur, *kt.shape[1:3]), vt)
+
+
+def latent_paged_attention(q_lat, q_rope, pages, table, pos, *, scale: float,
+                           dtype, layer: int):
+    """Absorbed latent (MLA) attention over table-indirected latent pages:
+    one op for a decode step and a prefill window.
+
+    ``q_lat`` ``(b, cur, heads, c)`` are the queries already carried into
+    the latent space (``q_nope W_kvb,k^T``), ``q_rope`` ``(b, cur, heads,
+    r)`` their rotated parts; ``pages`` the WHOLE stacked pool ``(c_pages
+    (layers, pages + 1, T, c), r_pages (layers, pages + 1, T, r))``
+    (``generate.LatentPages``; the last page is the write scratch), of
+    which stratum ``layer`` is read, a page a slot at a time, by gather:
+    no slice of the pool is ever a value.  ``table`` ``(b, max_pages)``,
+    ``-1`` unmapped; ``pos`` ``(b,)`` per-row depths or a scalar: window
+    position ``j`` attends the cached tokens ``<= pos + j`` (causal inside
+    a prefill window, whose own latents are in their page already).
+
+    ``score = (q_lat . c_kv + q_rope . k_rope) * scale`` in float32,
+    softmax in float32 ONLINE over ``(page_tokens,)`` tiles (running
+    maximum, denominator and ``(b, cur, heads, c)`` accumulator, as the
+    flash kernels carry them), so the ``(heads, cur, max_len)`` score
+    tensor of a 512-token window over 8,192 positions never exists; the
+    loop runs over the pages that the deepest row of the call reaches (a
+    traced bound), not over the table's width.  Returns ``sum_t p_t
+    c_kv,t`` ``(b, cur, heads, c)`` in ``dtype``: the caller carries it
+    out of the latent space (``W_kvb,v``).  Every row's first page holds a
+    visible token (position 0), so the running maximum is finite from the
+    first tile on and a masked score weighs exactly zero."""
+    c_pages, r_pages = pages
+    b, cur, h, c = q_lat.shape
+    pos = jnp.broadcast_to(jnp.asarray(pos), (b,))
+    tokens, max_pages = c_pages.shape[2], table.shape[1]
+    tbl = jnp.where(table >= 0, table, c_pages.shape[1] - 1)
+    q_pos = pos[:, None] + jnp.arange(cur)  # (b, cur)
+    reach = jnp.minimum((jnp.max(pos) + cur + tokens - 1) // tokens,
+                        max_pages)
+    f32 = jnp.float32
+
+    def tile(m, carry):
+        top, den, acc = carry
+        page = jax.lax.dynamic_index_in_dim(tbl, m, axis=1, keepdims=False)
+        ct = c_pages[layer, page].astype(dtype)  # (b, T, c)
+        rt = r_pages[layer, page].astype(dtype)
+        s = (jnp.einsum("bqhc,btc->bqht", q_lat, ct,
+                        preferred_element_type=f32)
+             + jnp.einsum("bqhr,btr->bqht", q_rope, rt,
+                          preferred_element_type=f32)) * scale
+        seen = (m * tokens + jnp.arange(tokens))[None, None, :] \
+            <= q_pos[:, :, None]
+        s = jnp.where(seen[:, :, None, :], s, _NEG_INF)
+        new_top = jnp.maximum(top, jnp.max(s, axis=-1))
+        p = jnp.exp(s - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        return (new_top, den * keep + jnp.sum(p, axis=-1),
+                acc * keep[..., None]
+                + jnp.einsum("bqht,btc->bqhc", p.astype(dtype), ct,
+                             preferred_element_type=f32))
+
+    # the scope names the loop in a profile read by hand; the benchmark's
+    # reader (perf/metrics/latent_attn_ms.py) knows it by this carry
+    with jax.named_scope("latent_attn"):
+        _, den, acc = jax.lax.fori_loop(
+            0, reach, tile, (jnp.full((b, cur, h), _NEG_INF, f32),
+                             jnp.zeros((b, cur, h), f32),
+                             jnp.zeros((b, cur, h, c), f32)))
+        return (acc / den[..., None]).astype(dtype)
 
 
 # ------------------------------------------------------- Pallas kernel

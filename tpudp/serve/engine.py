@@ -235,8 +235,8 @@ from jax import lax
 from tpudp.models.generate import (Int8Pages, KVCache, _forward_cached,
                                    _forward_paged, _forward_tree,
                                    _forward_tree_paged, _layer_pages,
-                                   _stack_pages, gather_pages,
-                                   update_cache_rows,
+                                   _stack_pages, gather_pages, page_layout,
+                                   page_type, update_cache_rows,
                                    validate_decode_config,
                                    write_token_pages)
 from tpudp.obs import FlightRecorder, Recorder
@@ -690,6 +690,56 @@ def _tree_verify_math(forward, commit, state, tokens, lengths, active,
     return state, out, n_emit, new_keys, new_counts
 
 
+def _build_latent_steps(cfg):
+    """The step programs of a latent-attention expert family
+    (``tpudp.models.pangu``): ``decode_paged`` and ``prefill_paged`` and
+    no other (the engine refuses at construction what would need one).
+    Same calling convention as their GPT-2 twins, one more result each:
+    the run's :data:`tpudp.models.pangu.SERVE_MOE_COUNTERS` as ``(4,)``
+    int32, summed over the expert layers, which the scheduler fetches
+    WITH the step's tokens (no sync of their own).  Rows of inactive
+    slots and of a chunk's padding reach no routed expert and are in no
+    count."""
+    from tpudp.models.pangu import serve_moe_counts
+
+    def _sum(routed):
+        return sum(serve_moe_counts(counts) for _, counts in routed)
+
+    @functools.partial(jax.jit, donate_argnums=(1, 10))
+    def decode_step_paged(params, pool, table, last_tokens, lengths,
+                          active, temps, top_k, top_p, keys, counts):
+        """One token for every slot through the latent page pool: the
+        shared ``_decode_math`` body over ``generate._forward_paged``'s
+        third family (absorbed attention through ``table``, the dropless
+        expert layer on the active rows)."""
+        TRACE_COUNTS["decode_paged_latent"] += 1
+        routed: list = []
+
+        def fwd(pool, tokens, lengths, active):
+            return _forward_paged(cfg, params, tokens, pool, table, lengths,
+                                  active, routed=routed)
+
+        return (*_decode_math(fwd, pool, last_tokens, lengths, active,
+                              temps, top_k, top_p, keys, counts),
+                _sum(routed))
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_step_paged(params, pool, row_table, tokens, pos, last):
+        """One page-aligned prompt chunk of one slot: its latents commit
+        as one page write a layer, the window attends causally through
+        the slot's table row, rows past ``last`` (a final chunk's padding)
+        reach no expert, and only row ``last`` goes through the head."""
+        TRACE_COUNTS["prefill_paged_latent"] += 1
+        routed: list = []
+        logits, new_pool = _forward_paged(
+            cfg, params, tokens, pool, row_table[None], pos,
+            jnp.ones((1,), bool), last=last, routed=routed)
+        return logits[:, 0], new_pool, _sum(routed)
+
+    return (None,) * 6 + (decode_step_paged, None, prefill_step_paged,
+                          None, None, None)
+
+
 @functools.lru_cache(maxsize=16)
 def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
     """Jitted step programs for one model CONFIG.  Every program takes
@@ -734,7 +784,12 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
 
     Shapes stay traced, so one build serves every engine geometry,
     compiling once per (num_slots, max_len[, k]) exactly as before.
+
+    A latent-attention config (``generate.page_layout``) builds its two
+    paged programs only (:func:`_build_latent_steps`).
     """
+    if page_layout(cfg) == "latent":
+        return _build_latent_steps(cfg)
 
     def _dense_fwd(params):
         """The dense indirection for the shared step bodies: plain
@@ -1408,7 +1463,15 @@ class Engine:
     """Continuous-batching engine over a slot-based KV arena.
 
     ``model`` is a tpudp GPT2 or Llama (dense attention/MLP — the same
-    family contract as ``generate()``); ``num_slots`` bounds concurrent
+    family contract as ``generate()``), or a latent-attention expert
+    model (``tpudp.models.pangu``), which is served through pages only
+    (``kv_pages > 0``; its cache is ``generate.LatentPages``, its expert
+    layers run inside the two step programs and count themselves in
+    ``metrics()["stats"]``) and refuses, by option name, what it has no
+    program for: ``kv_dtype``, ``speculate_k``, ``speculate_tree``,
+    ``decode_fuse > 1``, ``models=``, ``paged_attn`` other than einsum,
+    and the ticket methods (docs/SERVING.md, the family table).
+    ``num_slots`` bounds concurrent
     in-flight requests (queued requests wait for a free slot);
     ``max_len`` bounds ``prompt + max_new_tokens`` per request (default:
     the model's ``max_seq_len``, rounded down to a ``prefill_chunk``
@@ -1535,6 +1598,31 @@ class Engine:
                  flight_dir: str | None = None):
         cfg = model.config
         validate_decode_config(cfg, "Engine")
+        self._latent = page_layout(cfg) == "latent"
+        if self._latent:
+            # What this family does not serve yet, refused by option name
+            # (docs/SERVING.md, the family table).
+            for option, refused, why in (
+                    ("kv_pages", not kv_pages,
+                     "kv_pages must be > 0: latent pages have no dense "
+                     "slot arena"),
+                    ("kv_dtype", kv_dtype is not None,
+                     "latent pages are kept in the compute dtype"),
+                    ("speculate_tree", speculate_tree is not None,
+                     "no tree-verify program"),
+                    ("speculate_k", speculate_k > 0, "no verify program"),
+                    ("decode_fuse", decode_fuse > 1,
+                     "no fused decode program"),
+                    ("models", bool(models),
+                     "no co-residence: one model a pool"),
+                    ("paged_attn", paged_attn not in (None, "einsum"),
+                     "latent attention runs as XLA contractions through "
+                     "the block table ('einsum') only")):
+                if refused:
+                    raise ValueError(
+                        f"Engine({option}=...) is not served for the "
+                        f"latent-attention family "
+                        f"({type(cfg).__name__}): {why}")
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if prefill_chunk < 1:
@@ -1594,7 +1682,8 @@ class Engine:
         # tpu": an accelerator backend must never land on einsum unasked.
         self.paged_attn_requested = paged_attn
         if paged_attn is None:
-            paged_attn = ("kernel" if kv_pages
+            # (the latent family has no kernel: einsum on every backend)
+            paged_attn = ("kernel" if kv_pages and not self._latent
                           and jax.default_backend() != "cpu" else "einsum")
         if drafter is not None and speculate_k == 0:
             raise ValueError("drafter requires speculate_k >= 1 "
@@ -1716,9 +1805,10 @@ class Engine:
         # program this engine will run is an error, never an einsum.
         self.paged_attn_dispatch: dict[str, str] = {}
         if self._paged:
-            fams = ("decode_paged", "verify_paged", "prefill_paged",
-                    "fused_decode_paged", "fused_spec_paged",
-                    "tree_verify_paged")
+            fams = ("decode_paged", "prefill_paged") if self._latent else (
+                "decode_paged", "verify_paged", "prefill_paged",
+                "fused_decode_paged", "fused_spec_paged",
+                "tree_verify_paged")
             self.paged_attn_dispatch = {f: paged_attn for f in fams}
             if paged_attn == "kernel" and kv_dtype == "int8":
                 if (self.paged_attn_requested == "kernel"
@@ -1791,6 +1881,9 @@ class Engine:
         if self._paged:
             self._build_page_pools()
         self._keys = jnp.zeros((num_slots, 2), jnp.uint32)
+        # An expert family's per-run counters, on the device until the
+        # next token fetch takes them along (_count_moe).
+        self._moe_pending: list = []
         # Host-authoritative per-slot state, uploaded each step (tiny
         # arrays; values are data, never shapes).
         self._len = np.zeros(num_slots, np.int32)
@@ -1909,7 +2002,8 @@ class Engine:
 
     def _build_page_pools(self) -> None:
         """Carve ``kv_pages`` across the registered models' KV-geometry
-        groups: models sharing (layers, kv_heads, head_dim, dtype)
+        groups: models sharing what their page type calls its geometry
+        (``generate.page_type``: layers, kv_heads, head_dim, dtype)
         literally share ONE PagePool buffer — an idle tenant reserves
         zero pages instead of a dense ``(num_slots, max_len)`` arena —
         while distinct geometries split the page budget evenly (pages
@@ -1920,10 +2014,7 @@ class Engine:
 
         groups: dict[tuple, list[_ModelState]] = {}
         for ms in self._mstates.values():
-            cfg = ms.config
-            key = (cfg.num_layers,
-                   getattr(cfg, "kv_heads", cfg.num_heads),
-                   cfg.d_model // cfg.num_heads, str(cfg.dtype))
+            key = page_type(ms.config, self.kv_dtype).geometry(ms.config)
             groups.setdefault(key, []).append(ms)
         per_group = self.kv_pages // len(groups)
         if per_group < self._max_pages:
@@ -2259,6 +2350,13 @@ class Engine:
 
     # -- cross-host migration hooks (tpudp/serve/disagg.py) ------------
 
+    def _refuse_tickets(self, method: str) -> None:
+        if self._latent:
+            raise ValueError(
+                f"Engine.{method}() is not served for the latent-attention "
+                f"family ({type(self.config).__name__}): the migration "
+                f"wire format carries K/V pages only")
+
     def export_ticket(self, request: Request):
         """Detach a live request into a :class:`tpudp.serve.disagg.
         MigrationTicket` — the sender half of cross-host KV migration.
@@ -2277,6 +2375,8 @@ class Engine:
         tracks the request through the ticket and the receiver's new
         handle).  Raises :class:`ValueError` for a finished request."""
         from tpudp.serve import disagg as _dg
+
+        self._refuse_tickets("export_ticket")
 
         r = request
         if r.done:
@@ -2342,6 +2442,7 @@ class Engine:
         ``tpudp.serve.disagg`` — this method trusts its arrays but
         re-validates geometry (model, vocab, lengths, chunk size) and
         raises :class:`ValueError` on mismatch."""
+        self._refuse_tickets("admit_ticket")
         if not self._accepting:
             raise EngineClosed(
                 "Engine.drain()/close() was called; the engine no "
@@ -2495,6 +2596,8 @@ class Engine:
         rollup from the obs ring.  The serve bench's metric sidecar and
         the Prometheus exposition (``tpudp.obs.prometheus_text``) both
         render this dict."""
+        if self._moe_pending:  # a prefill chunk no decode followed yet
+            self._count_moe(jax.device_get(self._moe_pending))
         device: dict[str, dict] = {}
         totals = dict.fromkeys(OBS_DEVICE_COUNTERS, 0.0)
         for name, ms in self._mstates.items():
@@ -3060,6 +3163,7 @@ class Engine:
         })
         if self._watchdog is not None:
             self._watchdog.acknowledge()  # handled; next scope may proceed
+        self._moe_pending = []  # of the failed call, maybe: best effort
         rebuilt_pools: list = []
         for ms in self._mstates.values():
             if self._paged:
@@ -3148,9 +3252,12 @@ class Engine:
             # the paged prefill against the slot's table row.
             if not self._ensure_pages(ms, s, end):
                 return  # slot retired (defensive: pool exhausted)
-            last_logits, ms.pool.pages = self._device(
+            last_logits, ms.pool.pages, *routed = self._device(
                 "prefill", ms.prefill_paged, ms.pool.pages, ms.table[s],
                 buf, np.int32(start), np.int32(end - start - 1))
+            # an expert family's counts of this run: fetched with the
+            # next token fetch, never on their own
+            self._moe_pending += routed
         else:
             last_logits, ms.cache = self._device(
                 "prefill", ms.prefill_step, ms.cache, np.int32(s), buf,
@@ -3197,13 +3304,26 @@ class Engine:
             self._commit(s, tok, emitted)
             self.obs.end(span)
 
+    def _count_moe(self, fetched) -> None:
+        """Add the expert layers' fetched per-run counts
+        (``pangu.SERVE_MOE_COUNTERS``) to the host stats; the pending
+        device values they were fetched from are done with."""
+        from tpudp.models.pangu import SERVE_MOE_COUNTERS
+
+        self._moe_pending = []
+        for vals in fetched:
+            for key, val in zip(SERVE_MOE_COUNTERS, vals):
+                self.stats[key] += int(val)
+
     def _run_decode(self, ms: _ModelState, active, emitted) -> None:
         if self._paged:
-            ms.pool.pages, toks, self._keys, ms.obs_counts = self._device(
+            (ms.pool.pages, toks, self._keys, ms.obs_counts,
+             *routed) = self._device(
                 "decode", ms.decode_paged,
                 ms.pool.pages, ms.table, self._last, self._len, active,
                 self._temps, self._topk, self._topp, self._keys,
                 ms.obs_counts)
+            self._moe_pending += routed
         else:
             ms.cache, toks, self._keys, ms.obs_counts = self._device(
                 "decode", ms.decode_step,
@@ -3214,9 +3334,13 @@ class Engine:
         # fetch — Engine(decode_fuse=N) amortizes it to one fetch per
         # fused lax.while_loop window (_run_decode_fused); this path
         # remains for the host-intervention steps (admission, prefill,
-        # speculation, preemption) the fused window falls back to.
-        toks = np.asarray(toks)
+        # speculation, preemption) the fused window falls back to.  An
+        # expert family's counters (this run's and the prefill chunks'
+        # since the last fetch) ride the same transfer.
+        toks, *moe = jax.device_get((toks, *self._moe_pending))
         self.obs.end(span)
+        if moe:
+            self._count_moe(moe)
         self.stats["decode_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
         span = self.obs.begin("commit")

@@ -55,7 +55,7 @@ import functools
 import jax
 from jax import lax
 
-from tpudp.models.generate import Int8Pages, KVCache
+from tpudp.models.generate import KVCache, page_type
 from tpudp.serve.engine import TRACE_COUNTS
 
 
@@ -344,9 +344,10 @@ class PagePool:
         self._free = list(range(num_pages - 1, -1, -1))
 
     def _buffer(self):
-        cls = Int8Pages if self.kv_dtype == "int8" else KVCache
-        return cls.zeros(self.config, self.num_pages + 1,
-                         self.page_tokens)
+        # the page type is the config's (K/V per head, int8 K/V + scales,
+        # or a latent family's LatentPages)
+        return page_type(self.config, self.kv_dtype).zeros(
+            self.config, self.num_pages + 1, self.page_tokens)
 
     @property
     def free_pages(self) -> int:
