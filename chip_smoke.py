@@ -437,8 +437,9 @@ def phase_kernels(sizes: Sizes, workdir: str, rehearse: bool) -> str:
     for dtype, precision, atol in ((jnp.float32, "highest", KERNEL_ATOL_F32),
                                    (jnp.bfloat16, None, KERNEL_ATOL_BF16)):
         name = jnp.dtype(dtype).name
-        k = jnp.asarray(rng.standard_normal((n_pages + 1, T, h, dh)), dtype)
-        v = jnp.asarray(rng.standard_normal((n_pages + 1, T, h, dh)), dtype)
+        # the pool's stored form: a token's heads side by side in one row
+        k = jnp.asarray(rng.standard_normal((n_pages + 1, T, h * dh)), dtype)
+        v = jnp.asarray(rng.standard_normal((n_pages + 1, T, h * dh)), dtype)
 
         def attend(impl, q, tbl, p):
             fn = jax.jit(lambda q, k, v: paged_attention(

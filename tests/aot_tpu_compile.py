@@ -45,8 +45,8 @@ def paged(cur, batch, pos_shape):
         return paged_attention(q, (k, v), table, pos, dtype=BF16,
                                impl="kernel", interpret=False)
     return fn, (sds((batch, cur, H, DH), BF16),
-                sds((PAGES + 1, T, H, DH), BF16),
-                sds((PAGES + 1, T, H, DH), BF16),
+                sds((PAGES + 1, T, H * DH), BF16),  # a token row a line
+                sds((PAGES + 1, T, H * DH), BF16),
                 sds((batch, M), jnp.int32), sds(pos_shape, jnp.int32))
 
 
@@ -123,6 +123,29 @@ def lfm2_step():
                                                            tokens)
 
 
+def engine_steps(name, steps, params, pool, *, slots, width, chunk):
+    """The engine's paged decode and prefill programs (``_build_steps``'
+    seventh and ninth) with the shapes ``Engine.step`` calls them with."""
+    i32, f32 = jnp.int32, jnp.float32
+    return {
+        name("decode"): (steps[6], (
+            params, pool, sds((slots, width), i32), sds((slots,), i32),
+            sds((slots,), i32), sds((slots,), jnp.bool_), sds((slots,), f32),
+            sds((slots,), i32), sds((slots,), f32),
+            sds((slots, 2), jnp.uint32), sds((5,), f32))),
+        name("prefill"): (steps[8], (
+            params, pool, sds((width,), i32), sds((1, chunk), i32),
+            sds((), i32), sds((), i32)))}
+
+
+def shapes(tree, dtype=None):
+    """A tree of arrays or shapes, described on the topology's first chip
+    (float32 leaves in ``dtype`` where one is given: served weights)."""
+    return jax.tree.map(lambda a: sds(a.shape, dtype if dtype is not None
+                                      and a.dtype == jnp.float32
+                                      else a.dtype), tree)
+
+
 def latent_steps():
     """The serve engine's two programs for the latent-attention expert
     family at the benchmark cell's real sizes (perf/configs/
@@ -144,25 +167,49 @@ def latent_steps():
                            "pangu_ultra_moe_718b.json")) as f:
         config = {k: v for k, v in json.load(f).items() if k != "rehearsal"}
     cfg = PanguConfig.from_dict(config, dtype=BF16, param_dtype=BF16)
-    shapes = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: sds(a.shape, a.dtype), tree)
     params = shapes(jax.eval_shape(
         lambda k: Pangu(cfg).init(k, jnp.zeros((1, 16), jnp.int32))["params"],
         jax.random.PRNGKey(0)))
     slots, chunk, width = 64, 512, 8192 // 512
     pool = shapes(jax.eval_shape(
         lambda: LatentPages.zeros(cfg, LATENT_PAGES + 1, chunk)))
-    steps = engine._build_steps(cfg, "einsum")
-    i32, f32 = jnp.int32, jnp.float32
-    return {
-        "latent_decode": (steps[6], (
-            params, pool, sds((slots, width), i32), sds((slots,), i32),
-            sds((slots,), i32), sds((slots,), jnp.bool_), sds((slots,), f32),
-            sds((slots,), i32), sds((slots,), f32),
-            sds((slots, 2), jnp.uint32), sds((5,), f32))),
-        "latent_prefill": (steps[8], (
-            params, pool, sds((width,), i32), sds((1, chunk), i32),
-            sds((), i32), sds((), i32)))}
+    return engine_steps("latent_{}".format,
+                        engine._build_steps(cfg, "einsum"), params, pool,
+                        slots=slots, width=width, chunk=chunk)
+
+
+def gpt2m_steps(pages):
+    """The serve engine's paged decode and prefill programs for
+    perf/configs/gpt2_medium.json at `gpt2m.serve_closed`'s sizes (64
+    slots, 1,024 positions, 128-token pages, the kernel backend) over a
+    pool of ``pages`` pages: the pool in ONE layout with no copy of its
+    shape (until PR 35 it was stored ``(..., 16, 64)``, XLA kept it in
+    one tiling, Mosaic wanted another, and each program copied all of K
+    and V in and out: 10.9 GB of temporaries at 288 pages)."""
+    import json
+
+    from perf.families.gpt2 import build_model
+    from tpudp.models.generate import page_type
+    from tpudp.serve import engine
+
+    sys.modules["tpudp.ops.paged_attention"]._interpret_default = (
+        lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "gpt2_medium.json")) as f:
+        config = json.load(f)
+    model = build_model(config)  # as the cell's driver builds it
+    cfg = model.config
+    params = shapes(jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 16), jnp.int32))["params"],
+        jax.random.PRNGKey(0)), BF16)  # the cell serves bf16 weights
+    chunk = 128
+    pool = shapes(jax.eval_shape(
+        lambda: page_type(cfg).zeros(cfg, pages + 1, chunk)))
+    return engine_steps(f"gpt2m_{{}}_{pages}".format,
+                        engine._build_steps(cfg, "kernel"), params, pool,
+                        slots=64, width=config["n_positions"] // chunk,
+                        chunk=chunk)
 
 
 LATENT_PAGES = 1024
@@ -183,6 +230,7 @@ CASES = {
     "moe_layer": moe_layer(),
     "lfm2_train_step": lfm2_step(),
     **latent_steps(),
+    **gpt2m_steps(288), **gpt2m_steps(512),
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
         jnp.float32).sum(), argnums=(0, 1, 2)), (x, x, x)),
@@ -195,8 +243,8 @@ CASES = {
         lambda q, k, v, table, pos, wk, wv: tree_paged_attention(
             q, (k, v), table, pos, wk, wv, ANC, dtype=BF16,
             interpret=False),
-        (w, sds((PAGES + 1, T, H, DH), BF16),
-         sds((PAGES + 1, T, H, DH), BF16), sds((SLOTS, M), jnp.int32),
+        (w, sds((PAGES + 1, T, H * DH), BF16),
+         sds((PAGES + 1, T, H * DH), BF16), sds((SLOTS, M), jnp.int32),
          sds((SLOTS,), jnp.int32), w, w)),
 }
 
@@ -211,7 +259,10 @@ MOSAIC_OP = re.compile(
     r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
 
 failed = []
+only = set(sys.argv[1:])  # by hand: the families to compile; none = all
 for name, (fn, args) in CASES.items():
+    if only and name not in only:
+        continue
     try:
         lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
         assert "tpu_custom_call" in lowered.as_text(), "no Mosaic call"
@@ -223,11 +274,15 @@ for name, (fn, args) in CASES.items():
         print(f"OK {name}")
         print(f"KERNELS {name} " + ",".join(
             f"{k}={n}" for k, n in sorted(calls.items()) if n))
-        if name.startswith("latent_"):
+        if name.startswith(("latent_", "gpt2m_")):
             mem = compiled.memory_analysis()
-            layouts = sorted(set(re.findall(
-                rf"bf16\[5,{LATENT_PAGES + 1},512,\d+\]{{([\d,]+)", text)))
-            print(f"POOL {name} layouts={'|'.join(layouts)} bytes="
+            pool = (rf"bf16\[5,{LATENT_PAGES + 1},512,\d+\]"
+                    if name.startswith("latent_") else
+                    rf"bf16\[24,{int(name.rsplit('_', 1)[1]) + 1},128,1024\]")
+            layouts = sorted(set(re.findall(pool + r"{([\d,]+)", text)))
+            copies = len(re.findall(rf"= {pool}\S* copy\(", text))
+            print(f"POOL {name} layouts={'|'.join(layouts)} copies={copies}"
+                  f" temp={mem.temp_size_in_bytes} bytes="
                   f"{mem.argument_size_in_bytes + mem.output_size_in_bytes
                      + mem.temp_size_in_bytes - mem.alias_size_in_bytes}")
     except Exception as exc:  # noqa: BLE001 — report every family

@@ -513,7 +513,9 @@ def test_paged_llama_gqa_parity():
                  kv_pages=16, decode_fuse=4)
     # pages allocate at KV width — the GQA memory saving holds for the
     # pool exactly as it did for the dense arena
-    assert eng.page_pool.pages.k.shape[-2] == cfg.kv_heads == 2
+    # (a token's row is its kv_heads heads of head_dim side by side)
+    assert cfg.kv_heads == 2
+    assert eng.page_pool.pages.k.shape[-1] == 2 * (32 // 4)
     handles = [eng.submit(p, 6) for p in prompts]
     eng.run_until_complete()
     assert eng.stats["prefix_hit_tokens"] > 0

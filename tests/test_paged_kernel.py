@@ -201,8 +201,8 @@ def test_write_token_pages_touches_one_token_row_only():
     page from the gathered view; sentinel values prove the gather-free
     write never even reads those rows."""
     T, kv, dh = 8, 2, 4
-    pages = (jnp.full((5, T, kv, dh), 7.0, jnp.float32),
-             jnp.full((5, T, kv, dh), 7.0, jnp.float32))
+    pages = (jnp.full((5, T, kv * dh), 7.0, jnp.float32),
+             jnp.full((5, T, kv * dh), 7.0, jnp.float32))
     table = jnp.asarray([[2, 3, -1]], jnp.int32)
     k_new = jnp.ones((1, 1, kv, dh), jnp.float32) * 1.5
     v_new = jnp.ones((1, 1, kv, dh), jnp.float32) * 2.5
@@ -211,8 +211,8 @@ def test_write_token_pages_touches_one_token_row_only():
         pages, k_new, v_new, table, jnp.asarray([13], jnp.int32),
         jnp.ones((1,), bool))
     ok, ov = np.asarray(out_k), np.asarray(out_v)
-    np.testing.assert_array_equal(ok[3, 5], 1.5 * np.ones((kv, dh)))
-    np.testing.assert_array_equal(ov[3, 5], 2.5 * np.ones((kv, dh)))
+    np.testing.assert_array_equal(ok[3, 5], 1.5 * np.ones(kv * dh))
+    np.testing.assert_array_equal(ov[3, 5], 2.5 * np.ones(kv * dh))
     untouched_k = ok.copy()
     untouched_k[3, 5] = 7.0
     np.testing.assert_array_equal(untouched_k, 7.0 * np.ones_like(ok))
@@ -227,6 +227,83 @@ def test_write_token_pages_touches_one_token_row_only():
                               jnp.ones((1,), bool))
     uk = np.asarray(uk)
     assert (uk[:4] == 7.0).all() and (uk[4, 2] == 1.5).all()
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+@pytest.mark.parametrize("T, kv, dh", [(8, 2, 4), (16, 3, 24), (128, 2, 64)])
+def test_pool_rows_read_back_head_by_head_equal_the_dense_arena(T, kv, dh,
+                                                                kv_dtype):
+    """The stored form of a ``heads`` page (``generate.KVPages``): a token
+    is one row of ``kv * dh`` values, KV head ``h`` at lanes ``[h * dh,
+    (h + 1) * dh)``.  A pool written through ``write_token_pages`` (a
+    page-aligned chunk as ONE page write, then decode tokens as single
+    rows, two slots at different depths) is read back head by head
+    against the dense arena written with the same values by
+    ``update_cache_rows``: equal bit for bit (int8: the arena's rows
+    quantised per head), whatever the row's width is against the 128
+    lanes."""
+    from tpudp.models.generate import update_cache_rows
+
+    rng = np.random.default_rng(T + kv + dh)
+    S, M, P = 2, 3, 7
+    table = jnp.asarray([[4, 1, 6], [2, 5, -1]], jnp.int32)
+    live = jnp.ones((S,), bool)
+    dt = jnp.float32
+    if kv_dtype == "int8":
+        pages = (jnp.zeros((P + 1, T, kv * dh), jnp.int8),) * 2 + (
+            jnp.ones((P + 1, T, kv), jnp.float32),) * 2
+    else:
+        pages = (jnp.zeros((P + 1, T, kv * dh), dt),) * 2
+    arena_k = jnp.zeros((S, M * T, kv, dh), dt)
+    arena_v = jnp.zeros((S, M * T, kv, dh), dt)
+    draw = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape), dt)
+    # a page-aligned prefill chunk a slot (scalar position, batch 1)
+    for s, start in ((0, T), (1, 0)):
+        k_new, v_new = draw(1, T, kv, dh), draw(1, T, kv, dh)
+        pages = write_token_pages(pages, k_new, v_new, table[s:s + 1],
+                                  jnp.int32(start), live[:1])
+        arena_k = arena_k.at[s, start:start + T].set(k_new[0])
+        arena_v = arena_v.at[s, start:start + T].set(v_new[0])
+    # decode tokens: both slots at their own depths, a 2-token window
+    # that crosses a page boundary in slot 0
+    pos = jnp.asarray([2 * T - 1, T + 3], jnp.int32)
+    k_new, v_new = draw(S, 2, kv, dh), draw(S, 2, kv, dh)
+    pages = write_token_pages(pages, k_new, v_new, table, pos, live)
+    arena_k = update_cache_rows(arena_k, k_new, pos)
+    arena_v = update_cache_rows(arena_v, v_new, pos)
+
+    written = {0: range(T, 2 * T + 1), 1: list(range(T)) + [T + 3, T + 4]}
+    for buf, scale, arena in ((0, 2, arena_k), (1, 3, arena_v)):
+        rows = np.asarray(pages[buf])
+        assert rows.shape == (P + 1, T, kv * dh)
+        for s, positions in written.items():
+            for p in positions:
+                page, off = int(table[s, p // T]), p % T
+                for h in range(kv):
+                    got = rows[page, off, h * dh:(h + 1) * dh]
+                    want = np.asarray(arena[s, p, h])
+                    if kv_dtype == "int8":
+                        q8, sc = _quantize_kv(jnp.asarray(want))
+                        np.testing.assert_array_equal(got, np.asarray(q8))
+                        np.testing.assert_array_equal(
+                            np.asarray(pages[scale])[page, off, h],
+                            np.asarray(sc))
+                    else:
+                        np.testing.assert_array_equal(got, want)
+    # and through the block table the flat rows ARE the arena's rows
+    if kv_dtype is None:
+        from tpudp.ops.paged_attention import page_tiles
+
+        kt, vt = page_tiles(pages, table, dt, dh)  # (S, M, T, kv, dh)
+        for s, positions in written.items():
+            idx = np.asarray(list(positions))
+            np.testing.assert_array_equal(
+                np.asarray(kt.reshape(S, M * T, kv, dh))[s, idx],
+                np.asarray(arena_k)[s, idx])
+            np.testing.assert_array_equal(
+                np.asarray(vt.reshape(S, M * T, kv, dh))[s, idx],
+                np.asarray(arena_v)[s, idx])
 
 
 def test_engine_decode_step_writes_exactly_one_page(model_and_params):
@@ -274,32 +351,38 @@ def test_engine_decode_step_writes_exactly_one_page(model_and_params):
 # ---------------------------------------------------------------------------
 
 
-def _fragmented_fixture(kv_dtype=None, seed=2, cur=1, scalar_pos=None):
+def _fragmented_fixture(kv_dtype=None, seed=2, cur=1, scalar_pos=None,
+                        geometry=(8, 4, 2, 16)):
     """A pool + tables shaped like real COW traffic: slots 0 and 1 MAP
     THE SAME prefix pages (shared system prompt), diverge into private
     pages, and leave ``-1`` tail entries (clamping to scratch); slot 2
     is shallower.  ``cur`` widens the query window (the verify / prefill
     kernels' multi-token shape); ``scalar_pos`` swaps the per-slot depth
-    vector for the prefill chunk's shared scalar depth.  Returns
-    (pages tuple, table, pos, q, cfg-ish dims)."""
+    vector for the prefill chunk's shared scalar depth; ``geometry`` is
+    ``(page_tokens, heads, kv_heads, head_dim)``.  The pages are in the
+    pool's stored form: a token's KV heads side by side in one row
+    (``generate.KVPages`` / ``Int8Pages``).  Returns (pages tuple, table,
+    pos, q, cfg-ish dims)."""
     rng = np.random.default_rng(seed)
-    S, M, T, H, KV, DH = 3, 4, 8, 4, 2, 16
-    P = 8
+    T, H, KV, DH = geometry
+    S, M, P = 3, 4, 8
     kf = jnp.asarray(rng.standard_normal((P + 1, T, KV, DH)), jnp.float32)
     vf = jnp.asarray(rng.standard_normal((P + 1, T, KV, DH)), jnp.float32)
+    rows = lambda x: x.reshape(P + 1, T, KV * DH)  # noqa: E731
     if kv_dtype == "int8":
         k8, ks = _quantize_kv(kf)
         v8, vs = _quantize_kv(vf)
-        pages = (k8, v8, ks, vs)
+        pages = (rows(k8), rows(v8), ks, vs)
     else:
-        pages = (kf, vf)
+        pages = (rows(kf), rows(vf))
     table = jnp.asarray(np.array([
         [0, 1, 2, -1],   # shared pages 0,1 + private divergence page 2
         [0, 1, 3, 4],    # same prefix, different COW page, one deeper
         [5, -1, -1, -1],  # shallow slot
     ], np.int32))
+    # slot depths in pages of 8 at the default geometry: 2.1, 3.3, 0.5
     pos = (jnp.int32(scalar_pos) if scalar_pos is not None
-           else jnp.asarray([17, 26, 4], jnp.int32))
+           else jnp.asarray([2 * T + 1, 3 * T + 2, T // 2], jnp.int32))
     q = jnp.asarray(rng.standard_normal((S, cur, H, DH)), jnp.float32)
     return pages, table, pos, q, (S, M, T, H, KV, DH, P)
 
@@ -324,7 +407,7 @@ def _gather_oracle(pages, table, pos, q, dims):
     tbl = jnp.where(table >= 0, table, P)
 
     def grab(i):
-        g = pages[i][tbl]  # (S, M, T, KV, DH)
+        g = pages[i][tbl].reshape(S, M, T, KV, DH)  # rows back into heads
         if len(pages) == 4:
             g = (g.astype(jnp.float32)
                  * pages[i + 2][tbl][..., None]).astype(jnp.float32)
@@ -395,6 +478,41 @@ def test_kernel_int8_in_kernel_dequant_tolerance():
     # quantization-level agreement with the fp math (loose by design)
     np.testing.assert_allclose(fp_oracle, kernel8, atol=0.05)
     assert np.max(np.abs(fp_oracle - kernel8)) > 0  # really quantized
+
+
+#: (page_tokens, heads, kv_heads, head_dim): a GQA row of 72 values (no
+#: multiple of the 128 lanes), pages of 16 and of 128 tokens, a row of
+#: exactly one lane tile, and MHA (groups of one) at the cell's head size.
+PAGE_GEOMETRIES = {"gqa_row72_t16": (16, 6, 3, 24),
+                   "gqa_row128_t128": (128, 8, 2, 64),
+                   "mha_row256_t16": (16, 4, 4, 64)}
+
+
+@pytest.mark.parametrize("window", ["decode", "verify", "prefill"])
+@pytest.mark.parametrize("geometry", list(PAGE_GEOMETRIES))
+def test_kernels_find_a_head_inside_the_row_at_any_geometry(geometry,
+                                                            window):
+    """The three window shapes of the paged kernels over the flat page
+    row, at geometries the default fixture does not reach: a head is a
+    lane slice ``[h * dh, (h + 1) * dh)`` of the row whether the row is
+    under, exactly, or over the 128 lanes, for 16- and 128-token pages,
+    grouped (GQA) and not.  einsum ≡ oracle bitwise, kernel within fp
+    tolerance."""
+    T = PAGE_GEOMETRIES[geometry][0]
+    cur, scalar = {"decode": (1, None), "verify": (3, None),
+                   "prefill": (T, 2 * T)}[window]
+    pages, table, pos, q, dims = _fragmented_fixture(
+        cur=cur, scalar_pos=scalar, geometry=PAGE_GEOMETRIES[geometry])
+    if scalar is not None:  # the window's page is mapped in every slot
+        table = table.at[2].set(jnp.asarray([5, 6, 7, -1], jnp.int32))
+    oracle = np.asarray(_gather_oracle(pages, table, pos, q, dims))
+    einsum = np.asarray(paged_attention(
+        q, pages, table, pos, dtype=jnp.float32, grouped=True))
+    np.testing.assert_array_equal(oracle, einsum)
+    kernel = np.asarray(paged_attention(
+        q, pages, table, pos, dtype=jnp.float32, grouped=True,
+        impl="kernel", interpret=True))
+    np.testing.assert_allclose(oracle, kernel, rtol=2e-6, atol=2e-6)
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
@@ -630,13 +748,18 @@ def test_budget_ledger_strictly_below_pr13_gather_values():
 #: twin's (194_132 vs 191_032), so it is pinned to that value instead of
 #: asserted below — the static ledger was the only evidence behind the
 #: kernel default (PR 17); real peak memory is the chip's to say
-#: (ROADMAP S5).
+#: (ROADMAP S5).  Re-derived again by PR 35 (pages stored as flat token
+#: rows): the einsum twins gather rows and split them back into heads,
+#: and the static ledger counts that reshape as a second live tile set
+#: (decode 193_142 -> 200_814, verify 196_270 -> 202_382, fused decode
+#: 193_206 -> 200_878; XLA lowers it to a bitcast); every kernel program's
+#: own value stayed as it was, to the byte.
 EINSUM_TWIN_PEAK_LIVE = {
-    "serve.decode_paged_kernel": ("serve.decode_paged", 193_142),
-    "serve.verify_paged_kernel": ("serve.verify_paged", 196_270),
+    "serve.decode_paged_kernel": ("serve.decode_paged", 200_814),
+    "serve.verify_paged_kernel": ("serve.verify_paged", 202_382),
     "serve.prefill_paged_kernel": ("serve.prefill_paged", 191_032),
     "serve.fused_decode_paged_kernel": ("serve.fused_decode_paged",
-                                        193_206),
+                                        200_878),
     "serve.fused_spec_paged_kernel": ("serve.fused_spec_paged", 241_362),
     "serve.tree_verify_paged_kernel": ("serve.tree_verify_paged",
                                        212_188),

@@ -399,9 +399,14 @@ def test_page_types_and_their_geometry():
         gen.page_type(cfg, "int8")
     g = GPT2Config(vocab_size=64, max_seq_len=32, num_layers=2, num_heads=2,
                    d_model=16)
-    assert gen.page_type(g) is gen.KVCache
+    assert gen.page_type(g) is gen.KVPages  # the arena's type is KVCache
     assert gen.page_type(g, "int8") is gen.Int8Pages
-    assert gen.KVCache.geometry(g) == gen.Int8Pages.geometry(g) \
-        == (2, 2, 8, str(g.dtype))
+    assert gen.KVCache.geometry(g) == gen.KVPages.geometry(g) \
+        == gen.Int8Pages.geometry(g) == (2, 2, 8, str(g.dtype))
+    # a heads page is a token's row of kv_heads x head_dim values, fp and
+    # int8 alike; the int8 scales stay one a head
+    assert gen.KVPages.zeros(g, 5, 4).k.shape == (2, 5, 4, 16)
+    q8 = gen.Int8Pages.zeros(g, 5, 4)
+    assert q8.v.shape == (2, 5, 4, 16) and q8.v_scale.shape == (2, 5, 4, 2)
     with pytest.raises(ValueError, match="first_k_dense_replace"):
         dataclasses.replace(cfg, first_k_dense_replace=9)

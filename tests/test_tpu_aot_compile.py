@@ -3,7 +3,9 @@ WITHOUT a chip: libtpu can compile for a TPU topology description on a CPU
 host (tests/aot_tpu_compile.py).  This is a compile check only (lowering,
 Mosaic passes, VMEM fit at GPT-2-small geometry and, for the grouped
 matmuls and the whole expert layer with its row kernels, at the LFM2
-layer's real shapes; one whole LFM2-MoE train step at small widths); that
+layer's real shapes; one whole LFM2-MoE train step at small widths; the
+serve engine's decode and prefill programs at two benchmark cells' real
+sizes, held to one layout of the page pool and the chip's memory); that
 the compiled kernels
 compute the right numbers is `chip_smoke.py`'s job on the chip."""
 
@@ -20,6 +22,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 MOE_LAYER = {"moe_gmm", "moe_tgmm", "moe_swiglu", "moe_swiglu_bwd",
              "moe_combine", "moe_unwritten"}
 MOE_FORWARD = {"moe_gmm", "moe_swiglu", "moe_combine", "moe_unwritten"}
+# the engine's paged decode and prefill programs for GPT-2-medium at the
+# serving cell's sizes, over 288 pages (the cell's) and 512
+GPT2M_STEPS = {f"gpt2m_{step}_{pages}": kernel
+               for pages in (288, 512)
+               for step, kernel in (("decode", "paged_decode"),
+                                    ("prefill", "paged_prefill"))}
 
 
 @pytest.fixture(scope="module")
@@ -45,6 +53,14 @@ def _kernel_calls(proc) -> dict:
         if line.startswith("KERNELS ") and len(line.split()) == 3}
 
 
+def _pool_line(proc, program: str) -> dict:
+    """The child's ``POOL <program> key=value ...`` line, as a dict."""
+    lines = [ln.split() for ln in proc.stdout.splitlines()
+             if ln.startswith(f"POOL {program} ")]
+    assert len(lines) == 1, proc.stdout[-3000:]
+    return dict(kv.split("=") for kv in lines[0][2:])
+
+
 def test_kernel_families_compile_for_v5e_topology(compiled):
     proc = compiled
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-1500:]
@@ -53,8 +69,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     assert ok == {"flash_fwd", "flash_bwd", "flash_bwd_8k", "paged_decode",
                   "paged_window_verify", "paged_window_prefill",
                   "paged_tree", "moe_gmm_up", "moe_gmm_down", "moe_layer",
-                  "lfm2_train_step", "latent_decode", "latent_prefill"}, \
-        proc.stdout
+                  "lfm2_train_step", "latent_decode", "latent_prefill",
+                  *GPT2M_STEPS}, proc.stdout
 
 
 @pytest.mark.parametrize("family, kernels", [
@@ -70,7 +86,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("moe_layer", MOE_LAYER),
     ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
      | MOE_LAYER),
-    ("latent_decode", MOE_FORWARD), ("latent_prefill", MOE_FORWARD)])
+    ("latent_decode", MOE_FORWARD), ("latent_prefill", MOE_FORWARD),
+    *((program, {kernel}) for program, kernel in GPT2M_STEPS.items())])
 def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
     program's Mosaic custom calls have their ``pallas_call``'s ``name=``
@@ -100,9 +117,28 @@ def test_a_latent_step_program_fits_and_keeps_the_pool_in_place(compiled,
     weights, 1,024 pages of 512 tokens): within the v5e's 15.75 GB, and
     every operation on the page pool in its one row-major layout (a second
     layout means XLA copies the whole 3.4 GB pool, twice a program)."""
-    lines = [ln.split() for ln in compiled.stdout.splitlines()
-             if ln.startswith(f"POOL {program} ")]
-    assert len(lines) == 1, compiled.stdout[-3000:]
-    fields = dict(kv.split("=") for kv in lines[0][2:])
+    fields = _pool_line(compiled, program)
     assert fields["layouts"] == "3,2,1,0", fields
     assert 13.0e9 < int(fields["bytes"]) < 15.75e9, fields
+
+
+@pytest.mark.parametrize("program", list(GPT2M_STEPS))
+def test_a_gpt2m_step_program_reads_the_pool_as_it_is_stored(compiled,
+                                                             program):
+    """`gpt2m.serve_closed`'s two programs (64 slots, 128-token pages, the
+    kernel backend): the pool is stored as the paged kernels read it, a
+    token row of 16 heads x 64 a line (`generate.KVPages`), so every
+    pool-shaped value has ONE layout, no `copy` has the pool's shape (the
+    parent had four a program, 8.4 ms each on the chip: PERF.md section 6,
+    PR 35), the temporaries stay under 1 GB where the parent's were 10.9
+    GB, each layer runs its one Mosaic call, and 512 pages fit the chip
+    (they did not compile before)."""
+    fields = _pool_line(compiled, program)
+    assert fields["layouts"] == "3,2,1,0", fields
+    assert int(fields["copies"]) == 0, fields
+    assert int(fields["temp"]) < 1e9, fields
+    pages = int(program.rsplit("_", 1)[1])
+    pool = 2 * 24 * (pages + 1) * 128 * 1024 * 2  # K and V, bf16
+    assert pool + 0.7e9 < int(fields["bytes"]) < pool + 1.0e9 < 15.75e9, \
+        fields
+    assert _kernel_calls(compiled)[program] == {GPT2M_STEPS[program]: 24}

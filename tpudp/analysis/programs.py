@@ -250,7 +250,7 @@ def build_programs() -> dict:
     order."""
     import numpy as np
 
-    from tpudp.models.generate import KVCache
+    from tpudp.models.generate import KVCache, page_type
 
     programs: dict[str, tuple] = {}
 
@@ -327,7 +327,7 @@ def build_programs() -> dict:
     # (tests pin the gather-free values strictly below the PR 13
     # gather-based ones).
     n_pages = SERVE["pages"]
-    pool = KVCache.zeros(cfg, n_pages + 1, SERVE["chunk"])
+    pool = page_type(cfg).zeros(cfg, n_pages + 1, SERVE["chunk"])
     table = np.zeros((SERVE["slots"], SERVE["max_len"] // SERVE["chunk"]),
                      np.int32)
     pgeo2 = f"{geo}p{n_pages}"

@@ -46,6 +46,11 @@ from tpudp.models.gpt2 import GPT2Config, embed_tokens, lm_head
 
 
 class KVCache(NamedTuple):
+    """The DENSE arena: ``generate()``, ``beam_search()``, the unpaged
+    engine's slot rows, the speculation draft's scratch and the copy
+    prefix cache's blocks.  The page pool is not stored in this form:
+    :class:`KVPages`."""
+
     k: jnp.ndarray  # (layers, batch, max_len, kv_heads, head_dim)
     v: jnp.ndarray
 
@@ -65,9 +70,37 @@ class KVCache(NamedTuple):
         return cls(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
+class KVPages(NamedTuple):
+    """Page-pool buffers of the ``heads`` layout (GPT-2, LLaMA) in the
+    compute dtype: a cached token is ONE row a layer, its ``kv_heads``
+    heads of ``head_dim`` side by side on the lanes.  This is the form
+    the paged kernels read (``tpudp.ops.paged_attention``: a page block
+    is ``(page_tokens, kv_heads * head_dim)`` and a head is a lane slice
+    of the row), so the step programs touch the pool only through row
+    writes into the donated buffers and page reads by table value.  The
+    dense arena's ``(..., kv_heads, head_dim)`` minor pair, with a head
+    of 64 on the 128 lanes, made XLA keep the pool in one tiling and
+    hand the kernels another: four copies of the whole pool a program
+    (PERF.md section 6, PR 35).  The arena itself (:class:`KVCache`)
+    keeps its shape; only pages are stored flat."""
+
+    k: jnp.ndarray  # (layers, pages, page_tokens, kv_heads * head_dim)
+    v: jnp.ndarray
+
+    geometry = KVCache.geometry
+
+    @classmethod
+    def zeros(cls, cfg, num_pages: int, page_tokens: int) -> "KVPages":
+        layers, kv_heads, dh, _ = cls.geometry(cfg)
+        shape = (layers, num_pages, page_tokens, kv_heads * dh)
+        return cls(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+
+
 class Int8Pages(NamedTuple):
     """Quantized page-pool buffers (``Engine(kv_dtype="int8")``): k/v
-    stored int8 with per-(layer, page, token, head) fp32 scales — half
+    stored int8 in :class:`KVPages`' form (one token row of ``kv_heads *
+    head_dim`` values a layer) with per-(layer, page, token, head) fp32
+    scales — half
     the KV bytes per token of an fp32 pool behind the SAME block-table
     indirection (block ids, allocation order, and the radix tree are
     identical to the fp pool; only page payloads quantize).  Symmetric
@@ -76,7 +109,7 @@ class Int8Pages(NamedTuple):
     attention math, so outputs track the fp engine within quantization
     tolerance rather than bit-exactly (tests bound it)."""
 
-    k: jnp.ndarray        # (layers, pages, page_tokens, kv_heads, dh) int8
+    k: jnp.ndarray        # (layers, pages, page_tokens, kv_heads * dh) int8
     v: jnp.ndarray
     k_scale: jnp.ndarray  # (layers, pages, page_tokens, kv_heads) fp32
     v_scale: jnp.ndarray
@@ -86,10 +119,11 @@ class Int8Pages(NamedTuple):
     @classmethod
     def zeros(cls, cfg, num_pages: int, page_tokens: int) -> "Int8Pages":
         layers, kv_heads, dh, _ = cls.geometry(cfg)
-        shape = (layers, num_pages, page_tokens, kv_heads, dh)
+        shape = (layers, num_pages, page_tokens, kv_heads * dh)
+        scales = (layers, num_pages, page_tokens, kv_heads)
         return cls(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                   jnp.ones(shape[:-1], jnp.float32),
-                   jnp.ones(shape[:-1], jnp.float32))
+                   jnp.ones(scales, jnp.float32),
+                   jnp.ones(scales, jnp.float32))
 
 
 class LatentPages(NamedTuple):
@@ -103,7 +137,7 @@ class LatentPages(NamedTuple):
     pad or relayout either: the step programs touch the pool only through
     row writes into the donated buffers and page gathers out of them.
     Block ids, the table, the radix tree and the trailing scratch page are
-    the other page types' (:class:`KVCache`, :class:`Int8Pages`)."""
+    the other page types' (:class:`KVPages`, :class:`Int8Pages`)."""
 
     c: jnp.ndarray  # (layers, pages, page_tokens, kv_lora_rank)
     r: jnp.ndarray  # (layers, pages, page_tokens, latent_pad)
@@ -123,8 +157,9 @@ class LatentPages(NamedTuple):
 
 
 def page_layout(cfg) -> str:
-    """``'heads'`` (K and V per KV head: GPT-2, LLaMA) or ``'latent'``
-    (:class:`LatentPages`), from the model config."""
+    """``'heads'`` (K and V per KV head: GPT-2, LLaMA; :class:`KVPages`
+    or :class:`Int8Pages`) or ``'latent'`` (:class:`LatentPages`), from
+    the model config."""
     return getattr(cfg, "page_layout", "heads")
 
 
@@ -138,7 +173,7 @@ def page_type(cfg, kv_dtype: str | None = None):
                 f"kv_dtype={kv_dtype!r} is not implemented for latent "
                 "(MLA) pages: they are kept in the compute dtype")
         return LatentPages
-    return Int8Pages if kv_dtype == "int8" else KVCache
+    return Int8Pages if kv_dtype == "int8" else KVPages
 
 
 def _quantize_kv(x: jnp.ndarray):
@@ -151,13 +186,20 @@ def _quantize_kv(x: jnp.ndarray):
     return q.astype(jnp.int8), scale
 
 
+def _token_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """``(..., kv_heads, dh)`` -> ``(..., kv_heads * dh)``: a token's heads
+    side by side, the stored form of a ``heads`` page row."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
 def gather_pages(cfg, pool, table: jnp.ndarray) -> KVCache:
     """Materialize the logical dense view of a paged KV arena: per-slot
     block table ``(num_slots, max_pages)`` int32 into a page pool
-    (``KVCache`` or :class:`Int8Pages` of shape ``(layers, num_pages+1,
-    page_tokens, kv_heads, dh)``; the LAST page is the write scratch) ->
-    ``(layers, num_slots, max_pages*page_tokens, kv_heads, dh)``
-    KVCache in ``cfg.dtype``.
+    (:class:`KVPages` or :class:`Int8Pages` of shape ``(layers,
+    num_pages+1, page_tokens, kv_heads * dh)``; the LAST page is the write
+    scratch) -> ``(layers, num_slots, max_pages*page_tokens, kv_heads,
+    dh)`` KVCache in ``cfg.dtype`` (the gathered rows split back into
+    heads: the arena's shape).
 
     Unmapped entries (``-1``) clamp to the scratch page: their garbage
     lands only at positions beyond the owning slot's length, which the
@@ -167,20 +209,24 @@ def gather_pages(cfg, pool, table: jnp.ndarray) -> KVCache:
     holding the same values."""
     scratch = pool.k.shape[1] - 1
     tbl = jnp.where(table >= 0, table, scratch)
+    kv_heads = KVCache.geometry(cfg)[1]
 
-    def grab(buf):
-        g = buf[:, tbl]  # (L, S, M, T, ...) advanced-index gather
-        return g.reshape(g.shape[0], g.shape[1],
-                         g.shape[2] * g.shape[3], *g.shape[4:])
+    def grab(buf):  # (L, P, T, w) -> (L, S, M*T, w), w a row or its scales
+        g = buf[:, tbl]  # (L, S, M, T, w) advanced-index gather
+        return g.reshape(g.shape[0], g.shape[1], -1, g.shape[-1])
+
+    def heads(buf):  # the gathered rows, split back into heads
+        g = grab(buf)
+        return g.reshape(*g.shape[:-1], kv_heads, -1)
 
     if isinstance(pool, Int8Pages):
-        k = (grab(pool.k).astype(jnp.float32)
+        k = (heads(pool.k).astype(jnp.float32)
              * grab(pool.k_scale)[..., None]).astype(cfg.dtype)
-        v = (grab(pool.v).astype(jnp.float32)
+        v = (heads(pool.v).astype(jnp.float32)
              * grab(pool.v_scale)[..., None]).astype(cfg.dtype)
         return KVCache(k, v)
-    return KVCache(grab(pool.k).astype(cfg.dtype),
-                   grab(pool.v).astype(cfg.dtype))
+    return KVCache(heads(pool.k).astype(cfg.dtype),
+                   heads(pool.v).astype(cfg.dtype))
 
 
 def scatter_pages(pool, view: KVCache, table: jnp.ndarray,
@@ -204,7 +250,7 @@ def scatter_pages(pool, view: KVCache, table: jnp.ndarray,
     first = pos // T
     last = (pos + cur - 1) // T
 
-    def cut(buf, starts):  # (L, S, M*T, ...) -> (L, S, T, ...)
+    def cut(buf, starts):  # (L, S, M*T, kv, dh) -> (L, S, T, kv, dh)
         return jax.vmap(
             lambda b, p: lax.dynamic_slice_in_dim(b, p, T, axis=1),
             in_axes=(1, 0), out_axes=1)(buf, starts)
@@ -220,13 +266,14 @@ def scatter_pages(pool, view: KVCache, table: jnp.ndarray,
         if isinstance(pool, Int8Pages):
             qk, sk = _quantize_kv(ck)
             qv, sv = _quantize_kv(cv)
-            pool = Int8Pages(pool.k.at[:, page].set(qk),
-                             pool.v.at[:, page].set(qv),
+            pool = Int8Pages(pool.k.at[:, page].set(_token_rows(qk)),
+                             pool.v.at[:, page].set(_token_rows(qv)),
                              pool.k_scale.at[:, page].set(sk),
                              pool.v_scale.at[:, page].set(sv))
         else:
-            pool = KVCache(pool.k.at[:, page].set(ck.astype(pool.k.dtype)),
-                           pool.v.at[:, page].set(cv.astype(pool.v.dtype)))
+            pool = KVPages(
+                pool.k.at[:, page].set(_token_rows(ck).astype(pool.k.dtype)),
+                pool.v.at[:, page].set(_token_rows(cv).astype(pool.v.dtype)))
     return pool
 
 
@@ -242,12 +289,15 @@ def write_token_pages(pages, k_new: jnp.ndarray, v_new: jnp.ndarray,
     decode step's write traffic is one token's worth of KV, not a
     whole-page (let alone whole-view) rewrite.
 
-    ``pages`` is one LAYER's page buffers — ``(k, v)`` fp (the two may
-    differ in everything behind the token axis: :class:`LatentPages`'
-    ``(c, r)`` with ``k_new`` the latents and ``v_new`` the rotary keys
+    ``pages`` is one LAYER's page buffers — ``(k, v)`` fp, each
+    ``(pages, page_tokens, row)``: a token is one row, and ``k_new`` /
+    ``v_new`` ``(b, cur, kv_heads, dh)`` are written as rows of
+    ``kv_heads * dh`` values (:class:`KVPages`; the two buffers may
+    differ in the row's width: :class:`LatentPages`' ``(c, r)`` with
+    ``k_new`` ``(b, cur, c)`` the latents and ``v_new`` the rotary keys
     commit through this same function) or
-    ``(k, v, k_scale, v_scale)`` int8 (new vectors quantize with the
-    same symmetric-absmax math as :func:`scatter_pages`; since that
+    ``(k, v, k_scale, v_scale)`` int8 (new vectors quantize per head with
+    the same symmetric-absmax math as :func:`scatter_pages`; since that
     quantization is idempotent on already-quantized vectors, the pool
     bytes match the old whole-page rewrite exactly).  Writes of
     inactive slots, and of positions past the table (never expected —
@@ -265,6 +315,12 @@ def write_token_pages(pages, k_new: jnp.ndarray, v_new: jnp.ndarray,
     n_pages = table.shape[1]
     scratch = pages[0].shape[len(ix)] - 1
     b, cur = k_new.shape[0], k_new.shape[1]
+    if len(pages) == 4:
+        qk, sk = _quantize_kv(k_new)
+        qv, sv = _quantize_kv(v_new)
+        new = (_token_rows(qk), _token_rows(qv), sk, sv)
+    else:  # (b, cur, kv_heads, dh) or (b, cur, row) -> stored rows
+        new = (k_new.reshape(b, cur, -1), v_new.reshape(b, cur, -1))
     pos = jnp.asarray(pos)
     scalar_pos = not pos.ndim
     if scalar_pos:
@@ -284,15 +340,8 @@ def write_token_pages(pages, k_new: jnp.ndarray, v_new: jnp.ndarray,
         valid = (active & (pidx < n_pages) & (page >= 0)
                  & (pos % T == 0))
         page = jnp.where(valid, page, scratch)
-        if len(pages) == 4:
-            qk, sk = _quantize_kv(k_new)
-            qv, sv = _quantize_kv(v_new)
-            return (pages[0].at[(*ix, page)].set(qk),
-                    pages[1].at[(*ix, page)].set(qv),
-                    pages[2].at[(*ix, page)].set(sk),
-                    pages[3].at[(*ix, page)].set(sv))
-        return (pages[0].at[(*ix, page)].set(k_new.astype(pages[0].dtype)),
-                pages[1].at[(*ix, page)].set(v_new.astype(pages[1].dtype)))
+        return tuple(buf.at[(*ix, page)].set(val.astype(buf.dtype))
+                     for buf, val in zip(pages, new))
     for j in range(cur):
         p = pos + j
         pidx = p // T
@@ -301,19 +350,10 @@ def write_token_pages(pages, k_new: jnp.ndarray, v_new: jnp.ndarray,
         page = jnp.take_along_axis(table, safe[:, None], axis=1)[:, 0]
         valid = active & (pidx < n_pages) & (page >= 0)
         page = jnp.where(valid, page, scratch)
-        kj, vj = k_new[:, j], v_new[:, j]
-        if len(pages) == 4:
-            qk, sk = _quantize_kv(kj)
-            qv, sv = _quantize_kv(vj)
-            pages = (pages[0].at[(*ix, page, off)].set(qk),
-                     pages[1].at[(*ix, page, off)].set(qv),
-                     pages[2].at[(*ix, page, off)].set(sk),
-                     pages[3].at[(*ix, page, off)].set(sv))
-        else:
-            pages = (pages[0].at[(*ix, page, off)].set(
-                         kj.astype(pages[0].dtype)),
-                     pages[1].at[(*ix, page, off)].set(
-                         vj.astype(pages[1].dtype)))
+        # (every slice before the first write: the order of the pinned traces)
+        rows = [val[:, j] for val in new]
+        pages = tuple(buf.at[(*ix, page, off)].set(row.astype(buf.dtype))
+                      for buf, row in zip(pages, rows))
     return pages
 
 
@@ -321,22 +361,14 @@ def _layer_pages(pool, i: int):
     """One layer's page-buffer slice of the pool: ``(k, v)`` or the
     int8 quadruple — the unit :class:`_PagedKV` reads/writes, so only
     one layer's tiles are ever transient at a time."""
-    if isinstance(pool, Int8Pages):
-        return (pool.k[i], pool.v[i], pool.k_scale[i], pool.v_scale[i])
-    return (pool.k[i], pool.v[i])
+    return tuple(buf[i] for buf in pool)
 
 
 def _stack_pages(pool, layers: list):
     """Reassemble the pool pytree from per-layer page buffers (the
     paged mirror of ``_forward_cached``'s ``jnp.stack`` over layer
     caches; the donated pool aliases in place under XLA)."""
-    if isinstance(pool, Int8Pages):
-        return Int8Pages(jnp.stack([p[0] for p in layers]),
-                         jnp.stack([p[1] for p in layers]),
-                         jnp.stack([p[2] for p in layers]),
-                         jnp.stack([p[3] for p in layers]))
-    return KVCache(jnp.stack([p[0] for p in layers]),
-                   jnp.stack([p[1] for p in layers]))
+    return type(pool)(*(jnp.stack(bufs) for bufs in zip(*layers)))
 
 
 class _PagedKV:

@@ -13,6 +13,16 @@ that traffic was the paged engine's perf ceiling.  This module removes
 it: attention reads K/V **through the block table**, block-wise over
 ``(pages, page_size)`` tiles, one layer at a time.
 
+A page is ``(page_tokens, kv_heads * dh)``: one cached token a row, its
+KV heads side by side on the lanes (``generate.KVPages``; int8 payloads
+the same, with a ``(page_tokens, kv_heads)`` block of scales beside
+them).  The kernels take a page block in exactly that form and find a
+head as a lane slice of the row, and the einsum backend splits the
+GATHERED rows back into heads, so nothing ever reshapes, pads or
+relayouts the pool itself: stored ``(..., kv_heads, 64)`` it cost four
+copies of the whole pool a step program on the v5e (XLA kept the
+compact tiling, Mosaic fixed a lane-padded one; PERF.md section 6, PR 35).
+
 Two backends behind one op:
 
   * ``impl='einsum'`` (the engine default) — **bit-exact**: per-page
@@ -73,10 +83,12 @@ def _interpret_default() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def page_tiles(pages, table, dtype):
+def page_tiles(pages, table, dtype, dh: int):
     """Per-slot ``(b, M, T, kv, dh)`` K/V tiles indexed by the block
     table — the read half of the gather-free contract.  ``pages`` is
-    the per-layer page buffer pair ``(k, v)`` (fp) or quadruple
+    the per-layer page buffer pair ``(k, v)`` (fp), each ``(pages + 1,
+    T, kv * dh)``: the GATHERED rows are split back into heads of
+    ``dh``, the pool itself never is; or the quadruple
     ``(k, v, k_scale, v_scale)`` (int8; dequantized here with exactly
     ``generate.gather_pages``'s math, so int8 tile values match the
     gather path's bit-for-bit).  Unmapped table entries (``-1``) clamp
@@ -85,13 +97,18 @@ def page_tiles(pages, table, dtype):
     as the dense arena's garbage-beyond-``pos`` rows."""
     scratch = pages[0].shape[0] - 1
     tbl = jnp.where(table >= 0, table, scratch)
+
+    def heads(buf):  # (b, M, T, kv * dh) -> (b, M, T, kv, dh)
+        rows = buf[tbl]
+        return rows.reshape(*rows.shape[:-1], -1, dh)
+
     if len(pages) == 4:
         k8, v8, ks, vs = pages
-        k = (k8[tbl].astype(jnp.float32) * ks[tbl][..., None]).astype(dtype)
-        v = (v8[tbl].astype(jnp.float32) * vs[tbl][..., None]).astype(dtype)
+        k = (heads(k8).astype(jnp.float32) * ks[tbl][..., None]).astype(dtype)
+        v = (heads(v8).astype(jnp.float32) * vs[tbl][..., None]).astype(dtype)
         return k, v
     k, v = pages
-    return k[tbl].astype(dtype), v[tbl].astype(dtype)
+    return heads(k).astype(dtype), heads(v).astype(dtype)
 
 
 def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
@@ -102,7 +119,7 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
     steps) or a scalar (the prefill window: ONE batched contraction
     over the whole window, mirroring the scalar-``pos`` dense path)."""
     b, cur, h, dh = q.shape
-    kt, vt = page_tiles(pages, table, dtype)  # (b, M, T, kv, dh)
+    kt, vt = page_tiles(pages, table, dtype, dh)  # (b, M, T, kv, dh)
     kv = kt.shape[3]
     max_len = kt.shape[1] * kt.shape[2]
     scale = dh ** -0.5
@@ -232,6 +249,19 @@ def latent_paged_attention(q_lat, q_rope, pages, table, pos, *, scale: float,
 
 
 # ------------------------------------------------------- Pallas kernel
+#
+# A page block is ``(page_tokens, kv * dh)``: one cached token a row, its
+# KV heads side by side on the lanes (``generate.KVPages``), which is how
+# the pool is stored, so a block is DMA'd as it lies and XLA never
+# relayouts the pool around a call.  A head is a lane slice of the row.
+
+
+def _head(blk, scales, ki: int, dh: int):
+    """KV head ``ki`` of a float32 page block ``(T, kv * dh)`` ->
+    ``(T, dh)``, dequantised by its per-token scale column where the
+    block is an int8 payload (``scales`` ``(T, kv)``)."""
+    x = blk[:, ki * dh:(ki + 1) * dh]
+    return x if scales is None else x * scales[:, ki:ki + 1]
 
 
 def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
@@ -268,18 +298,18 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(mapped)  # -1 (unmapped) pages: skip — nothing to attend
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale  # (h, dh)
-        k_blk = k_ref[0].astype(jnp.float32)      # (T, kv, dh)
+        dh = q.shape[-1]
+        k_blk = k_ref[0].astype(jnp.float32)      # (T, kv * dh)
         v_blk = v_ref[0].astype(jnp.float32)
-        if int8:
-            k_blk = k_blk * ks_ref[0].astype(jnp.float32)[..., None]
-            v_blk = v_blk * vs_ref[0].astype(jnp.float32)[..., None]
+        ks = ks_ref[0].astype(jnp.float32) if int8 else None  # (T, kv)
+        vs = vs_ref[0].astype(jnp.float32) if int8 else None
         # Query head j attends KV head j // groups (the GQA mapping;
         # groups == 1 is MHA).  Static per-KV-head 2D dots keep the MXU
         # happy — kv is a small compile-time constant.
         rows = []
         for ki in range(kv):
             qk = q[ki * groups:(ki + 1) * groups]  # (g, dh)
-            rows.append(jnp.dot(qk, k_blk[:, ki, :].T,
+            rows.append(jnp.dot(qk, _head(k_blk, ks, ki, dh).T,
                                 preferred_element_type=jnp.float32))
         s_blk = jnp.concatenate(rows, axis=0)  # (h, T)
         k_pos = m * page_tokens + lax.broadcasted_iota(
@@ -296,7 +326,7 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         pv = []
         for ki in range(kv):
             pv.append(jnp.dot(p[ki * groups:(ki + 1) * groups],
-                              v_blk[:, ki, :],
+                              _head(v_blk, vs, ki, dh),
                               preferred_element_type=jnp.float32))
         acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(pv, axis=0)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -333,7 +363,8 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
     k_pages, v_pages = pages[0], pages[1]
     n_real = k_pages.shape[len(lx)] - 1  # trailing page is write scratch
     page_tokens = k_pages.shape[1 + len(lx)]
-    kv = k_pages.shape[2 + len(lx)]
+    row = k_pages.shape[2 + len(lx)]  # kv * dh: a token's stored row
+    kv = row // dh
     n_pages = table.shape[1]
     groups = h // kv
     scale = dh ** -0.5
@@ -342,11 +373,7 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
     tbl = jnp.asarray(table, jnp.int32).reshape(-1)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
 
-    def page_map(s, m, tbl_ref, pos_ref):
-        t = tbl_ref[s * n_pages + m]
-        return (*lx, jnp.where(t >= 0, t, scratch_page), 0, 0, 0)
-
-    def scale_map(s, m, tbl_ref, pos_ref):
+    def page_map(s, m, tbl_ref, pos_ref):  # payload and scale blocks
         t = tbl_ref[s * n_pages + m]
         return (*lx, jnp.where(t >= 0, t, scratch_page), 0, 0)
 
@@ -356,12 +383,12 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
     ins = (pages[0], pages[1]) + ((pages[2], pages[3]) if int8 else ())
     in_specs = [
         pl.BlockSpec((1, h, dh), lambda s, m, t, p: (s, 0, 0)),
-        pl.BlockSpec((*pb, 1, page_tokens, kv, dh), page_map),
-        pl.BlockSpec((*pb, 1, page_tokens, kv, dh), page_map),
+        pl.BlockSpec((*pb, 1, page_tokens, row), page_map),
+        pl.BlockSpec((*pb, 1, page_tokens, row), page_map),
     ]
     if int8:
-        in_specs += [pl.BlockSpec((*pb, 1, page_tokens, kv), scale_map),
-                     pl.BlockSpec((*pb, 1, page_tokens, kv), scale_map)]
+        in_specs += [pl.BlockSpec((*pb, 1, page_tokens, kv), page_map),
+                     pl.BlockSpec((*pb, 1, page_tokens, kv), page_map)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_pages),
@@ -433,16 +460,15 @@ def _window_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(mapped)  # -1 (unmapped) pages: skip — nothing to attend
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale  # (width, h, dh)
-        k_blk = k_ref[0].astype(jnp.float32)      # (T, kv, dh)
+        k_blk = k_ref[0].astype(jnp.float32)      # (T, kv * dh)
         v_blk = v_ref[0].astype(jnp.float32)
-        if int8:
-            k_blk = k_blk * ks_ref[0].astype(jnp.float32)[..., None]
-            v_blk = v_blk * vs_ref[0].astype(jnp.float32)[..., None]
+        ks = ks_ref[0].astype(jnp.float32) if int8 else None  # (T, kv)
+        vs = vs_ref[0].astype(jnp.float32) if int8 else None
         blocks = []
         for ki in range(kv):
             qk = q[:, ki * groups:(ki + 1) * groups, :].reshape(
                 width * groups, dh)
-            blocks.append(jnp.dot(qk, k_blk[:, ki, :].T,
+            blocks.append(jnp.dot(qk, _head(k_blk, ks, ki, dh).T,
                                   preferred_element_type=jnp.float32))
         s_blk = jnp.concatenate(blocks, axis=0)  # (rows, T)
         k_pos = m * page_tokens + lax.broadcasted_iota(
@@ -462,7 +488,8 @@ def _window_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         for ki in range(kv):
             pv.append(jnp.dot(
                 p[ki * width * groups:(ki + 1) * width * groups],
-                v_blk[:, ki, :], preferred_element_type=jnp.float32))
+                _head(v_blk, vs, ki, dh),
+                preferred_element_type=jnp.float32))
         acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(pv, axis=0)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
@@ -495,7 +522,8 @@ def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
     pb = (None,) * len(lx)
     k_pages = pages[0]
     page_tokens = k_pages.shape[1 + len(lx)]
-    kv = k_pages.shape[2 + len(lx)]
+    row = k_pages.shape[2 + len(lx)]  # kv * dh: a token's stored row
+    kv = row // dh
     n_pages = table.shape[1]
     groups = h // kv
     scale = dh ** -0.5
@@ -507,11 +535,7 @@ def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
     tbl = jnp.asarray(table, jnp.int32).reshape(-1)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
 
-    def page_map(s, t, m, tbl_ref, pos_ref):
-        pg = tbl_ref[s * n_pages + m]
-        return (*lx, jnp.where(pg >= 0, pg, scratch_page), 0, 0, 0)
-
-    def scale_map(s, t, m, tbl_ref, pos_ref):
+    def page_map(s, t, m, tbl_ref, pos_ref):  # payload and scale blocks
         pg = tbl_ref[s * n_pages + m]
         return (*lx, jnp.where(pg >= 0, pg, scratch_page), 0, 0)
 
@@ -522,12 +546,12 @@ def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
     in_specs = [
         pl.BlockSpec((1, width, h, dh),
                      lambda s, t, m, tb, p: (s, t, 0, 0)),
-        pl.BlockSpec((*pb, 1, page_tokens, kv, dh), page_map),
-        pl.BlockSpec((*pb, 1, page_tokens, kv, dh), page_map),
+        pl.BlockSpec((*pb, 1, page_tokens, row), page_map),
+        pl.BlockSpec((*pb, 1, page_tokens, row), page_map),
     ]
     if int8:
-        in_specs += [pl.BlockSpec((*pb, 1, page_tokens, kv), scale_map),
-                     pl.BlockSpec((*pb, 1, page_tokens, kv), scale_map)]
+        in_specs += [pl.BlockSpec((*pb, 1, page_tokens, kv), page_map),
+                     pl.BlockSpec((*pb, 1, page_tokens, kv), page_map)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, q_tiles, n_pages),
@@ -581,7 +605,7 @@ def _tree_kernel(tbl_ref, pos_ref, anc_ref, q_ref, k_ref, v_ref,
         for ki in range(kv):
             qk = q[:, ki * groups:(ki + 1) * groups, :].reshape(
                 t1 * groups, dh)
-            blocks.append(jnp.dot(qk, k_src[:, ki, :].T,
+            blocks.append(jnp.dot(qk, _head(k_src, None, ki, dh).T,
                                   preferred_element_type=jnp.float32))
         return jnp.concatenate(blocks, axis=0)  # (rows, n_keys)
 
@@ -598,7 +622,8 @@ def _tree_kernel(tbl_ref, pos_ref, anc_ref, q_ref, k_ref, v_ref,
         for ki in range(kv):
             pv.append(jnp.dot(
                 p[ki * t1 * groups:(ki + 1) * t1 * groups],
-                v_src[:, ki, :], preferred_element_type=jnp.float32))
+                _head(v_src, None, ki, dh),
+                preferred_element_type=jnp.float32))
         acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(pv, axis=0)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
@@ -607,7 +632,7 @@ def _tree_kernel(tbl_ref, pos_ref, anc_ref, q_ref, k_ref, v_ref,
 
     @pl.when(mapped)
     def _cache_page():
-        k_blk = k_ref[0].astype(jnp.float32)  # (T, kv, dh)
+        k_blk = k_ref[0].astype(jnp.float32)  # (T, kv * dh)
         v_blk = v_ref[0].astype(jnp.float32)
         s_blk = _scores(k_blk)
         k_pos = mi * page_tokens + lax.broadcasted_iota(
@@ -617,7 +642,7 @@ def _tree_kernel(tbl_ref, pos_ref, anc_ref, q_ref, k_ref, v_ref,
 
     @pl.when(m == n_pages)
     def _window_block():
-        wk = wk_ref[0].astype(jnp.float32)  # (t1, kv, dh)
+        wk = wk_ref[0].astype(jnp.float32)  # (t1, kv * dh): page rows
         wv = wv_ref[0].astype(jnp.float32)
         s_blk = _scores(wk)  # (rows, t1)
         anc = jnp.array([[anc_ref[j * t1 + c] for c in range(t1)]
@@ -654,7 +679,8 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
             "the einsum fallback (Engine records the dispatch)")
     b, t1, h, dh = q.shape
     k_pages = pages[0]
-    page_tokens, kv = k_pages.shape[1], k_pages.shape[2]
+    page_tokens, row = k_pages.shape[1], k_pages.shape[2]
+    kv = row // dh
     n_pages = table.shape[1]
     groups = h // kv
     scale = dh ** -0.5
@@ -668,10 +694,13 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
         mi = jnp.minimum(m, n_pages - 1)
         pg = tbl_ref[s * n_pages + mi]
         pg = jnp.where((m < n_pages) & (pg >= 0), pg, scratch_page)
-        return (pg, 0, 0, 0)
+        return (pg, 0, 0)
 
     def slot_map(s, m, tbl_ref, pos_ref, anc_ref):
         return (s, 0, 0, 0)
+
+    def window_map(s, m, tbl_ref, pos_ref, anc_ref):
+        return (s, 0, 0)
 
     kernel = functools.partial(
         _tree_kernel, kv=kv, groups=groups, t1=t1,
@@ -682,10 +711,10 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
         grid=(b, n_pages + 1),
         in_specs=[
             pl.BlockSpec((1, t1, h, dh), slot_map),
-            pl.BlockSpec((1, page_tokens, kv, dh), page_map),
-            pl.BlockSpec((1, page_tokens, kv, dh), page_map),
-            pl.BlockSpec((1, t1, kv, dh), slot_map),
-            pl.BlockSpec((1, t1, kv, dh), slot_map),
+            pl.BlockSpec((1, page_tokens, row), page_map),
+            pl.BlockSpec((1, page_tokens, row), page_map),
+            pl.BlockSpec((1, t1, row), window_map),
+            pl.BlockSpec((1, t1, row), window_map),
         ],
         out_specs=pl.BlockSpec((1, t1, h, dh), slot_map),
         scratch_shapes=[
@@ -700,7 +729,8 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
         out_shape=jax.ShapeDtypeStruct((b, t1, h, dh), dtype),
         interpret=interpret,
         name="paged_tree",
-    )(tbl, pos0, anc_flat, q, *pages, wk, wv)
+    )(tbl, pos0, anc_flat, q, *pages, wk.reshape(b, t1, row),
+      wv.reshape(b, t1, row))  # the window's K/V as page rows
 
 
 # ----------------------------------------------------------- public op
@@ -715,9 +745,12 @@ def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
 
     ``q``: ``(b, cur, heads, dh)`` queries (RoPE already applied for
     LLaMA).  ``pages``: one LAYER's page buffers — ``(k, v)`` each
-    ``(num_pages + 1, page_tokens, kv_heads, dh)`` (the last page is
-    the write scratch), or ``(k, v, k_scale, v_scale)`` for int8
-    payloads.  ``table``: ``(b, max_pages)`` int32 block table, ``-1``
+    ``(num_pages + 1, page_tokens, kv_heads * dh)`` (a token's KV heads
+    side by side in one row, ``generate.KVPages``; the last page is
+    the write scratch; ``kv_heads`` is the row's width over ``q``'s
+    ``dh``), or ``(k, v, k_scale, v_scale)`` for int8 payloads (scales
+    ``(num_pages + 1, page_tokens, kv_heads)``).
+    ``table``: ``(b, max_pages)`` int32 block table, ``-1``
     unmapped.  ``pos``: ``(b,)`` per-row depths (window position ``j``
     attends keys ``<= pos[b] + j``; the serve engine's vector-position
     contract) or a scalar (the prefill window's shared depth).
@@ -762,9 +795,11 @@ def tree_paged_attention(q, pages, table, pos0, wk, wv, anc, *, dtype,
     """Tree-structured attention over table-indirected cache pages plus
     an in-flight node window — the kernel half of ``tree_verify_paged``.
 
-    ``q``: ``(b, T+1, heads, dh)`` node queries; ``wk``/``wv``:
-    ``(b, T+1, kv, dh)`` window K/V (computed this forward, NEVER
-    written to pages — rejected branches must leave zero pool bytes);
+    ``q``: ``(b, T+1, heads, dh)`` node queries; ``pages``: one layer's
+    ``(k, v)``, each ``(num_pages + 1, page_tokens, kv * dh)``;
+    ``wk``/``wv``: ``(b, T+1, kv, dh)`` window K/V (computed this
+    forward, NEVER written to pages — rejected branches must leave zero
+    pool bytes; they enter the kernel as page rows of their own);
     ``anc``: the static ``(T+1, T+1)`` ancestor-or-self mask (row j
     sees column c iff c is an ancestor of j or j itself), entering the
     kernel as a scalar-prefetched per-shape constant.  Cache visibility

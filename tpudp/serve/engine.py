@@ -977,9 +977,10 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
     # reads K/V through the table inside the attention contraction
     # (bit-identical outputs — tpudp.ops.paged_attention's contract —
     # with the dense logical view never materialized); "gather" keeps
-    # PR 13's gather→dense→scatter baseline.  The pool (KVCache or
-    # Int8Pages pytree) is donated like the dense arena; the TABLE is
-    # host-authoritative and read-only on device.
+    # PR 13's gather→dense→scatter baseline.  The pool (a KVPages or
+    # Int8Pages pytree: token rows of kv_heads * head_dim values, the
+    # form the paged kernels read) is donated like the dense arena; the
+    # TABLE is host-authoritative and read-only on device.
     kernel_build = paged_attn == "kernel"
     win_impl = "gather" if paged_attn == "gather" else (
         "kernel" if kernel_build else "einsum")

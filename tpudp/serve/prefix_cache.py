@@ -344,8 +344,11 @@ class PagePool:
         self._free = list(range(num_pages - 1, -1, -1))
 
     def _buffer(self):
-        # the page type is the config's (K/V per head, int8 K/V + scales,
-        # or a latent family's LatentPages)
+        # the page type is the config's (generate.page_type): K/V token
+        # rows of kv_heads * head_dim values (KVPages: the form the paged
+        # kernels read, NOT the dense arena's and the copy cache's
+        # (..., kv_heads, head_dim) KVCache above), the same rows in int8
+        # + a scale a head, or a latent family's LatentPages
         return page_type(self.config, self.kv_dtype).zeros(
             self.config, self.num_pages + 1, self.page_tokens)
 
@@ -396,7 +399,9 @@ class PagePool:
     def read_page(self, page: int) -> dict:
         """Host copies of one allocated page's slice of every pool
         buffer (k, v, and the int8 scales when present), keyed by
-        field name — the unit of cross-host KV migration
+        field name and in the pool's stored form (``(layers,
+        page_tokens, kv_heads * head_dim)`` token rows for k and v) —
+        the unit of cross-host KV migration
         (``tpudp/serve/disagg.py``).  Read-only: shared pages (radix
         tree, other slots) are untouched."""
         import numpy as np
