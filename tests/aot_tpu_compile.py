@@ -178,6 +178,41 @@ def latent_steps():
                         slots=slots, width=width, chunk=chunk)
 
 
+def laguna_steps():
+    """The serve engine's two programs for the window-and-full-attention
+    expert family at the benchmark cell's real sizes (perf/configs/
+    laguna_xs2.json; 64 slots, 8,192 positions, 512-token pages: 1,024
+    global pages and 64 x 2 window pages, each pool with its scratch page,
+    the kernel backend): a page block is ``(512, 1,024)`` bf16 = 1 MB, four
+    times `gpt2m.serve_closed`'s, and the kernels upcast it to float32, so
+    this is where VMEM would refuse; the whole program within the chip's
+    memory; each pool in ONE layout with no copy of its shape."""
+    import json
+
+    from tpudp.models.generate import WindowedPages
+    from tpudp.models.laguna import Laguna, LagunaConfig
+    from tpudp.serve import engine
+
+    for name in ("tpudp.ops.grouped_matmul", "tpudp.ops.paged_attention"):
+        sys.modules[name]._interpret_default = lambda: False
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", "laguna_xs2.json")) as f:
+        config = {k: v for k, v in json.load(f).items() if k != "rehearsal"}
+    cfg = LagunaConfig.from_dict(config, dtype=BF16, param_dtype=BF16)
+    params = shapes(jax.eval_shape(
+        lambda k: Laguna(cfg).init(k, jnp.zeros((1, 16), jnp.int32))["params"],
+        jax.random.PRNGKey(0)))
+    slots, chunk, width = 64, 512, 8192 // 512
+    pool = shapes(jax.eval_shape(lambda: WindowedPages.zeros(
+        cfg, LAGUNA_PAGES[0] + 1, chunk, LAGUNA_PAGES[1] + 1)))
+    steps = engine_steps("laguna_{}".format,
+                         engine._build_steps(cfg, "kernel"), params, pool,
+                         slots=slots, width=width, chunk=chunk)
+    # the engine hands this family the pair of its two pools' tables
+    return {name: (fn, (*args[:2], (args[2], args[2]), *args[3:]))
+            for name, (fn, args) in steps.items()}
+
+
 def gpt2m_steps(pages):
     """The serve engine's paged decode and prefill programs for
     perf/configs/gpt2_medium.json at `gpt2m.serve_closed`'s sizes (64
@@ -213,6 +248,7 @@ def gpt2m_steps(pages):
 
 
 LATENT_PAGES = 1024
+LAGUNA_PAGES = (1024, 128)  # global pages; window pages: 64 slots x 2
 ANC = tuple(map(tuple, np.tril(np.ones((5, 5), np.int32))))
 x = sds((2, 1024, H, DH), BF16)
 x8k = sds((1, 8192, H, DH), BF16)  # the chooser's blocks at a long sequence
@@ -230,6 +266,7 @@ CASES = {
     "moe_layer": moe_layer(),
     "lfm2_train_step": lfm2_step(),
     **latent_steps(),
+    **laguna_steps(),
     **gpt2m_steps(288), **gpt2m_steps(512),
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
@@ -253,7 +290,8 @@ CASES = {
 # HLO instruction itself is then ``flash_fwd.<n>``, which is what the
 # benchmark's kernel_ms.* readers match in a device trace).
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
-           "paged_prefill", "paged_tree", "moe_gmm", "moe_tgmm",
+           "paged_prefill", "paged_decode_window", "paged_prefill_window",
+           "paged_tree", "moe_gmm", "moe_tgmm",
            "moe_swiglu", "moe_swiglu_bwd", "moe_combine", "moe_unwritten")
 MOSAIC_OP = re.compile(
     r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
@@ -274,10 +312,13 @@ for name, (fn, args) in CASES.items():
         print(f"OK {name}")
         print(f"KERNELS {name} " + ",".join(
             f"{k}={n}" for k, n in sorted(calls.items()) if n))
-        if name.startswith(("latent_", "gpt2m_")):
+        if name.startswith(("latent_", "gpt2m_", "laguna_")):
             mem = compiled.memory_analysis()
             pool = (rf"bf16\[5,{LATENT_PAGES + 1},512,\d+\]"
                     if name.startswith("latent_") else
+                    rf"bf16\[(?:2,{LAGUNA_PAGES[0] + 1}|3,"
+                    rf"{LAGUNA_PAGES[1] + 1}),512,1024\]"
+                    if name.startswith("laguna_") else
                     rf"bf16\[24,{int(name.rsplit('_', 1)[1]) + 1},128,1024\]")
             layouts = sorted(set(re.findall(pool + r"{([\d,]+)", text)))
             copies = len(re.findall(rf"= {pool}\S* copy\(", text))

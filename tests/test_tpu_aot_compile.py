@@ -4,7 +4,7 @@ host (tests/aot_tpu_compile.py).  This is a compile check only (lowering,
 Mosaic passes, VMEM fit at GPT-2-small geometry and, for the grouped
 matmuls and the whole expert layer with its row kernels, at the LFM2
 layer's real shapes; one whole LFM2-MoE train step at small widths; the
-serve engine's decode and prefill programs at two benchmark cells' real
+serve engine's decode and prefill programs at three benchmark cells' real
 sizes, held to one layout of the page pool and the chip's memory); that
 the compiled kernels
 compute the right numbers is `chip_smoke.py`'s job on the chip."""
@@ -70,6 +70,7 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
                   "paged_window_verify", "paged_window_prefill",
                   "paged_tree", "moe_gmm_up", "moe_gmm_down", "moe_layer",
                   "lfm2_train_step", "latent_decode", "latent_prefill",
+                  "laguna_decode", "laguna_prefill",
                   *GPT2M_STEPS}, proc.stdout
 
 
@@ -87,6 +88,9 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
      | MOE_LAYER),
     ("latent_decode", MOE_FORWARD), ("latent_prefill", MOE_FORWARD),
+    ("laguna_decode", MOE_FORWARD | {"paged_decode", "paged_decode_window"}),
+    ("laguna_prefill", MOE_FORWARD | {"paged_prefill",
+                                      "paged_prefill_window"}),
     *((program, {kernel}) for program, kernel in GPT2M_STEPS.items())])
 def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
@@ -120,6 +124,34 @@ def test_a_latent_step_program_fits_and_keeps_the_pool_in_place(compiled,
     fields = _pool_line(compiled, program)
     assert fields["layouts"] == "3,2,1,0", fields
     assert 13.0e9 < int(fields["bytes"]) < 15.75e9, fields
+
+
+@pytest.mark.parametrize("program, kernels", [
+    ("laguna_decode", {"paged_decode": 2, "paged_decode_window": 3}),
+    ("laguna_prefill", {"paged_prefill": 2, "paged_prefill_window": 3})])
+def test_a_windowed_step_program_fits_and_keeps_both_pools_in_place(
+        compiled, program, kernels):
+    """The serve engine's programs for the window-and-full-attention
+    expert family at the benchmark cell's sizes (`laguna.serve_mixed_8k`:
+    7.74 GB of weights, 1,024 global pages and 128 window pages of 512
+    tokens x 1,024 values, the kernel backend): the `(512, 1,024)` page
+    block fits the kernels' VMEM (four times `gpt2m.serve_closed`'s),
+    the program fits the v5e's 15.75 GB, both pools keep their one
+    row-major layout with no `copy` of either's shape and under 1 GB of
+    temporaries, and the two layer kinds' attention calls carry their own
+    names (two full layers, three sliding ones) beside the expert layers'
+    twelve grouped products."""
+    fields = _pool_line(compiled, program)
+    assert fields["layouts"] == "3,2,1,0", fields
+    assert int(fields["copies"]) == 0, fields
+    assert int(fields["temp"]) < 1e9, fields
+    pools = 2 * (2 * 1025 + 3 * 129) * 512 * 1024 * 2  # K and V, bf16
+    weights = 2 * 3869857792
+    assert pools + weights < int(fields["bytes"]) < pools + weights + 1e9 \
+        < 15.75e9, fields
+    calls = _kernel_calls(compiled)[program]
+    assert {k: calls[k] for k in kernels} == kernels, calls
+    assert calls["moe_gmm"] == 12
 
 
 @pytest.mark.parametrize("program", list(GPT2M_STEPS))

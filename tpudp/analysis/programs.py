@@ -37,6 +37,7 @@ AUDIT_SOURCES = (
     "tpudp/models/gpt2.py",
     "tpudp/models/llama.py",
     "tpudp/models/pangu.py",
+    "tpudp/models/laguna.py",
     "tpudp/models/moe.py",
     "tpudp/ops/sampling.py",
     "tpudp/ops/attention.py",
@@ -64,11 +65,13 @@ TRACE_COUNTER_PROGRAMS = {
     "decode_paged": "serve.decode_paged",
     "decode_paged_kernel": "serve.decode_paged_kernel",
     "decode_paged_latent": "serve.decode_paged_latent",
+    "decode_paged_windowed": "serve.decode_paged_windowed",
     "verify_paged": "serve.verify_paged",
     "verify_paged_kernel": "serve.verify_paged_kernel",
     "prefill_paged": "serve.prefill_paged",
     "prefill_paged_kernel": "serve.prefill_paged_kernel",
     "prefill_paged_latent": "serve.prefill_paged_latent",
+    "prefill_paged_windowed": "serve.prefill_paged_windowed",
     "fused_decode_paged": "serve.fused_decode_paged",
     "fused_decode_paged_kernel": "serve.fused_decode_paged_kernel",
     "fused_spec_decode": "serve.fused_spec_decode",
@@ -107,6 +110,10 @@ PROGRAM_DONATIONS = {
     # the latent-attention expert family's two programs (LatentPages pool)
     "serve.decode_paged_latent": (1, 10),
     "serve.prefill_paged_latent": (1,),
+    # the window-and-full-attention expert family's two (WindowedPages:
+    # both pools donate together; the two tables never)
+    "serve.decode_paged_windowed": (1, 10),
+    "serve.prefill_paged_windowed": (1,),
     "serve.verify_paged": (1, 11),
     "serve.verify_paged_kernel": (1, 11),
     "serve.prefill_paged": (1,),
@@ -439,6 +446,29 @@ def build_programs() -> dict:
     programs[f"serve.prefill_paged_latent@{pgeo2}c{SERVE['chunk']}"] = (
         lsteps[8], (lparams, lpool, table[0], h["chunk"], np.int32(0),
                     np.int32(SERVE["chunk"] - 1)))
+
+    # The window-and-full-attention expert family (tpudp/models/laguna.py):
+    # its two programs over a WindowedPages pool and the pair of tables,
+    # at the same smoke geometry (einsum: the interpreted kernels have
+    # tests of their own).
+    from tpudp.models.generate import WindowedPages
+    from tpudp.models.laguna import Laguna, LagunaConfig
+
+    wcfg = LagunaConfig(vocab_size=SERVE["vocab"],
+                        max_position_embeddings=SERVE["seq"],
+                        sliding_window=2 * SERVE["chunk"])
+    wparams = Laguna(wcfg).init(jax.random.PRNGKey(0),
+                                jnp.zeros((1, 8), jnp.int32))["params"]
+    wsteps = _engine._build_steps(wcfg, "einsum")
+    wpool = WindowedPages.zeros(wcfg, n_pages + 1, SERVE["chunk"],
+                                3 * SERVE["slots"] + 1)
+    programs[f"serve.decode_paged_windowed@{pgeo2}"] = (
+        wsteps[6], (wparams, wpool, (table, table), h["last"], h["lens"],
+                    h["active"], h["temps"], h["topk"], h["topp"],
+                    h["keys"], h["counts"]))
+    programs[f"serve.prefill_paged_windowed@{pgeo2}c{SERVE['chunk']}"] = (
+        wsteps[8], (wparams, wpool, (table[0], table[0]), h["chunk"],
+                    np.int32(0), np.int32(SERVE["chunk"] - 1)))
 
     programs["serve.sample_row@v%d" % SERVE["vocab"]] = (
         _engine._sample_row,

@@ -27,10 +27,13 @@ in ``llama.block_decode`` never widens it back; for generation lengths
 where the cache is the constraint, raise ``max_len`` only as far as
 needed (static shape).
 
-A third family is served through the PAGED forward only
+Two more families are served through the PAGED forward only
 (:func:`_forward_paged`): latent attention with routed experts
-(``tpudp.models.pangu``), whose cache is :class:`LatentPages`.  It has no
-dense cache twin, so ``generate()`` and ``beam_search()`` refuse it.
+(``tpudp.models.pangu``), whose cache is :class:`LatentPages`, and
+window and full attention layers mixed, with routed experts
+(``tpudp.models.laguna``), whose cache is :class:`WindowedPages`.
+Neither has a dense cache twin, so ``generate()`` and ``beam_search()``
+refuse them.
 """
 
 from __future__ import annotations
@@ -156,10 +159,53 @@ class LatentPages(NamedTuple):
                    jnp.zeros((layers, num_pages, page_tokens, r), cfg.dtype))
 
 
+class WindowedPages(NamedTuple):
+    """Page pools of a family whose attention layers are of two kinds
+    (``tpudp.models.laguna``): ``full`` holds the full-attention layers'
+    K/V and ``window`` the sliding-window layers', each a :class:`KVPages`
+    over the layers of its kind (both kinds have the same KV heads and
+    head size, so one row width; a pool a kind because their pages live
+    differently long).  A full layer's page stays until its request
+    ends.  A window layer's page is dead once the window has passed its
+    last token, so the engine keeps a block table a pool, frees the
+    window table's entries behind the window, and sizes the window pool
+    by the window and not by the context (``window_pages``); each pool
+    has its own trailing scratch page.  A library caller with nothing to
+    free passes one table for both and equal counts."""
+
+    full: KVPages
+    window: KVPages
+
+    @classmethod
+    def geometry(cls, cfg) -> tuple:
+        kinds = tuple(kind == "sliding_attention"
+                      for kind in cfg.layer_types)
+        return ("windowed", kinds, cfg.sliding_window,
+                cfg.num_key_value_heads, cfg.head_dim,
+                str(jnp.dtype(cfg.dtype)))
+
+    @classmethod
+    def zeros(cls, cfg, num_pages: int, page_tokens: int,
+              window_pages: int | None = None) -> "WindowedPages":
+        """``num_pages`` pages a full layer (scratch included, as the
+        other page types count them) and ``window_pages`` a window layer:
+        ``None`` the same count."""
+        _, kinds, _, kv_heads, dh, _ = cls.geometry(cfg)
+        wp = num_pages if window_pages is None else window_pages
+
+        def pages(layers, count):
+            shape = (layers, count, page_tokens, kv_heads * dh)
+            return KVPages(jnp.zeros(shape, cfg.dtype),
+                           jnp.zeros(shape, cfg.dtype))
+
+        return cls(pages(kinds.count(False), num_pages),
+                   pages(kinds.count(True), wp))
+
+
 def page_layout(cfg) -> str:
     """``'heads'`` (K and V per KV head: GPT-2, LLaMA; :class:`KVPages`
-    or :class:`Int8Pages`) or ``'latent'`` (:class:`LatentPages`), from
-    the model config."""
+    or :class:`Int8Pages`), ``'latent'`` (:class:`LatentPages`) or
+    ``'windowed'`` (:class:`WindowedPages`), from the model config."""
     return getattr(cfg, "page_layout", "heads")
 
 
@@ -167,12 +213,13 @@ def page_type(cfg, kv_dtype: str | None = None):
     """The page-pool pytree class a config's cache lives in.  Each has
     ``zeros(cfg, num_pages, page_tokens)`` and ``geometry(cfg)``, the
     tuple two models must share to share one pool."""
-    if page_layout(cfg) == "latent":
+    layout = page_layout(cfg)
+    if layout != "heads":
         if kv_dtype is not None:
             raise ValueError(
-                f"kv_dtype={kv_dtype!r} is not implemented for latent "
-                "(MLA) pages: they are kept in the compute dtype")
-        return LatentPages
+                f"kv_dtype={kv_dtype!r} is not implemented for {layout} "
+                "pages: they are kept in the compute dtype")
+        return LatentPages if layout == "latent" else WindowedPages
     return Int8Pages if kv_dtype == "int8" else KVPages
 
 
@@ -384,10 +431,10 @@ class _PagedKV:
     layer."""
 
     __slots__ = ("cfg", "pages", "table", "pos", "active", "grouped",
-                 "impl", "layer")
+                 "impl", "layer", "window")
 
     def __init__(self, cfg, pages, table, pos, active, *, grouped, impl,
-                 layer=None):
+                 layer=None, window=None):
         self.cfg = cfg
         self.pages = pages
         self.table = table
@@ -402,6 +449,9 @@ class _PagedKV:
         # an XLA value (the kernel programs' peak-live edge over their
         # einsum twins).
         self.layer = layer
+        # A sliding-window layer's span (``laguna``): queries also mask
+        # keys ``window`` or more positions behind them.  None: causal.
+        self.window = window
 
     def write(self, k: jnp.ndarray, v: jnp.ndarray) -> None:
         self.pages = write_token_pages(self.pages, k, v, self.table,
@@ -413,7 +463,8 @@ class _PagedKV:
 
         return paged_attention(q, self.pages, self.table, self.pos,
                                dtype=self.cfg.dtype, grouped=self.grouped,
-                               impl=self.impl, layer=self.layer)
+                               impl=self.impl, layer=self.layer,
+                               window=self.window)
 
 
 def _row_major(x: jnp.ndarray) -> jnp.ndarray:
@@ -522,17 +573,21 @@ def _forward_tree_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
 
 def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
                    table: jnp.ndarray, pos: jnp.ndarray,
-                   active: jnp.ndarray, impl: str = "einsum", *,
+                   active: jnp.ndarray, impl: str | None = None, *,
                    last=None, routed: list | None = None):
     """Page-table-indirected twin of :func:`_forward_cached` for the
     serve engine's paged arena.  Returns ``(logits, pool)``.
 
-    Three families dispatch here: GPT-2, LLaMA, and the latent-attention
-    expert family (``tpudp.models.pangu.forward_paged``: absorbed MLA over
-    :class:`LatentPages`, the dropless expert layer; it has the one
-    path and ``impl`` does not reach it (the engine refuses
-    ``paged_attn`` other than einsum); the one family that takes ``last``
-    and ``routed``, which see there).
+    Four families dispatch here: GPT-2, LLaMA, and the two expert
+    families, which take ``last`` and ``routed`` (see their own
+    ``forward_paged``): latent attention (``tpudp.models.pangu``:
+    absorbed MLA over :class:`LatentPages`; it has the one path and
+    ``impl`` does not reach it, the engine refuses ``paged_attn`` other
+    than einsum) and window and full attention layers mixed
+    (``tpudp.models.laguna``: :class:`WindowedPages`, ``table`` one array
+    or the pair of the two pools' tables, ``impl`` einsum or kernel as
+    below, unset the kernels on an accelerator).  For GPT-2 and LLaMA an
+    unset ``impl`` is ``'einsum'``.
 
     ``impl='einsum'`` (the engine default) and ``'kernel'`` are
     GATHER-FREE: each layer's block twin writes the window's new K/V
@@ -556,6 +611,12 @@ def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
 
         return _pangu.forward_paged(cfg, params, tokens, pool, table, pos,
                                     active, last=last, routed=routed)
+    if page_layout(cfg) == "windowed":
+        from tpudp.models import laguna as _laguna
+
+        return _laguna.forward_paged(cfg, params, tokens, pool, table, pos,
+                                     active, impl, last=last, routed=routed)
+    impl = impl or "einsum"
     if impl == "gather":
         view = gather_pages(cfg, pool, table)
         logits, view = _forward_cached(cfg, params, tokens, view, pos)
@@ -873,24 +934,29 @@ def validate_decode_config(cfg, fn_name: str) -> None:
     config with ``attn_impl='dense'`` to decode them.  Shared by the
     generate()/beam_search() entry points and tpudp.serve.Engine."""
     mlp_impl = getattr(cfg, "mlp_impl", "dense")  # LlamaConfig: dense only
-    # (the latent family's expert layer IS served: pangu.block_paged)
+    # (the two expert families' expert layers ARE served: pangu.block_paged,
+    # laguna.block_paged; their configs' attn_impl names the MODULE's
+    # attention and 'dense' is its one value)
     if cfg.attn_impl != "dense" or mlp_impl != "dense":
         raise ValueError(
-            f"{fn_name} supports dense-attention/dense-MLP configs "
-            f"(decode runs the dense-math twins; a flash/ring-trained "
-            f"config would decode with different rounding than it trained "
-            f"with — rebuild the config with attn_impl='dense' to decode "
-            f"its weights); got attn_impl={cfg.attn_impl!r} "
-            f"mlp_impl={mlp_impl!r}")
+            f"{fn_name} supports configs with attn_impl='dense' and a "
+            f"dense MLP or a served expert layer (the dropless one of the "
+            f"pangu and laguna families; GPT-2's mlp_impl='moe' has no "
+            f"decode twin): decode runs the dense-math twins, and a "
+            f"flash/ring-trained config would decode with different "
+            f"rounding than it trained with — rebuild the config with "
+            f"attn_impl='dense' to decode its weights; got "
+            f"attn_impl={cfg.attn_impl!r} mlp_impl={mlp_impl!r}")
 
 
 def _validate_decode(cfg, prompt, max_new_tokens: int, fn_name: str) -> int:
     """Shared decode-entry checks; returns the total sequence length."""
-    if page_layout(cfg) == "latent":
+    if page_layout(cfg) != "heads":
         raise ValueError(
-            f"{fn_name} has no dense-cache twin for a latent-attention "
-            f"config ({type(cfg).__name__}): its cache exists only as "
-            f"pages; serve it through tpudp.serve.Engine(kv_pages=N)")
+            f"{fn_name} has no dense-cache twin for a "
+            f"{page_layout(cfg)}-pages config ({type(cfg).__name__}): its "
+            f"cache exists only as pages; serve it through "
+            f"tpudp.serve.Engine(kv_pages=N)")
     validate_decode_config(cfg, fn_name)
     prompt_len = prompt.shape[1]
     total = prompt_len + max_new_tokens

@@ -396,6 +396,49 @@ def dropless_moe(x, gate, w1, w3, w2, *, top_k: int, first_expert: int = 0,
     return y.astype(dtype).reshape(x.shape), chosen, counts
 
 
+#: What ``Engine.metrics()["stats"]`` counts of the expert layers, in the
+#: order the serving twins return them (``forward_paged``'s ``routed``).
+SERVE_MOE_COUNTERS = ("moe_rows", "moe_rows_held", "moe_experts_touched",
+                      "moe_layer_runs")
+
+
+def serve_moe_counts(counts: jnp.ndarray) -> jnp.ndarray:
+    """:data:`SERVE_MOE_COUNTERS` of one expert layer's run from
+    :func:`dropless_moe`'s ``counts``, as int32."""
+    loads = counts[2:]
+    return jnp.stack([counts[0], jnp.sum(loads), jnp.sum(loads > 0),
+                      jnp.ones((), counts.dtype)]).astype(jnp.int32)
+
+
+def swiglu(p: dict, u: jnp.ndarray, dtype) -> jnp.ndarray:
+    """A SwiGLU of raw parameters ``p`` (``w1``, ``w3``, ``w2``, each a
+    ``kernel``): ``W_2 (silu(W_1 u) * W_3 u)`` in ``dtype``."""
+    def mm(w, x):
+        return x.astype(dtype) @ p[w]["kernel"].astype(dtype)
+
+    return mm("w2", nn.silu(mm("w1", u)) * mm("w3", u))
+
+
+def routed_and_shared(moe: dict, shared: dict, u: jnp.ndarray, **routing):
+    """A serving block's expert layer on raw parameters: the held routed
+    experts' part (:func:`dropless_moe` of ``moe``'s ``gate``, ``w1``,
+    ``w3``, ``w2`` under ``routing``, sigmoid scores) plus the shared
+    SwiGLU expert every token takes.  Returns ``(m, (chosen, counts))``,
+    the pair what a paged forward appends to its ``routed``."""
+    m, chosen, counts = dropless_moe(
+        u, moe["gate"], moe["w1"], moe["w3"], moe["w2"], score_fn="sigmoid",
+        **routing)
+    return m + swiglu(shared, u, routing["dtype"]), (chosen, counts)
+
+
+def scaled_init(init, scale: float):
+    """``init``'s draw times ``scale`` (``init`` itself at 1)."""
+    if scale == 1.0:
+        return init
+    return lambda key, shape, dtype: (init(key, shape, dtype)
+                                      * scale).astype(dtype)
+
+
 class DroplessMoe(nn.Module):
     """One chip's share of a routed SwiGLU expert layer, no token dropped:
     ``(..., d) -> (..., d)``.
@@ -455,6 +498,7 @@ class DroplessMoe(nn.Module):
     impl: str = "gmm"  # 'gmm' | 'dense'
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32  # what `init` makes the leaves in
+    down_init_scale: float = 1.0  # the experts' `w2` start at this x lecun
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -465,7 +509,8 @@ class DroplessMoe(nn.Module):
         gate = self.param("gate", nn.initializers.lecun_normal(), (d, e), pd)
         w1 = self.param("w1", stacked, (g, d, f), pd)
         w3 = self.param("w3", stacked, (g, d, f), pd)
-        w2 = self.param("w2", stacked, (g, f, d), pd)
+        w2 = self.param("w2", scaled_init(stacked, self.down_init_scale),
+                        (g, f, d), pd)
         bias = self.param("expert_bias", nn.initializers.zeros, (e,),
                           jnp.float32) if self.selection_bias else None
         y, chosen, counts = dropless_moe(

@@ -49,12 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudp.models.llama import apply_rope
-from tpudp.models.moe import DroplessMoe, dropless_moe
-
-#: What ``Engine.metrics()["stats"]`` counts of the expert layers, in the
-#: order the serving twin returns them (``forward_paged``'s ``routed``).
-SERVE_MOE_COUNTERS = ("moe_rows", "moe_rows_held", "moe_experts_touched",
-                      "moe_layer_runs")
+from tpudp.models.moe import DroplessMoe, routed_and_shared, swiglu
 
 
 @dataclass(frozen=True)
@@ -273,11 +268,6 @@ def _mm(p: dict, x: jnp.ndarray, dtype) -> jnp.ndarray:
     return x.astype(dtype) @ p["kernel"].astype(dtype)
 
 
-def _swiglu(p: dict, u: jnp.ndarray, dtype) -> jnp.ndarray:
-    return _mm(p["w2"], nn.silu(_mm(p["w1"], u, dtype))
-               * _mm(p["w3"], u, dtype), dtype)
-
-
 def latent_pad(cfg: PanguConfig) -> int:
     """Width the rotary part is stored at: up to a multiple of the 128
     lanes, so that neither page buffer has a minor dimension XLA would
@@ -334,27 +324,15 @@ def block_paged(cfg: PanguConfig, p: dict, x: jnp.ndarray, index: int,
     u = _rms(p["rms_pre_mlp"], x, cfg.rms_norm_eps).astype(cfg.dtype)
     routed = None
     if index < cfg.first_k_dense_replace:
-        m = _swiglu(p["mlp"], u, cfg.dtype)
+        m = swiglu(p["mlp"], u, cfg.dtype)
     else:
-        moe = p["moe"]
-        m, chosen, counts = dropless_moe(
-            u, moe["gate"], moe["w1"], moe["w3"], moe["w2"],
-            top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
-            score_fn="sigmoid", normalize=cfg.norm_topk_prob,
+        m, routed = routed_and_shared(
+            p["moe"], p["shared"], u, top_k=cfg.num_experts_per_tok,
+            first_expert=cfg.first_expert, normalize=cfg.norm_topk_prob,
             scaling=cfg.routed_scaling_factor, dtype=cfg.dtype,
             live=live.reshape(-1))
-        m = m + _swiglu(p["shared"], u, cfg.dtype)
-        routed = (chosen, counts)
     return x + _rms(p["rms_post_mlp"], m,
                     cfg.rms_norm_eps).astype(cfg.dtype), routed
-
-
-def serve_moe_counts(counts: jnp.ndarray) -> jnp.ndarray:
-    """:data:`SERVE_MOE_COUNTERS` of one expert layer's run from
-    :func:`dropless_moe`'s ``counts``, as int32."""
-    loads = counts[2:]
-    return jnp.stack([counts[0], jnp.sum(loads), jnp.sum(loads > 0),
-                      jnp.ones((), counts.dtype)]).astype(jnp.int32)
 
 
 def forward_paged(cfg: PanguConfig, params: dict, tokens: jnp.ndarray, pool,

@@ -111,13 +111,23 @@ def page_tiles(pages, table, dtype, dh: int):
     return heads(k).astype(dtype), heads(v).astype(dtype)
 
 
-def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
+def _seen(k_pos, q_pos, window):
+    """Which keys a query at ``q_pos`` attends: those at or before it
+    and, on a sliding-window layer, fewer than ``window`` positions
+    behind it.  ``window=None`` adds nothing to the causal trace."""
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (k_pos > q_pos - window)
+
+
+def _einsum_paged(q, pages, table, pos, *, dtype, grouped, window=None):
     """The bit-exact blockwise path.  ``q``: ``(b, cur, h, dh)``;
     ``pos``: ``(b,)`` per-row depths (window position ``j`` attends
     keys ``<= pos + j`` — one contraction per position, the vmapped
     form that keeps a k+1 verify window bitwise equal to k+1 single
     steps) or a scalar (the prefill window: ONE batched contraction
-    over the whole window, mirroring the scalar-``pos`` dense path)."""
+    over the whole window, mirroring the scalar-``pos`` dense path).
+    ``window``: a sliding layer's span (:func:`_seen`)."""
     b, cur, h, dh = q.shape
     kt, vt = page_tiles(pages, table, dtype, dh)  # (b, M, T, kv, dh)
     kv = kt.shape[3]
@@ -134,8 +144,8 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
             def _attend(qj, pj):  # qj (b, kv, g, dh), pj (b,)
                 lg = (jnp.einsum("bkgd,bptkd->bkgpt", qj, kt)
                       * scale).reshape(b, kv, g, max_len)
-                vis = jnp.arange(max_len)[None, None, None, :] \
-                    <= pj[:, None, None, None]
+                vis = _seen(jnp.arange(max_len)[None, None, None, :],
+                            pj[:, None, None, None], window)
                 lg = jnp.where(vis, lg, jnp.finfo(lg.dtype).min)
                 pr = jax.nn.softmax(lg.astype(jnp.float32),
                                     axis=-1).astype(dtype)
@@ -147,7 +157,7 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
             lg = (jnp.einsum("bqkgd,bptkd->bkgqpt", qg, kt)
                   * scale).reshape(b, kv, g, cur, max_len)
             q_pos = pos + jnp.arange(cur)[:, None]
-            visible = jnp.arange(max_len)[None, :] <= q_pos
+            visible = _seen(jnp.arange(max_len)[None, :], q_pos, window)
             lg = jnp.where(visible[None, None, None], lg,
                            jnp.finfo(lg.dtype).min)
             pr = jax.nn.softmax(lg.astype(jnp.float32),
@@ -162,7 +172,8 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
         def _attend(qj, pj):  # qj (b, h, dh), pj (b,)
             lg = (jnp.einsum("bhd,bpthd->bhpt", qj, kt)
                   * scale).reshape(b, h, max_len)
-            vis = jnp.arange(max_len)[None, None, :] <= pj[:, None, None]
+            vis = _seen(jnp.arange(max_len)[None, None, :],
+                        pj[:, None, None], window)
             lg = jnp.where(vis, lg, jnp.finfo(lg.dtype).min)
             pr = jax.nn.softmax(lg.astype(jnp.float32),
                                 axis=-1).astype(dtype)
@@ -174,7 +185,7 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped):
     lg = (jnp.einsum("bqhd,bpthd->bhqpt", q, kt)
           * scale).reshape(b, h, cur, max_len)
     q_pos = pos + jnp.arange(cur)[:, None]
-    visible = jnp.arange(max_len)[None, :] <= q_pos
+    visible = _seen(jnp.arange(max_len)[None, :], q_pos, window)
     lg = jnp.where(visible[None, None], lg, jnp.finfo(lg.dtype).min)
     pr = jax.nn.softmax(lg.astype(jnp.float32), axis=-1).astype(dtype)
     return jnp.einsum("bhqpt,bpthd->bqhd",
@@ -266,7 +277,7 @@ def _head(blk, scales, ki: int, dh: int):
 
 def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                    kv: int, groups: int, page_tokens: int, n_pages: int,
-                   scale: float, int8: bool):
+                   scale: float, int8: bool, window: int | None = None):
     """One ``(slot, page)`` grid step of the paged-decode kernel.
 
     The block specs already fetched THIS slot's page ``m`` by table
@@ -314,7 +325,9 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         s_blk = jnp.concatenate(rows, axis=0)  # (h, T)
         k_pos = m * page_tokens + lax.broadcasted_iota(
             jnp.int32, (h, page_tokens), 1)
-        s_blk = jnp.where(k_pos <= pos_ref[s], s_blk, _NEG_INF)
+        # (a mapped page wholly behind a sliding layer's window weighs
+        # one a key until the first visible key's alpha wipes it: zero)
+        s_blk = jnp.where(_seen(k_pos, pos_ref[s], window), s_blk, _NEG_INF)
         m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (h, 1)
         l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
@@ -339,7 +352,8 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
 
 # tpudp: kernel-program(serve.decode_paged_kernel)
-def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
+def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None,
+                  window=None):
     """Dispatch one decode step (``cur == 1``) through the Pallas
     paged-decode kernel.  ``q``: ``(b, 1, h, dh)``; the grid is
     ``(b, M)`` with the online-softmax carry persisting across the
@@ -379,7 +393,7 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
 
     kernel = functools.partial(
         _decode_kernel, kv=kv, groups=groups, page_tokens=page_tokens,
-        n_pages=n_pages, scale=scale, int8=int8)
+        n_pages=n_pages, scale=scale, int8=int8, window=window)
     ins = (pages[0], pages[1]) + ((pages[2], pages[3]) if int8 else ())
     in_specs = [
         pl.BlockSpec((1, h, dh), lambda s, m, t, p: (s, 0, 0)),
@@ -405,7 +419,9 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), dtype),
         interpret=interpret,
-        name="paged_decode",
+        # a sliding layer's calls under a name of their own: the trace
+        # tells the two layer kinds apart
+        name="paged_decode" if window is None else "paged_decode_window",
     )(tbl, pos, q[:, 0], *ins)
     return out[:, None]
 
@@ -422,7 +438,8 @@ def _window_tile(width: int) -> int:
 
 def _window_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                    kv: int, groups: int, width: int, page_tokens: int,
-                   n_pages: int, scale: float, int8: bool):
+                   n_pages: int, scale: float, int8: bool,
+                   window: int | None = None):
     """One ``(slot, query-tile, page)`` grid step of the paged
     flash-window kernel — the multi-token generalization of
     ``_decode_kernel`` that covers chunked prefill (scalar base
@@ -475,7 +492,8 @@ def _window_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
             jnp.int32, (rows, page_tokens), 1)
         row_ids = lax.broadcasted_iota(jnp.int32, (rows, page_tokens), 0)
         win_j = t * width + (row_ids % (width * groups)) // groups
-        s_blk = jnp.where(k_pos <= pos_ref[s] + win_j, s_blk, _NEG_INF)
+        s_blk = jnp.where(_seen(k_pos, pos_ref[s] + win_j, window), s_blk,
+                          _NEG_INF)
         m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (rows, 1)
         l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
@@ -505,7 +523,8 @@ def _window_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
 
 # tpudp: kernel-program(serve.verify_paged_kernel)
-def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
+def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None,
+                  window=None):
     """Dispatch a multi-token window (k+1 verify, vector ``pos``; or a
     prefill chunk, scalar ``pos``) through the flash-window kernel.
     Grid ``(b, chunk_tiles, M)`` with the online-softmax carry
@@ -541,7 +560,8 @@ def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
 
     kernel = functools.partial(
         _window_kernel, kv=kv, groups=groups, width=width,
-        page_tokens=page_tokens, n_pages=n_pages, scale=scale, int8=int8)
+        page_tokens=page_tokens, n_pages=n_pages, scale=scale, int8=int8,
+        window=window)
     ins = (pages[0], pages[1]) + ((pages[2], pages[3]) if int8 else ())
     in_specs = [
         pl.BlockSpec((1, width, h, dh),
@@ -569,7 +589,7 @@ def _window_paged(q, pages, table, pos, *, dtype, interpret, layer=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, cur, h, dh), dtype),
         interpret=interpret,
-        name="paged_prefill",
+        name="paged_prefill" if window is None else "paged_prefill_window",
     )(tbl, pos, q, *ins)
 
 
@@ -738,7 +758,8 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
 
 def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
                     impl: str = "einsum", interpret: bool | None = None,
-                    layer: int | None = None) -> jnp.ndarray:
+                    layer: int | None = None,
+                    window: int | None = None) -> jnp.ndarray:
     """Attention for already-projected queries over table-indirected
     K/V pages — the ONE paged-attention op behind the serve engine's
     gather-free step programs.
@@ -770,7 +791,16 @@ def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
 
     ``layer`` (kernel impl only) is whole-pool mode: ``pages`` carry
     the FULL stacked pool and the kernels' BlockSpecs pick the stratum
-    — the per-layer slice never exists as an XLA value."""
+    — the per-layer slice never exists as an XLA value.
+
+    ``window`` (static) makes the layer a sliding-window one: a query
+    also masks the keys ``window`` or more positions behind it (``k_pos >
+    q_pos - window``).  The mask alone decides what it sees: a table
+    entry behind the window may be ``-1``, its page freed (the kernels
+    skip it, the einsum path masks the scratch page it reads), or still
+    mapped.  Its Mosaic calls are named ``paged_decode_window`` /
+    ``paged_prefill_window``.  ``None`` traces exactly the causal
+    program."""
     if impl not in ("einsum", "kernel"):
         raise ValueError(
             f"unknown paged-attention impl {impl!r}; choose from "
@@ -783,11 +813,13 @@ def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
             interpret = _interpret_default()
         if pos.ndim and q.shape[1] == 1:
             return _kernel_paged(q, pages, table, pos, dtype=dtype,
-                                 interpret=interpret, layer=layer)
+                                 interpret=interpret, layer=layer,
+                                 window=window)
         return _window_paged(q, pages, table, pos, dtype=dtype,
-                             interpret=interpret, layer=layer)
+                             interpret=interpret, layer=layer,
+                             window=window)
     return _einsum_paged(q, pages, table, pos, dtype=dtype,
-                         grouped=grouped)
+                         grouped=grouped, window=window)
 
 
 def tree_paged_attention(q, pages, table, pos0, wk, wv, anc, *, dtype,

@@ -690,21 +690,24 @@ def _tree_verify_math(forward, commit, state, tokens, lengths, active,
     return state, out, n_emit, new_keys, new_counts
 
 
+def _moe_counts(routed):
+    """A step's :data:`tpudp.models.moe.SERVE_MOE_COUNTERS` as ``(4,)``
+    int32, summed over its expert layers (``routed``: what a family's
+    ``forward_paged`` appended)."""
+    from tpudp.models.moe import serve_moe_counts
+
+    return sum(serve_moe_counts(counts) for _, counts in routed)
+
+
 def _build_latent_steps(cfg):
     """The step programs of a latent-attention expert family
     (``tpudp.models.pangu``): ``decode_paged`` and ``prefill_paged`` and
     no other (the engine refuses at construction what would need one).
     Same calling convention as their GPT-2 twins, one more result each:
-    the run's :data:`tpudp.models.pangu.SERVE_MOE_COUNTERS` as ``(4,)``
-    int32, summed over the expert layers, which the scheduler fetches
+    the run's :func:`_moe_counts`, which the scheduler fetches
     WITH the step's tokens (no sync of their own).  Rows of inactive
     slots and of a chunk's padding reach no routed expert and are in no
     count."""
-    from tpudp.models.pangu import serve_moe_counts
-
-    def _sum(routed):
-        return sum(serve_moe_counts(counts) for _, counts in routed)
-
     @functools.partial(jax.jit, donate_argnums=(1, 10))
     def decode_step_paged(params, pool, table, last_tokens, lengths,
                           active, temps, top_k, top_p, keys, counts):
@@ -721,7 +724,7 @@ def _build_latent_steps(cfg):
 
         return (*_decode_math(fwd, pool, last_tokens, lengths, active,
                               temps, top_k, top_p, keys, counts),
-                _sum(routed))
+                _moe_counts(routed))
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_step_paged(params, pool, row_table, tokens, pos, last):
@@ -734,7 +737,51 @@ def _build_latent_steps(cfg):
         logits, new_pool = _forward_paged(
             cfg, params, tokens, pool, row_table[None], pos,
             jnp.ones((1,), bool), last=last, routed=routed)
-        return logits[:, 0], new_pool, _sum(routed)
+        return logits[:, 0], new_pool, _moe_counts(routed)
+
+    return (None,) * 6 + (decode_step_paged, None, prefill_step_paged,
+                          None, None, None)
+
+
+def _build_windowed_steps(cfg, paged_attn: str):
+    """The step programs of a family whose attention layers are window
+    and full mixed, with routed experts (``tpudp.models.laguna``):
+    ``decode_paged`` and ``prefill_paged`` and no other, as
+    :func:`_build_latent_steps`, with its extra result.  ``pool`` is a
+    ``generate.WindowedPages`` (both pools donated) and ``table`` the pair
+    ``(full layers' table, window layers' table)``, the second with the
+    entries behind the window freed by the host before dispatch.
+    ``paged_attn``: ``'kernel'`` or ``'einsum'``."""
+
+    @functools.partial(jax.jit, donate_argnums=(1, 10))
+    def decode_step_paged(params, pool, table, last_tokens, lengths,
+                          active, temps, top_k, top_p, keys, counts):
+        """One token for every slot through the two page pools: the
+        shared ``_decode_math`` body over ``generate._forward_paged``'s
+        fourth family."""
+        TRACE_COUNTS["decode_paged_windowed"] += 1
+        routed: list = []
+
+        def fwd(pool, tokens, lengths, active):
+            return _forward_paged(cfg, params, tokens, pool, tuple(table),
+                                  lengths, active, paged_attn, routed=routed)
+
+        return (*_decode_math(fwd, pool, last_tokens, lengths, active,
+                              temps, top_k, top_p, keys, counts),
+                _moe_counts(routed))
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_step_paged(params, pool, row_table, tokens, pos, last):
+        """One page-aligned prompt chunk of one slot: its K/V commit as
+        one page write a layer into that layer's pool, the window attends
+        through the slot's two table rows, rows past ``last`` reach no
+        expert, and only row ``last`` goes through the head."""
+        TRACE_COUNTS["prefill_paged_windowed"] += 1
+        routed: list = []
+        logits, new_pool = _forward_paged(
+            cfg, params, tokens, pool, tuple(row[None] for row in row_table),
+            pos, jnp.ones((1,), bool), paged_attn, last=last, routed=routed)
+        return logits[:, 0], new_pool, _moe_counts(routed)
 
     return (None,) * 6 + (decode_step_paged, None, prefill_step_paged,
                           None, None, None)
@@ -785,11 +832,14 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
     Shapes stay traced, so one build serves every engine geometry,
     compiling once per (num_slots, max_len[, k]) exactly as before.
 
-    A latent-attention config (``generate.page_layout``) builds its two
-    paged programs only (:func:`_build_latent_steps`).
+    A latent-attention or a windowed config (``generate.page_layout``)
+    builds its two paged programs only (:func:`_build_latent_steps`,
+    :func:`_build_windowed_steps`).
     """
     if page_layout(cfg) == "latent":
         return _build_latent_steps(cfg)
+    if page_layout(cfg) == "windowed":
+        return _build_windowed_steps(cfg, paged_attn)
 
     def _dense_fwd(params):
         """The dense indirection for the shared step bodies: plain
@@ -1281,7 +1331,7 @@ class _ModelState:
               "fused_paged", "fused_spec_paged", "tree_paged")
     __slots__ = ("name", "model", "config", "params", *_STEPS,
                  "cache", "prefix_cache", "pool", "index",
-                 "table", "slot_nodes", "obs_counts")
+                 "table", "wtable", "slot_nodes", "obs_counts")
 
     def __init__(self, name, model, params, steps, draft_params=None):
         self.name = name
@@ -1312,6 +1362,12 @@ class _ModelState:
         self.pool = None
         self.index = None
         self.table = None
+        # A family with sliding-window layers (generate.WindowedPages)
+        # keeps a second table of the same shape over the pool's window
+        # pages: entry j is mapped while logical page j can still be
+        # attended and freed once the window has passed it.  None for
+        # every other family: THE test where the windowed path parts.
+        self.wtable = None
         self.slot_nodes = None
         # OBS_DEVICE_COUNTERS accumulator: rides this model's step
         # programs (donated in, rebound from each result), fetched only
@@ -1471,8 +1527,14 @@ class Engine:
     ``metrics()["stats"]``) and refuses, by option name, what it has no
     program for: ``kv_dtype``, ``speculate_k``, ``speculate_tree``,
     ``decode_fuse > 1``, ``models=``, ``paged_attn`` other than einsum,
-    and the ticket methods (docs/SERVING.md, the family table).
-    ``num_slots`` bounds concurrent
+    and the ticket methods (docs/SERVING.md, the family table); or a
+    model whose attention layers are window and full mixed
+    (``tpudp.models.laguna``), served through pages only likewise: its
+    cache is ``generate.WindowedPages``, a pool a layer kind behind a
+    block table each, the window layers' pages freed behind the window
+    (``kv_pages`` counts the global pool's and must hold every slot's
+    full reservation; the window pool's size follows from the window);
+    prefix sharing is off for it.  ``num_slots`` bounds concurrent
     in-flight requests (queued requests wait for a free slot);
     ``max_len`` bounds ``prompt + max_new_tokens`` per request (default:
     the model's ``max_seq_len``, rounded down to a ``prefill_chunk``
@@ -1599,30 +1661,47 @@ class Engine:
                  flight_dir: str | None = None):
         cfg = model.config
         validate_decode_config(cfg, "Engine")
-        self._latent = page_layout(cfg) == "latent"
-        if self._latent:
-            # What this family does not serve yet, refused by option name
+        layout = page_layout(cfg)
+        #: The family's name in a refusal; None for GPT-2 and LLaMA, which
+        #: have every program.  The two expert families have their two
+        #: paged programs only.
+        self._two_programs = {
+            "latent": "latent-attention",
+            "windowed": "window-and-full-attention"}.get(layout)
+        if self._two_programs:
+            # What these families do not serve yet, refused by option name
             # (docs/SERVING.md, the family table).
-            for option, refused, why in (
-                    ("kv_pages", not kv_pages,
-                     "kv_pages must be > 0: latent pages have no dense "
-                     "slot arena"),
-                    ("kv_dtype", kv_dtype is not None,
-                     "latent pages are kept in the compute dtype"),
-                    ("speculate_tree", speculate_tree is not None,
-                     "no tree-verify program"),
-                    ("speculate_k", speculate_k > 0, "no verify program"),
-                    ("decode_fuse", decode_fuse > 1,
-                     "no fused decode program"),
-                    ("models", bool(models),
-                     "no co-residence: one model a pool"),
+            refusals = [
+                ("kv_pages", not kv_pages,
+                 f"kv_pages must be > 0: {layout} pages have no dense "
+                 "slot arena"),
+                ("kv_dtype", kv_dtype is not None,
+                 f"{layout} pages are kept in the compute dtype"),
+                ("speculate_tree", speculate_tree is not None,
+                 "no tree-verify program"),
+                ("speculate_k", speculate_k > 0, "no verify program"),
+                ("decode_fuse", decode_fuse > 1, "no fused decode program"),
+                ("models", bool(models), "no co-residence: one model a pool")]
+            if layout == "latent":
+                refusals.append(
                     ("paged_attn", paged_attn not in (None, "einsum"),
                      "latent attention runs as XLA contractions through "
-                     "the block table ('einsum') only")):
+                     "the block table ('einsum') only"))
+            else:
+                full = num_slots * ((cfg.max_seq_len if max_len is None
+                                     else max_len) // max(prefill_chunk, 1))
+                refusals += [
+                    ("paged_attn", paged_attn == "gather",
+                     "no dense view of two pools: 'einsum' or 'kernel'"),
+                    ("kv_pages", 0 < kv_pages < full,
+                     f"kv_pages must hold every slot's full reservation "
+                     f"({full} pages): a vacated slot's window pages "
+                     f"cannot be resumed yet")]
+            for option, refused, why in refusals:
                 if refused:
                     raise ValueError(
                         f"Engine({option}=...) is not served for the "
-                        f"latent-attention family "
+                        f"{self._two_programs} family "
                         f"({type(cfg).__name__}): {why}")
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -1684,7 +1763,7 @@ class Engine:
         self.paged_attn_requested = paged_attn
         if paged_attn is None:
             # (the latent family has no kernel: einsum on every backend)
-            paged_attn = ("kernel" if kv_pages and not self._latent
+            paged_attn = ("kernel" if kv_pages and layout != "latent"
                           and jax.default_backend() != "cpu" else "einsum")
         if drafter is not None and speculate_k == 0:
             raise ValueError("drafter requires speculate_k >= 1 "
@@ -1806,7 +1885,8 @@ class Engine:
         # program this engine will run is an error, never an einsum.
         self.paged_attn_dispatch: dict[str, str] = {}
         if self._paged:
-            fams = ("decode_paged", "prefill_paged") if self._latent else (
+            fams = ("decode_paged", "prefill_paged") \
+                if self._two_programs else (
                 "decode_paged", "verify_paged", "prefill_paged",
                 "fused_decode_paged", "fused_spec_paged",
                 "tree_verify_paged")
@@ -2025,13 +2105,23 @@ class Engine:
                 f"below the {self._max_pages} pages one max_len "
                 f"({self.max_len}) request needs; raise kv_pages")
         for members in groups.values():
-            pool = PagePool(members[0].config, per_group,
-                            self.prefill_chunk, self.kv_dtype)
+            cfg = members[0].config
+            # Sliding-window layers get a pool of their own, sized by the
+            # window and never by the context: the pages a slot's window
+            # can overlap, and the one being written.
+            window = getattr(cfg, "sliding_window", None) \
+                if page_layout(cfg) == "windowed" else None
+            pool = PagePool(
+                cfg, per_group, self.prefill_chunk, self.kv_dtype,
+                window_pages=0 if window is None else self.num_slots * (
+                    -(-window // self.prefill_chunk) + 1))
             for ms in members:
                 ms.pool = pool
                 ms.index = PageIndex(pool)
                 ms.table = np.full((self.num_slots, self._max_pages),
                                    -1, np.int32)
+                if window is not None:
+                    ms.wtable = np.full_like(ms.table, -1)
                 ms.slot_nodes = [dict() for _ in range(self.num_slots)]
 
     @property
@@ -2352,11 +2442,12 @@ class Engine:
     # -- cross-host migration hooks (tpudp/serve/disagg.py) ------------
 
     def _refuse_tickets(self, method: str) -> None:
-        if self._latent:
+        if self._two_programs:
             raise ValueError(
-                f"Engine.{method}() is not served for the latent-attention "
-                f"family ({type(self.config).__name__}): the migration "
-                f"wire format carries K/V pages only")
+                f"Engine.{method}() is not served for the "
+                f"{self._two_programs} family "
+                f"({type(self.config).__name__}): the migration wire "
+                f"format carries the K/V pages of one pool only")
 
     def export_ticket(self, request: Request):
         """Detach a live request into a :class:`tpudp.serve.disagg.
@@ -2645,7 +2736,11 @@ class Engine:
             out["page_pools"] = [
                 {"num_pages": p.num_pages, "used_pages": p.used_pages,
                  "free_pages": p.free_pages,
-                 "page_bytes": p.page_bytes()} for p in pools]
+                 "page_bytes": p.page_bytes(),
+                 # (a windowed family's second pool; 0 pages otherwise)
+                 "window_pages": p.window_pages,
+                 "window_used_pages": p.window_used_pages}
+                for p in pools]
             # The backend dispatch record: what was asked for, what it
             # resolved to, and the per-program-family impl actually
             # traced — a kernel engine's einsum fall-backs (features
@@ -2657,6 +2752,8 @@ class Engine:
                 "fallbacks": sorted(
                     f for f, impl in self.paged_attn_dispatch.items()
                     if self.paged_attn == "kernel" and impl != "kernel"),
+                # the span of a windowed family's sliding layers
+                "window": getattr(self.config, "sliding_window", None),
             }
         if self.stats.get("draft_tokens"):
             out["acceptance_rate"] = self.acceptance_rate
@@ -2762,7 +2859,13 @@ class Engine:
         final chunk always re-prefills: that re-prefill writes a FRESH
         private page — the copy-on-write at the divergence block —
         while the mapped shared pages are never written (the slot's
-        first write position is at or past the page after the hit)."""
+        first write position is at or past the page after the hit).
+
+        Skipped for a family with sliding-window layers: a hit would
+        need the window layers' K/V of the last matched block, and
+        those pages are freed behind the window."""
+        if ms.wtable is not None:
+            return
         self.stats["prefix_lookups"] += 1
         nodes = ms.index.lookup(r._fill)
         n_map = min(len(nodes), (r._fill.size - 1) // self.prefill_chunk)
@@ -2789,7 +2892,10 @@ class Engine:
         from an earlier hit are already the tree's (adopt just touches
         them), and a chunk another request published meanwhile keeps
         the tree's page (the slot's identical private duplicate drops
-        at vacate)."""
+        at vacate).  Skipped, like the lookup, for a family with
+        sliding-window layers."""
+        if ms.wtable is not None:
+            return
         n_blocks = min(r._nfill, r._fill.size) // self.prefill_chunk
         if not n_blocks:
             return
@@ -2817,6 +2923,10 @@ class Engine:
             ms.pool.release(page)
         ms.table[s] = -1
         ms.slot_nodes[s] = {}
+        if ms.wtable is not None:
+            for page in ms.wtable[s][ms.wtable[s] >= 0]:
+                ms.pool.release_window(int(page))
+            ms.wtable[s] = -1
 
     def _alloc_page(self, ms: _ModelState, protect: int) -> int | None:
         """One exclusive page for slot ``protect``, evicting cold tree
@@ -2926,7 +3036,48 @@ class Engine:
                                  f"small for the admitted workload"))
                 return False
             ms.table[s, pidx] = page
+        if ms.wtable is not None:
+            self._roll_window_pages(ms, s, upto, need)
         return True
+
+    @staticmethod
+    def _dead_window_pages(start: int, window: int, page_tokens: int) -> int:
+        """How many leading logical pages no query at ``start`` or later
+        can attend on a sliding layer: those whose last token ``pos -
+        window`` has passed (a query at ``q`` sees keys ``> q -
+        window``)."""
+        return max(start - window + 1, 0) // page_tokens
+
+    def _roll_window_pages(self, ms: _ModelState, s: int, upto: int,
+                           need: int) -> None:
+        """The window layers' half of :meth:`_ensure_pages` for the
+        program about to run slot ``s`` from position ``self._len[s]``
+        to ``upto``: release the window-table entries the window has
+        passed (their pages go back to the window pool; the kernels skip
+        a ``-1`` entry and the mask hides it anyway), back the entries
+        the program writes, and count what the window layers' calls of
+        this run must read and multiply (host arithmetic on lengths the
+        scheduler holds: no device value is touched)."""
+        chunk, window = self.prefill_chunk, ms.config.sliding_window
+        start = int(self._len[s])
+        row = ms.wtable[s]
+        j = self._dead_window_pages(start, window, chunk) - 1
+        while j >= 0 and row[j] >= 0:  # one page a crossing, as a rule
+            ms.pool.release_window(int(row[j]))
+            row[j] = -1
+            self.stats["window_pages_freed"] += 1
+            j -= 1
+        for pidx in range(start // chunk, need):
+            if row[pidx] < 0:
+                row[pidx] = ms.pool.alloc_window()
+        layers = ms.pool.pages.window.k.shape[0]  # the sliding layers
+        first = max(start - window + 1, 0)  # oldest key the run attends
+        short = min(upto, window - 1)  # queries below it see q + 1 keys
+        pairs = max(upto - max(start, window - 1), 0) * window
+        if short > start:
+            pairs += (short * (short + 1) - start * (start + 1)) // 2
+        self.stats["window_rows_read"] += layers * (upto - first)
+        self.stats["window_pairs"] += layers * pairs
 
     def _ensure_decode_pages(self, ms: _ModelState, active,
                              fuse: bool):
@@ -2991,6 +3142,9 @@ class Engine:
                         raise RuntimeError(
                             f"slot {s} pins page {page} absent from its "
                             f"table row")
+        for ms in self._mstates.values():
+            if ms.wtable is not None:
+                ms.pool.check_window(ms.wtable[ms.wtable >= 0].tolist())
         for pool in pools:
             expected: dict[int, int] = {}
             for ms in self._mstates.values():
@@ -3181,6 +3335,8 @@ class Engine:
                     rebuilt_pools.append(ms.pool)
                 ms.index.reset()
                 ms.table[:] = -1
+                if ms.wtable is not None:
+                    ms.wtable[:] = -1
                 ms.slot_nodes = [dict() for _ in range(self.num_slots)]
                 self.stats["prefix_flushes"] += 1
             else:
@@ -3253,8 +3409,13 @@ class Engine:
             # the paged prefill against the slot's table row.
             if not self._ensure_pages(ms, s, end):
                 return  # slot retired (defensive: pool exhausted)
+            # (a COPY of the window row: the host frees its entries while
+            # this program may still be in flight, and a backend may read
+            # a numpy argument in place; the global row only ever grows)
+            row = ms.table[s] if ms.wtable is None \
+                else (ms.table[s], ms.wtable[s].copy())
             last_logits, ms.pool.pages, *routed = self._device(
-                "prefill", ms.prefill_paged, ms.pool.pages, ms.table[s],
+                "prefill", ms.prefill_paged, ms.pool.pages, row,
                 buf, np.int32(start), np.int32(end - start - 1))
             # an expert family's counts of this run: fetched with the
             # next token fetch, never on their own
@@ -3307,9 +3468,9 @@ class Engine:
 
     def _count_moe(self, fetched) -> None:
         """Add the expert layers' fetched per-run counts
-        (``pangu.SERVE_MOE_COUNTERS``) to the host stats; the pending
+        (``moe.SERVE_MOE_COUNTERS``) to the host stats; the pending
         device values they were fetched from are done with."""
-        from tpudp.models.pangu import SERVE_MOE_COUNTERS
+        from tpudp.models.moe import SERVE_MOE_COUNTERS
 
         self._moe_pending = []
         for vals in fetched:
@@ -3318,10 +3479,14 @@ class Engine:
 
     def _run_decode(self, ms: _ModelState, active, emitted) -> None:
         if self._paged:
+            table = ms.table
+            if ms.wtable is not None:
+                table = (table, ms.wtable.copy())  # as the prefill's row
+                self.stats["window_pages_live"] += ms.pool.window_used_pages
             (ms.pool.pages, toks, self._keys, ms.obs_counts,
              *routed) = self._device(
                 "decode", ms.decode_paged,
-                ms.pool.pages, ms.table, self._last, self._len, active,
+                ms.pool.pages, table, self._last, self._len, active,
                 self._temps, self._topk, self._topp, self._keys,
                 ms.obs_counts)
             self._moe_pending += routed

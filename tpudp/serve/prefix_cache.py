@@ -325,7 +325,7 @@ class PagePool:
     """
 
     def __init__(self, cfg, num_pages: int, page_tokens: int,
-                 kv_dtype: str | None = None):
+                 kv_dtype: str | None = None, window_pages: int = 0):
         if num_pages < 1:
             raise ValueError(f"num_pages must be >= 1, got {num_pages}")
         if page_tokens < 1:
@@ -339,6 +339,13 @@ class PagePool:
         self.page_tokens = page_tokens
         self.kv_dtype = kv_dtype
         self.scratch = num_pages  # the +1 guard page (never allocated)
+        # A family with sliding-window layers (generate.WindowedPages)
+        # keeps those layers' K/V in a second pool of ``window_pages``
+        # pages (+ its own scratch) beside this one.  A window page has
+        # one holder, the slot whose window overlaps it (no prefix
+        # sharing for such a family), so a free list is all its state.
+        self.window_pages = window_pages
+        self._wfree = list(range(window_pages - 1, -1, -1))
         self.pages = self._buffer()
         self._rc: dict[int, int] = {}
         self._free = list(range(num_pages - 1, -1, -1))
@@ -349,8 +356,9 @@ class PagePool:
         # kernels read, NOT the dense arena's and the copy cache's
         # (..., kv_heads, head_dim) KVCache above), the same rows in int8
         # + a scale a head, or a latent family's LatentPages
+        window = (self.window_pages + 1,) if self.window_pages else ()
         return page_type(self.config, self.kv_dtype).zeros(
-            self.config, self.num_pages + 1, self.page_tokens)
+            self.config, self.num_pages + 1, self.page_tokens, *window)
 
     @property
     def free_pages(self) -> int:
@@ -364,8 +372,31 @@ class PagePool:
         """HBM bytes of one page across k/v (and scales in int8 mode) —
         the unit of the serve bench's fixed-byte capacity comparison."""
         total = sum(int(buf.size) * buf.dtype.itemsize
-                    for buf in self.pages)
+                    for buf in getattr(self.pages, "full", self.pages))
         return total // (self.num_pages + 1)
+
+    @property
+    def window_used_pages(self) -> int:
+        return self.window_pages - len(self._wfree)
+
+    def alloc_window(self) -> int:
+        """One page of the window pool.  It is sized to what the slots'
+        windows can overlap at once, so it cannot run dry under the
+        engine's own rule (an IndexError here is a scheduler bug)."""
+        return self._wfree.pop()
+
+    def release_window(self, page: int) -> None:
+        self._wfree.append(page)
+
+    def check_window(self, mapped: list[int]) -> None:
+        """Window-pool consistency against the pages the window tables
+        map: each allocated page mapped exactly once, none both mapped
+        and free."""
+        if sorted(mapped + self._wfree) != list(range(self.window_pages)):
+            raise RuntimeError(
+                f"window pages mapped {sorted(mapped)} + free "
+                f"{sorted(self._wfree)} are not the pool's "
+                f"{self.window_pages}")
 
     def alloc(self) -> int | None:
         """One exclusive page (rc=1), or None when the pool is empty —
@@ -395,6 +426,7 @@ class PagePool:
         self.pages = self._buffer()
         self._rc = {}
         self._free = list(range(self.num_pages - 1, -1, -1))
+        self._wfree = list(range(self.window_pages - 1, -1, -1))
 
     def read_page(self, page: int) -> dict:
         """Host copies of one allocated page's slice of every pool
