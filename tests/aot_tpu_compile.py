@@ -150,7 +150,10 @@ def latent_steps():
     """The serve engine's two programs for the latent-attention expert
     family at the benchmark cell's real sizes (perf/configs/
     pangu_ultra_moe_718b.json; 64 slots, 8,192 positions, 512-token pages,
-    1,024 of them): the grouped kernels at a decode step's 512 rows and a
+    1,024 of them, the kernel backend): absorbed attention as one
+    ``latent_attn`` Mosaic call a layer (a chunk's 1,024-row blocks against
+    a ``(512, 512 + 128)`` page; no ``while`` loop over page tiles is
+    left), the grouped kernels at a decode step's 512 rows and a
     chunk's 4,096, the whole program within the chip's memory, and the
     page pool in ONE layout (PR 34 found XLA transposing all 3.4 GB of it
     into a token-minor layout and back around a page write)."""
@@ -160,8 +163,8 @@ def latent_steps():
     from tpudp.models.pangu import Pangu, PanguConfig
     from tpudp.serve import engine
 
-    sys.modules["tpudp.ops.grouped_matmul"]._interpret_default = (
-        lambda: False)
+    for name in ("tpudp.ops.grouped_matmul", "tpudp.ops.paged_attention"):
+        sys.modules[name]._interpret_default = lambda: False
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "perf", "configs",
                            "pangu_ultra_moe_718b.json")) as f:
@@ -174,7 +177,7 @@ def latent_steps():
     pool = shapes(jax.eval_shape(
         lambda: LatentPages.zeros(cfg, LATENT_PAGES + 1, chunk)))
     return engine_steps("latent_{}".format,
-                        engine._build_steps(cfg, "einsum"), params, pool,
+                        engine._build_steps(cfg, "kernel"), params, pool,
                         slots=slots, width=width, chunk=chunk)
 
 
@@ -291,7 +294,7 @@ CASES = {
 # benchmark's kernel_ms.* readers match in a device trace).
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
            "paged_prefill", "paged_decode_window", "paged_prefill_window",
-           "paged_tree", "moe_gmm", "moe_tgmm",
+           "paged_tree", "latent_attn", "moe_gmm", "moe_tgmm",
            "moe_swiglu", "moe_swiglu_bwd", "moe_combine", "moe_unwritten")
 MOSAIC_OP = re.compile(
     r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
@@ -322,7 +325,13 @@ for name, (fn, args) in CASES.items():
                     rf"bf16\[24,{int(name.rsplit('_', 1)[1]) + 1},128,1024\]")
             layouts = sorted(set(re.findall(pool + r"{([\d,]+)", text)))
             copies = len(re.findall(rf"= {pool}\S* copy\(", text))
+            # the XLA form of absorbed attention: a loop over page tiles
+            # that carries the running maximum, denominator, accumulator
+            loops = len(re.findall(
+                r"= \(s32\[\]\S* (?:f32\[\d+,\d+,128\]\S* ){2}"
+                r"f32\[\d+,\d+,128,512\]\S* .* while\(", text))
             print(f"POOL {name} layouts={'|'.join(layouts)} copies={copies}"
+                  f" attn_loops={loops}"
                   f" temp={mem.temp_size_in_bytes} bytes="
                   f"{mem.argument_size_in_bytes + mem.output_size_in_bytes
                      + mem.temp_size_in_bytes - mem.alias_size_in_bytes}")

@@ -3,7 +3,8 @@ page type and the absorbed paged attention, the engine's two programs for
 it) against the plain float32 reference in perf/families/pangu_moe.py, on
 seeded weights at small sizes: the module, absorbed = expanded attention,
 the engine's prefill and decode through pages, the expert shares, the
-counters, the page write, and every refusal."""
+counters, the page write, the ``latent_attn`` kernel (interpreted) against
+the XLA loop, and every refusal."""
 
 import dataclasses
 import importlib
@@ -179,6 +180,61 @@ def test_latent_attention_masks_by_row_depth():
         np.testing.assert_allclose(got[b, 0], want, atol=2e-5)
 
 
+# (table rows, pos: a scalar is a prefill window, cur, heads)
+KERNEL_CASES = {
+    # the window sits in its third page; the entries behind its reach are
+    # unmapped and the grid still walks them
+    "a_chunk_across_pages": ([[7, 2, 9, -1, -1]], 16, 8, 4),
+    "slots_at_different_depths": ([[3, 1, -1], [0, 5, 8]], [9, 20], 1, 4),
+    "a_slot_with_no_page": ([[3, 1, -1], [-1, -1, -1]], [9, 0], 1, 4),
+    # 16 tokens x 128 heads are two row blocks of 1,024 (eight tokens):
+    # tokens 5..12 straddle the first page boundary and 13..20 the second,
+    # and the last block sees three pages where the first sees two
+    "a_row_block_off_the_page_boundary": ([[4, 11, 6, -1]], 5, 16, 128),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_latent_kernel_equals_the_loop_on_the_same_pages(case):
+    """``impl='kernel'`` (the ``latent_attn`` Mosaic call, interpreted
+    here) against ``impl='einsum'`` (the XLA loop the two tests above
+    hold to the expanded form) on the same random pages; a slot whose
+    table row is all ``-1`` comes back as zeros from the kernel."""
+    rows, pos, cur, heads = KERNEL_CASES[case]
+    _, _, cfg, _, pool = _paged_setup()
+    rng = jax.random.PRNGKey(11)
+    pages = (jax.random.normal(rng, pool.c.shape),
+             jax.random.normal(jax.random.fold_in(rng, 1), pool.r.shape))
+    table = jnp.asarray(rows, jnp.int32)
+    b = table.shape[0]
+    q_lat = jax.random.normal(jax.random.fold_in(rng, 2),
+                              (b, cur, heads, 32))
+    q_rope = jax.random.normal(jax.random.fold_in(rng, 3),
+                               (b, cur, heads, 128))
+    kw = dict(scale=0.2, dtype=jnp.float32, layer=1)
+    want = latent_paged_attention(q_lat, q_rope, pages, table,
+                                  jnp.asarray(pos, jnp.int32), **kw)
+    got = latent_paged_attention(q_lat, q_rope, pages, table,
+                                 jnp.asarray(pos, jnp.int32), impl="kernel",
+                                 **kw)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    mapped = np.asarray(table[:, 0] >= 0)
+    np.testing.assert_allclose(np.asarray(got)[mapped],
+                               np.asarray(want)[mapped], atol=2e-5)
+    assert np.all(np.asarray(got)[~mapped] == 0)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_the_latent_op_refuses_an_impl_it_does_not_have():
+    _, _, _, _, pool = _paged_setup()
+    q = jnp.zeros((1, 8, 4, 32))
+    with pytest.raises(ValueError, match="unknown latent paged-attention"):
+        latent_paged_attention(q, jnp.zeros((1, 8, 4, 128)), tuple(pool),
+                               jnp.zeros((1, 3), jnp.int32), jnp.int32(0),
+                               scale=0.2, dtype=jnp.float32, layer=0,
+                               impl="gather")
+
+
 def test_the_latent_page_write_leaves_other_pages_alone():
     _, _, cfg, _, pool = _paged_setup(pages=5)
     pages = tuple(jnp.full(b.shape, 7.0) for b in pool)
@@ -246,6 +302,63 @@ def test_the_engine_serves_prefill_and_decode_through_latent_pages():
         gap = rows.max(-1) - rows[np.arange(7), h.tokens]
         assert gap.max() <= 2e-4, gap
     eng.close()
+
+
+def test_the_engine_serves_through_the_latent_kernel():
+    """``paged_attn='kernel'`` is accepted for the family and shown in
+    ``metrics()``: both programs trace the ``latent_attn`` call (their own
+    TRACE_COUNTS keys), a slot stays empty throughout, and every greedy
+    token is still the reference's argmax given the system's choices."""
+    from tpudp.serve.engine import TRACE_COUNTS
+
+    config, model, params = _setup()
+    before = dict(TRACE_COUNTS)
+    eng = _engine(model, params, num_slots=4, paged_attn="kernel")
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 256, size=n, dtype=np.int32)
+               for n in (5, 19, 30)]
+    handles = [eng.submit(p, 5) for p in prompts]
+    eng.run_until_complete()
+    attn = eng.metrics()["paged_attn"]
+    assert attn["requested"] == attn["resolved"] == "kernel"
+    assert attn["dispatch"] == {"decode_paged": "kernel",
+                                "prefill_paged": "kernel"}
+    assert not attn.get("fallbacks")
+    for key in ("decode_paged_latent_kernel", "prefill_paged_latent_kernel"):
+        assert TRACE_COUNTS[key] > 0
+    for key in ("decode_paged_latent", "prefill_paged_latent"):
+        assert TRACE_COUNTS[key] == before.get(key, 0)
+    for p, h in zip(prompts, handles):
+        assert h.ok and len(h.tokens) == 5
+        seq = jnp.asarray(np.concatenate([p, h.tokens]))[None]
+        got, chosen = _chosen(model, params, seq)
+        want, _ = _reference(config, params, seq, chosen)
+        rows = np.asarray(want)[0, p.size - 1:p.size + 4]
+        gap = rows.max(-1) - rows[np.arange(5), h.tokens]
+        assert gap.max() <= 2e-4, gap
+    eng.close()
+
+
+def test_forward_paged_takes_the_backend_and_unset_is_einsum_on_the_cpu():
+    """``generate._forward_paged``'s ``impl`` reaches the latent family:
+    unset traces the XLA loop on the CPU platform (the benchmark's routed
+    check calls it so; on an accelerator that is the kernel), ``'kernel'``
+    the Mosaic call, and the two agree."""
+    _, _, cfg, params, pool = _paged_setup()
+    table = jnp.asarray([[2, 5, -1], [7, 0, 3]], jnp.int32)
+    tokens = jnp.asarray([[3], [9]], jnp.int32)
+    args = (tokens, pool, table, jnp.asarray([4, 17], jnp.int32),
+            jnp.ones((2,), bool))
+
+    def text(impl):
+        return str(jax.make_jaxpr(lambda p: gen._forward_paged(
+            cfg, p, *args, impl))(params))
+
+    assert "pallas_call" not in text(None) and text(None) == text("einsum")
+    assert "pallas_call" in text("kernel")
+    want, _ = gen._forward_paged(cfg, params, *args)
+    got, _ = gen._forward_paged(cfg, params, *args, "kernel")
+    np.testing.assert_allclose(got, want, atol=2e-4)
 
 
 def test_forward_paged_logits_match_the_reference_position_by_position():
@@ -351,7 +464,7 @@ REFUSED = [
     ("speculate_k", dict(speculate_k=2)),
     ("speculate_tree", dict(speculate_k=2, speculate_tree="binary2")),
     ("decode_fuse", dict(decode_fuse=4)),
-    ("paged_attn", dict(paged_attn="kernel")),
+    ("paged_attn", dict(paged_attn="gather")),
 ]
 
 

@@ -333,6 +333,9 @@ def test_program_donations_mirror_rules_tables():
         # dispatch through the same two attributes, same donations
         "serve.decode_paged_latent": "decode_paged",
         "serve.prefill_paged_latent": "prefill_paged",
+        # ... and their twins through the latent_attn Mosaic call (PR 37)
+        "serve.decode_paged_latent_kernel": "decode_paged",
+        "serve.prefill_paged_latent_kernel": "prefill_paged",
         # the window-and-full-attention expert family's two (PR 36)
         "serve.decode_paged_windowed": "decode_paged",
         "serve.prefill_paged_windowed": "prefill_paged",

@@ -87,7 +87,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("moe_layer", MOE_LAYER),
     ("lfm2_train_step", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
      | MOE_LAYER),
-    ("latent_decode", MOE_FORWARD), ("latent_prefill", MOE_FORWARD),
+    ("latent_decode", MOE_FORWARD | {"latent_attn"}),
+    ("latent_prefill", MOE_FORWARD | {"latent_attn"}),
     ("laguna_decode", MOE_FORWARD | {"paged_decode", "paged_decode_window"}),
     ("laguna_prefill", MOE_FORWARD | {"paged_prefill",
                                       "paged_prefill_window"}),
@@ -118,12 +119,20 @@ def test_a_latent_step_program_fits_and_keeps_the_pool_in_place(compiled,
                                                                 program):
     """The serve engine's programs for the latent-attention expert family
     at the benchmark cell's sizes (`pangu_moe.serve_closed_2k`: 9.84 GB of
-    weights, 1,024 pages of 512 tokens): within the v5e's 15.75 GB, and
-    every operation on the page pool in its one row-major layout (a second
-    layout means XLA copies the whole 3.4 GB pool, twice a program)."""
+    weights, 1,024 pages of 512 tokens, the kernel backend): within the
+    v5e's 15.75 GB, every operation on the page pool in its one row-major
+    layout (a second layout means XLA copies the whole 3.4 GB pool, twice
+    a program), and absorbed attention as one `latent_attn` Mosaic call a
+    layer: no loop over page tiles that carries the running maximum, the
+    denominator and the float32 accumulator `f32[.., 128], f32[.., 128],
+    f32[.., 128, 512]` through HBM is left (PERF.md section 6, PR 37: the
+    134 MB tiles of that loop were 49% of the cell's device step)."""
     fields = _pool_line(compiled, program)
     assert fields["layouts"] == "3,2,1,0", fields
+    assert int(fields["copies"]) == 0, fields
+    assert int(fields["attn_loops"]) == 0, fields
     assert 13.0e9 < int(fields["bytes"]) < 15.75e9, fields
+    assert _kernel_calls(compiled)[program]["latent_attn"] == 5
 
 
 @pytest.mark.parametrize("program, kernels", [

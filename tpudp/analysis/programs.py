@@ -65,12 +65,14 @@ TRACE_COUNTER_PROGRAMS = {
     "decode_paged": "serve.decode_paged",
     "decode_paged_kernel": "serve.decode_paged_kernel",
     "decode_paged_latent": "serve.decode_paged_latent",
+    "decode_paged_latent_kernel": "serve.decode_paged_latent_kernel",
     "decode_paged_windowed": "serve.decode_paged_windowed",
     "verify_paged": "serve.verify_paged",
     "verify_paged_kernel": "serve.verify_paged_kernel",
     "prefill_paged": "serve.prefill_paged",
     "prefill_paged_kernel": "serve.prefill_paged_kernel",
     "prefill_paged_latent": "serve.prefill_paged_latent",
+    "prefill_paged_latent_kernel": "serve.prefill_paged_latent_kernel",
     "prefill_paged_windowed": "serve.prefill_paged_windowed",
     "fused_decode_paged": "serve.fused_decode_paged",
     "fused_decode_paged_kernel": "serve.fused_decode_paged_kernel",
@@ -110,6 +112,9 @@ PROGRAM_DONATIONS = {
     # the latent-attention expert family's two programs (LatentPages pool)
     "serve.decode_paged_latent": (1, 10),
     "serve.prefill_paged_latent": (1,),
+    # ... and their twins through the latent_attn Mosaic call
+    "serve.decode_paged_latent_kernel": (1, 10),
+    "serve.prefill_paged_latent_kernel": (1,),
     # the window-and-full-attention expert family's two (WindowedPages:
     # both pools donate together; the two tables never)
     "serve.decode_paged_windowed": (1, 10),
@@ -446,6 +451,16 @@ def build_programs() -> dict:
     programs[f"serve.prefill_paged_latent@{pgeo2}c{SERVE['chunk']}"] = (
         lsteps[8], (lparams, lpool, table[0], h["chunk"], np.int32(0),
                     np.int32(SERVE["chunk"] - 1)))
+    # ... and their kernel twins (Engine(paged_attn='kernel'), the default
+    # on an accelerator): absorbed attention as the latent_attn Mosaic
+    # call, traced in interpret mode like the other kernel programs.
+    lksteps = _engine._build_steps(lcfg, "kernel")
+    programs[f"serve.decode_paged_latent_kernel@{pgeo2}"] = (
+        lksteps[6], programs[f"serve.decode_paged_latent@{pgeo2}"][1])
+    programs[
+        f"serve.prefill_paged_latent_kernel@{pgeo2}c{SERVE['chunk']}"] = (
+        lksteps[8],
+        programs[f"serve.prefill_paged_latent@{pgeo2}c{SERVE['chunk']}"][1])
 
     # The window-and-full-attention expert family (tpudp/models/laguna.py):
     # its two programs over a WindowedPages pool and the pair of tables,

@@ -480,13 +480,15 @@ class _LatentKV:
     ``(c, r)`` buffers.  ``layer`` is set by the forward before each
     block; ``write`` commits the window's latents as single-page token
     writes (:func:`write_token_pages`), ``attend`` runs the absorbed
-    attention through the block table."""
+    attention through the block table, as XLA contractions
+    (``impl='einsum'``) or as the ``latent_attn`` Mosaic call
+    (``'kernel'``)."""
 
-    __slots__ = ("cfg", "pages", "table", "pos", "active", "layer")
+    __slots__ = ("cfg", "pages", "table", "pos", "active", "impl", "layer")
 
-    def __init__(self, cfg, pages, table, pos, active):
+    def __init__(self, cfg, pages, table, pos, active, impl):
         self.cfg, self.pages, self.table = cfg, pages, table
-        self.pos, self.active, self.layer = pos, active, None
+        self.pos, self.active, self.impl, self.layer = pos, active, impl, None
 
     def write(self, c_kv: jnp.ndarray, k_rope: jnp.ndarray) -> None:
         # a whole page-aligned chunk (b == 1) commits as ONE page write,
@@ -507,7 +509,7 @@ class _LatentKV:
         return latent_paged_attention(
             q_lat, q_rope, self.pages, self.table, self.pos,
             scale=self.cfg.score_scale, dtype=self.cfg.dtype,
-            layer=self.layer)
+            layer=self.layer, impl=self.impl)
 
 
 class _TreePagedKV:
@@ -581,13 +583,12 @@ def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
     Four families dispatch here: GPT-2, LLaMA, and the two expert
     families, which take ``last`` and ``routed`` (see their own
     ``forward_paged``): latent attention (``tpudp.models.pangu``:
-    absorbed MLA over :class:`LatentPages`; it has the one path and
-    ``impl`` does not reach it, the engine refuses ``paged_attn`` other
-    than einsum) and window and full attention layers mixed
-    (``tpudp.models.laguna``: :class:`WindowedPages`, ``table`` one array
-    or the pair of the two pools' tables, ``impl`` einsum or kernel as
-    below, unset the kernels on an accelerator).  For GPT-2 and LLaMA an
-    unset ``impl`` is ``'einsum'``.
+    absorbed MLA over :class:`LatentPages`) and window and full attention
+    layers mixed (``tpudp.models.laguna``: :class:`WindowedPages`,
+    ``table`` one array or the pair of the two pools' tables).  Both take
+    ``impl`` einsum or kernel as below (no ``'gather'``: neither has a
+    dense view), unset the kernels on an accelerator and einsum on the
+    CPU.  For GPT-2 and LLaMA an unset ``impl`` is ``'einsum'``.
 
     ``impl='einsum'`` (the engine default) and ``'kernel'`` are
     GATHER-FREE: each layer's block twin writes the window's new K/V
@@ -610,7 +611,7 @@ def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
         from tpudp.models import pangu as _pangu
 
         return _pangu.forward_paged(cfg, params, tokens, pool, table, pos,
-                                    active, last=last, routed=routed)
+                                    active, impl, last=last, routed=routed)
     if page_layout(cfg) == "windowed":
         from tpudp.models import laguna as _laguna
 

@@ -337,13 +337,19 @@ def block_paged(cfg: PanguConfig, p: dict, x: jnp.ndarray, index: int,
 
 def forward_paged(cfg: PanguConfig, params: dict, tokens: jnp.ndarray, pool,
                   table: jnp.ndarray, pos: jnp.ndarray, active: jnp.ndarray,
-                  *, last=None, routed: list | None = None):
+                  impl: str | None = None, *, last=None,
+                  routed: list | None = None):
     """``(b, cur)`` tokens at per-slot depths ``pos`` (or one shared
     scalar depth: a prefill chunk) through the latent page pool:
     ``(logits, pool)``.  Every layer works on the WHOLE stacked pool
     buffers (writes scatter at ``[layer, page, ...]``, reads index the
-    layer inside the gather), so no slice of the pool is ever a value of
-    its own and nothing restacks.
+    layer inside the gather or the kernel's block spec), so no slice of
+    the pool is ever a value of its own and nothing restacks.
+
+    ``impl``: ``'einsum'`` or ``'kernel'``
+    (``ops.paged_attention.latent_paged_attention``); ``None`` is the
+    kernel on an accelerator and einsum on the CPU (the engine's rule for
+    an unset ``paged_attn``).
 
     ``last`` (a traced scalar; prefill): rows past it are the chunk's
     padding, and only row ``last`` goes through the head (``logits``
@@ -353,6 +359,8 @@ def forward_paged(cfg: PanguConfig, params: dict, tokens: jnp.ndarray, pool,
     trace-time out-parameter, like the page store)."""
     from tpudp.models.generate import _LatentKV
 
+    if impl is None:
+        impl = "einsum" if jax.default_backend() == "cpu" else "kernel"
     b, cur = tokens.shape
     pos = jnp.broadcast_to(jnp.asarray(pos), (b,))
     positions = pos[:, None] + jnp.arange(cur)
@@ -360,7 +368,7 @@ def forward_paged(cfg: PanguConfig, params: dict, tokens: jnp.ndarray, pool,
     if last is not None:
         live = live & (jnp.arange(cur) <= last)
     x = params["wte"]["embedding"].astype(cfg.dtype)[tokens]
-    store = _LatentKV(cfg, tuple(pool), table, pos, active)
+    store = _LatentKV(cfg, tuple(pool), table, pos, active, impl)
     for i in range(cfg.num_hidden_layers):
         store.layer = i
         x, out = block_paged(cfg, params[f"h_{i}"], x, i, store, positions,

@@ -54,9 +54,13 @@ MHA einsum forms and LLaMA's grouped (GQA) forms — selected by
 op-for-op (the bitwise contract is per-family).
 
 :func:`latent_paged_attention` is the third family's: absorbed latent
-(MLA) attention over ``generate.LatentPages``, XLA contractions a page
-tile at a time under an online softmax (no kernel, no dense twin to be
-bitwise with; tolerance-bounded against the expanded form).
+(MLA) attention over ``generate.LatentPages`` under an online softmax a
+page tile at a time (no dense twin to be bitwise with; tolerance-bounded
+against the expanded form).  ``impl='einsum'`` runs the tiles as XLA
+contractions in a ``fori_loop``; ``impl='kernel'`` is ONE Mosaic call a
+layer (``latent_attn``) whose score, softmax and value tiles never leave
+VMEM: absorbed MLA is multi-query attention, so a token's heads are
+query ROWS against one shared key row a cached token.
 """
 
 from __future__ import annotations
@@ -193,7 +197,8 @@ def _einsum_paged(q, pages, table, pos, *, dtype, grouped, window=None):
 
 
 def latent_paged_attention(q_lat, q_rope, pages, table, pos, *, scale: float,
-                           dtype, layer: int):
+                           dtype, layer: int, impl: str = "einsum",
+                           interpret: bool | None = None):
     """Absorbed latent (MLA) attention over table-indirected latent pages:
     one op for a decode step and a prefill window.
 
@@ -218,7 +223,23 @@ def latent_paged_attention(q_lat, q_rope, pages, table, pos, *, scale: float,
     c_kv,t`` ``(b, cur, heads, c)`` in ``dtype``: the caller carries it
     out of the latent space (``W_kvb,v``).  Every row's first page holds a
     visible token (position 0), so the running maximum is finite from the
-    first tile on and a masked score weighs exactly zero."""
+    first tile on and a masked score weighs exactly zero.
+
+    ``impl='kernel'`` runs the same recurrence on the same operand types
+    as one Mosaic call (:func:`_latent_paged`; interpreted on the CPU
+    platform unless ``interpret`` says otherwise), tolerance-bounded against
+    this loop like the other paged kernels against theirs.  There a row
+    that sees no key at all comes back as zeros."""
+    if impl not in ("einsum", "kernel"):
+        raise ValueError(
+            f"unknown latent paged-attention impl {impl!r}; choose from "
+            f"'einsum' (XLA contractions a page tile) or 'kernel' (Mosaic)")
+    if impl == "kernel":
+        return _latent_paged(
+            q_lat, q_rope, pages, table, pos, scale=scale, dtype=dtype,
+            layer=layer,
+            interpret=_interpret_default() if interpret is None
+            else interpret)
     c_pages, r_pages = pages
     b, cur, h, c = q_lat.shape
     pos = jnp.broadcast_to(jnp.asarray(pos), (b,))
@@ -257,6 +278,157 @@ def latent_paged_attention(q_lat, q_rope, pages, table, pos, *, scale: float,
                              jnp.zeros((b, cur, h), f32),
                              jnp.zeros((b, cur, h, c), f32)))
         return (acc / den[..., None]).astype(dtype)
+
+
+# ------------------------------------------------ latent Pallas kernel
+
+#: Where the running maximum starts.  Above the mask's ``_NEG_INF``, so a
+#: masked score weighs ``exp(-1e30 + 1e20) == 0`` even before a row has
+#: met its first visible key, and below every score a model can produce.
+_LATENT_MAX0 = -1e20
+
+
+def _latent_block_rows(rows: int) -> int:
+    """Query rows a grid step: the largest divisor of ``rows`` up to 1,024
+    that fills whole sublane tiles (a prefill chunk's 8 tokens x 128
+    heads; a decode slot's ``heads`` rows are one block)."""
+    for cand in range(min(rows, 1024), 7, -1):
+        if rows % cand == 0 and cand % 8 == 0:
+            return cand
+    return rows
+
+
+def _latent_kernel(tbl_ref, pos_ref, ql_ref, qr_ref, c_ref, r_ref, o_ref,
+                   acc_ref, m_ref, l_ref, *, heads: int, block_rows: int,
+                   page_tokens: int, n_pages: int, scale: float, dtype):
+    """One ``(slot, row block, table entry)`` grid step of absorbed latent
+    attention.  Query row ``r`` of a slot is head ``r % heads`` of window
+    token ``r // heads`` and sees the keys ``<= pos[slot] + r // heads``;
+    every row reads the SAME key row a cached token (the latent and the
+    rotary lanes) and the same value row (the latent), so a page is one
+    ``(rows, c) x (c, T)`` + ``(rows, r) x (r, T)`` score product and one
+    ``(rows, T) x (T, c)`` value product, operands in ``dtype``, float32
+    accumulation and softmax.  Entries that are ``-1`` or lie wholly past
+    the block's last query do nothing (and fetched nothing: the index map
+    held the resident page); pages wholly visible to the block's first
+    query skip the mask."""
+    import jax.lax as lax
+    from jax.experimental import pallas as pl
+
+    s, i, m = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    row0 = i * block_rows
+    first = pos_ref[s] + row0 // heads  # the block's first query position
+    last = pos_ref[s] + (row0 + block_rows - 1) // heads
+    page0 = m * page_tokens
+
+    @pl.when(m == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _LATENT_MAX0)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def tile(masked: bool):
+        ct = c_ref[0].astype(dtype)  # (T, c): keys AND values
+        rt = r_ref[0].astype(dtype)  # (T, r)
+        nt = (((1,), (1,)), ((), ()))  # contract both operands' lanes
+        sc = (lax.dot_general(ql_ref[0], ct, nt,
+                              preferred_element_type=jnp.float32)
+              + lax.dot_general(qr_ref[0], rt, nt,
+                                preferred_element_type=jnp.float32)) * scale
+        if masked:
+            k_pos = page0 + lax.broadcasted_iota(
+                jnp.int32, (block_rows, page_tokens), 1)
+            q_pos = pos_ref[s] + (row0 + lax.broadcasted_iota(
+                jnp.int32, (block_rows, 1), 0)) // heads
+            sc = jnp.where(k_pos <= q_pos, sc, _NEG_INF)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]  # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)  # (rows, T)
+        l_ref[...] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(dtype), ct, preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    wanted = (tbl_ref[s * n_pages + m] >= 0) & (page0 <= last)
+    whole = page0 + page_tokens - 1 <= first
+    pl.when(wanted & whole)(functools.partial(tile, False))
+    pl.when(wanted & jnp.logical_not(whole))(functools.partial(tile, True))
+
+    @pl.when(m == pl.num_programs(2) - 1)
+    def _finalize():  # a row that met no key: 0 / 1e-30, zeros
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(
+            o_ref.dtype)
+
+
+# tpudp: kernel-program(serve.decode_paged_latent_kernel)
+def _latent_paged(q_lat, q_rope, pages, table, pos, *, scale, dtype, layer,
+                  interpret):
+    """Dispatch :func:`latent_paged_attention` through the Mosaic kernel,
+    a decode step (vector ``pos``) and a prefill window (scalar ``pos``)
+    alike.  The queries are viewed as rows ``(b, cur * heads, ...)`` (free
+    reshapes), the grid is ``(slot, row block, table entry)`` with the
+    online-softmax carry in VMEM scratch across the innermost axis, the
+    block table and ``pos`` ride as scalar prefetch, and a page block is
+    DMA'd from the WHOLE stacked pool by table value with ``layer`` picked
+    in the BlockSpec: no slice of the pool is ever a value.  An entry the
+    row block does not reach maps to the last page it does, which is
+    resident already, so skipped grid steps move no bytes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c_pages, r_pages = pages
+    b, cur, h, c = q_lat.shape
+    rope = q_rope.shape[-1]
+    page_tokens, n_pages = c_pages.shape[2], table.shape[1]
+    scratch_page = c_pages.shape[1] - 1
+    rows = cur * h
+    block_rows = _latent_block_rows(rows)
+
+    tbl = jnp.asarray(table, jnp.int32).reshape(-1)
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    # the table entries the deepest row of the call reaches (a traced
+    # bound, the XLA loop's): the grid does not walk the table's width
+    reach = jnp.clip((jnp.max(pos) + cur + page_tokens - 1) // page_tokens,
+                     1, n_pages)
+
+    def rows_map(s, i, m, tbl_ref, pos_ref):
+        return (s, i, 0)
+
+    def page_map(s, i, m, tbl_ref, pos_ref):
+        last = pos_ref[s] + (i * block_rows + block_rows - 1) // h
+        t = tbl_ref[s * n_pages + jnp.minimum(m, last // page_tokens)]
+        return (layer, jnp.where(t >= 0, t, scratch_page), 0, 0)
+
+    kernel = functools.partial(
+        _latent_kernel, heads=h, block_rows=block_rows,
+        page_tokens=page_tokens, n_pages=n_pages, scale=scale, dtype=dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, rows // block_rows, reach),
+        in_specs=[
+            pl.BlockSpec((1, block_rows, c), rows_map),
+            pl.BlockSpec((1, block_rows, rope), rows_map),
+            pl.BlockSpec((None, 1, page_tokens, c), page_map),
+            pl.BlockSpec((None, 1, page_tokens, rope), page_map),
+        ],
+        out_specs=pl.BlockSpec((1, block_rows, c), rows_map),
+        scratch_shapes=[
+            pltpu.VMEM((block_rows, c), jnp.float32),
+            pltpu.VMEM((block_rows, _LANES), jnp.float32),
+            pltpu.VMEM((block_rows, _LANES), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, c), dtype),
+        interpret=interpret,
+        name="latent_attn",
+    )(tbl, pos, q_lat.reshape(b, rows, c), q_rope.reshape(b, rows, rope),
+      c_pages, r_pages)
+    return out.reshape(b, cur, h, c)
 
 
 # ------------------------------------------------------- Pallas kernel

@@ -699,7 +699,7 @@ def _moe_counts(routed):
     return sum(serve_moe_counts(counts) for _, counts in routed)
 
 
-def _build_latent_steps(cfg):
+def _build_latent_steps(cfg, paged_attn: str):
     """The step programs of a latent-attention expert family
     (``tpudp.models.pangu``): ``decode_paged`` and ``prefill_paged`` and
     no other (the engine refuses at construction what would need one).
@@ -707,7 +707,11 @@ def _build_latent_steps(cfg):
     the run's :func:`_moe_counts`, which the scheduler fetches
     WITH the step's tokens (no sync of their own).  Rows of inactive
     slots and of a chunk's padding reach no routed expert and are in no
-    count."""
+    count.  ``paged_attn``: ``'kernel'`` (absorbed attention as the
+    ``latent_attn`` Mosaic call, a TRACE_COUNTS key and pinned trace of
+    its own) or ``'einsum'`` (XLA contractions a page tile)."""
+    kernel = paged_attn == "kernel"
+
     @functools.partial(jax.jit, donate_argnums=(1, 10))
     def decode_step_paged(params, pool, table, last_tokens, lengths,
                           active, temps, top_k, top_p, keys, counts):
@@ -715,12 +719,15 @@ def _build_latent_steps(cfg):
         shared ``_decode_math`` body over ``generate._forward_paged``'s
         third family (absorbed attention through ``table``, the dropless
         expert layer on the active rows)."""
-        TRACE_COUNTS["decode_paged_latent"] += 1
+        if kernel:
+            TRACE_COUNTS["decode_paged_latent_kernel"] += 1
+        else:
+            TRACE_COUNTS["decode_paged_latent"] += 1
         routed: list = []
 
         def fwd(pool, tokens, lengths, active):
             return _forward_paged(cfg, params, tokens, pool, table, lengths,
-                                  active, routed=routed)
+                                  active, paged_attn, routed=routed)
 
         return (*_decode_math(fwd, pool, last_tokens, lengths, active,
                               temps, top_k, top_p, keys, counts),
@@ -732,11 +739,14 @@ def _build_latent_steps(cfg):
         as one page write a layer, the window attends causally through
         the slot's table row, rows past ``last`` (a final chunk's padding)
         reach no expert, and only row ``last`` goes through the head."""
-        TRACE_COUNTS["prefill_paged_latent"] += 1
+        if kernel:
+            TRACE_COUNTS["prefill_paged_latent_kernel"] += 1
+        else:
+            TRACE_COUNTS["prefill_paged_latent"] += 1
         routed: list = []
         logits, new_pool = _forward_paged(
             cfg, params, tokens, pool, row_table[None], pos,
-            jnp.ones((1,), bool), last=last, routed=routed)
+            jnp.ones((1,), bool), paged_attn, last=last, routed=routed)
         return logits[:, 0], new_pool, _moe_counts(routed)
 
     return (None,) * 6 + (decode_step_paged, None, prefill_step_paged,
@@ -837,7 +847,7 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
     :func:`_build_windowed_steps`).
     """
     if page_layout(cfg) == "latent":
-        return _build_latent_steps(cfg)
+        return _build_latent_steps(cfg, paged_attn)
     if page_layout(cfg) == "windowed":
         return _build_windowed_steps(cfg, paged_attn)
 
@@ -1526,8 +1536,8 @@ class Engine:
     layers run inside the two step programs and count themselves in
     ``metrics()["stats"]``) and refuses, by option name, what it has no
     program for: ``kv_dtype``, ``speculate_k``, ``speculate_tree``,
-    ``decode_fuse > 1``, ``models=``, ``paged_attn`` other than einsum,
-    and the ticket methods (docs/SERVING.md, the family table); or a
+    ``decode_fuse > 1``, ``models=``, ``paged_attn='gather'``, and the
+    ticket methods (docs/SERVING.md, the family table); or a
     model whose attention layers are window and full mixed
     (``tpudp.models.laguna``), served through pages only likewise: its
     cache is ``generate.WindowedPages``, a pool a layer kind behind a
@@ -1681,18 +1691,13 @@ class Engine:
                  "no tree-verify program"),
                 ("speculate_k", speculate_k > 0, "no verify program"),
                 ("decode_fuse", decode_fuse > 1, "no fused decode program"),
-                ("models", bool(models), "no co-residence: one model a pool")]
-            if layout == "latent":
-                refusals.append(
-                    ("paged_attn", paged_attn not in (None, "einsum"),
-                     "latent attention runs as XLA contractions through "
-                     "the block table ('einsum') only"))
-            else:
+                ("models", bool(models), "no co-residence: one model a pool"),
+                ("paged_attn", paged_attn == "gather",
+                 f"no dense view of {layout} pages: 'einsum' or 'kernel'")]
+            if layout == "windowed":
                 full = num_slots * ((cfg.max_seq_len if max_len is None
                                      else max_len) // max(prefill_chunk, 1))
                 refusals += [
-                    ("paged_attn", paged_attn == "gather",
-                     "no dense view of two pools: 'einsum' or 'kernel'"),
                     ("kv_pages", 0 < kv_pages < full,
                      f"kv_pages must hold every slot's full reservation "
                      f"({full} pages): a vacated slot's window pages "
@@ -1762,8 +1767,7 @@ class Engine:
         # tpu": an accelerator backend must never land on einsum unasked.
         self.paged_attn_requested = paged_attn
         if paged_attn is None:
-            # (the latent family has no kernel: einsum on every backend)
-            paged_attn = ("kernel" if kv_pages and layout != "latent"
+            paged_attn = ("kernel" if kv_pages
                           and jax.default_backend() != "cpu" else "einsum")
         if drafter is not None and speculate_k == 0:
             raise ValueError("drafter requires speculate_k >= 1 "
