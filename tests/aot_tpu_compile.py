@@ -132,7 +132,7 @@ def engine_steps(name, steps, params, pool, *, slots, width, chunk):
             params, pool, sds((slots, width), i32), sds((slots,), i32),
             sds((slots,), i32), sds((slots,), jnp.bool_), sds((slots,), f32),
             sds((slots,), i32), sds((slots,), f32),
-            sds((slots, 2), jnp.uint32), sds((5,), f32))),
+            sds((slots, 2), jnp.uint32))),
         name("prefill"): (steps[8], (
             params, pool, sds((width,), i32), sds((1, chunk), i32),
             sds((), i32), sds((), i32)))}

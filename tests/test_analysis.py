@@ -135,6 +135,22 @@ def test_docstring_mention_is_not_a_suppression(tmp_path):
     assert findings == []
 
 
+def test_a_readiness_check_on_a_hot_path_is_not_a_sync(tmp_path):
+    """``jax.Array.is_ready()`` does not wait for the device (the serving
+    engine's exposed clock asks it at its phase boundaries): on a hot
+    path the host-sync rule flags the ``.block_until_ready()`` beside it
+    and not the check."""
+    findings = _lint_source(tmp_path, (
+        "class Engine:\n"
+        "    # tpudp: hot-path\n"
+        "    def step(self):\n"
+        "        out = self._device('decode', self.decode_step, self._keys)\n"
+        "        if out.is_ready():\n"
+        "            self.n = 0\n"
+        "        out.block_until_ready()\n"))
+    assert [(f.rule, f.line) for f in findings] == [("host-sync", 7)]
+
+
 # -- CLI contract ------------------------------------------------------
 
 

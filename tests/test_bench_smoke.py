@@ -257,7 +257,7 @@ def test_serve_bench_obs_check_row_and_sidecar(tmp_path):
     'within 3% on the CPU smoke host' — the row records the measured
     ratio and the within_3pct verdict; the smoke test pins the
     CONTRACT: parity intact, a real ratio measured, and the per-stage
-    metrics sidecar written with live device counters)."""
+    metrics sidecar written with live host counters)."""
     sidecar = tmp_path / "serve_bench_metrics.json"
     proc = _run("benchmarks/serve_bench.py", {
         "SERVE_PLATFORM": "cpu", "SERVE_OBS_CHECK": "1",
@@ -279,8 +279,10 @@ def test_serve_bench_obs_check_row_and_sidecar(tmp_path):
     doc = json.loads(sidecar.read_text())
     assert doc["kind"] == "serve_bench_metrics"
     on = doc["stages"]["obs_check"]["on"]
-    assert on["device_counters"]["tokens"] > 0
+    assert on["stats"]["tokens"] > 0
     assert on["spans"]  # span rollup rode along
+    assert sum(on["exposed"].values()) == pytest.approx(
+        on["stats"]["exposed_s"])
 
 
 @pytest.mark.slow  # ~35s (4-layer target x 64-token decode x 3 engines);

@@ -1,6 +1,7 @@
 """tpudp.obs — structured telemetry: recorder ring semantics, overhead
-budget, Perfetto export round-trip, Prometheus exposition, zero-sync
-device counters, flight-recorder dumps on serve step faults / watchdog
+budget, Perfetto export round-trip, Prometheus exposition, the serving
+engine's phase spans and exposed clock, flight-recorder dumps on serve
+step faults / watchdog
 timeouts / training rollbacks, and the lint cleanliness of the obs
 layer itself (the telemetry must pass the repo's own static analysis —
 the design constraint the whole subsystem is shaped around)."""
@@ -23,7 +24,7 @@ from tpudp.data.loader import DataLoader
 from tpudp.models.generate import generate
 from tpudp.models.gpt2 import GPT2, GPT2Config
 from tpudp.serve import Engine
-from tpudp.serve.engine import OBS_DEVICE_COUNTERS
+from tpudp.serve.engine import EXPOSED_PHASES, STEP_PHASES
 from tpudp.serve.faults import FaultySteps
 from tpudp.serve.tenancy import TenantClass
 from tpudp.train import Trainer, init_state, make_optimizer
@@ -340,21 +341,22 @@ PROMPTS = [np.arange(1, 9, dtype=np.int32),
 
 
 def test_engine_device_counters_match_host_stats(lm):
-    """The zero-sync device counters must agree with the host-side
-    accounting they mirror: on a pure greedy decode run, the device
-    'tokens' counter is exactly stats['tokens'] minus the first tokens
-    (those ride the prefill's sample_row, which the device counters
-    deliberately exclude), and slot_steps matches active_slot_steps."""
+    """The host stats count what the programs' counter accumulator used
+    to count (it only duplicated them, and is gone): on a pure greedy
+    decode run every token but the first of each request comes from a
+    decode step, one a slot a step, and no request left by EOS."""
     model, params = lm
     eng = Engine(model, params, num_slots=2, max_len=32, prefill_chunk=8)
     eng.generate_many(PROMPTS, 8)
     m = eng.metrics()
-    dev = m["device_counters"]
-    assert set(dev) == set(OBS_DEVICE_COUNTERS)
-    assert dev["tokens"] == m["stats"]["tokens"] - len(PROMPTS)
-    assert dev["slot_steps"] == m["stats"]["active_slot_steps"]
-    assert dev["steps"] == m["stats"]["decode_steps"]
-    assert dev["eos_exits"] == 0.0
+    st = m["stats"]
+    assert not {"device_counters", "device_counters_per_model"} & set(m)
+    assert st["tokens"] == 8 * len(PROMPTS)
+    assert st["active_slot_steps"] == st["tokens"] - len(PROMPTS)
+    # one prompt is prefilled a step, so the first request decodes one
+    # step alone before the two decode side by side
+    assert st["decode_steps"] == 8
+    assert st["completed"] == len(PROMPTS)
     # spans cover the whole device-call taxonomy of this run
     assert {"prefill", "sample", "decode"} <= set(m["spans"])
     # lifecycle events landed (admit + finish per request)
@@ -363,11 +365,13 @@ def test_engine_device_counters_match_host_stats(lm):
 
 
 def _children_inside(snapshot, parent="step"):
-    """Every span that is not ``parent`` lies inside one ``parent``."""
+    """Every span that is not ``parent`` lies inside one ``parent`` —
+    but ``exposed``, which runs on through the caller's code between
+    two steps by design."""
     spans = [r for r in snapshot if r["kind"] == "span" and r["dur"] is not None]
     outer = [(r["t0"], r["t0"] + r["dur"]) for r in spans if r["name"] == parent]
     return all(any(a <= r["t0"] and r["t0"] + r["dur"] <= b for a, b in outer)
-               for r in spans if r["name"] != parent)
+               for r in spans if r["name"] not in (parent, "exposed"))
 
 
 def test_engine_step_phase_spans_and_seconds_counters(lm):
@@ -408,10 +412,13 @@ def test_engine_step_phase_spans_and_seconds_counters(lm):
 
 def test_engine_span_totals_are_monotone_past_the_ring(lm):
     """A run several times longer than the ring: every total in
-    ``metrics()["spans"]`` and every seconds counter only grows."""
+    ``metrics()["spans"]`` and every seconds counter only grows.  (The
+    ring still holds one whole step, ~20 records with the phase spans,
+    the exposed clock and the lifecycle events: a span the ring laps
+    while it is open is dropped, by the recorder's contract.)"""
     model, params = lm
     eng = Engine(model, params, num_slots=2, max_len=32, prefill_chunk=8)
-    eng.obs = obs.Recorder(name="serve", capacity=16)
+    eng.obs = obs.Recorder(name="serve", capacity=32)
     handles = [eng.submit(p, 12) for p in PROMPTS * 2]
     prev, laps = None, 0
     while not all(h.done for h in handles):
@@ -513,14 +520,149 @@ def test_engine_obs_off_is_inert_and_parity_neutral(lm):
     assert new <= set(on.metrics()["stats"])
     assert not new & set(eng.metrics()["stats"])
     assert eng.metrics()["spans"] == {}
-    # device counters still accumulate (they ride the programs, not the
-    # host recorder) — metrics() stays truthful either way
-    assert eng.metrics()["device_counters"]["tokens"] > 0
+    # the host stats still count — metrics() stays truthful either way
+    assert eng.metrics()["stats"]["tokens"] == on.metrics()["stats"]["tokens"]
+
+
+# -- the exposed clock -------------------------------------------------
+
+
+def test_recorder_pieces_split_a_span_and_sum_to_its_total():
+    """``piece`` charges an open span's time so far to a part; ``end``
+    charges the last piece; the parts are in ``summary()`` and sum to
+    the span's total, which counts the span once."""
+    rec = obs.Recorder(name="t", capacity=8)
+    tok = rec.begin("exposed")
+    time.sleep(0.002)
+    rec.piece(tok, "a")
+    time.sleep(0.001)
+    rec.piece(tok, "b")
+    rec.piece(tok, "a")
+    rec.end(tok, "c")
+    rec.end(rec.begin("plain"))
+    got = rec.summary()
+    parts = got["exposed"]["parts"]
+    assert set(parts) == {"a", "b", "c"} and parts["a"] >= 0.002
+    assert sum(parts.values()) == pytest.approx(got["exposed"]["total_s"])
+    assert got["exposed"]["count"] == 1 and "parts" not in got["plain"]
+    dur = next(r["dur"] for r in rec.snapshot() if r["name"] == "exposed")
+    assert got["exposed"]["total_s"] == pytest.approx(dur)
+    rec.piece(obs.NO_SPAN, "a")   # no span, no error
+    obs.Recorder(enabled=False).piece(0, "a")
+    rec.clear()
+    assert rec.summary() == {}
+
+
+def test_engine_exposed_clock_is_bounded_and_sums_by_phase(lm):
+    """``exposed_s`` lies between 0 and the time from the first step to
+    the read, its phases sum to it, the dispatch share is a part of it,
+    and ``step``'s self time (the stat is the steps' own, span by span)
+    is under a tenth of a step; an engine without work accrues
+    nothing."""
+    model, params = lm
+    for kw in ({}, {"kv_pages": 12}, {"decode_fuse": 4}):
+        eng = Engine(model, params, num_slots=2, max_len=32,
+                     prefill_chunk=8, **kw)
+        eng.generate_many(PROMPTS, 4)   # compiles outside the reading
+        t0 = time.monotonic()
+        before = eng.metrics()
+        eng.generate_many(PROMPTS * 2, 8)
+        m = eng.metrics()
+        wall = time.monotonic() - t0
+        st = {k: m["stats"][k] - before["stats"][k] for k in m["stats"]}
+        assert 0.0 < st["exposed_s"] <= wall, kw
+        assert 0.0 <= st["exposed_dispatch_s"] <= st["exposed_s"]
+        assert set(m["exposed"]) == set(EXPOSED_PHASES)
+        assert sum(m["exposed"].values()) == pytest.approx(
+            m["stats"]["exposed_s"], rel=1e-9, abs=1e-12)
+        assert m["exposed"]["fetch"] == m["exposed"]["first_token_wait"] == 0
+        # step's self time from its spans in the ring, step by step: it
+        # sums to the stat, and a typical step spends under a tenth of
+        # its time outside its phases (the median, so that one step the
+        # OS preempts does not decide)
+        spans = [r for r in eng.obs.snapshot() if r["kind"] == "span"
+                 and r["dur"] is not None and r["t0"] >= t0
+                 - eng.obs.anchor_monotonic]
+        shares, own = [], 0.0
+        for st_rec in (r for r in spans if r["name"] == "step"):
+            a, b = st_rec["t0"], st_rec["t0"] + st_rec["dur"]
+            kids = sum(r["dur"] for r in spans if r["name"] in STEP_PHASES
+                       and a <= r["t0"] and r["t0"] + r["dur"] <= b)
+            own += st_rec["dur"] - kids
+            shares.append((st_rec["dur"] - kids) / st_rec["dur"])
+        assert own == pytest.approx(st["step_self_s"], rel=1e-6, abs=1e-9)
+        assert 0.0 <= st["step_self_s"] and np.median(shares) < 0.1, kw
+        assert {"chunk", "batch", "keys"} <= set(m["spans"])
+        idle = eng.metrics()["stats"]["exposed_s"]
+        for _ in range(3):
+            eng.step()   # no slot in use, nothing queued
+        assert eng.metrics()["stats"]["exposed_s"] == idle
+
+
+def test_a_sleep_inside_a_dispatch_is_exposed_dispatch_time(lm):
+    """A fault hook that sleeps inside decode dispatches while the device
+    has drained (one request, past its prompt: the previous step's fetch
+    opened the clock) shows up in ``exposed_dispatch_s``, charged to
+    ``decode``."""
+    from tpudp.serve.faults import SlowSteps
+
+    model, params = lm
+    Engine(model, params, num_slots=1, max_len=32,
+           prefill_chunk=8).generate_many(PROMPTS[:1], 2)   # warm
+    hook = SlowSteps({4, 6}, 0.05, kind="decode")
+    eng = Engine(model, params, num_slots=1, max_len=32, prefill_chunk=8,
+                 step_fault_hook=hook)
+    eng.generate_many(PROMPTS[:1], 8)
+    assert len(hook.fired) == 2
+    m = eng.metrics()
+    assert m["stats"]["exposed_dispatch_s"] >= 0.1
+    assert m["exposed"]["decode"] >= 0.1
+    assert m["stats"]["dispatch_s"] >= m["stats"]["exposed_dispatch_s"]
+
+
+class _CountingProbe:
+    """Stands in for the newest device output: counts readiness checks."""
+
+    def __init__(self):
+        self.checks = 0
+
+    def is_ready(self):
+        self.checks += 1
+        return True
+
+
+@pytest.mark.parametrize("path", ["single", "fused", "speculative"])
+def test_exposed_clock_off_runs_no_check_and_keeps_the_tokens(lm, path):
+    """With ``obs=False`` no readiness check runs and the counters are
+    absent; with it on or off the tokens are the same, bit for bit, on
+    the single-step, fused and speculative paths."""
+    model, params = lm
+    kw = {"single": {}, "fused": {"decode_fuse": 4},
+          "speculative": {"speculate_k": 2}}[path]
+    outs = {}
+    for on in (True, False):
+        eng = Engine(model, params, num_slots=2, max_len=40,
+                     prefill_chunk=8, obs=on, **kw)
+        probe = eng._newest = _CountingProbe()
+        outs[on] = eng.generate_many(PROMPTS, 10)
+        m = eng.metrics()
+        if on:  # the clock followed the engine's own device work
+            assert eng._newest is not probe and m["stats"]["exposed_s"] > 0
+            assert path != "speculative" or m["stats"]["verify_steps"] > 0
+        else:
+            assert probe.checks == 0 and eng._newest is probe
+            assert "exposed" not in m
+            assert not {"exposed_s", "exposed_dispatch_s",
+                        "step_self_s"} & set(m["stats"])
+    for a, b in zip(outs[True], outs[False]):
+        assert np.array_equal(a, b)
 
 
 def test_fused_window_counts_eos_exit_on_device(lm):
-    """Only the fused loop knows per-slot eos ids on device — its
-    eos_exits counter must record an in-window EOS exit."""
+    """An EOS the fused loop meets on device stops the row inside the
+    window, and the host replay retires it through ``_commit`` as
+    ``FinishReason.EOS``: the host stats count that exit (the device's
+    own eos counter only duplicated it)."""
     model, params = lm
     probe = Engine(model, params, num_slots=1, max_len=32,
                    prefill_chunk=8)
@@ -537,7 +679,10 @@ def test_fused_window_counts_eos_exit_on_device(lm):
     h = eng.submit(PROMPTS[0], 6, eos_id=eos)
     eng.run_until_complete()
     assert h.finish_reason.value == "eos"
-    assert eng.metrics()["device_counters"]["eos_exits"] == 1.0
+    st = eng.metrics()["stats"]
+    assert h.tokens[-1] == eos and len(h.tokens) < 6
+    assert st["fused_windows"] >= 1 and st["completed"] == 1
+    assert st["tokens"] == len(h.tokens)
 
 
 def test_engine_step_fault_dumps_flight_record(tmp_path, lm):

@@ -97,49 +97,49 @@ TRACE_COUNTER_PROGRAMS = {
 #: peak-live-bytes sweep: a donated buffer's storage is reusable after
 #: its last read, a non-donated one is resident for the whole call.
 PROGRAM_DONATIONS = {
-    "serve.decode_step": (1, 9),
-    "serve.verify_step": (1, 10),
+    "serve.decode_step": (1,),
+    "serve.verify_step": (1,),
     "serve.prefill_chunk": (1,),
-    "serve.fused_decode": (1, 12),
-    "serve.fused_decode_stream": (1, 12),
+    "serve.fused_decode": (1,),
+    "serve.fused_decode_stream": (1,),
     # Paged twins (Engine(kv_pages=N)): the shared page POOL donates in
     # place of the dense arena; the block table is host-authoritative
     # and never donated.  The kernel twins (Engine(paged_attn='kernel')
     # — the TPU default) share their einsum twins' signatures and
     # donation facts program-for-program.
-    "serve.decode_paged": (1, 10),
-    "serve.decode_paged_kernel": (1, 10),
+    "serve.decode_paged": (1,),
+    "serve.decode_paged_kernel": (1,),
     # the latent-attention expert family's two programs (LatentPages pool)
-    "serve.decode_paged_latent": (1, 10),
+    "serve.decode_paged_latent": (1,),
     "serve.prefill_paged_latent": (1,),
     # ... and their twins through the latent_attn Mosaic call
-    "serve.decode_paged_latent_kernel": (1, 10),
+    "serve.decode_paged_latent_kernel": (1,),
     "serve.prefill_paged_latent_kernel": (1,),
     # the window-and-full-attention expert family's two (WindowedPages:
     # both pools donate together; the two tables never)
-    "serve.decode_paged_windowed": (1, 10),
+    "serve.decode_paged_windowed": (1,),
     "serve.prefill_paged_windowed": (1,),
-    "serve.verify_paged": (1, 11),
-    "serve.verify_paged_kernel": (1, 11),
+    "serve.verify_paged": (1,),
+    "serve.verify_paged_kernel": (1,),
     "serve.prefill_paged": (1,),
     "serve.prefill_paged_kernel": (1,),
-    "serve.fused_decode_paged": (1, 13),
-    "serve.fused_decode_paged_stream": (1, 13),
-    "serve.fused_decode_paged_kernel": (1, 13),
+    "serve.fused_decode_paged": (1,),
+    "serve.fused_decode_paged_stream": (1,),
+    "serve.fused_decode_paged_kernel": (1,),
     # On-device speculation (Engine(speculate_k=k, decode_fuse=N,
     # drafter=DraftModelDrafter(...))): the fused draft→verify→accept
-    # while_loop donates the target arena/pool and the counters — the
-    # draft model's KV arena is carry-local scratch, never an argument.
+    # while_loop donates the target arena/pool — the draft model's KV
+    # arena is carry-local scratch, never an argument.
     # The tree-verify window donates like verify_step (its paged twin's
     # accepted-path commit is what makes rejected branches zero-write).
-    "serve.fused_spec_decode": (2, 14),
-    "serve.fused_spec_decode_stream": (2, 14),
-    "serve.fused_spec_paged": (2, 15),
-    "serve.fused_spec_paged_stream": (2, 15),
-    "serve.fused_spec_paged_kernel": (2, 15),
-    "serve.tree_verify": (1, 10),
-    "serve.tree_verify_paged": (1, 11),
-    "serve.tree_verify_paged_kernel": (1, 11),
+    "serve.fused_spec_decode": (2,),
+    "serve.fused_spec_decode_stream": (2,),
+    "serve.fused_spec_paged": (2,),
+    "serve.fused_spec_paged_stream": (2,),
+    "serve.fused_spec_paged_kernel": (2,),
+    "serve.tree_verify": (1,),
+    "serve.tree_verify_paged": (1,),
+    "serve.tree_verify_paged_kernel": (1,),
     "serve.sample_row": (),
     "serve.draft_model": (),
     "prefix.copy_block_in": (0,),
@@ -248,10 +248,6 @@ def _serve_args():
         chunk=np.zeros((1, SERVE["chunk"]), np.int32),
         budgets=np.zeros(s, np.int32),
         eos=np.full(s, -1, np.int32),
-        # OBS_DEVICE_COUNTERS accumulator (tpudp.obs zero-sync device
-        # counters) — the shape the engine passes every decode/verify/
-        # fused call.
-        counts=jnp.zeros((5,), jnp.float32),
     )
     return cfg, params, cache, host
 
@@ -278,11 +274,10 @@ def build_programs() -> dict:
     geo = f"s{SERVE['slots']}m{SERVE['max_len']}"
     programs[f"serve.decode_step@{geo}"] = (
         decode, (params, cache, h["last"], h["lens"], h["active"], h["temps"],
-                 h["topk"], h["topp"], h["keys"], h["counts"]))
+                 h["topk"], h["topp"], h["keys"]))
     programs[f"serve.verify_step@{geo}k{SERVE['k']}"] = (
         verify, (params, cache, h["window"], h["lens"], h["active"], h["ndraft"],
-                 h["temps"], h["topk"], h["topp"], h["keys"],
-                 h["counts"]))
+                 h["temps"], h["topk"], h["topp"], h["keys"]))
     programs[f"serve.prefill_chunk@{geo}c{SERVE['chunk']}"] = (
         prefill, (params, cache, np.int32(0), h["chunk"], np.int32(0),
                   np.int32(SERVE["chunk"] - 1)))
@@ -293,7 +288,7 @@ def build_programs() -> dict:
     # naming the program.
     fused_args = (params, cache, h["last"], h["lens"], h["active"], h["temps"],
                   h["topk"], h["topp"], h["keys"], h["budgets"], h["eos"],
-                  np.int32(-1), h["counts"])
+                  np.int32(-1))
     import functools
 
     programs[f"serve.fused_decode@{geo}n{SERVE['fuse']}"] = (
@@ -311,7 +306,7 @@ def build_programs() -> dict:
     # name.
     spec_args = (params, dparams, cache, h["hist"], h["last"], h["lens"], h["active"],
                  h["temps"], h["topk"], h["topp"], h["keys"],
-                 h["budgets"], h["eos"], np.int32(-1), h["counts"])
+                 h["budgets"], h["eos"], np.int32(-1))
     sgeo = f"{geo}k{SERVE['k']}n{SERVE['fuse']}"
     programs[f"serve.fused_spec_decode@{sgeo}"] = (
         functools.partial(fused_spec, n_draft_k=SERVE["k"],
@@ -325,7 +320,7 @@ def build_programs() -> dict:
     # the lock identity, like n_steps on the fused window).
     tgeo = f"{geo}t{len(TREE_PARENTS)}"
     tree_args = (params, cache, h["tree"], h["lens"], h["active"], h["ndraft"],
-                 h["temps"], h["topk"], h["topp"], h["keys"], h["counts"])
+                 h["temps"], h["topk"], h["topp"], h["keys"])
     programs[f"serve.tree_verify@{tgeo}"] = (
         functools.partial(tree_verify, parents=TREE_PARENTS), tree_args)
     # Paged twins (Engine(kv_pages=N)): same math read through per-slot
@@ -345,12 +340,11 @@ def build_programs() -> dict:
     pgeo2 = f"{geo}p{n_pages}"
     programs[f"serve.decode_paged@{pgeo2}"] = (
         decode_paged, (params, pool, table, h["last"], h["lens"], h["active"],
-                       h["temps"], h["topk"], h["topp"], h["keys"],
-                       h["counts"]))
+                       h["temps"], h["topk"], h["topp"], h["keys"]))
     programs[f"serve.verify_paged@{pgeo2}k{SERVE['k']}"] = (
         verify_paged, (params, pool, table, h["window"], h["lens"], h["active"],
                        h["ndraft"], h["temps"], h["topk"], h["topp"],
-                       h["keys"], h["counts"]))
+                       h["keys"]))
     programs[f"serve.prefill_paged@{pgeo2}c{SERVE['chunk']}"] = (
         prefill_paged, (params, pool, table[0], h["chunk"], np.int32(0),
                         np.int32(SERVE["chunk"] - 1)))
@@ -361,7 +355,7 @@ def build_programs() -> dict:
     fused_paged_args = (
         params, pool, table, h["last"], h["lens"], h["active"], h["temps"],
         h["topk"], h["topp"], h["keys"], h["budgets"], h["eos"],
-        np.int32(-1), h["counts"])
+        np.int32(-1))
     programs[f"serve.fused_decode_paged@{pgeo2}n{SERVE['fuse']}"] = (
         functools.partial(fused_paged, n_steps=SERVE["fuse"], stream=False),
         fused_paged_args)
@@ -376,7 +370,7 @@ def build_programs() -> dict:
     spec_paged_args = (
         params, dparams, pool, table, h["hist"], h["last"], h["lens"], h["active"],
         h["temps"], h["topk"], h["topp"], h["keys"], h["budgets"],
-        h["eos"], np.int32(-1), h["counts"])
+        h["eos"], np.int32(-1))
     programs[f"serve.fused_spec_paged@{pgeo2}k{SERVE['k']}n{SERVE['fuse']}"] = (
         functools.partial(fused_spec_paged, n_draft_k=SERVE["k"],
                           n_steps=SERVE["fuse"], stream=False),
@@ -388,7 +382,7 @@ def build_programs() -> dict:
     programs[f"serve.tree_verify_paged@{pgeo2}t{len(TREE_PARENTS)}"] = (
         functools.partial(tree_paged, parents=TREE_PARENTS),
         (params, pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
-         h["temps"], h["topk"], h["topp"], h["keys"], h["counts"]))
+         h["temps"], h["topk"], h["topp"], h["keys"]))
     # The Pallas kernel twins (Engine(paged_attn='kernel') — the TPU
     # default): same signatures/donations as their einsum twins
     # program-for-program, but the attention contractions run the
@@ -406,11 +400,11 @@ def build_programs() -> dict:
     programs[f"serve.decode_paged_kernel@{pgeo2}"] = (
         decode_paged_kernel,
         (params, pool, table, h["last"], h["lens"], h["active"], h["temps"],
-         h["topk"], h["topp"], h["keys"], h["counts"]))
+         h["topk"], h["topp"], h["keys"]))
     programs[f"serve.verify_paged_kernel@{pgeo2}k{SERVE['k']}"] = (
         verify_k, (params, pool, table, h["window"], h["lens"], h["active"],
                    h["ndraft"], h["temps"], h["topk"], h["topp"],
-                   h["keys"], h["counts"]))
+                   h["keys"]))
     programs[f"serve.prefill_paged_kernel@{pgeo2}c{SERVE['chunk']}"] = (
         prefill_k, (params, pool, table[0], h["chunk"], np.int32(0),
                     np.int32(SERVE["chunk"] - 1)))
@@ -425,7 +419,7 @@ def build_programs() -> dict:
     programs[f"serve.tree_verify_paged_kernel@{pgeo2}t{len(TREE_PARENTS)}"] = (
         functools.partial(tree_k, parents=TREE_PARENTS),
         (params, pool, table, h["tree"], h["lens"], h["active"], h["ndraft"],
-         h["temps"], h["topk"], h["topp"], h["keys"], h["counts"]))
+         h["temps"], h["topk"], h["topp"], h["keys"]))
 
     # The latent-attention expert family (tpudp/models/pangu.py): its two
     # programs over a LatentPages pool, at the same smoke geometry.  The
@@ -446,8 +440,7 @@ def build_programs() -> dict:
     lpool = LatentPages.zeros(lcfg, n_pages + 1, SERVE["chunk"])
     programs[f"serve.decode_paged_latent@{pgeo2}"] = (
         lsteps[6], (lparams, lpool, table, h["last"], h["lens"], h["active"],
-                    h["temps"], h["topk"], h["topp"], h["keys"],
-                    h["counts"]))
+                    h["temps"], h["topk"], h["topp"], h["keys"]))
     programs[f"serve.prefill_paged_latent@{pgeo2}c{SERVE['chunk']}"] = (
         lsteps[8], (lparams, lpool, table[0], h["chunk"], np.int32(0),
                     np.int32(SERVE["chunk"] - 1)))
@@ -480,7 +473,7 @@ def build_programs() -> dict:
     programs[f"serve.decode_paged_windowed@{pgeo2}"] = (
         wsteps[6], (wparams, wpool, (table, table), h["last"], h["lens"],
                     h["active"], h["temps"], h["topk"], h["topp"],
-                    h["keys"], h["counts"]))
+                    h["keys"]))
     programs[f"serve.prefill_paged_windowed@{pgeo2}c{SERVE['chunk']}"] = (
         wsteps[8], (wparams, wpool, (table[0], table[0]), h["chunk"],
                     np.int32(0), np.int32(SERVE["chunk"] - 1)))

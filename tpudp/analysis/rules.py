@@ -60,25 +60,22 @@ DEVICE_CALL_ATTRS = {
 #: Known donating callables (attribute or bare name) → donated
 #: positional indices.  Mirrors the ``donate_argnums`` at their build
 #: sites; locally-defined jit functions are additionally discovered
-#: from their own decorators.  The second index on the serve step
-#: programs is the OBS_DEVICE_COUNTERS accumulator (tpudp.obs) — tiny,
-#: but donated like the arena, so a read of the stale counters buffer
-#: after a step is the same class of bug as a stale-cache read.
+#: from their own decorators.
 DONATING = {
-    "decode_step": (0, 8), "verify_step": (0, 9), "prefill_step": (0,),
-    "fused_step": (0, 11), "train_step": (0,), "copy_block_in": (0,),
+    "decode_step": (0,), "verify_step": (0,), "prefill_step": (0,),
+    "fused_step": (0,), "train_step": (0,), "copy_block_in": (0,),
     "copy_block_out": (1,),
     # Paged twins (Engine(kv_pages=N)): the shared page pool donates in
     # the dense arena's place (the block table never does — it is
     # host-authoritative and uploaded per call).
-    "decode_paged": (0, 9), "verify_paged": (0, 10),
-    "prefill_paged": (0,), "fused_paged": (0, 12),
+    "decode_paged": (0,), "verify_paged": (0,),
+    "prefill_paged": (0,), "fused_paged": (0,),
     # On-device speculation (ISSUE 16): the fused speculative window and
-    # the tree-verify window donate the target arena/pool + the obs
-    # counters; the draft model's KV is carry-local scratch, never an
-    # argument, so it has no donation row.
-    "fused_spec_step": (0, 12), "fused_spec_paged": (0, 13),
-    "tree_step": (0, 9), "tree_paged": (0, 10),
+    # the tree-verify window donate the target arena/pool; the draft
+    # model's KV is carry-local scratch, never an argument, so it has no
+    # donation row.
+    "fused_spec_step": (0,), "fused_spec_paged": (0,),
+    "tree_step": (0,), "tree_paged": (0,),
 }
 
 #: Pass-through wrappers: ``self._device("kind", fn, *args)`` runs
@@ -152,7 +149,7 @@ SYNC_METHODS = {"item", "tolist", "block_until_ready"}
 #: tpudp.obs recorder API split (the obs-in-hot-path rule): the
 #: ALLOCATION-FREE calls sanctioned on designated hot paths vs the
 #: convenient allocating forms that belong off them.
-OBS_FAST_METHODS = {"begin", "end", "count"}
+OBS_FAST_METHODS = {"begin", "end", "piece", "count"}
 OBS_ALLOC_METHODS = {"span", "event"}
 
 

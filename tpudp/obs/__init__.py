@@ -10,12 +10,12 @@ One subsystem, four layers (docs/OBSERVABILITY.md):
     spans also add to per-name cumulative totals that never lap, and
     under a JAX profiler session each is a ``tpudp.<recorder>.<span>``
     annotation on the trace's clock.
-  * **Zero-sync device counters**: per-step scalars accumulated INSIDE
-    the existing step programs (``tpudp/serve/engine.py``
-    ``OBS_DEVICE_COUNTERS``) and carried in the arrays the engine
-    already shuttles — fetched only by ``Engine.metrics()`` snapshots,
-    never on a hot path, so ``tpudp.analysis lint`` stays at zero
-    host-sync findings.
+  * **Zero-sync device counters**: values accumulated INSIDE the
+    existing step programs and carried in the arrays the runtime
+    already fetches (the trainer's ``obs_norms`` / ``obs_moe``; the
+    serving expert layers' ``moe_*`` counts, which ride the token
+    fetch) — never a fetch of their own on a hot path, so
+    ``tpudp.analysis lint`` stays at zero host-sync findings.
   * **Flight recorder** (:mod:`tpudp.obs.flight`): the ring persists as
     a per-host ``flightrec-*.json`` on watchdog timeouts, step-failure
     containment, and resilience rollbacks — enable by directory

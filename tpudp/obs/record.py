@@ -11,17 +11,17 @@ to observe.  So the core is a **preallocated monotonic-clock ring**:
     preallocated ring record and returns an integer token; ``end``
     stamps t1 into that record iff the ring has not lapped it.  Two
     ``time.monotonic()`` reads and a few attribute stores per span; no
-    container growth, no device touch.  The ``obs-in-hot-path`` lint
-    rule pins exactly this API as the only one allowed inside the
-    designated hot paths.
+    container growth, no device touch.  :meth:`Recorder.piece` splits
+    an open span's time by what the program was doing meanwhile (the
+    serve engine's ``exposed`` span by its phases) at one more clock
+    read a piece.  The ``obs-in-hot-path`` lint rule pins exactly this
+    API as the only one allowed inside the designated hot paths.
   * :meth:`Recorder.event` / :meth:`Recorder.span` — the convenient
     (allocating) API for everything OFF the hot path: request
     admission/retirement, recovery decisions, checkpoint writes.
     Events carry a ``**fields`` dict; ``span`` is a context manager.
   * :meth:`Recorder.count` — host-side named counters (a plain
-    ``Counter``); the device-side zero-sync counters live in the step
-    programs (``tpudp/serve/engine.py``) and are only *fetched* here by
-    ``metrics()`` snapshots, never on a hot path.
+    ``Counter``).
 
 The ring holds the last ``capacity`` records per recorder — old
 telemetry is dropped, never compacted; that bounded-loss contract is
@@ -29,8 +29,8 @@ what makes the recorder safe to leave on in production and is exactly
 what the flight recorder (``tpudp/obs/flight.py``) wants: the last N
 spans before a fault ARE the black box.  What must NOT be lost when the
 ring laps are the totals: ``end`` also adds every closed span to a
-cumulative per-name ``(count, total_s)`` that :meth:`Recorder.summary`
-returns — monotone counters a window delta or a Prometheus ``rate()``
+cumulative per-name ``(count, total_s)`` (and a pieced span's parts) that
+:meth:`Recorder.summary` returns — monotone counters a window delta or a Prometheus ``rate()``
 can be taken of.
 
 Timestamps are ``time.monotonic()`` (immune to wall-clock steps); each
@@ -62,13 +62,14 @@ class _Rec:
     """One preallocated ring slot, reused in place (never reallocated —
     the hot path only stores into existing attributes)."""
 
-    __slots__ = ("seq", "kind", "name", "t0", "t1", "fields", "ann")
+    __slots__ = ("seq", "kind", "name", "t0", "tp", "t1", "fields", "ann")
 
     def __init__(self):
         self.seq = -1       # ring generation; -1 = never written
         self.kind = ""      # "span" | "event"
         self.name = ""
         self.t0 = 0.0
+        self.tp = 0.0       # start of the span's piece not yet charged
         self.t1 = -1.0      # -1.0 = span still open
         self.fields = None  # dict for events / tagged spans, else None
         self.ann = None     # the open TraceAnnotation under a profiler
@@ -85,7 +86,7 @@ class Recorder:
     __slots__ = ("name", "enabled", "capacity", "counters",
                  "anchor_monotonic", "anchor_wall",
                  "_ring", "_seq", "_last_done", "_id", "_totals",
-                 "_annotation", "_trace_prefix")
+                 "_parts", "_annotation", "_trace_prefix")
 
     def __init__(self, name: str = "", capacity: int = 4096,
                  enabled: bool = True):
@@ -102,6 +103,7 @@ class Recorder:
         self._last_done = NO_SPAN
         self._id = next(_RECORDER_IDS)
         self._totals: dict[str, list] = {}  # name -> [count, total_s]
+        self._parts: dict[str, dict] = {}   # name -> {part: seconds}
         self._annotation = None  # jax.profiler.TraceAnnotation, once seen
         self._trace_prefix = f"tpudp.{name}." if name else "tpudp."
 
@@ -128,7 +130,7 @@ class Recorder:
             rec.ann.__enter__()
         else:
             rec.ann = None
-        rec.t0 = time.monotonic()
+        rec.t0 = rec.tp = time.monotonic()
         self._seq = seq + 1
         return seq
 
@@ -141,10 +143,11 @@ class Recorder:
         self._annotation = getattr(profiler, "TraceAnnotation", None)
         return self._annotation
 
-    def end(self, token: int) -> None:
-        """Close the span ``begin`` opened.  A token the ring has since
-        lapped (or :data:`NO_SPAN`) is silently dropped — bounded loss,
-        never an error, never a stall."""
+    def end(self, token: int, part: str | None = None) -> None:
+        """Close the span ``begin`` opened; ``part`` takes the span's last
+        piece (see :meth:`piece`).  A token the ring has since lapped (or
+        :data:`NO_SPAN`) is silently dropped — bounded loss, never an
+        error, never a stall."""
         if token < 0 or not self.enabled:
             return
         rec = self._ring[token % self.capacity]
@@ -154,11 +157,36 @@ class Recorder:
             if rec.ann is not None:
                 rec.ann.__exit__(None, None, None)
                 rec.ann = None
-            total = self._totals.get(rec.name)
-            if total is None:
-                total = self._totals[rec.name] = [0, 0.0]
-            total[0] += 1
-            total[1] += t1 - rec.t0
+            self._charge(rec, part, t1)[0] += 1
+
+    def piece(self, token: int, part: str) -> None:
+        """Charge the open span ``token``'s time since its last piece (or
+        its begin) to ``part``: a span whose time is split by what the
+        program was doing while it ran.  Each piece adds to the span's
+        total as it is charged, so a name's parts always sum to its
+        ``total_s``; the count grows when the span ends.  Hot-path safe
+        like :meth:`end`: one clock read and a few stores."""
+        if token < 0 or not self.enabled:
+            return
+        rec = self._ring[token % self.capacity]
+        if rec.seq == token:
+            self._charge(rec, part, time.monotonic())
+
+    def _charge(self, rec: _Rec, part: str | None, t: float) -> list:
+        """Add ``rec``'s piece up to ``t`` to its totals (and to ``part``'s
+        share of them); returns the totals' ``[count, total_s]``."""
+        total = self._totals.get(rec.name)
+        if total is None:
+            total = self._totals[rec.name] = [0, 0.0]
+        dt = t - rec.tp
+        total[1] += dt
+        if part is not None:
+            parts = self._parts.get(rec.name)
+            if parts is None:
+                parts = self._parts[rec.name] = {}
+            parts[part] = parts.get(part, 0.0) + dt
+        rec.tp = t
+        return total
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump a host-side named counter (Counter add — hot-path safe)."""
@@ -247,9 +275,13 @@ class Recorder:
         ``{name: {"count": n, "total_s": s}}``.  Monotone, so two
         snapshots' difference is a window's time and the Prometheus
         exposition of it can be ``rate()``d — the rollup ``metrics()``
-        snapshots embed."""
-        return {name: {"count": n, "total_s": s}
-                for name, (n, s) in list(self._totals.items())}
+        snapshots embed.  A span charged in pieces (:meth:`piece`) also
+        has ``"parts"``: ``{part: seconds}``, summing to its total."""
+        out = {name: {"count": n, "total_s": s}
+               for name, (n, s) in list(self._totals.items())}
+        for name, parts in list(self._parts.items()):
+            out[name]["parts"] = dict(parts)
+        return out
 
     def clear(self) -> None:
         self._seq = 0
@@ -259,3 +291,4 @@ class Recorder:
             rec.ann = None
         self.counters.clear()
         self._totals.clear()
+        self._parts.clear()

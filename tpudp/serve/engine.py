@@ -205,16 +205,18 @@ device call rides an allocation-free span named after its kind (the
 ``_device`` seam — the same names the fault injectors and watchdog
 regions use), request lifecycle lands as events off the hot path
 (admit/finish/preempt/quarantine/containment, tenant+priority tagged),
-and each model's step programs accumulate ZERO-SYNC device counters
-(``OBS_DEVICE_COUNTERS``) fetched only by :meth:`Engine.metrics` —
+the rest of ``step`` is spanned by phase, and the ``exposed`` span
+clocks the device's waits from inside: it is open exactly while the
+engine knows none of its programs is in flight (found by its blocking
+fetches and by ``is_ready()`` checks at the phase boundaries), and its
+time is charged to the phase the host was in (``EXPOSED_PHASES``) —
 telemetry adds no host sync to any designated hot path, which
 ``tpudp.analysis lint`` enforces.  Step-failure containment and
 watchdog timeouts dump the span ring to per-host flight records
 (``flight_dir`` / ``TPUDP_FLIGHT_DIR``; no directory = no writes), so
 a kill always leaves a timeline naming the failing region.
-``obs=False`` no-ops the host recorder (the device counters still
-ride the programs); the default engine's outputs, stats schema, and
-trace counts are unchanged either way.
+``obs=False`` no-ops the recorder and the exposed clock (no readiness
+check runs); outputs and trace counts are the same either way.
 """
 
 from __future__ import annotations
@@ -239,7 +241,7 @@ from tpudp.models.generate import (Int8Pages, KVCache, _forward_cached,
                                    page_type, update_cache_rows,
                                    validate_decode_config,
                                    write_token_pages)
-from tpudp.obs import FlightRecorder, Recorder
+from tpudp.obs import NO_SPAN, FlightRecorder, Recorder
 from tpudp.ops.sampling import (sample_tokens, split_keys, tree_depths,
                                 verify_tokens, verify_tree_tokens)
 
@@ -248,24 +250,6 @@ from tpudp.ops.sampling import (sample_tokens, split_keys, tree_depths,
 # compiles ONCE per engine geometry no matter how many requests churn
 # through the slots.
 TRACE_COUNTS = collections.Counter()
-
-#: Zero-sync device counters (tpudp.obs layer 2): per-step scalars
-#: accumulated INSIDE the step programs, in this order, in a tiny
-#: float32 vector each program takes (donated) and returns alongside
-#: its existing outputs — the counter values ride the result tuples the
-#: engine already fetches at window edges, so the telemetry adds no new
-#: device_get to any designated hot path (``tpudp.analysis lint``
-#: enforces that; ``Engine.metrics()`` is the only reader and fetches
-#: OFF the hot path).  "eos_exits" is counted only where the program
-#: knows the per-slot eos ids (the fused decode loop); the single-step
-#: paths account EOS on the host via FinishReason, as before.
-OBS_DEVICE_COUNTERS = ("steps", "tokens", "slot_steps",
-                       "draft_accepted", "eos_exits")
-
-
-def _zero_obs_counts():
-    return jnp.zeros((len(OBS_DEVICE_COUNTERS),), jnp.float32)
-
 
 #: Span names of the ``_device`` seam: each times the DISPATCH of one
 #: step program (asynchronous — the device's own time is felt in the
@@ -282,7 +266,24 @@ OBS_PHASE_SECONDS = {
     "dispatch_s": DEVICE_SPANS,
     "first_token_wait_s": ("first_token_wait",), "pages_s": ("pages",),
     "fetch_wait_s": ("fetch",), "commit_s": ("commit",),
+    "chunk_s": ("chunk",), "keys_s": ("keys",), "batch_s": ("batch",),
+    "canary_s": ("canary",),
 }
+
+#: The spans that are ``step``'s direct children: its time in none of
+#: them is the scheduler's own, ``stats["step_self_s"]``.  The prefix
+#: cache's block copies are not among them: they are dispatched inside
+#: ``admit`` and ``commit``.
+STEP_PHASES = ("canary", "admit", "chunk", "keys", "first_token_wait",
+               "commit", "batch", "pages", "fetch",
+               *(k for k in DEVICE_SPANS if not k.startswith("prefix_")))
+
+#: Where the ``exposed`` span's time goes (``Engine.metrics()
+#: ["exposed"]``): the phase the host was in while none of the engine's
+#: device work was in flight — a child of ``step``, a block copy's
+#: dispatch, ``step``'s own time, or ``caller``, the caller's code
+#: between two ``step`` calls.
+EXPOSED_PHASES = ("caller", "step", *STEP_PHASES, "prefix_in", "prefix_out")
 
 
 class FinishReason(str, enum.Enum):
@@ -377,52 +378,41 @@ def _stream_tap(ring_id, toks, running) -> None:
 
 
 def _decode_math(forward, state, last_tokens, lengths, active, temps,
-                 top_k, top_p, keys, counts):
+                 top_k, top_p, keys):
     """The ONE decode-step body shared by the dense and paged programs:
     ``forward`` hides the KV indirection (dense arena row writes vs
     page gather/scatter — it receives ``active`` so the paged scatter
     can mask), everything else — sampling, the per-slot PRNG advance
-    discipline, the OBS counter stacking — exists exactly once, so the
-    two twins can never drift apart."""
+    discipline — exists exactly once, so the two twins can never drift
+    apart."""
     logits, state = forward(state, last_tokens[:, None], lengths, active)
     carry, sub = split_keys(keys)
     toks = sample_tokens(logits[:, 0], temps, top_k, top_p, sub)
     # Only rows that actually sampled advance their key chain — a
     # request's draw stream must not depend on co-resident requests.
     new_keys = jnp.where(active[:, None], carry, keys)
-    zero = jnp.zeros((), counts.dtype)
-    one = jnp.ones((), counts.dtype)
-    act = jnp.sum(active).astype(counts.dtype)
-    new_counts = counts + jnp.stack([one, act, act, zero, zero])
-    return state, toks, new_keys, new_counts
+    return state, toks, new_keys
 
 
 def _verify_math(forward, state, tokens, lengths, active, n_draft,
-                 temps, top_k, top_p, keys, counts):
+                 temps, top_k, top_p, keys):
     """The ONE speculative-verify body shared by the dense and paged
     programs (window scoring, longest-agreeing-prefix acceptance, PRNG
-    and counter discipline — see :func:`_decode_math`)."""
+    discipline — see :func:`_decode_math`)."""
     logits, state = forward(state, tokens, lengths, active)
     carry, sub = split_keys(keys)
     out, n_emit = verify_tokens(logits, tokens[:, 1:], n_draft,
                                 temps, top_k, top_p, sub)
     new_keys = jnp.where(active[:, None], carry, keys)
-    zero = jnp.zeros((), counts.dtype)
-    one = jnp.ones((), counts.dtype)
-    act = jnp.sum(active).astype(counts.dtype)
-    emitted = jnp.sum(jnp.where(active, n_emit, 0)).astype(counts.dtype)
-    accepted = jnp.sum(jnp.where(active & (n_draft > 0), n_emit - 1,
-                                 0)).astype(counts.dtype)
-    new_counts = counts + jnp.stack([one, emitted, act, accepted, zero])
-    return state, out, n_emit, new_keys, new_counts
+    return state, out, n_emit, new_keys
 
 
 def _fused_decode_math(forward, state, last_tokens, lengths, active,
                        temps, top_k, top_p, keys, budgets, eos_ids,
-                       ring_id, counts, *, n_steps, stream):
+                       ring_id, *, n_steps, stream):
     """The ONE fused-window ``lax.while_loop`` shared by the dense and
     paged programs: loop carry, early-exit predicate, per-iteration
-    commit/PRNG/counter discipline, and the optional ordered
+    commit/PRNG discipline, and the optional ordered
     ``io_callback`` stream tap all exist exactly once — only the
     per-iteration ``forward`` (arena vs page indirection) differs."""
     n_slots = last_tokens.shape[0]
@@ -430,12 +420,11 @@ def _fused_decode_math(forward, state, last_tokens, lengths, active,
     n_emit0 = jnp.zeros((n_slots,), jnp.int32)
 
     def cond(carry):
-        (i, _state, _last, _lens, running, _keys, _out, _n_emit,
-         _counts) = carry
+        i, _state, _last, _lens, running, _keys, _out, _n_emit = carry
         return (i < n_steps) & jnp.any(running)
 
     def body(carry):
-        i, state, last, lens, running, keys, out, n_emit, counts = carry
+        i, state, last, lens, running, keys, out, n_emit = carry
         logits, state = forward(state, last[:, None], lens, running)
         carry_keys, sub = split_keys(keys)
         toks = sample_tokens(logits[:, 0], temps, top_k, top_p, sub)
@@ -454,26 +443,19 @@ def _fused_decode_math(forward, state, last_tokens, lengths, active,
         col = jnp.arange(n_steps)[None, :] == n_emit[:, None]
         out = jnp.where(col & running[:, None], toks[:, None], out)
         n_emit = jnp.where(running, n_emit + 1, n_emit)
-        zero = jnp.zeros((), counts.dtype)
-        one = jnp.ones((), counts.dtype)
-        run = jnp.sum(running).astype(counts.dtype)
-        eos_now = jnp.sum(running & (toks == eos_ids)).astype(
-            counts.dtype)
-        counts = counts + jnp.stack([one, run, run, zero, eos_now])
         running = running & (toks != eos_ids) & (n_emit < budgets)
-        return (i + 1, state, toks, lens, running, keys, out, n_emit,
-                counts)
+        return i + 1, state, toks, lens, running, keys, out, n_emit
 
-    iters, state, _last, _lens, _running, keys, out, n_emit, counts = (
+    iters, state, _last, _lens, _running, keys, out, n_emit = (
         lax.while_loop(cond, body,
                        (jnp.int32(0), state, last_tokens, lengths,
-                        active, keys, out0, n_emit0, counts)))
-    return state, out, n_emit, keys, iters, counts
+                        active, keys, out0, n_emit0)))
+    return state, out, n_emit, keys, iters
 
 
 def _fused_spec_math(forward, draft_cfg, draft_params, state, hist,
                      last_tokens, lengths, active, temps, top_k, top_p,
-                     keys, budgets, eos_ids, ring_id, counts, *,
+                     keys, budgets, eos_ids, ring_id, *,
                      n_draft_k, n_steps, stream,
                      chunk_draft_prefill=False):
     """The ONE fused speculative-decode ``lax.while_loop`` shared by the
@@ -521,12 +503,12 @@ def _fused_spec_math(forward, draft_cfg, draft_params, state, hist,
 
     def cond(carry):
         (i, _state, _hist, _last, _lens, running, _keys, _out, _n_emit,
-         _n_win, _n_acc, _counts) = carry
+         _n_win, _n_acc) = carry
         return (i < n_steps) & jnp.any(running)
 
     def body(carry):
         (i, state, hist, last, lens, running, keys, out, n_emit, n_win,
-         n_acc, counts) = carry
+         n_acc) = carry
         carry_keys, sub = split_keys(keys)
         # -- draft: k greedy tokens per slot from the draft model (the
         # batched _draft_greedy), re-prefilled from hist each window.
@@ -620,22 +602,15 @@ def _fused_spec_math(forward, draft_cfg, draft_params, state, hist,
         acc = jnp.where(running & (nd > 0), n_w - 1, 0)
         n_acc = n_acc + acc
         hit_eos = running & (take == eos_at + 1)
-        one = jnp.ones((), counts.dtype)
-        counts = counts + jnp.stack(
-            [one, jnp.sum(take).astype(counts.dtype),
-             jnp.sum(running).astype(counts.dtype),
-             jnp.sum(acc).astype(counts.dtype),
-             jnp.sum(hit_eos).astype(counts.dtype)])
         running = running & ~hit_eos & (n_emit < budgets)
         return (i + 1, state, hist, last, lens, running, keys, out,
-                n_emit, n_win, n_acc, counts)
+                n_emit, n_win, n_acc)
 
     (iters, state, _hist, _last, _lens, _running, keys, out, n_emit,
-     n_win, n_acc, counts) = lax.while_loop(
+     n_win, n_acc) = lax.while_loop(
         cond, body, (jnp.int32(0), state, hist, last_tokens, lengths,
-                     active, keys, out0, zeros_i, zeros_i, zeros_i,
-                     counts))
-    return state, out, n_emit, n_win, n_acc, keys, iters, counts
+                     active, keys, out0, zeros_i, zeros_i, zeros_i))
+    return state, out, n_emit, n_win, n_acc, keys, iters
 
 
 def _ancestor_matrix(parents: tuple) -> tuple:
@@ -656,8 +631,7 @@ def _ancestor_matrix(parents: tuple) -> tuple:
 
 
 def _tree_verify_math(forward, commit, state, tokens, lengths, active,
-                      n_cand, temps, top_k, top_p, keys, counts, *,
-                      parents):
+                      n_cand, temps, top_k, top_p, keys, *, parents):
     """The ONE tree-verify body shared by the dense and paged programs:
     score a static tree of candidate branches (``tokens`` ``(slots,
     T+1)``, node 0 = each row's last token) in a single tree-masked
@@ -667,8 +641,8 @@ def _tree_verify_math(forward, commit, state, tokens, lengths, active,
     window K/V instead of writing it (the no-write tree twins), and
     ``commit`` lands path node ``d``'s vectors at position ``lens + d``
     (dense arena-row writes, or PR 14 single-page writes where rejected
-    branches route to the scratch page: zero pool writes).  PRNG and
-    counter discipline are ``_verify_math``'s verbatim; the returned
+    branches route to the scratch page: zero pool writes).  The PRNG
+    discipline is ``_verify_math``'s verbatim; the returned
     tuple has the verify step's exact shape so the host replay seam is
     shared."""
     depths = tree_depths(parents)
@@ -680,14 +654,7 @@ def _tree_verify_math(forward, commit, state, tokens, lengths, active,
                                            top_k, top_p, sub)
     new_keys = jnp.where(active[:, None], carry, keys)
     state = commit(state, wk, wv, lengths, path, n_emit, active)
-    zero = jnp.zeros((), counts.dtype)
-    one = jnp.ones((), counts.dtype)
-    act = jnp.sum(active).astype(counts.dtype)
-    emitted = jnp.sum(jnp.where(active, n_emit, 0)).astype(counts.dtype)
-    accepted = jnp.sum(jnp.where(active & (n_cand > 0), n_emit - 1,
-                                 0)).astype(counts.dtype)
-    new_counts = counts + jnp.stack([one, emitted, act, accepted, zero])
-    return state, out, n_emit, new_keys, new_counts
+    return state, out, n_emit, new_keys
 
 
 def _moe_counts(routed):
@@ -712,9 +679,9 @@ def _build_latent_steps(cfg, paged_attn: str):
     its own) or ``'einsum'`` (XLA contractions a page tile)."""
     kernel = paged_attn == "kernel"
 
-    @functools.partial(jax.jit, donate_argnums=(1, 10))
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step_paged(params, pool, table, last_tokens, lengths,
-                          active, temps, top_k, top_p, keys, counts):
+                          active, temps, top_k, top_p, keys):
         """One token for every slot through the latent page pool: the
         shared ``_decode_math`` body over ``generate._forward_paged``'s
         third family (absorbed attention through ``table``, the dropless
@@ -730,7 +697,7 @@ def _build_latent_steps(cfg, paged_attn: str):
                                   active, paged_attn, routed=routed)
 
         return (*_decode_math(fwd, pool, last_tokens, lengths, active,
-                              temps, top_k, top_p, keys, counts),
+                              temps, top_k, top_p, keys),
                 _moe_counts(routed))
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -763,9 +730,9 @@ def _build_windowed_steps(cfg, paged_attn: str):
     entries behind the window freed by the host before dispatch.
     ``paged_attn``: ``'kernel'`` or ``'einsum'``."""
 
-    @functools.partial(jax.jit, donate_argnums=(1, 10))
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step_paged(params, pool, table, last_tokens, lengths,
-                          active, temps, top_k, top_p, keys, counts):
+                          active, temps, top_k, top_p, keys):
         """One token for every slot through the two page pools: the
         shared ``_decode_math`` body over ``generate._forward_paged``'s
         fourth family."""
@@ -777,7 +744,7 @@ def _build_windowed_steps(cfg, paged_attn: str):
                                   lengths, active, paged_attn, routed=routed)
 
         return (*_decode_math(fwd, pool, last_tokens, lengths, active,
-                              temps, top_k, top_p, keys, counts),
+                              temps, top_k, top_p, keys),
                 _moe_counts(routed))
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -860,24 +827,22 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             return _forward_cached(cfg, params, tokens, cache, lengths)
         return fwd
 
-    @functools.partial(jax.jit, donate_argnums=(1, 9))
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step(params, cache, last_tokens, lengths, active, temps,
-                    top_k, top_p, keys, counts):
+                    top_k, top_p, keys):
         """One token for every slot: feed each row's last token at its
         own depth, sample per-row (``_decode_math`` — the body shared
         with the paged twin).  All sampling params and positions
         are traced arrays, so this compiles once per (num_slots,
         max_len).  The cache is donated: XLA updates the arena in place
-        instead of copying it every step.  ``counts`` is the
-        OBS_DEVICE_COUNTERS accumulator (donated too — a handful of
-        float adds riding the step, fetched only by metrics())."""
+        instead of copying it every step."""
         TRACE_COUNTS["decode_step"] += 1
         return _decode_math(_dense_fwd(params), cache, last_tokens, lengths,
-                            active, temps, top_k, top_p, keys, counts)
+                            active, temps, top_k, top_p, keys)
 
-    @functools.partial(jax.jit, donate_argnums=(1, 10))
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def verify_step(params, cache, tokens, lengths, active, n_draft, temps,
-                    top_k, top_p, keys, counts):
+                    top_k, top_p, keys):
         """One speculative window for every slot: feed each row's
         ``[last, d_0 .. d_{k-1}]`` window at its own depth, accept the
         longest draft prefix the target model agrees with
@@ -891,13 +856,13 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
         become visible, like every other masked write in the arena)."""
         TRACE_COUNTS["verify_step"] += 1
         return _verify_math(_dense_fwd(params), cache, tokens, lengths, active,
-                            n_draft, temps, top_k, top_p, keys, counts)
+                            n_draft, temps, top_k, top_p, keys)
 
-    @functools.partial(jax.jit, donate_argnums=(1, 12),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("n_steps", "stream"))
     def fused_decode_step(params, cache, last_tokens, lengths, active, temps,
                           top_k, top_p, keys, budgets, eos_ids, ring_id,
-                          counts, *, n_steps, stream=False):
+                          *, n_steps, stream=False):
         """Up to ``n_steps`` decode iterations in ONE device program: a
         ``lax.while_loop`` whose body is exactly the decode step's math
         (same vector-position forward, same per-row masked sampling, the
@@ -916,17 +881,15 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
         ``stream`` (static) an ordered ``io_callback`` taps each
         iteration's committed tokens into the host ring buffer named by
         ``ring_id`` — an observability side channel, never the commit
-        path.  ``counts`` (the OBS_DEVICE_COUNTERS accumulator) rides
-        the loop carry: steps/tokens per iteration plus the EOS exits
-        only this program can see on device.  Returns ``(cache, out,
-        n_emit, keys, iters, counts)``; the ONE host fetch per window
+        path.  Returns ``(cache, out, n_emit, keys, iters)``; the ONE
+        host fetch per window
         replaces the per-token fetch.  Loop body/carry/predicate live
         in ``_fused_decode_math`` — the one copy shared with the paged
         twin."""
         TRACE_COUNTS["fused_decode"] += 1
         return _fused_decode_math(
             _dense_fwd(params), cache, last_tokens, lengths, active, temps,
-            top_k, top_p, keys, budgets, eos_ids, ring_id, counts,
+            top_k, top_p, keys, budgets, eos_ids, ring_id,
             n_steps=n_steps, stream=stream)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -952,12 +915,12 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
     if draft_cfg is None:
         fused_spec_step = None
     else:
-        @functools.partial(jax.jit, donate_argnums=(2, 14),
+        @functools.partial(jax.jit, donate_argnums=(2,),
                            static_argnames=("n_draft_k", "n_steps",
                                             "stream"))
         def fused_spec_step(params, draft_params, cache, hist,
                             last_tokens, lengths, active, temps, top_k,
-                            top_p, keys, budgets, eos_ids, ring_id, counts,
+                            top_p, keys, budgets, eos_ids, ring_id,
                             *, n_draft_k, n_steps, stream=False):
             """Up to ``n_steps`` SPECULATIVE windows in ONE device
             program: each ``lax.while_loop`` iteration drafts
@@ -971,14 +934,14 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             context; committed tokens scatter back into it between
             windows).  Compiles once per (num_slots, max_len, k,
             n_steps); returns ``(cache, out, n_emit, n_windows,
-            n_accepted, keys, iters, counts)`` with ONE host fetch per
+            n_accepted, keys, iters)`` with ONE host fetch per
             multi-window program — the per-window draft gather AND
             verify fetch are gone."""
             TRACE_COUNTS["fused_spec_decode"] += 1
             return _fused_spec_math(
                 _dense_fwd(params), draft_cfg, draft_params, cache, hist,
                 last_tokens, lengths, active, temps, top_k, top_p, keys,
-                budgets, eos_ids, ring_id, counts, n_draft_k=n_draft_k,
+                budgets, eos_ids, ring_id, n_draft_k=n_draft_k,
                 n_steps=n_steps, stream=stream)
 
     def _tree_dense_fwd(params):
@@ -1007,10 +970,10 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
                 v_all, vsel, lengths + d)
         return KVCache(k_all, v_all)
 
-    @functools.partial(jax.jit, donate_argnums=(1, 10),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("parents",))
     def tree_verify_step(params, cache, tokens, lengths, active, n_cand, temps,
-                         top_k, top_p, keys, counts, *, parents):
+                         top_k, top_p, keys, *, parents):
         """One speculative TREE window for every slot
         (``Engine(speculate_tree=shape)``): ``tokens`` ``(num_slots,
         T+1)`` holds each row's last token at node 0 and the drafter's
@@ -1027,7 +990,7 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
         return _tree_verify_math(
             _tree_dense_fwd(params), _tree_dense_commit, cache, tokens,
             lengths,
-            active, n_cand, temps, top_k, top_p, keys, counts,
+            active, n_cand, temps, top_k, top_p, keys,
             parents=parents)
 
     # -- paged twins (Engine(kv_pages=N)): identical math read through
@@ -1056,9 +1019,9 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
         return fwd
 
     if paged_attn == "kernel":
-        @functools.partial(jax.jit, donate_argnums=(1, 10))
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def decode_step_paged(params, pool, table, last_tokens, lengths,
-                              active, temps, top_k, top_p, keys, counts):
+                              active, temps, top_k, top_p, keys):
             """Paged decode through the PALLAS paged-decode kernel
             (``Engine(paged_attn='kernel')`` — the TPU default): same sampling/
             PRNG contract and shared ``_decode_math`` body as the
@@ -1069,11 +1032,11 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             TRACE_COUNTS["decode_paged_kernel"] += 1
             return _decode_math(_paged_fwd(params, table, "kernel"), pool,
                                 last_tokens, lengths, active, temps,
-                                top_k, top_p, keys, counts)
+                                top_k, top_p, keys)
     else:
-        @functools.partial(jax.jit, donate_argnums=(1, 10))
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def decode_step_paged(params, pool, table, last_tokens, lengths,
-                              active, temps, top_k, top_p, keys, counts):
+                              active, temps, top_k, top_p, keys):
             """Paged decode: one token for every slot, KV read/written
             through ``table`` into ``pool``.  Same sampling/PRNG
             contract as ``decode_step`` — literally the same
@@ -1082,12 +1045,12 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             TRACE_COUNTS["decode_paged"] += 1
             return _decode_math(_paged_fwd(params, table, paged_attn), pool,
                                 last_tokens, lengths, active, temps,
-                                top_k, top_p, keys, counts)
+                                top_k, top_p, keys)
 
     if kernel_build:
-        @functools.partial(jax.jit, donate_argnums=(1, 11))
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def verify_step_paged(params, pool, table, tokens, lengths, active,
-                              n_draft, temps, top_k, top_p, keys, counts):
+                              n_draft, temps, top_k, top_p, keys):
             """Paged speculative verify through the flash-window kernel:
             the k+1 window attends its own in-window prefix and the
             cache in ONE kernel launch per layer (per-row visibility
@@ -1098,7 +1061,7 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             TRACE_COUNTS["verify_paged_kernel"] += 1
             return _verify_math(_paged_fwd(params, table, "kernel"), pool,
                                 tokens, lengths, active, n_draft, temps,
-                                top_k, top_p, keys, counts)
+                                top_k, top_p, keys)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def prefill_step_paged(params, pool, row_table, tokens, pos, last):
@@ -1116,11 +1079,11 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
                 logits, last, axis=1, keepdims=False)  # (1, vocab)
             return last_logits, new_pool
 
-        @functools.partial(jax.jit, donate_argnums=(1, 13),
+        @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n_steps", "stream"))
         def fused_decode_step_paged(params, pool, table, last_tokens, lengths,
                                     active, temps, top_k, top_p, keys,
-                                    budgets, eos_ids, ring_id, counts, *,
+                                    budgets, eos_ids, ring_id, *,
                                     n_steps, stream=False):
             """Paged fused decode with the decode KERNEL inside the
             ``lax.while_loop`` body: every iteration's attention is one
@@ -1132,11 +1095,11 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             return _fused_decode_math(
                 _paged_fwd(params, table, "kernel"), pool, last_tokens,
                 lengths, active, temps, top_k, top_p, keys, budgets, eos_ids,
-                ring_id, counts, n_steps=n_steps, stream=stream)
+                ring_id, n_steps=n_steps, stream=stream)
     else:
-        @functools.partial(jax.jit, donate_argnums=(1, 11))
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def verify_step_paged(params, pool, table, tokens, lengths, active,
-                              n_draft, temps, top_k, top_p, keys, counts):
+                              n_draft, temps, top_k, top_p, keys):
             """Paged speculative verify (the shared ``_verify_math``
             body): the k+1 window's writes may cross one page boundary
             — each window position commits into its own page-containing
@@ -1144,7 +1107,7 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             TRACE_COUNTS["verify_paged"] += 1
             return _verify_math(_paged_fwd(params, table, win_impl), pool,
                                 tokens, lengths, active, n_draft, temps,
-                                top_k, top_p, keys, counts)
+                                top_k, top_p, keys)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def prefill_step_paged(params, pool, row_table, tokens, pos, last):
@@ -1162,11 +1125,11 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
                 logits, last, axis=1, keepdims=False)  # (1, vocab)
             return last_logits, new_pool
 
-        @functools.partial(jax.jit, donate_argnums=(1, 13),
+        @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n_steps", "stream"))
         def fused_decode_step_paged(params, pool, table, last_tokens, lengths,
                                     active, temps, top_k, top_p, keys,
-                                    budgets, eos_ids, ring_id, counts, *,
+                                    budgets, eos_ids, ring_id, *,
                                     n_steps, stream=False):
             """Paged fused decode window: the dense fused loop —
             ``_fused_decode_math``, the one shared copy of carry,
@@ -1182,18 +1145,18 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             return _fused_decode_math(
                 _paged_fwd(params, table, win_impl), pool, last_tokens,
                 lengths, active, temps, top_k, top_p, keys, budgets, eos_ids,
-                ring_id, counts, n_steps=n_steps, stream=stream)
+                ring_id, n_steps=n_steps, stream=stream)
 
     if draft_cfg is None:
         fused_spec_paged = None
     elif kernel_build:
-        @functools.partial(jax.jit, donate_argnums=(2, 15),
+        @functools.partial(jax.jit, donate_argnums=(2,),
                            static_argnames=("n_draft_k", "n_steps",
                                             "stream"))
         def fused_spec_paged(params, draft_params, pool, table, hist,
                              last_tokens, lengths, active, temps, top_k,
                              top_p, keys, budgets, eos_ids, ring_id,
-                             counts, *, n_draft_k, n_steps, stream=False):
+                             *, n_draft_k, n_steps, stream=False):
             """Paged fused speculation with KERNELS inside the loop
             body: each iteration's k+1 verify window runs the
             flash-window kernel (per-row window visibility through the
@@ -1206,17 +1169,17 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             return _fused_spec_math(
                 _paged_fwd(params, table, "kernel"), draft_cfg, draft_params,
                 pool, hist, last_tokens, lengths, active, temps, top_k,
-                top_p, keys, budgets, eos_ids, ring_id, counts,
+                top_p, keys, budgets, eos_ids, ring_id,
                 n_draft_k=n_draft_k, n_steps=n_steps, stream=stream,
                 chunk_draft_prefill=True)
     else:
-        @functools.partial(jax.jit, donate_argnums=(2, 15),
+        @functools.partial(jax.jit, donate_argnums=(2,),
                            static_argnames=("n_draft_k", "n_steps",
                                             "stream"))
         def fused_spec_paged(params, draft_params, pool, table, hist,
                              last_tokens, lengths, active, temps, top_k,
                              top_p, keys, budgets, eos_ids, ring_id,
-                             counts, *, n_draft_k, n_steps, stream=False):
+                             *, n_draft_k, n_steps, stream=False):
             """Paged fused speculative window: ``_fused_spec_math`` —
             the one shared copy of draft/verify/accept carry — with the
             paged indirection inside the loop (the table is
@@ -1229,7 +1192,7 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             return _fused_spec_math(
                 _paged_fwd(params, table, win_impl), draft_cfg, draft_params,
                 pool, hist, last_tokens, lengths, active, temps, top_k,
-                top_p, keys, budgets, eos_ids, ring_id, counts,
+                top_p, keys, budgets, eos_ids, ring_id,
                 n_draft_k=n_draft_k, n_steps=n_steps, stream=stream)
 
     def _tree_paged_fwd(params, table):
@@ -1277,10 +1240,10 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
         return fwd
 
     if kernel_build:
-        @functools.partial(jax.jit, donate_argnums=(1, 11),
+        @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("parents",))
         def tree_verify_paged(params, pool, table, tokens, lengths, active,
-                              n_cand, temps, top_k, top_p, keys, counts,
+                              n_cand, temps, top_k, top_p, keys,
                               *, parents):
             """Paged tree window on the kernel build: fp pools run the
             TREE KERNEL (cache pages streamed through the table, the
@@ -1294,17 +1257,17 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
                 return _tree_verify_math(
                     _tree_paged_fwd(params, table), _tree_paged_commit(table),
                     pool, tokens, lengths, active, n_cand, temps, top_k,
-                    top_p, keys, counts, parents=parents)
+                    top_p, keys, parents=parents)
             TRACE_COUNTS["tree_verify_paged_kernel"] += 1
             return _tree_verify_math(
                 _tree_kernel_fwd(params, table), _tree_paged_commit(table),
                 pool, tokens, lengths, active, n_cand, temps, top_k, top_p,
-                keys, counts, parents=parents)
+                keys, parents=parents)
     else:
-        @functools.partial(jax.jit, donate_argnums=(1, 11),
+        @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("parents",))
         def tree_verify_paged(params, pool, table, tokens, lengths, active,
-                              n_cand, temps, top_k, top_p, keys, counts,
+                              n_cand, temps, top_k, top_p, keys,
                               *, parents):
             """Paged speculative tree window (the shared
             ``_tree_verify_math`` body): tree-masked scoring over the
@@ -1314,7 +1277,7 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             return _tree_verify_math(
                 _tree_paged_fwd(params, table), _tree_paged_commit(table),
                 pool, tokens, lengths, active, n_cand, temps, top_k,
-                top_p, keys, counts, parents=parents)
+                top_p, keys, parents=parents)
 
     return (decode_step, verify_step, prefill_step, fused_decode_step,
             fused_spec_step, tree_verify_step,
@@ -1341,7 +1304,7 @@ class _ModelState:
               "fused_paged", "fused_spec_paged", "tree_paged")
     __slots__ = ("name", "model", "config", "params", *_STEPS,
                  "cache", "prefix_cache", "pool", "index",
-                 "table", "wtable", "slot_nodes", "obs_counts")
+                 "table", "wtable", "slot_nodes")
 
     def __init__(self, name, model, params, steps, draft_params=None):
         self.name = name
@@ -1379,10 +1342,6 @@ class _ModelState:
         # every other family: THE test where the windowed path parts.
         self.wtable = None
         self.slot_nodes = None
-        # OBS_DEVICE_COUNTERS accumulator: rides this model's step
-        # programs (donated in, rebound from each result), fetched only
-        # by Engine.metrics().
-        self.obs_counts = _zero_obs_counts()
 
 
 @jax.jit
@@ -1394,6 +1353,20 @@ def _sample_row(logits, temp, top_k, top_p, key):
     carry, sub = split_keys(key[None])
     tok = sample_tokens(logits, temp[None], top_k[None], top_p[None], sub)
     return tok[0], carry[0]
+
+
+def _readiness_probe(out):
+    """The output of a just-dispatched program that the exposed clock
+    asks ``is_ready()``: the first array among the result's top-level
+    entries.  A state a program returns (an arena or a page pool, a
+    tuple of arrays) is passed over: the next program donates it, and a
+    deleted buffer cannot be asked.  A result that is only such a state
+    (the prefix cache's block copies) gives None."""
+    if type(out) is tuple:
+        for x in out:
+            if isinstance(x, jax.Array):
+                return x
+    return None
 
 
 class Request:
@@ -2025,6 +1998,15 @@ class Engine:
         # dumps are enabled by directory (``flight_dir`` or
         # TPUDP_FLIGHT_DIR), so the default engine writes nothing.
         self.obs = Recorder(name="serve", enabled=obs)
+        # The exposed clock (``_poll``, ``_enqueued``, ``_device``): the
+        # open ``exposed`` span's token, the phase the host is in (what
+        # the span's time is charged to), and an output of the newest
+        # device work the engine enqueued — one that no later program
+        # donates, so a readiness check on it never meets a deleted
+        # buffer (None: unknown until the next dispatch).
+        self._xtok = NO_SPAN
+        self._xphase = "caller"
+        self._newest = self._keys
         self.flight = FlightRecorder(self.obs, flight_dir,
                                      component="serve")
         if watchdog is not None and getattr(watchdog, "flight",
@@ -2325,15 +2307,26 @@ class Engine:
         emitted: list[tuple[Request, int]] = []
         if self._closed or self._quarantined:
             return emitted
-        self._maybe_canary()
-        if self._quarantined:
-            return emitted  # the canary just condemned this engine
-        # Phase spans (tpudp.obs begin/end, children of ``step`` by
-        # enclosure on this one scheduler thread): ``step``'s time in no
-        # child is the scheduler's own.  A child an exception cuts short
-        # stays open in the ring — the flight recorder's "where it was".
+        # Phase spans (tpudp.obs begin/end through ``_enter`` / ``_leave``,
+        # children of ``step`` by enclosure on this one scheduler thread:
+        # ``STEP_PHASES``): ``step``'s time in no child is the scheduler's
+        # own.  A child an exception cuts short stays open in the ring —
+        # the flight recorder's "where it was".  The exposed clock checks
+        # for a drained device (``_poll``) at the end of admission and of
+        # ``batch``, at the start of ``pages``, of each dispatch
+        # (``_device``) and of each commit after a fetch.
         obs = self.obs
+        if self._xtok >= 0:  # the device idled through the caller's code
+            obs.piece(self._xtok, "caller")
+        self._xphase = "step"
         in_step = obs.begin("step")
+        if self.canary_every_s is not None:
+            span = self._enter("canary")
+            self._maybe_canary()
+            self._leave(span)
+            if self._quarantined:  # the canary just condemned this engine
+                self._end_step(in_step)
+                return emitted
         try:
             # Deadline expiry and admission sit INSIDE the containment
             # region: with prefix caching on, a deadline retirement can
@@ -2343,13 +2336,15 @@ class Engine:
             # other step failure instead of escaping to the caller.
             # Cache off, neither touches device state and this changes
             # nothing.
-            span = obs.begin("admit")
+            span = self._enter("admit")
             self._expire_deadlines()
             if self._sched is not None:
                 self._preempt_for_priority()
             self._admit()
             slot = self._next_prefill_slot()
-            obs.end(span)
+            if self._slots.count(None) < self.num_slots:
+                self._poll()
+            self._leave(span)
             if slot is not None:
                 self._run_prefill_chunk(slot, emitted)
             # Fuse only on PURE-DECODE iterations: nothing queued (so
@@ -2364,10 +2359,15 @@ class Engine:
             # decoding slots — with no co-resident models registered
             # this is exactly the old single decode step.
             for ms in self._mstates.values():
+                span = self._enter("batch")
                 active = np.array(
                     [r is not None and r._nfill == r._fill.size
                      and r._ms is ms for r in self._slots])
-                if not active.any():
+                decoding = active.any()
+                if decoding:
+                    self._poll()  # the prefill chunk may have ended
+                self._leave(span)
+                if not decoding:
                     continue
                 if self._paged:
                     # Back every table entry the step is about to
@@ -2378,10 +2378,12 @@ class Engine:
                     # most recent co-resident slot through the
                     # bit-exact resume path — so the device program
                     # only ever sees fully-backed tables.
-                    span = obs.begin("pages")
+                    span = self._enter("pages")
+                    self._poll()
                     active = self._ensure_decode_pages(ms, active, fuse)
-                    obs.end(span)
-                    if not active.any():
+                    decoding = active.any()
+                    self._leave(span)
+                    if not decoding:
                         continue
                 if self.speculate_k and not self._drafter_quarantined:
                     if self.speculate_tree is not None:
@@ -2402,8 +2404,71 @@ class Engine:
             # live on the canary handle, never in the emitted pairs.
             emitted = [(r, t) for (r, t) in emitted
                        if not getattr(r, "_canary", False)]
-        obs.end(in_step)
+        self._end_step(in_step)
         return emitted
+
+    # -- the exposed clock (docs/OBSERVABILITY.md) ----------------------
+    #
+    # The ``exposed`` span is open exactly while the engine KNOWS none of
+    # its device work is in flight: it opens when a blocking fetch of the
+    # newest work has returned or a readiness check of it (``_poll``)
+    # comes back true, and closes when the next work has been enqueued
+    # (``_device`` returning, ``_enqueued``).  The device drained at some
+    # moment before the engine learnt it, so the span is a lower bound
+    # on the device's idle time.  Its time is charged in pieces to the
+    # phase the host was in (``EXPOSED_PHASES``).
+
+    def _enter(self, phase: str) -> int:
+        """Open the span of one of ``step``'s phases (``STEP_PHASES``);
+        the open ``exposed`` span's time so far goes to the phase the
+        host leaves."""
+        span = self.obs.begin(phase)
+        if self._xtok >= 0:
+            self.obs.piece(self._xtok, self._xphase)
+        self._xphase = phase
+        return span
+
+    def _leave(self, span: int) -> None:
+        """Close a phase's span: the host is back in ``step``'s own
+        code."""
+        if self._xtok >= 0:
+            self.obs.piece(self._xtok, self._xphase)
+        self._xphase = "step"
+        self.obs.end(span)
+
+    def _poll(self) -> None:
+        """The readiness check at a phase boundary: if the output of the
+        newest device work is ready, nothing of the engine's is in
+        flight and ``exposed`` opens now.  ``is_ready()`` does not wait;
+        with the recorder off it is never called."""
+        if (self._xtok < 0 and self.obs.enabled
+                and self._newest is not None and self._newest.is_ready()):
+            self._xtok = self.obs.begin("exposed")
+
+    def _enqueued(self, out) -> None:
+        """Device work was just enqueued outside ``_device`` (an eager
+        operation): ``exposed`` closes, its last piece going to the
+        phase the host is in, and ``out`` — an output no later program
+        donates, or None where there is none — is the newest work."""
+        if self._xtok >= 0:
+            self.obs.end(self._xtok, self._xphase)
+            self._xtok = NO_SPAN
+        if self.obs.enabled:
+            self._newest = out
+
+    def _end_step(self, in_step: int) -> None:
+        """Close ``step``.  An engine left with no slot in use and nothing
+        queued closes ``exposed`` too: with no work, idle is not
+        exposed.  Otherwise the span runs on into the caller's code."""
+        if self._xtok >= 0:
+            if (self._slots.count(None) == self.num_slots
+                    and not self.queue_depth):
+                self.obs.end(self._xtok, "step")
+                self._xtok = NO_SPAN
+            else:
+                self.obs.piece(self._xtok, "step")
+        self._xphase = "caller"
+        self.obs.end(in_step)
 
     def cancel(self, request: Request) -> bool:
         """Retire ``request`` immediately — queued or in flight — and
@@ -2497,6 +2562,7 @@ class Engine:
                     page = int(ms.table[s, i])
                     if page >= 0:
                         pages.append(ms.pool.read_page(page))
+                self._enqueued(None)  # the page reads ran on the device
             if ((self._paged or ms.prefix_cache is not None)
                     and self._accepting):
                 self._publish_prefix(ms, s, r)
@@ -2594,6 +2660,7 @@ class Engine:
                     break
                 ms.pool.write_page(page, payload)
                 adopted.append(page)
+            self._enqueued(None)  # the page writes run on the device
             if adopted:
                 ms.index.adopt(r._fill, adopted)
                 for page in adopted:
@@ -2685,40 +2752,45 @@ class Engine:
 
     def metrics(self) -> dict:
         """One structured snapshot of everything the engine knows about
-        itself: the host stats counters, queue/slot occupancy, the
-        per-model ZERO-SYNC device counters (OBS_DEVICE_COUNTERS — this
-        is their one read point, a single small fetch per model OFF the
-        designated hot paths), per-tenant counters, and the span
-        rollup from the obs ring.  The serve bench's metric sidecar and
-        the Prometheus exposition (``tpudp.obs.prometheus_text``) both
-        render this dict."""
+        itself: the host stats counters, queue/slot occupancy,
+        per-tenant counters, and the span rollup from the obs ring.
+        With the recorder on, ``stats`` also holds the phase seconds
+        (``OBS_PHASE_SECONDS``), ``step_self_s`` (``step``'s time in none
+        of ``STEP_PHASES``), ``exposed_s`` and ``exposed_dispatch_s``
+        (the ``exposed`` span's time, and its part inside the dispatches
+        of ``DEVICE_SPANS``), and ``exposed`` is that time by phase
+        (``EXPOSED_PHASES``; it sums to ``exposed_s``).  An open
+        ``exposed`` span is charged up to this read, so two reads'
+        difference is the exposed time between them.  The serve bench's
+        metric sidecar and the Prometheus exposition
+        (``tpudp.obs.prometheus_text``) both render this dict."""
         if self._moe_pending:  # a prefill chunk no decode followed yet
             self._count_moe(jax.device_get(self._moe_pending))
-        device: dict[str, dict] = {}
-        totals = dict.fromkeys(OBS_DEVICE_COUNTERS, 0.0)
-        for name, ms in self._mstates.items():
-            vals = np.asarray(ms.obs_counts)
-            row = {k: float(v) for k, v in zip(OBS_DEVICE_COUNTERS, vals)}
-            device[name or "default"] = row
-            for k, v in row.items():
-                totals[k] += v
+        if self._xtok >= 0:
+            self.obs.piece(self._xtok, self._xphase)
         spans = self.obs.summary()
         stats = dict(self.stats)
+        parts = spans.get("exposed", {}).get("parts", {})
         if self.obs.enabled:
             for key, names in OBS_PHASE_SECONDS.items():
                 stats[key] = sum(spans[n]["total_s"] for n in names
                                  if n in spans)
+            stats["step_self_s"] = stats["step_s"] - sum(
+                spans[n]["total_s"] for n in STEP_PHASES if n in spans)
+            stats["exposed_s"] = spans.get("exposed", {}).get("total_s", 0.0)
+            stats["exposed_dispatch_s"] = sum(parts.get(n, 0.0)
+                                              for n in DEVICE_SPANS)
         out = {
             "stats": stats,
             "queue_depth": self.queue_depth,
             "slots_in_use": self.slots_in_use,
             "num_slots": self.num_slots,
-            "device_counters": totals,
-            "device_counters_per_model": device,
             "spans": spans,
             "obs_counters": dict(self.obs.counters),
             "flight_dumps": self.flight.dumps,
         }
+        if self.obs.enabled:
+            out["exposed"] = {p: parts.get(p, 0.0) for p in EXPOSED_PHASES}
         if self.canary_every_s is not None or self._quarantined:
             out["canary"] = {
                 "runs": self.stats["canary_runs"],
@@ -2796,6 +2868,7 @@ class Engine:
             key = (jnp.asarray(r._resume_key) if r._resume_key is not None
                    else jax.random.PRNGKey(r.seed))
             self._keys = self._keys.at[s].set(key)
+            self._enqueued(self._keys)
             self.stats["admitted"] += 1
             self.obs.event(
                 "admit", rid=r.id, slot=s, tenant=r.tenant,
@@ -3285,18 +3358,32 @@ class Engine:
         taxonomy (``DEVICE_SPANS``), and the region name the watchdog
         reports on a hang.  The call is asynchronous, so the span times
         the DISPATCH; the device's time shows where the host blocks on
-        the result (the ``fetch`` / ``first_token_wait`` spans)."""
+        the result (the ``fetch`` / ``first_token_wait`` spans).  Outside
+        the commit loop the call starts with the exposed clock's
+        readiness check, and an open ``exposed`` span closes once the
+        call returns: the program is enqueued.  The dispatch's share
+        of the span is charged to ``kind``."""
         idx = self._device_calls
         self._device_calls += 1
         tok = self.obs.begin(kind)
+        if self._xtok >= 0:
+            self.obs.piece(self._xtok, self._xphase)
+        elif self._xphase != "commit":  # no check inside the commit loop
+            self._poll()
         try:
             with self._guard(guard_timeout_s
                              if guard_timeout_s is not None
                              else self._step_timeout_s, name=kind):
                 if self.step_fault_hook is not None:
                     self.step_fault_hook(kind, idx)
-                return fn(*args, **kwargs)
+                out = fn(*args, **kwargs)
+                if self.obs.enabled:
+                    self._newest = _readiness_probe(out)
+            return out
         finally:
+            if self._xtok >= 0:
+                self.obs.end(self._xtok, kind)
+                self._xtok = NO_SPAN
             self.obs.end(tok)
 
     def _contain_step_failure(self, exc: BaseException) -> None:
@@ -3310,6 +3397,7 @@ class Engine:
         engine keeps serving."""
         self.stats["step_failures"] += 1
         self.last_step_error = exc
+        self._enqueued(None)  # the rebuild below enqueues device work
         self.obs.event("containment", error=type(exc).__name__,
                        detail=str(exc)[:200])
         # Black box BEFORE the rebuild mutates state: the ring's tail is
@@ -3346,12 +3434,6 @@ class Engine:
             else:
                 ms.cache = KVCache.zeros(ms.config, self.num_slots,
                                          self.max_len)
-            # The failed call may have consumed the donated counters
-            # buffer too — rebuild it.  The pre-fault values are LOST
-            # (fetching a possibly-donated buffer here could raise and
-            # mask the fault being contained); device counters are
-            # best-effort telemetry, host stats stay authoritative.
-            ms.obs_counts = _zero_obs_counts()
             # A rebuilt arena invalidates the published blocks
             # wholesale: the failed call may have been a block copy
             # with either buffer donated, and after an arbitrary device
@@ -3398,6 +3480,7 @@ class Engine:
         return min(pending)[1] if pending else None
 
     def _run_prefill_chunk(self, s: int, emitted) -> None:
+        span = self._enter("chunk")
         r = self._slots[s]
         if r.first_chunk_time is None:
             r.first_chunk_time = time.perf_counter()
@@ -3407,17 +3490,20 @@ class Engine:
         end = min(start + self.prefill_chunk, fill.size)
         buf = np.zeros((1, self.prefill_chunk), np.int32)
         buf[0, :end - start] = fill[start:end]
+        # Back the chunk's page first (page-pressure vacates can only
+        # hit OTHER slots — this one is protected), then run the paged
+        # prefill against the slot's table row.
+        if self._paged and not self._ensure_pages(ms, s, end):
+            self._leave(span)
+            return  # slot retired (defensive: pool exhausted)
         if self._paged:
-            # Back the chunk's page first (page-pressure vacates can
-            # only hit OTHER slots — this one is protected), then run
-            # the paged prefill against the slot's table row.
-            if not self._ensure_pages(ms, s, end):
-                return  # slot retired (defensive: pool exhausted)
             # (a COPY of the window row: the host frees its entries while
             # this program may still be in flight, and a backend may read
             # a numpy argument in place; the global row only ever grows)
             row = ms.table[s] if ms.wtable is None \
                 else (ms.table[s], ms.wtable[s].copy())
+        self._leave(span)
+        if self._paged:
             last_logits, ms.pool.pages, *routed = self._device(
                 "prefill", ms.prefill_paged, ms.pool.pages, row,
                 buf, np.int32(start), np.int32(end - start - 1))
@@ -3452,23 +3538,31 @@ class Engine:
             # FIRST — exactly generate()'s prefill-then-sample order;
             # for a requeued one, event ``len(tokens) + 1`` under the
             # resumed key chain).
+            span = self._enter("keys")  # the slot's key: an eager slice
+            key = self._keys[s]
+            self._enqueued(key)
+            self._leave(span)
             tok, carry = self._device(
                 "sample", _sample_row, last_logits, self._temps[s],
-                self._topk[s], self._topp[s], self._keys[s])
+                self._topk[s], self._topp[s], key)
+            span = self._enter("keys")
             self._keys = self._keys.at[s].set(carry)
+            self._enqueued(self._keys)
+            self._leave(span)
             # The sync that keeps this step's decode from being queued
             # behind the prompt's last chunk: the host waits here for
             # the chunk and the sampler to finish.
-            span = self.obs.begin("first_token_wait")
+            span = self._enter("first_token_wait")
             # tpudp: lint-ok(host-sync): the FIRST-token commit — one
             # fetch per completed prefill, not per decoded token; the
             # decoded tokens ride decode_fuse windows
             # (_run_decode_fused) when fusing is on.
             tok = int(tok)
-            self.obs.end(span)
-            span = self.obs.begin("commit")
+            self._leave(span)
+            span = self._enter("commit")
+            self._poll()  # the key chain's update was enqueued after it
             self._commit(s, tok, emitted)
-            self.obs.end(span)
+            self._leave(span)
 
     def _count_moe(self, fetched) -> None:
         """Add the expert layers' fetched per-run counts
@@ -3482,24 +3576,24 @@ class Engine:
                 self.stats[key] += int(val)
 
     def _run_decode(self, ms: _ModelState, active, emitted) -> None:
+        table = ms.table
+        if ms.wtable is not None:
+            span = self._enter("batch")
+            table = (table, ms.wtable.copy())  # as the prefill's row
+            self.stats["window_pages_live"] += ms.pool.window_used_pages
+            self._leave(span)
         if self._paged:
-            table = ms.table
-            if ms.wtable is not None:
-                table = (table, ms.wtable.copy())  # as the prefill's row
-                self.stats["window_pages_live"] += ms.pool.window_used_pages
-            (ms.pool.pages, toks, self._keys, ms.obs_counts,
-             *routed) = self._device(
+            ms.pool.pages, toks, self._keys, *routed = self._device(
                 "decode", ms.decode_paged,
                 ms.pool.pages, table, self._last, self._len, active,
-                self._temps, self._topk, self._topp, self._keys,
-                ms.obs_counts)
+                self._temps, self._topk, self._topp, self._keys)
             self._moe_pending += routed
         else:
-            ms.cache, toks, self._keys, ms.obs_counts = self._device(
+            ms.cache, toks, self._keys = self._device(
                 "decode", ms.decode_step,
                 ms.cache, self._last, self._len, active, self._temps,
-                self._topk, self._topp, self._keys, ms.obs_counts)
-        span = self.obs.begin("fetch")
+                self._topk, self._topp, self._keys)
+        span = self._enter("fetch")
         # tpudp: lint-ok(host-sync): the single-step path's per-token
         # fetch — Engine(decode_fuse=N) amortizes it to one fetch per
         # fused lax.while_loop window (_run_decode_fused); this path
@@ -3508,16 +3602,17 @@ class Engine:
         # expert family's counters (this run's and the prefill chunks'
         # since the last fetch) ride the same transfer.
         toks, *moe = jax.device_get((toks, *self._moe_pending))
-        self.obs.end(span)
+        self._leave(span)
+        span = self._enter("commit")
+        self._poll()
         if moe:
             self._count_moe(moe)
         self.stats["decode_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
-        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             self._len[s] += 1  # the fed token's KV landed this step
             self._commit(int(s), int(toks[s]), emitted)
-        self.obs.end(span)
+        self._leave(span)
 
     def _run_decode_fused(self, ms: _ModelState, active, emitted) -> None:
         """One fused window: up to ``decode_fuse`` decode iterations in
@@ -3533,6 +3628,7 @@ class Engine:
         carry, leaving the host state bit-identical to having run
         ``n_emit[s]`` single steps — which is why any later fall-back
         to the single-step path resumes exactly."""
+        span = self._enter("batch")
         budgets = np.zeros(self.num_slots, np.int32)
         eos = np.full(self.num_slots, -1, np.int32)
         for s in np.nonzero(active)[0]:
@@ -3546,31 +3642,32 @@ class Engine:
         # not misdiagnose a healthy window as a wedged call.
         budget_s = (self._step_timeout_s * self.decode_fuse
                     if self._step_timeout_s is not None else None)
+        self._leave(span)
         if self._paged:
-            (ms.pool.pages, out, n_emit, keys, iters,
-             ms.obs_counts) = self._device(
+            ms.pool.pages, out, n_emit, keys, iters = self._device(
                 "fused_decode", ms.fused_paged,
                 ms.pool.pages, ms.table, self._last, self._len, active,
                 self._temps, self._topk, self._topp, self._keys,
-                budgets, eos, np.int32(self._ring_id), ms.obs_counts,
+                budgets, eos, np.int32(self._ring_id),
                 guard_timeout_s=budget_s,
                 n_steps=self.decode_fuse, stream=self._fuse_stream)
         else:
-            (ms.cache, out, n_emit, keys, iters,
-             ms.obs_counts) = self._device(
+            ms.cache, out, n_emit, keys, iters = self._device(
                 "fused_decode", ms.fused_step,
                 ms.cache, self._last, self._len, active, self._temps,
                 self._topk, self._topp, self._keys, budgets, eos,
-                np.int32(self._ring_id), ms.obs_counts,
+                np.int32(self._ring_id),
                 guard_timeout_s=budget_s,
                 n_steps=self.decode_fuse, stream=self._fuse_stream)
-        span = self.obs.begin("fetch")
+        span = self._enter("fetch")
         # tpudp: lint-ok(host-sync): the per-WINDOW fetch — one round
         # trip per up-to-decode_fuse-token window, the amortized
         # replacement for the single-step path's per-token fetch.
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
-        self.obs.end(span)
+        self._leave(span)
+        span = self._enter("commit")
+        self._poll()
         self.stats["fused_windows"] += 1
         self.stats["fused_steps"] += int(iters)  # tpudp: lint-ok(host-sync): same fetch
         # Each loop iteration is one batched decode over the arena, and
@@ -3579,7 +3676,6 @@ class Engine:
         # occupancy consumers keep working with fusing on
         # (active / (decode_steps + fused_steps) x num_slots).
         self.stats["active_slot_steps"] += int(n_emit.sum())
-        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             # Take the window-final key carry PER SLOT, just before that
@@ -3592,12 +3688,13 @@ class Engine:
             # up-front `self._keys = keys` would skip an interrupted
             # later slot's chain ahead of its stream.
             self._keys = self._keys.at[s].set(keys[s])
+            self._enqueued(self._keys)
             for j in range(int(n_emit[s])):
                 if self._slots[s] is not r:
                     break  # retired (EOS / budget / cancel) mid-replay
                 self._len[s] += 1
                 self._commit(int(s), int(out[s, j]), emitted)
-        self.obs.end(span)
+        self._leave(span)
 
     def _quarantine_drafter(self, reason: str, r: Request | None = None,
                             proposed: int = 0) -> None:
@@ -3767,8 +3864,10 @@ class Engine:
         the dispatch switches between two warm programs, it never
         creates a new one."""
         k = self.speculate_k
+        span = self._enter("batch")
         proposed = self._gather_drafts(ms, active, k)
         if not proposed:  # nothing drafted, or the drafter just got cut
+            self._leave(span)
             self._run_decode(ms, active, emitted)
             return
         tokens = np.zeros((self.num_slots, k + 1), np.int32)
@@ -3778,30 +3877,29 @@ class Engine:
             tokens[s, 1:1 + draft.size] = draft  # validated in-vocab
             n_draft[s] = draft.size
             self._slots[s].draft_proposed += int(draft.size)
+        self._leave(span)
         if self._paged:
-            (ms.pool.pages, out, n_emit, self._keys,
-             ms.obs_counts) = self._device(
+            ms.pool.pages, out, n_emit, self._keys = self._device(
                 "verify", ms.verify_paged,
                 ms.pool.pages, ms.table, tokens, self._len, active,
-                n_draft, self._temps, self._topk, self._topp, self._keys,
-                ms.obs_counts)
+                n_draft, self._temps, self._topk, self._topp, self._keys)
         else:
-            (ms.cache, out, n_emit, self._keys,
-             ms.obs_counts) = self._device(
+            ms.cache, out, n_emit, self._keys = self._device(
                 "verify", ms.verify_step,
                 ms.cache, tokens, self._len, active, n_draft, self._temps,
-                self._topk, self._topp, self._keys, ms.obs_counts)
-        span = self.obs.begin("fetch")
+                self._topk, self._topp, self._keys)
+        span = self._enter("fetch")
         # tpudp: lint-ok(host-sync): the per-window verify fetch (one
         # round trip per k+1-token window, amortized over accepts) —
         # fusing the drafter into the device program removes it.
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
-        self.obs.end(span)
+        self._leave(span)
+        span = self._enter("commit")
+        self._poll()
         self.stats["verify_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
         self.stats["draft_tokens"] += int(n_draft.sum())
-        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             accepted = int(n_emit[s]) - 1
@@ -3815,7 +3913,7 @@ class Engine:
                 # per commit advances the row past exactly those writes.
                 self._len[s] += 1
                 self._commit(s, int(out[s, j]), emitted)
-        self.obs.end(span)
+        self._leave(span)
 
     def _run_spec_fused(self, ms: _ModelState, active, emitted) -> None:
         """One fused SPECULATIVE window: up to ``decode_fuse``
@@ -3830,6 +3928,7 @@ class Engine:
         the loop predicate by construction — a later fall-back to
         host-drafted verify (or plain decode) resumes bit-exactly."""
         k = self.speculate_k
+        span = self._enter("batch")
         budgets = np.zeros(self.num_slots, np.int32)
         eos = np.full(self.num_slots, -1, np.int32)
         hist = np.zeros((self.num_slots, self.max_len), np.int32)
@@ -3846,25 +3945,25 @@ class Engine:
         # window and the draft work per window.
         budget_s = (self._step_timeout_s * self.decode_fuse * (k + 2)
                     if self._step_timeout_s is not None else None)
+        self._leave(span)
         if self._paged:
-            (ms.pool.pages, out, n_emit, n_win, n_acc, keys, iters,
-             ms.obs_counts) = self._device(
+            (ms.pool.pages, out, n_emit, n_win, n_acc, keys,
+             iters) = self._device(
                 "fused_spec", ms.fused_spec_paged,
                 ms.pool.pages, ms.table, hist, self._last, self._len,
                 active, self._temps, self._topk, self._topp, self._keys,
-                budgets, eos, np.int32(self._ring_id), ms.obs_counts,
+                budgets, eos, np.int32(self._ring_id),
                 guard_timeout_s=budget_s, n_draft_k=k,
                 n_steps=self.decode_fuse, stream=self._fuse_stream)
         else:
-            (ms.cache, out, n_emit, n_win, n_acc, keys, iters,
-             ms.obs_counts) = self._device(
+            ms.cache, out, n_emit, n_win, n_acc, keys, iters = self._device(
                 "fused_spec", ms.fused_spec_step,
                 ms.cache, hist, self._last, self._len, active,
                 self._temps, self._topk, self._topp, self._keys,
-                budgets, eos, np.int32(self._ring_id), ms.obs_counts,
+                budgets, eos, np.int32(self._ring_id),
                 guard_timeout_s=budget_s, n_draft_k=k,
                 n_steps=self.decode_fuse, stream=self._fuse_stream)
-        span = self.obs.begin("fetch")
+        span = self._enter("fetch")
         # tpudp: lint-ok(host-sync): the per-PROGRAM fetch — one round
         # trip per up-to-decode_fuse speculative windows, replacing the
         # host-drafted path's per-window draft gather + verify fetch.
@@ -3872,7 +3971,9 @@ class Engine:
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
         n_win = np.asarray(n_win)  # tpudp: lint-ok(host-sync): same fetch
         n_acc = np.asarray(n_acc)  # tpudp: lint-ok(host-sync): same fetch
-        self.obs.end(span)
+        self._leave(span)
+        span = self._enter("commit")
+        self._poll()
         self.stats["fused_spec_windows"] += 1
         self.stats["fused_spec_steps"] += int(iters)  # tpudp: lint-ok(host-sync): same fetch
         # A row participates in one verify window per loop iteration it
@@ -3881,7 +3982,6 @@ class Engine:
         self.stats["active_slot_steps"] += int(n_win.sum())
         self.stats["draft_tokens"] += int(n_win.sum()) * k
         self.stats["draft_accepted"] += int(n_acc.sum())
-        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             r.draft_proposed += int(n_win[s]) * k
@@ -3889,12 +3989,13 @@ class Engine:
             # Per-slot key carry just before that slot's replay — the
             # containment-mid-replay argument of _run_decode_fused.
             self._keys = self._keys.at[s].set(keys[s])
+            self._enqueued(self._keys)
             for j in range(int(n_emit[s])):
                 if self._slots[s] is not r:
                     break  # retired (EOS / budget / cancel) mid-replay
                 self._len[s] += 1
                 self._commit(int(s), int(out[s, j]), emitted)
-        self.obs.end(span)
+        self._leave(span)
 
     def _gather_tree_drafts(self, ms, active, shape):
         """Host-side TREE proposals behind the same fault-isolation
@@ -3963,8 +4064,10 @@ class Engine:
         token); a step where NOTHING drafted falls through to the plain
         decode step like ``_run_verify`` does."""
         shape = self.speculate_tree
+        span = self._enter("batch")
         proposed = self._gather_tree_drafts(ms, active, shape)
         if not proposed:  # nothing drafted, or the drafter just got cut
+            self._leave(span)
             self._run_decode(ms, active, emitted)
             return
         tokens = np.zeros((self.num_slots, shape.num_candidates + 1),
@@ -3975,30 +4078,30 @@ class Engine:
             tokens[s, 1:] = draft  # validated in-vocab, exactly T wide
             n_cand[s] = draft.size
             self._slots[s].draft_proposed += int(draft.size)
+        self._leave(span)
         if self._paged:
-            (ms.pool.pages, out, n_emit, self._keys,
-             ms.obs_counts) = self._device(
+            ms.pool.pages, out, n_emit, self._keys = self._device(
                 "tree_verify", ms.tree_paged,
                 ms.pool.pages, ms.table, tokens, self._len, active,
                 n_cand, self._temps, self._topk, self._topp, self._keys,
-                ms.obs_counts, parents=shape.parents)
+                parents=shape.parents)
         else:
-            (ms.cache, out, n_emit, self._keys,
-             ms.obs_counts) = self._device(
+            ms.cache, out, n_emit, self._keys = self._device(
                 "tree_verify", ms.tree_step,
                 ms.cache, tokens, self._len, active, n_cand,
                 self._temps, self._topk, self._topp, self._keys,
-                ms.obs_counts, parents=shape.parents)
-        span = self.obs.begin("fetch")
+                parents=shape.parents)
+        span = self._enter("fetch")
         # tpudp: lint-ok(host-sync): the per-window verify fetch — the
         # tree twin of _run_verify's, one round trip per tree window.
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)  # tpudp: lint-ok(host-sync): same fetch
-        self.obs.end(span)
+        self._leave(span)
+        span = self._enter("commit")
+        self._poll()
         self.stats["tree_verify_steps"] += 1
         self.stats["active_slot_steps"] += int(active.sum())
         self.stats["draft_tokens"] += int(n_cand.sum())
-        span = self.obs.begin("commit")
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             accepted = int(n_emit[s]) - 1
@@ -4009,7 +4112,7 @@ class Engine:
                     break  # retired (EOS / budget / cancel) mid-window
                 self._len[s] += 1
                 self._commit(s, int(out[s, j]), emitted)
-        self.obs.end(span)
+        self._leave(span)
 
     def _commit(self, s: int, tok: int, emitted) -> None:
         r = self._slots[s]
@@ -4111,7 +4214,9 @@ class Engine:
         construction be cleared for the other."""
         r = self._slots[s]
         self._release_slot_pages(r._ms, s)
-        key = np.asarray(self._keys[s])
+        key = self._keys[s]
+        self._enqueued(key)
+        key = np.asarray(key)
         self._slots[s] = None
         self._len[s] = 0
         self._temps[s] = 0.0
