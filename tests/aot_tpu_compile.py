@@ -135,7 +135,7 @@ def engine_steps(name, steps, params, pool, *, slots, width, chunk):
             sds((slots, 2), jnp.uint32))),
         name("prefill"): (steps[8], (
             params, pool, sds((width,), i32), sds((1, chunk), i32),
-            sds((), i32), sds((), i32)))}
+            sds((9,), i32), sds((slots, 2), jnp.uint32)))}
 
 
 def shapes(tree, dtype=None):

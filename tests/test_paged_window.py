@@ -131,19 +131,20 @@ def test_window_none_traces_the_parents_program(case):
 def test_the_other_families_lock_entries_did_not_move():
     """The engine's registered programs of GPT-2 and the latent family
     keep the fingerprints the lockfile had when the window family came,
-    but for the programs' counter accumulator, since removed (the audit
-    test holds the tree to the lockfile)."""
+    but for the programs' counter accumulator, since removed, and the
+    prefill programs' first-token tail, since added (the audit test holds
+    the tree to the lockfile)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "tools", "trace_lock.json")) as f:
         programs = json.load(f)["programs"]
     parent = {
         "serve.decode_paged@s2m32p6": "d2d61685bec1c5e8",
         "serve.decode_paged_kernel@s2m32p6": "afff2aa3002a25d8",
-        "serve.prefill_paged@s2m32p6c8": "83f167b0d07eef45",
-        "serve.prefill_paged_kernel@s2m32p6c8": "3b458c30d5ca7b61",
+        "serve.prefill_paged@s2m32p6c8": "74dc2f514dfb93bc",
+        "serve.prefill_paged_kernel@s2m32p6c8": "e7e554c0883b4c62",
         "serve.verify_paged_kernel@s2m32p6k3": "175e06f932497d8e",
         "serve.decode_paged_latent@s2m32p6": "6c5086a9a37c29b9",
-        "serve.prefill_paged_latent@s2m32p6c8": "2db23caf114e51e1"}
+        "serve.prefill_paged_latent@s2m32p6c8": "b3d1810e1e2d13c7"}
     for name, start in parent.items():
         assert programs[name]["fingerprint"].startswith(start), name
     assert {"serve.decode_paged_windowed@s2m32p6",

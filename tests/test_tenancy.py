@@ -660,6 +660,8 @@ PR5_BASE_STATS = {
     "deadline_expired", "shed", "step_failures", "requeued", "errors",
     # the TTFT split at each request's first token (PR 26; obs on)
     "first_tokens", "ttft_s", "ttft_queue_s", "ttft_prefill_wait_s",
+    # the first tokens sampled inside the prefill program ending a prompt
+    "first_tokens_in_prefill",
 }
 PR5_SPEC_STATS = {"verify_steps", "draft_tokens", "draft_accepted"}
 PR5_PREFIX_STATS = {"prefix_lookups", "prefix_hit_tokens",

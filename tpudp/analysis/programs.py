@@ -60,7 +60,6 @@ TRACE_COUNTER_PROGRAMS = {
     "decode_step": "serve.decode_step",
     "verify_step": "serve.verify_step",
     "prefill_chunk": "serve.prefill_chunk",
-    "sample_row": "serve.sample_row",
     "fused_decode": "serve.fused_decode",
     "decode_paged": "serve.decode_paged",
     "decode_paged_kernel": "serve.decode_paged_kernel",
@@ -140,7 +139,6 @@ PROGRAM_DONATIONS = {
     "serve.tree_verify": (1,),
     "serve.tree_verify_paged": (1,),
     "serve.tree_verify_paged_kernel": (1,),
-    "serve.sample_row": (),
     "serve.draft_model": (),
     "prefix.copy_block_in": (0,),
     "prefix.copy_block_out": (1,),
@@ -232,6 +230,7 @@ def _serve_args():
     import numpy as np
 
     from tpudp.models.generate import KVCache
+    from tpudp.serve import engine as _engine
 
     s, m, k = SERVE["slots"], SERVE["max_len"], SERVE["k"]
     cfg, params = _tiny_lm()
@@ -246,6 +245,8 @@ def _serve_args():
         hist=np.zeros((s, m), np.int32),
         tree=np.zeros((s, len(TREE_PARENTS)), np.int32),
         chunk=np.zeros((1, SERVE["chunk"]), np.int32),
+        meta=_engine._prefill_meta(0, SERVE["chunk"] - 1, 0, None, 0.0, 0,
+                                   1.0),
         budgets=np.zeros(s, np.int32),
         eos=np.full(s, -1, np.int32),
     )
@@ -278,9 +279,10 @@ def build_programs() -> dict:
     programs[f"serve.verify_step@{geo}k{SERVE['k']}"] = (
         verify, (params, cache, h["window"], h["lens"], h["active"], h["ndraft"],
                  h["temps"], h["topk"], h["topp"], h["keys"]))
+    # A prefill chunk's host scalars ride in one packed int32 array and
+    # its program ends with the first-token sample into the key chain.
     programs[f"serve.prefill_chunk@{geo}c{SERVE['chunk']}"] = (
-        prefill, (params, cache, np.int32(0), h["chunk"], np.int32(0),
-                  np.int32(SERVE["chunk"] - 1)))
+        prefill, (params, cache, h["chunk"], h["meta"], h["keys"]))
     # Fused decode window, both variants: the stream twin pins the
     # ordered io_callback in its host-callback census, so ANY change to
     # the callback count inside the loop (a new host round trip — the
@@ -346,8 +348,8 @@ def build_programs() -> dict:
                        h["ndraft"], h["temps"], h["topk"], h["topp"],
                        h["keys"]))
     programs[f"serve.prefill_paged@{pgeo2}c{SERVE['chunk']}"] = (
-        prefill_paged, (params, pool, table[0], h["chunk"], np.int32(0),
-                        np.int32(SERVE["chunk"] - 1)))
+        prefill_paged, (params, pool, table[0], h["chunk"], h["meta"],
+                        h["keys"]))
     # Both stream variants, like the dense fused window: the stream
     # twin pins the ordered io_callback in its census, so a host
     # round-trip change inside the PAGED loop fails the audit by name
@@ -406,8 +408,8 @@ def build_programs() -> dict:
                    h["ndraft"], h["temps"], h["topk"], h["topp"],
                    h["keys"]))
     programs[f"serve.prefill_paged_kernel@{pgeo2}c{SERVE['chunk']}"] = (
-        prefill_k, (params, pool, table[0], h["chunk"], np.int32(0),
-                    np.int32(SERVE["chunk"] - 1)))
+        prefill_k, (params, pool, table[0], h["chunk"], h["meta"],
+                    h["keys"]))
     programs[f"serve.fused_decode_paged_kernel@{pgeo2}n{SERVE['fuse']}"] = (
         functools.partial(fused_k, n_steps=SERVE["fuse"], stream=False),
         fused_paged_args)
@@ -442,8 +444,8 @@ def build_programs() -> dict:
         lsteps[6], (lparams, lpool, table, h["last"], h["lens"], h["active"],
                     h["temps"], h["topk"], h["topp"], h["keys"]))
     programs[f"serve.prefill_paged_latent@{pgeo2}c{SERVE['chunk']}"] = (
-        lsteps[8], (lparams, lpool, table[0], h["chunk"], np.int32(0),
-                    np.int32(SERVE["chunk"] - 1)))
+        lsteps[8], (lparams, lpool, table[0], h["chunk"], h["meta"],
+                    h["keys"]))
     # ... and their kernel twins (Engine(paged_attn='kernel'), the default
     # on an accelerator): absorbed attention as the latent_attn Mosaic
     # call, traced in interpret mode like the other kernel programs.
@@ -476,12 +478,7 @@ def build_programs() -> dict:
                     h["keys"]))
     programs[f"serve.prefill_paged_windowed@{pgeo2}c{SERVE['chunk']}"] = (
         wsteps[8], (wparams, wpool, (table[0], table[0]), h["chunk"],
-                    np.int32(0), np.int32(SERVE["chunk"] - 1)))
-
-    programs["serve.sample_row@v%d" % SERVE["vocab"]] = (
-        _engine._sample_row,
-        (np.zeros((1, SERVE["vocab"]), np.float32), np.float32(0.0),
-         np.int32(0), np.float32(1.0), h["keys"][0]))
+                    h["meta"], h["keys"]))
 
     # -- prefix-cache block copies (prefix_cache.py) -------------------
     from tpudp.serve import prefix_cache as _prefix

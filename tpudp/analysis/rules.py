@@ -54,7 +54,7 @@ DEVICE_CALL_ATTRS = {
     "verify_step", "prefill_step", "fused_step", "decode_paged",
     "verify_paged", "prefill_paged", "fused_paged", "fused_spec_step",
     "fused_spec_paged", "tree_step", "tree_paged", "copy_block_in",
-    "copy_block_out", "_sample_row",
+    "copy_block_out",
 }
 
 #: Known donating callables (attribute or bare name) → donated

@@ -668,17 +668,16 @@ class DisaggCluster:
 
     def _journal(self) -> None:
         """Refresh every live request's failover journal entry: tokens
-        + the per-slot PRNG chain as of the step that just ran (the
-        keys array is never donated, so between steps it holds the
-        chain as of the last committed token — exactly the
-        vacate/resume carry, read without vacating)."""
+        + the per-slot PRNG chain as of the step that just ran
+        (``Engine._slot_key``: the chain as of the last committed token
+        — exactly the vacate/resume carry, read without vacating)."""
         for creq in self.requests:
             if creq.done or not self.hosts[creq.host].alive:
                 continue
             r = creq.handle
             eng = self.hosts[creq.host].engine
             if r._slot is not None and eng._slots[r._slot] is r:
-                key = np.asarray(eng._keys[r._slot])
+                key = eng._slot_key(r._slot)
             else:
                 key = r._resume_key
             creq.snap = (list(r.tokens), key, r.migrations,

@@ -254,7 +254,7 @@ TRACE_COUNTS = collections.Counter()
 #: Span names of the ``_device`` seam: each times the DISPATCH of one
 #: step program (asynchronous — the device's own time is felt in the
 #: ``fetch`` / ``first_token_wait`` spans that block on its result).
-DEVICE_SPANS = ("prefill", "sample", "decode", "verify", "tree_verify",
+DEVICE_SPANS = ("prefill", "decode", "verify", "tree_verify",
                 "fused_decode", "fused_spec", "prefix_in", "prefix_out")
 
 #: ``Engine.metrics()["stats"]`` seconds counters and the spans each
@@ -266,15 +266,14 @@ OBS_PHASE_SECONDS = {
     "dispatch_s": DEVICE_SPANS,
     "first_token_wait_s": ("first_token_wait",), "pages_s": ("pages",),
     "fetch_wait_s": ("fetch",), "commit_s": ("commit",),
-    "chunk_s": ("chunk",), "keys_s": ("keys",), "batch_s": ("batch",),
-    "canary_s": ("canary",),
+    "chunk_s": ("chunk",), "batch_s": ("batch",), "canary_s": ("canary",),
 }
 
 #: The spans that are ``step``'s direct children: its time in none of
 #: them is the scheduler's own, ``stats["step_self_s"]``.  The prefix
 #: cache's block copies are not among them: they are dispatched inside
 #: ``admit`` and ``commit``.
-STEP_PHASES = ("canary", "admit", "chunk", "keys", "first_token_wait",
+STEP_PHASES = ("canary", "admit", "chunk", "first_token_wait",
                "commit", "batch", "pages", "fetch",
                *(k for k in DEVICE_SPANS if not k.startswith("prefix_")))
 
@@ -392,6 +391,23 @@ def _decode_math(forward, state, last_tokens, lengths, active, temps,
     # request's draw stream must not depend on co-resident requests.
     new_keys = jnp.where(active[:, None], carry, keys)
     return state, toks, new_keys
+
+
+def _first_token(logits, keys, meta):
+    """The ONE first-token tail every prefill program ends with: the
+    chunk's ``last`` row of logits ``(1, vocab)`` is sampled as a decode
+    step samples a row — ``split_keys`` on the slot's initial key, then
+    ``sample_tokens`` with the subkey — and, on the chunk that ends the
+    prompt, the carry becomes the slot's entry of the key chain ``keys``.
+    ``meta`` is the chunk's packed host scalars (:func:`_prefill_meta`).
+    Any other chunk samples greedily and leaves the chain as it was; its
+    token is never read.  Returns ``(token, keys)``."""
+    slot, final = meta[2], meta[3] != 0
+    key = lax.bitcast_convert_type(meta[4:6], jnp.uint32)
+    temp, top_p = lax.bitcast_convert_type(meta[7:9], jnp.float32)
+    carry, sub = split_keys(key[None])
+    tok = sample_tokens(logits, temp[None], meta[6][None], top_p[None], sub)
+    return tok[0], keys.at[slot].set(jnp.where(final, carry[0], keys[slot]))
 
 
 def _verify_math(forward, state, tokens, lengths, active, n_draft,
@@ -701,20 +717,22 @@ def _build_latent_steps(cfg, paged_attn: str):
                 _moe_counts(routed))
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_step_paged(params, pool, row_table, tokens, pos, last):
+    def prefill_step_paged(params, pool, row_table, tokens, meta, keys):
         """One page-aligned prompt chunk of one slot: its latents commit
         as one page write a layer, the window attends causally through
         the slot's table row, rows past ``last`` (a final chunk's padding)
-        reach no expert, and only row ``last`` goes through the head."""
+        reach no expert, and only row ``last`` goes through the head and
+        the shared first-token tail (:func:`_first_token`)."""
         if kernel:
             TRACE_COUNTS["prefill_paged_latent_kernel"] += 1
         else:
             TRACE_COUNTS["prefill_paged_latent"] += 1
         routed: list = []
         logits, new_pool = _forward_paged(
-            cfg, params, tokens, pool, row_table[None], pos,
-            jnp.ones((1,), bool), paged_attn, last=last, routed=routed)
-        return logits[:, 0], new_pool, _moe_counts(routed)
+            cfg, params, tokens, pool, row_table[None], meta[0],
+            jnp.ones((1,), bool), paged_attn, last=meta[1], routed=routed)
+        return (*_first_token(logits[:, 0], keys, meta), new_pool,
+                _moe_counts(routed))
 
     return (None,) * 6 + (decode_step_paged, None, prefill_step_paged,
                           None, None, None)
@@ -748,17 +766,20 @@ def _build_windowed_steps(cfg, paged_attn: str):
                 _moe_counts(routed))
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_step_paged(params, pool, row_table, tokens, pos, last):
+    def prefill_step_paged(params, pool, row_table, tokens, meta, keys):
         """One page-aligned prompt chunk of one slot: its K/V commit as
         one page write a layer into that layer's pool, the window attends
         through the slot's two table rows, rows past ``last`` reach no
-        expert, and only row ``last`` goes through the head."""
+        expert, and only row ``last`` goes through the head and the
+        shared first-token tail (:func:`_first_token`)."""
         TRACE_COUNTS["prefill_paged_windowed"] += 1
         routed: list = []
         logits, new_pool = _forward_paged(
             cfg, params, tokens, pool, tuple(row[None] for row in row_table),
-            pos, jnp.ones((1,), bool), paged_attn, last=last, routed=routed)
-        return logits[:, 0], new_pool, _moe_counts(routed)
+            meta[0], jnp.ones((1,), bool), paged_attn, last=meta[1],
+            routed=routed)
+        return (*_first_token(logits[:, 0], keys, meta), new_pool,
+                _moe_counts(routed))
 
     return (None,) * 6 + (decode_step_paged, None, prefill_step_paged,
                           None, None, None)
@@ -893,22 +914,26 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
             n_steps=n_steps, stream=stream)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_step(params, cache, slot, tokens, pos, last):
+    def prefill_step(params, cache, tokens, meta, keys):
         """One fixed-size prompt chunk for one slot: slice the slot's
         arena row, run the scalar-pos cached forward (batch 1), write
-        the row back.  ``slot``/``pos``/``last`` are traced scalars —
-        chunk number, slot index, and prompt length never recompile.
-        Returns the logits at the chunk's LAST VALID token (index
-        ``last``; the tail of a final partial chunk is padding) and the
-        updated arena."""
+        the row back.  The start position, the chunk's last valid row,
+        the slot and the sampling scalars ride in ``meta``
+        (:func:`_prefill_meta`), traced — chunk number, slot index, and
+        prompt length never recompile.  The logits at the chunk's LAST
+        VALID token (index ``last``; the tail of a final partial chunk
+        is padding) go through the shared first-token tail
+        (:func:`_first_token`); returns its token, the key chain, and
+        the updated arena."""
         TRACE_COUNTS["prefill_chunk"] += 1
+        slot = meta[2]
         k = lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1)
         v = lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1)
         logits, row = _forward_cached(cfg, params, tokens,
-                                      KVCache(k, v), pos)
+                                      KVCache(k, v), meta[0])
         last_logits = lax.dynamic_index_in_dim(
-            logits, last, axis=1, keepdims=False)  # (1, vocab)
-        return last_logits, KVCache(
+            logits, meta[1], axis=1, keepdims=False)  # (1, vocab)
+        return *_first_token(last_logits, keys, meta), KVCache(
             lax.dynamic_update_slice_in_dim(cache.k, row.k, slot, axis=1),
             lax.dynamic_update_slice_in_dim(cache.v, row.v, slot, axis=1))
 
@@ -1064,20 +1089,21 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
                                 top_k, top_p, keys)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def prefill_step_paged(params, pool, row_table, tokens, pos, last):
+        def prefill_step_paged(params, pool, row_table, tokens, meta, keys):
             """Paged prompt chunk through the flash-prefill kernel
             (grid ``chunk_tiles × kv_pages``, causal in-chunk mask,
             online-softmax carry in VMEM): the chunk's KV commits as
             one whole-page write first, then attention streams pages —
             the max_pages-wide score tiles of the einsum path are
-            never materialized."""
+            never materialized.  Ends with the shared first-token tail,
+            as its einsum twin."""
             TRACE_COUNTS["prefill_paged_kernel"] += 1
             logits, new_pool = _forward_paged(
-                cfg, params, tokens, pool, row_table[None], pos,
+                cfg, params, tokens, pool, row_table[None], meta[0],
                 jnp.ones((1,), bool), impl="kernel")
             last_logits = lax.dynamic_index_in_dim(
-                logits, last, axis=1, keepdims=False)  # (1, vocab)
-            return last_logits, new_pool
+                logits, meta[1], axis=1, keepdims=False)  # (1, vocab)
+            return *_first_token(last_logits, keys, meta), new_pool
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n_steps", "stream"))
@@ -1110,20 +1136,21 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
                                 top_k, top_p, keys)
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def prefill_step_paged(params, pool, row_table, tokens, pos, last):
+        def prefill_step_paged(params, pool, row_table, tokens, meta, keys):
             """Paged prompt chunk for one slot: the same scalar-pos
             cached forward the dense prefill runs, read/written through
-            the slot's table row.  Chunk starts are page-aligned (pages
-            are sized to ``prefill_chunk``), so exactly one real page
-            is written per chunk — on the gather-free path as per-token
-            commits into that page, never a view scatter."""
+            the slot's table row, and the same first-token tail.  Chunk
+            starts are page-aligned (pages are sized to
+            ``prefill_chunk``), so exactly one real page is written per
+            chunk — on the gather-free path as per-token commits into
+            that page, never a view scatter."""
             TRACE_COUNTS["prefill_paged"] += 1
             logits, new_pool = _forward_paged(
-                cfg, params, tokens, pool, row_table[None], pos,
+                cfg, params, tokens, pool, row_table[None], meta[0],
                 jnp.ones((1,), bool), impl=win_impl)
             last_logits = lax.dynamic_index_in_dim(
-                logits, last, axis=1, keepdims=False)  # (1, vocab)
-            return last_logits, new_pool
+                logits, meta[1], axis=1, keepdims=False)  # (1, vocab)
+            return *_first_token(last_logits, keys, meta), new_pool
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            static_argnames=("n_steps", "stream"))
@@ -1344,15 +1371,34 @@ class _ModelState:
         self.slot_nodes = None
 
 
-@jax.jit
-def _sample_row(logits, temp, top_k, top_p, key):
-    """First-token sample after a finished prefill: one row through the
-    same masked-sampling op the decode step uses, advancing the slot's
-    key chain exactly once."""
-    TRACE_COUNTS["sample_row"] += 1
-    carry, sub = split_keys(key[None])
-    tok = sample_tokens(logits, temp[None], top_k[None], top_p[None], sub)
-    return tok[0], carry[0]
+def _seed_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` made on the host, bit for bit: the
+    seed as a 64-bit integer (past 64 bits ``OverflowError``, as there),
+    its high word (0 unless 64-bit types are on, where JAX has cut the
+    seed to 32 bits) and its low word as ``uint32``."""
+    seed = int(np.int64(seed))
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
+
+
+def _prefill_meta(start: int, last: int, slot: int, key, temp: float,
+                  top_k: int, top_p: float) -> np.ndarray:
+    """A prefill program's host scalars in ONE int32 array (the float
+    entries by bit pattern): the chunk's start position, its last valid
+    row, the slot, whether the chunk ends the prompt and is sampled
+    (``key`` given: the slot's initial key), the key, and the slot's
+    ``top_k``, temperature and ``top_p``.  A chunk with no key samples
+    greedily and its token is not read (:func:`_first_token`)."""
+    meta = np.zeros(9, np.int32)
+    meta[:3] = start, last, slot
+    if key is None:
+        temp, top_p = 0.0, 1.0
+    else:
+        meta[3] = 1
+        meta[4:6] = np.asarray(key, np.uint32).view(np.int32)
+        meta[6] = top_k
+    meta[7:] = np.array([temp, top_p], np.float32).view(np.int32)
+    return meta
 
 
 def _readiness_probe(out):
@@ -1938,7 +1984,13 @@ class Engine:
                         f"{sorted(k for k in self._mstates if k)})")
         if self._paged:
             self._build_page_pools()
+        # Each slot's PRNG key chain lives on the device and is touched
+        # there only by step programs.  Until a slot's first sample (which
+        # the prefill program that ends its fill makes) its initial key
+        # stays on the host: ``_pending_keys[s]``, None once the chain is
+        # on the device (``_slot_key`` reads whichever holds it).
         self._keys = jnp.zeros((num_slots, 2), jnp.uint32)
+        self._pending_keys: list[np.ndarray | None] = [None] * num_slots
         # An expert family's per-run counters, on the device until the
         # next token fetch takes them along (_count_moe).
         self._moe_pending: list = []
@@ -2770,6 +2822,10 @@ class Engine:
             self.obs.piece(self._xtok, self._xphase)
         spans = self.obs.summary()
         stats = dict(self.stats)
+        # (how often the first-token tail engaged, and the eager updates
+        # of the key chain the fused and speculative replays still make)
+        for key in ("first_tokens_in_prefill", "eager_key_updates"):
+            stats.setdefault(key, 0)
         parts = spans.get("exposed", {}).get("parts", {})
         if self.obs.enabled:
             for key, names in OBS_PHASE_SECONDS.items():
@@ -2864,11 +2920,12 @@ class Engine:
             # A step-failure requeue (or a preemption) resumes the
             # request's saved PRNG chain (already advanced once per
             # committed token), so the retried request's remaining
-            # draws are bit-identical to an uninterrupted run.
-            key = (jnp.asarray(r._resume_key) if r._resume_key is not None
-                   else jax.random.PRNGKey(r.seed))
-            self._keys = self._keys.at[s].set(key)
-            self._enqueued(self._keys)
+            # draws are bit-identical to an uninterrupted run.  The key
+            # waits on the host for the prefill program that ends the
+            # fill: admission does no device work.
+            self._pending_keys[s] = (
+                np.asarray(r._resume_key, np.uint32)
+                if r._resume_key is not None else _seed_key(r.seed))
             self.stats["admitted"] += 1
             self.obs.event(
                 "admit", rid=r.id, slot=s, tenant=r.tenant,
@@ -3502,56 +3559,47 @@ class Engine:
             # a numpy argument in place; the global row only ever grows)
             row = ms.table[s] if ms.wtable is None \
                 else (ms.table[s], ms.wtable[s].copy())
+        final = end == fill.size
+        # A requeued/preempted request can have been vacated AFTER its
+        # final commit — a hang surfacing in its retirement publish
+        # interrupts _retire between the commit and _finish — so its
+        # terminal condition already holds.  Its last chunk then samples
+        # nothing: the resume must reproduce the retirement the
+        # interrupted step was performing, not extend the stream.
+        eos = (final and r.eos_id is not None and bool(r.tokens)
+               and r.tokens[-1] == r.eos_id)
+        spent = eos or (final and len(r.tokens) >= r.max_new_tokens)
+        # Fill fully cached: the chunk's last-token logits are the
+        # request's next sampling event (for a fresh request, the FIRST
+        # — exactly generate()'s prefill-then-sample order; for a
+        # requeued one, event ``len(tokens) + 1`` under the resumed key
+        # chain), sampled inside the program with the slot's pending key.
+        meta = _prefill_meta(
+            start, end - start - 1, s,
+            self._pending_keys[s] if final and not spent else None,
+            self._temps[s], self._topk[s], self._topp[s])
         self._leave(span)
         if self._paged:
-            last_logits, ms.pool.pages, *routed = self._device(
+            tok, self._keys, ms.pool.pages, *routed = self._device(
                 "prefill", ms.prefill_paged, ms.pool.pages, row,
-                buf, np.int32(start), np.int32(end - start - 1))
+                buf, meta, self._keys)
             # an expert family's counts of this run: fetched with the
             # next token fetch, never on their own
             self._moe_pending += routed
         else:
-            last_logits, ms.cache = self._device(
-                "prefill", ms.prefill_step, ms.cache, np.int32(s), buf,
-                np.int32(start), np.int32(end - start - 1))
+            tok, self._keys, ms.cache = self._device(
+                "prefill", ms.prefill_step, ms.cache, buf, meta,
+                self._keys)
         r._nfill = end
         self._len[s] = end
         self.stats["prefill_chunks"] += 1
-        if end == fill.size:
-            # A requeued/preempted request can have been vacated AFTER
-            # its final commit — a hang surfacing in its retirement
-            # publish interrupts _retire between the commit and _finish
-            # — so its terminal condition already holds.  Retire it now
-            # instead of sampling a token past its budget (or past its
-            # committed eos): the resume must reproduce the retirement
-            # the interrupted step was performing, not extend the
-            # stream.
-            if r.eos_id is not None and r.tokens \
-                    and r.tokens[-1] == r.eos_id:
-                self._retire(s, FinishReason.EOS)
-                return
-            if len(r.tokens) >= r.max_new_tokens:
-                self._retire(s, FinishReason.COMPLETE)
-                return
-            # Fill fully cached: the chunk's last-token logits are the
-            # request's next sampling event (for a fresh request, the
-            # FIRST — exactly generate()'s prefill-then-sample order;
-            # for a requeued one, event ``len(tokens) + 1`` under the
-            # resumed key chain).
-            span = self._enter("keys")  # the slot's key: an eager slice
-            key = self._keys[s]
-            self._enqueued(key)
-            self._leave(span)
-            tok, carry = self._device(
-                "sample", _sample_row, last_logits, self._temps[s],
-                self._topk[s], self._topp[s], key)
-            span = self._enter("keys")
-            self._keys = self._keys.at[s].set(carry)
-            self._enqueued(self._keys)
-            self._leave(span)
+        if spent:
+            self._retire(s, FinishReason.EOS if eos
+                         else FinishReason.COMPLETE)
+        elif final:
             # The sync that keeps this step's decode from being queued
             # behind the prompt's last chunk: the host waits here for
-            # the chunk and the sampler to finish.
+            # the chunk, and the token sampled at its end, to finish.
             span = self._enter("first_token_wait")
             # tpudp: lint-ok(host-sync): the FIRST-token commit — one
             # fetch per completed prefill, not per decoded token; the
@@ -3559,8 +3607,10 @@ class Engine:
             # (_run_decode_fused) when fusing is on.
             tok = int(tok)
             self._leave(span)
+            self._pending_keys[s] = None  # the chain is on the device now
+            self.stats["first_tokens_in_prefill"] += 1
             span = self._enter("commit")
-            self._poll()  # the key chain's update was enqueued after it
+            self._poll()
             self._commit(s, tok, emitted)
             self._leave(span)
 
@@ -3689,6 +3739,7 @@ class Engine:
             # later slot's chain ahead of its stream.
             self._keys = self._keys.at[s].set(keys[s])
             self._enqueued(self._keys)
+            self.stats["eager_key_updates"] += 1
             for j in range(int(n_emit[s])):
                 if self._slots[s] is not r:
                     break  # retired (EOS / budget / cancel) mid-replay
@@ -3990,6 +4041,7 @@ class Engine:
             # containment-mid-replay argument of _run_decode_fused.
             self._keys = self._keys.at[s].set(keys[s])
             self._enqueued(self._keys)
+            self.stats["eager_key_updates"] += 1
             for j in range(int(n_emit[s])):
                 if self._slots[s] is not r:
                     break  # retired (EOS / budget / cancel) mid-replay
@@ -4205,18 +4257,16 @@ class Engine:
 
     def _vacate_slot(self, s: int) -> Request:
         """Clear slot ``s``'s per-slot state and prepare its request
-        for a bit-identical resume: the per-slot PRNG chain — the keys
-        array is never donated, so it holds the chain as of the last
-        COMMITTED token — is saved on the handle, and the refill
-        becomes ``prompt + tokens``.  The one carry-over path shared by
-        step-failure requeue and preemption: both resume under the same
-        contract, so a new per-slot array added to one must by
-        construction be cleared for the other."""
+        for a bit-identical resume: the per-slot PRNG chain as of the
+        last COMMITTED token (:meth:`_slot_key`) is saved on the handle,
+        and the refill becomes ``prompt + tokens``.  The one carry-over
+        path shared by step-failure requeue and preemption: both resume
+        under the same contract, so a new per-slot array added to one
+        must by construction be cleared for the other."""
         r = self._slots[s]
         self._release_slot_pages(r._ms, s)
-        key = self._keys[s]
-        self._enqueued(key)
-        key = np.asarray(key)
+        key = self._slot_key(s)
+        self._pending_keys[s] = None
         self._slots[s] = None
         self._len[s] = 0
         self._temps[s] = 0.0
@@ -4228,6 +4278,18 @@ class Engine:
         r._fill = np.concatenate([r.prompt,
                                   np.asarray(r.tokens, np.int32)])
         return r
+
+    def _slot_key(self, s: int) -> np.ndarray:
+        """Slot ``s``'s PRNG key chain as of its last committed token:
+        the host's pending key until the slot's first sample, the
+        device's chain after it (an eager slice and a fetch: the keys
+        array is never donated, so between steps it holds that chain)."""
+        key = self._pending_keys[s]
+        if key is None:
+            key = self._keys[s]
+            self._enqueued(key)
+            key = np.asarray(key)
+        return key
 
     def _retire(self, s: int, reason: FinishReason,
                 error: BaseException | None = None) -> None:
@@ -4245,6 +4307,7 @@ class Engine:
         self._release_slot_pages(r._ms, s)
         r._slot = None
         self._slots[s] = None
+        self._pending_keys[s] = None
         self._len[s] = 0  # slot recycled; the next prefill overwrites from 0
         # Reset sampling params too: a stale temperature/top-k on an
         # EMPTY slot would keep tripping the sampling op's any-sampled /

@@ -20,7 +20,7 @@ Two injection seams, both first-class engine API:
   * **Step faults** — :class:`FaultySteps` and :class:`SlowSteps` are
     ``Engine(step_fault_hook=...)`` callables invoked as
     ``hook(kind, index)`` immediately before each jitted device call
-    (``kind`` in ``{"prefill", "sample", "decode", "verify",
+    (``kind`` in ``{"prefill", "decode", "verify",
     "prefix_in", "prefix_out"}`` — the last two only with prefix
     caching on;
     ``index`` is the engine's monotonically increasing device-call
