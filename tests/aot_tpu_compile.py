@@ -216,6 +216,46 @@ def laguna_steps():
             for name, (fn, args) in steps.items()}
 
 
+def brumby_steps():
+    """The serve engine's two programs for the power-retention family at
+    the benchmark cell's real sizes (perf/configs/brumby_14b_base.json; 48
+    slots, 512-token chunks, the kernel backend): the state arena (6.64 GB
+    at 48 slots: 6.54 of float32 state, 0.10 of tails) in place beside
+    5.75 GB of weights, the decode program reading it through one
+    ``retention_decode`` Mosaic call a layer.  The slot arena's programs
+    are ``_build_steps``' first and third."""
+    import json
+
+    from perf.families.brumby_retention import build_model
+    from tpudp.models.generate import RetentionState
+    from tpudp.serve import engine
+
+    from tpudp.ops import retention
+
+    retention._interpret_default = lambda: False
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "brumby_14b_base.json")) as f:
+        model = build_model(json.load(f))  # as the cell's driver builds it
+    cfg = model.config
+    params = shapes(jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 16), jnp.int32))["params"],
+        jax.random.PRNGKey(0)))
+    state = shapes(jax.eval_shape(
+        lambda: RetentionState.zeros(cfg, BRUMBY_SLOTS)))
+    steps = engine._build_steps(cfg, "kernel")
+    i32, f32, slots = jnp.int32, jnp.float32, BRUMBY_SLOTS
+    return {
+        "brumby_decode": (steps[0], (
+            params, state, sds((slots,), i32), sds((slots,), i32),
+            sds((slots,), jnp.bool_), sds((slots,), f32),
+            sds((slots,), i32), sds((slots,), f32),
+            sds((slots, 2), jnp.uint32))),
+        "brumby_prefill": (steps[2], (
+            params, state, sds((1, 512), i32), sds((9,), i32),
+            sds((slots, 2), jnp.uint32)))}
+
+
 def gpt2m_steps(pages):
     """The serve engine's paged decode and prefill programs for
     perf/configs/gpt2_medium.json at `gpt2m.serve_closed`'s sizes (64
@@ -250,6 +290,9 @@ def gpt2m_steps(pages):
                         chunk=chunk)
 
 
+BRUMBY_SLOTS = 48
+# programs with no Mosaic call: a retention prefill chunk is XLA's
+NO_KERNEL = {"brumby_prefill"}
 LATENT_PAGES = 1024
 LAGUNA_PAGES = (1024, 128)  # global pages; window pages: 64 slots x 2
 ANC = tuple(map(tuple, np.tril(np.ones((5, 5), np.int32))))
@@ -270,6 +313,7 @@ CASES = {
     "lfm2_train_step": lfm2_step(),
     **latent_steps(),
     **laguna_steps(),
+    **brumby_steps(),
     **gpt2m_steps(288), **gpt2m_steps(512),
     "flash_fwd": (flash, (x, x, x)),
     "flash_bwd": (jax.grad(lambda q, k, v: flash(q, k, v).astype(
@@ -295,7 +339,8 @@ CASES = {
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
            "paged_prefill", "paged_decode_window", "paged_prefill_window",
            "paged_tree", "latent_attn", "moe_gmm", "moe_tgmm",
-           "moe_swiglu", "moe_swiglu_bwd", "moe_combine", "moe_unwritten")
+           "moe_swiglu", "moe_swiglu_bwd", "moe_combine", "moe_unwritten",
+           "retention_decode")
 MOSAIC_OP = re.compile(
     r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"')
 
@@ -306,7 +351,8 @@ for name, (fn, args) in CASES.items():
         continue
     try:
         lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
-        assert "tpu_custom_call" in lowered.as_text(), "no Mosaic call"
+        assert "tpu_custom_call" in lowered.as_text() \
+            or name in NO_KERNEL, "no Mosaic call"
         compiled = lowered.compile()
         text = compiled.as_text()
         ops = MOSAIC_OP.findall(text)
@@ -315,10 +361,12 @@ for name, (fn, args) in CASES.items():
         print(f"OK {name}")
         print(f"KERNELS {name} " + ",".join(
             f"{k}={n}" for k, n in sorted(calls.items()) if n))
-        if name.startswith(("latent_", "gpt2m_", "laguna_")):
+        if name.startswith(("latent_", "gpt2m_", "laguna_", "brumby_")):
             mem = compiled.memory_analysis()
             pool = (rf"bf16\[5,{LATENT_PAGES + 1},512,\d+\]"
                     if name.startswith("latent_") else
+                    rf"f32\[4,{BRUMBY_SLOTS},8,8256,128\]"
+                    if name.startswith("brumby_") else
                     rf"bf16\[(?:2,{LAGUNA_PAGES[0] + 1}|3,"
                     rf"{LAGUNA_PAGES[1] + 1}),512,1024\]"
                     if name.startswith("laguna_") else

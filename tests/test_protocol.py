@@ -339,6 +339,11 @@ def test_program_donations_mirror_rules_tables():
         # the window-and-full-attention expert family's two (PR 36)
         "serve.decode_paged_windowed": "decode_paged",
         "serve.prefill_paged_windowed": "prefill_paged",
+        # the power-retention family's dispatch through the slot
+        # arena's two attributes, the state arena donated as the KV one
+        "serve.decode_retention": "decode_step",
+        "serve.decode_retention_kernel": "decode_step",
+        "serve.prefill_retention": "prefill_step",
         "serve.verify_paged": "verify_paged",
         # ... and likewise for the remaining ISSUE 17 kernel twins:
         # each dispatches through the same engine attribute as its

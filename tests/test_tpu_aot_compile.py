@@ -4,8 +4,9 @@ host (tests/aot_tpu_compile.py).  This is a compile check only (lowering,
 Mosaic passes, VMEM fit at GPT-2-small geometry and, for the grouped
 matmuls and the whole expert layer with its row kernels, at the LFM2
 layer's real shapes; one whole LFM2-MoE train step at small widths; the
-serve engine's decode and prefill programs at three benchmark cells' real
-sizes, held to one layout of the page pool and the chip's memory); that
+serve engine's decode and prefill programs at four benchmark cells' real
+sizes, held to one layout of the page pool or state arena and the chip's
+memory); that
 the compiled kernels
 compute the right numbers is `chip_smoke.py`'s job on the chip."""
 
@@ -38,7 +39,7 @@ def compiled():
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "aot_tpu_compile.py")],
-        capture_output=True, text=True, timeout=600, env=env)
+        capture_output=True, text=True, timeout=900, env=env)
     if proc.returncode == 77:
         pytest.skip(proc.stdout.strip().splitlines()[-1])
     return proc
@@ -71,6 +72,7 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
                   "paged_tree", "moe_gmm_up", "moe_gmm_down", "moe_layer",
                   "lfm2_train_step", "latent_decode", "latent_prefill",
                   "laguna_decode", "laguna_prefill",
+                  "brumby_decode", "brumby_prefill",
                   *GPT2M_STEPS}, proc.stdout
 
 
@@ -92,6 +94,8 @@ def test_kernel_families_compile_for_v5e_topology(compiled):
     ("laguna_decode", MOE_FORWARD | {"paged_decode", "paged_decode_window"}),
     ("laguna_prefill", MOE_FORWARD | {"paged_prefill",
                                       "paged_prefill_window"}),
+    ("brumby_decode", {"retention_decode"}),
+    ("brumby_prefill", set()),
     *((program, {kernel}) for program, kernel in GPT2M_STEPS.items())])
 def test_each_mosaic_call_carries_its_kernels_name(compiled, family, kernels):
     """The stable names the device trace is read by (PR 26): the compiled
@@ -161,6 +165,29 @@ def test_a_windowed_step_program_fits_and_keeps_both_pools_in_place(
     calls = _kernel_calls(compiled)[program]
     assert {k: calls[k] for k in kernels} == kernels, calls
     assert calls["moe_gmm"] == 12
+
+
+@pytest.mark.parametrize("program", ["brumby_decode", "brumby_prefill"])
+def test_a_retention_step_program_fits_and_keeps_the_state_in_place(
+        compiled, program):
+    """The serve engine's programs for the power-retention family at the
+    benchmark cell's sizes (`brumby.serve_closed_longout`: 5.75 GB of
+    weights, a 48-slot arena of 6.64 GB: four layers x 8 KV heads of an
+    `(8,256, 128)` float32 state, its normaliser and a 128-row tail): the
+    program fits the v5e's 15.75 GB, the state keeps its one row-major
+    layout with no `copy` of its shape and under 1 GB of temporaries (a
+    prefill chunk reads and writes its slot's rows of the arena in place:
+    a whole layer's slice of it was 1.6 GB of temporaries), and a decode
+    program reads the state through one `retention_decode` call a layer."""
+    fields = _pool_line(compiled, program)
+    assert fields["layouts"] == "4,3,2,1,0", fields
+    assert int(fields["copies"]) == 0, fields
+    assert int(fields["temp"]) < 1e9, fields
+    weights, arena = 2 * 2877241344, 6.642e9
+    assert weights + arena < int(fields["bytes"]) < 15.75e9, fields
+    calls = _kernel_calls(compiled).get(program, {})
+    assert calls == ({"retention_decode": 4} if program == "brumby_decode"
+                     else {}), calls
 
 
 @pytest.mark.parametrize("program", list(GPT2M_STEPS))

@@ -38,10 +38,12 @@ AUDIT_SOURCES = (
     "tpudp/models/llama.py",
     "tpudp/models/pangu.py",
     "tpudp/models/laguna.py",
+    "tpudp/models/brumby.py",
     "tpudp/models/moe.py",
     "tpudp/ops/sampling.py",
     "tpudp/ops/attention.py",
     "tpudp/ops/paged_attention.py",
+    "tpudp/ops/retention.py",
     "tpudp/ops/losses.py",
     "tpudp/train.py",
     "tpudp/parallel/sync.py",
@@ -73,6 +75,9 @@ TRACE_COUNTER_PROGRAMS = {
     "prefill_paged_latent": "serve.prefill_paged_latent",
     "prefill_paged_latent_kernel": "serve.prefill_paged_latent_kernel",
     "prefill_paged_windowed": "serve.prefill_paged_windowed",
+    "decode_retention": "serve.decode_retention",
+    "decode_retention_kernel": "serve.decode_retention_kernel",
+    "prefill_retention": "serve.prefill_retention",
     "fused_decode_paged": "serve.fused_decode_paged",
     "fused_decode_paged_kernel": "serve.fused_decode_paged_kernel",
     "fused_spec_decode": "serve.fused_spec_decode",
@@ -118,6 +123,11 @@ PROGRAM_DONATIONS = {
     # both pools donate together; the two tables never)
     "serve.decode_paged_windowed": (1,),
     "serve.prefill_paged_windowed": (1,),
+    # the power-retention family's two (RetentionState, a slot arena of
+    # fixed-size states: donated like the dense arena)
+    "serve.decode_retention": (1,),
+    "serve.decode_retention_kernel": (1,),
+    "serve.prefill_retention": (1,),
     "serve.verify_paged": (1,),
     "serve.verify_paged_kernel": (1,),
     "serve.prefill_paged": (1,),
@@ -479,6 +489,28 @@ def build_programs() -> dict:
     programs[f"serve.prefill_paged_windowed@{pgeo2}c{SERVE['chunk']}"] = (
         wsteps[8], (wparams, wpool, (table[0], table[0]), h["chunk"],
                     h["meta"], h["keys"]))
+
+    # The power-retention family (tpudp/models/brumby.py): its two
+    # programs over a RetentionState slot arena at the same smoke
+    # geometry (fold blocks of the chunk's size), the decode read as XLA
+    # contractions and as the retention_decode Mosaic call (interpreted).
+    from tpudp.models.brumby import Brumby, BrumbyConfig
+    from tpudp.models.generate import RetentionState
+
+    rcfg = BrumbyConfig(vocab_size=SERVE["vocab"],
+                        max_position_embeddings=SERVE["seq"],
+                        fold_tokens=SERVE["chunk"])
+    rparams = Brumby(rcfg).init(jax.random.PRNGKey(0),
+                                jnp.zeros((1, 8), jnp.int32))["params"]
+    rstate = RetentionState.zeros(rcfg, SERVE["slots"])
+    rdecode = (rparams, rstate, h["last"], h["lens"], h["active"],
+               h["temps"], h["topk"], h["topp"], h["keys"])
+    rsteps = _engine._build_steps(rcfg, "einsum")
+    programs[f"serve.decode_retention@{geo}"] = (rsteps[0], rdecode)
+    programs[f"serve.decode_retention_kernel@{geo}"] = (
+        _engine._build_steps(rcfg, "kernel")[0], rdecode)
+    programs[f"serve.prefill_retention@{geo}c{SERVE['chunk']}"] = (
+        rsteps[2], (rparams, rstate, h["chunk"], h["meta"], h["keys"]))
 
     # -- prefix-cache block copies (prefix_cache.py) -------------------
     from tpudp.serve import prefix_cache as _prefix

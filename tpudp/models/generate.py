@@ -34,6 +34,10 @@ window and full attention layers mixed, with routed experts
 (``tpudp.models.laguna``), whose cache is :class:`WindowedPages`.
 Neither has a dense cache twin, so ``generate()`` and ``beam_search()``
 refuse them.
+
+A fifth family keeps no K/V by token at all: power retention
+(``tpudp.models.brumby``), whose cache is :class:`RetentionState`, a
+fixed-size state a slot, run by :func:`_forward_state`.
 """
 
 from __future__ import annotations
@@ -202,10 +206,56 @@ class WindowedPages(NamedTuple):
                    pages(kinds.count(True), wp))
 
 
+class RetentionState(NamedTuple):
+    """The slot arena of a power-retention family
+    (``tpudp.models.brumby``): a fixed-size state a slot, whatever the
+    context.  Per layer, slot and KV head: ``S`` ``(D, head_dim)`` and
+    ``z`` ``(D,)`` in float32 (``D = head_dim (head_dim + 1) / 2``,
+    the symmetric form of the degree-2 feature map), and the tail of the
+    ``fold_tokens`` (``C``) positions since the last fold: ``tail_k``,
+    ``tail_v`` ``(C, head_dim)`` in the compute type and ``tail_g``
+    ``(C,)`` their cumulative log-gates from the fold, float32.  A slot's
+    tail holds ``pos mod C`` rows: the fold boundary is every ``C``
+    absolute positions.  Indexed by slot, zeroed by the prefill chunk at
+    position 0, never paged, shared or vacated."""
+
+    S: jnp.ndarray  # (layers, slots, kv_heads, D, head_dim)
+    z: jnp.ndarray  # (layers, slots, kv_heads, D)
+    tail_k: jnp.ndarray  # (layers, slots, kv_heads, C, head_dim)
+    tail_v: jnp.ndarray
+    tail_g: jnp.ndarray  # (layers, slots, kv_heads, C)
+
+    @classmethod
+    def geometry(cls, cfg) -> tuple:
+        return ("state", cfg.num_layers, cfg.num_key_value_heads,
+                cfg.state_features, cfg.head_dim, cfg.fold_tokens,
+                str(jnp.dtype(cfg.dtype)))
+
+    @classmethod
+    def zeros(cls, cfg, num_slots: int) -> "RetentionState":
+        _, layers, kv, D, dh, c, cdt = cls.geometry(cfg)
+        lead = (layers, num_slots, kv)
+        return cls(jnp.zeros(lead + (D, dh), jnp.float32),
+                   jnp.zeros(lead + (D,), jnp.float32),
+                   jnp.zeros(lead + (c, dh), cdt),
+                   jnp.zeros(lead + (c, dh), cdt),
+                   jnp.zeros(lead + (c,), jnp.float32))
+
+
+def slot_arena(cfg, num_slots: int, max_len: int):
+    """A config's unpaged slot arena: :class:`KVCache` of ``max_len``
+    positions, or for a power-retention family its
+    :class:`RetentionState` (nothing in it grows with ``max_len``)."""
+    if page_layout(cfg) == "state":
+        return RetentionState.zeros(cfg, num_slots)
+    return KVCache.zeros(cfg, num_slots, max_len)
+
+
 def page_layout(cfg) -> str:
     """``'heads'`` (K and V per KV head: GPT-2, LLaMA; :class:`KVPages`
-    or :class:`Int8Pages`), ``'latent'`` (:class:`LatentPages`) or
-    ``'windowed'`` (:class:`WindowedPages`), from the model config."""
+    or :class:`Int8Pages`), ``'latent'`` (:class:`LatentPages`),
+    ``'windowed'`` (:class:`WindowedPages`) or ``'state'`` (no pages: a
+    :class:`RetentionState` a slot), from the model config."""
     return getattr(cfg, "page_layout", "heads")
 
 
@@ -214,6 +264,9 @@ def page_type(cfg, kv_dtype: str | None = None):
     ``zeros(cfg, num_pages, page_tokens)`` and ``geometry(cfg)``, the
     tuple two models must share to share one pool."""
     layout = page_layout(cfg)
+    if layout == "state":
+        raise ValueError(f"{type(cfg).__name__} keeps a state a slot and "
+                         "no pages")
     if layout != "heads":
         if kv_dtype is not None:
             raise ValueError(
@@ -666,6 +719,26 @@ def _forward_paged(cfg, params: dict, tokens: jnp.ndarray, pool,
     return head(cfg, params, x), new_pool
 
 
+def _forward_state(cfg, params: dict, tokens: jnp.ndarray, state,
+                   pos: jnp.ndarray, active: jnp.ndarray,
+                   impl: str | None = None, *, slot=None, last=None,
+                   every_row: bool = False):
+    """The slot-arena twin of :func:`_forward_paged` for the fifth
+    family, power retention (``tpudp.models.brumby``): ``(logits,
+    state)`` through a :class:`RetentionState`.  A decode step (``slot``
+    None) feeds one token a slot at depths ``pos``; a prefill chunk feeds
+    slot ``slot`` from position ``pos`` with rows past ``last`` as
+    padding (``every_row``: every row's logits, not only ``last``'s).
+    ``impl``: the decode read, ``'einsum'`` or ``'kernel'``
+    (``tpudp.ops.retention``), unset the kernel on an accelerator and
+    einsum on the CPU."""
+    from tpudp.models import brumby as _brumby
+
+    return _brumby.forward_state(cfg, params, tokens, state, pos, active,
+                                 impl, slot=slot, last=last,
+                                 every_row=every_row)
+
+
 def _layer_norm(p: dict, x: jnp.ndarray, eps: float) -> jnp.ndarray:
     """Exactly the training model's LayerNorm (flax apply on the raw
     subtree, same epsilon), so decode can never drift numerically from
@@ -952,6 +1025,11 @@ def validate_decode_config(cfg, fn_name: str) -> None:
 
 def _validate_decode(cfg, prompt, max_new_tokens: int, fn_name: str) -> int:
     """Shared decode-entry checks; returns the total sequence length."""
+    if page_layout(cfg) == "state":
+        raise ValueError(
+            f"{fn_name} has no dense-cache twin for a power-retention "
+            f"config ({type(cfg).__name__}): its cache is a state a slot; "
+            f"serve it through tpudp.serve.Engine")
     if page_layout(cfg) != "heads":
         raise ValueError(
             f"{fn_name} has no dense-cache twin for a "

@@ -235,11 +235,11 @@ import numpy as np
 from jax import lax
 
 from tpudp.models.generate import (Int8Pages, KVCache, _forward_cached,
-                                   _forward_paged, _forward_tree,
-                                   _forward_tree_paged, _layer_pages,
-                                   _stack_pages, gather_pages, page_layout,
-                                   page_type, update_cache_rows,
-                                   validate_decode_config,
+                                   _forward_paged, _forward_state,
+                                   _forward_tree, _forward_tree_paged,
+                                   _layer_pages, _stack_pages, gather_pages,
+                                   page_layout, page_type, slot_arena,
+                                   update_cache_rows, validate_decode_config,
                                    write_token_pages)
 from tpudp.obs import NO_SPAN, FlightRecorder, Recorder
 from tpudp.ops.sampling import (sample_tokens, split_keys, tree_depths,
@@ -268,6 +268,13 @@ OBS_PHASE_SECONDS = {
     "fetch_wait_s": ("fetch",), "commit_s": ("commit",),
     "chunk_s": ("chunk",), "batch_s": ("batch",), "canary_s": ("canary",),
 }
+
+#: ``Engine.metrics()["stats"]`` counters of a power-retention family's
+#: work, counted on the host from the slots' depths (no fetch): slot-layers
+#: whose state a decode run read, tail rows it attended, slot-layers whose
+#: full tail it folded, and prompt tokens its prefill chunks took.
+RETENTION_STATS = ("retention_state_reads", "retention_tail_rows",
+                   "retention_folds", "retention_prefill_tokens")
 
 #: The spans that are ``step``'s direct children: its time in none of
 #: them is the scheduler's own, ``stats["step_self_s"]``.  The prefix
@@ -785,6 +792,51 @@ def _build_windowed_steps(cfg, paged_attn: str):
                           None, None, None)
 
 
+def _build_retention_steps(cfg, paged_attn: str):
+    """The step programs of a power-retention family
+    (``tpudp.models.brumby``): ``decode_step`` and ``prefill_step`` over
+    its slot arena, ``generate.RetentionState`` (donated: updated in
+    place), and no other (the engine refuses at construction what would
+    need one).  Same calling convention as the dense arena's programs.
+    ``paged_attn``: the decode read, ``'kernel'`` (the
+    ``retention_decode`` Mosaic call, a TRACE_COUNTS key of its own) or
+    ``'einsum'``; a prefill chunk runs the chunked form in XLA either
+    way."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_step(params, state, last_tokens, lengths, active, temps,
+                    top_k, top_p, keys):
+        """One token for every slot: the shared ``_decode_math`` body over
+        ``generate._forward_state`` (each decoding slot's tail row written,
+        state and tail read, the tails that filled folded)."""
+        if paged_attn == "kernel":
+            TRACE_COUNTS["decode_retention_kernel"] += 1
+        else:
+            TRACE_COUNTS["decode_retention"] += 1
+
+        def fwd(state, tokens, lengths, active):
+            return _forward_state(cfg, params, tokens, state, lengths,
+                                  active, paged_attn)
+
+        return _decode_math(fwd, state, last_tokens, lengths, active, temps,
+                            top_k, top_p, keys)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_step(params, state, tokens, meta, keys):
+        """One prompt chunk of one slot from its state (zero at position
+        0), its whole fold blocks folded and the rest left as the slot's
+        tail; rows past ``last`` leave both as they were, and only row
+        ``last`` goes through the head and the shared first-token tail
+        (:func:`_first_token`)."""
+        TRACE_COUNTS["prefill_retention"] += 1
+        logits, state = _forward_state(
+            cfg, params, tokens, state, meta[0], jnp.ones((1,), bool),
+            paged_attn, slot=meta[2], last=meta[1])
+        return *_first_token(logits[:, 0], keys, meta), state
+
+    return (decode_step, None, prefill_step) + (None,) * 9
+
+
 @functools.lru_cache(maxsize=16)
 def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
     """Jitted step programs for one model CONFIG.  Every program takes
@@ -832,8 +884,11 @@ def _build_steps(cfg, paged_attn: str = "einsum", draft_cfg=None):
 
     A latent-attention or a windowed config (``generate.page_layout``)
     builds its two paged programs only (:func:`_build_latent_steps`,
-    :func:`_build_windowed_steps`).
+    :func:`_build_windowed_steps`), a power-retention config its two
+    slot-arena programs (:func:`_build_retention_steps`).
     """
+    if page_layout(cfg) == "state":
+        return _build_retention_steps(cfg, paged_attn)
     if page_layout(cfg) == "latent":
         return _build_latent_steps(cfg, paged_attn)
     if page_layout(cfg) == "windowed":
@@ -1693,11 +1748,36 @@ class Engine:
         layout = page_layout(cfg)
         #: The family's name in a refusal; None for GPT-2 and LLaMA, which
         #: have every program.  The two expert families have their two
-        #: paged programs only.
+        #: paged programs only, the power-retention family its two
+        #: slot-arena programs.
         self._two_programs = {
             "latent": "latent-attention",
-            "windowed": "window-and-full-attention"}.get(layout)
-        if self._two_programs:
+            "windowed": "window-and-full-attention",
+            "state": "power-retention"}.get(layout)
+        self._retention = layout == "state"
+        if self._retention:
+            refusals = [
+                ("kv_pages", kv_pages > 0,
+                 "the state arena is indexed by slot: no pages"),
+                ("kv_dtype", kv_dtype is not None,
+                 "the state is kept in its own type, the tail in the "
+                 "compute type"),
+                ("prefix_cache_blocks", prefix_cache_blocks > 0,
+                 "no prefix reuse: a state is not a function of a prefix's "
+                 "blocks (no state snapshots)"),
+                ("speculate_tree", speculate_tree is not None,
+                 "no tree-verify program"),
+                ("speculate_k", speculate_k > 0,
+                 "no verify program (no rollback of a state)"),
+                ("decode_fuse", decode_fuse > 1, "no fused decode program"),
+                ("models", bool(models), "no co-residence: one model an "
+                 "arena"),
+                ("paged_attn", paged_attn == "gather",
+                 "no dense view of a state: 'einsum' or 'kernel'"),
+                ("prefill_chunk", prefill_chunk % cfg.fold_tokens != 0,
+                 f"prefill_chunk must be a multiple of fold_tokens "
+                 f"({cfg.fold_tokens}): a chunk starts on a fold boundary")]
+        elif self._two_programs:
             # What these families do not serve yet, refused by option name
             # (docs/SERVING.md, the family table).
             refusals = [
@@ -1721,6 +1801,7 @@ class Engine:
                      f"kv_pages must hold every slot's full reservation "
                      f"({full} pages): a vacated slot's window pages "
                      f"cannot be resumed yet")]
+        if self._two_programs:
             for option, refused, why in refusals:
                 if refused:
                     raise ValueError(
@@ -1773,7 +1854,7 @@ class Engine:
                 f"Pallas hot-path kernels, tolerance-bounded); got "
                 f"{paged_attn!r}")
         if paged_attn is not None and paged_attn != "einsum" \
-                and not kv_pages:
+                and not kv_pages and not self._retention:
             raise ValueError(
                 f"paged_attn={paged_attn!r} requires kv_pages > 0 — the "
                 f"paged-attention backend choice only exists behind the "
@@ -1786,7 +1867,7 @@ class Engine:
         # tpu": an accelerator backend must never land on einsum unasked.
         self.paged_attn_requested = paged_attn
         if paged_attn is None:
-            paged_attn = ("kernel" if kv_pages
+            paged_attn = ("kernel" if (kv_pages or self._retention)
                           and jax.default_backend() != "cpu" else "einsum")
         if drafter is not None and speculate_k == 0:
             raise ValueError("drafter requires speculate_k >= 1 "
@@ -2097,7 +2178,8 @@ class Engine:
                     f"speculation requires a shared tokenizer")
         dcfg, dparams = self._draft_pair or (None, None)
         ms = _ModelState(name, model, params,
-                         _build_steps(cfg, self.paged_attn if self._paged
+                         _build_steps(cfg, self.paged_attn
+                                      if self._paged or self._retention
                                       else "einsum", dcfg),
                          draft_params=dparams)
         # Prefix cache: blocks sized to prefill_chunk so a cached block
@@ -2116,7 +2198,7 @@ class Engine:
         # registered (_build_page_pools); until then ms.cache stays
         # None, which every dense-only path below is gated on.
         if not self._paged:
-            ms.cache = KVCache.zeros(cfg, self.num_slots, self.max_len)
+            ms.cache = slot_arena(cfg, self.num_slots, self.max_len)
         self._mstates[name] = ms
 
     def _build_page_pools(self) -> None:
@@ -2568,7 +2650,8 @@ class Engine:
                 f"Engine.{method}() is not served for the "
                 f"{self._two_programs} family "
                 f"({type(self.config).__name__}): the migration wire "
-                f"format carries the K/V pages of one pool only")
+                f"format carries the K/V pages of one pool only"
+                + (", not a slot's state" if self._retention else ""))
 
     def export_ticket(self, request: Request):
         """Detach a live request into a :class:`tpudp.serve.disagg.
@@ -2887,6 +2970,16 @@ class Engine:
                 # the span of a windowed family's sliding layers
                 "window": getattr(self.config, "sliding_window", None),
             }
+        if self._retention:
+            from tpudp.models.brumby import state_bytes
+
+            for key in RETENTION_STATS:
+                stats.setdefault(key, 0)
+            out["retention_state_bytes"] = state_bytes(self.config,
+                                                       self.num_slots)
+            out["retention"] = {"requested": self.paged_attn_requested,
+                                "resolved": self.paged_attn,
+                                "fold_tokens": self.config.fold_tokens}
         if self.stats.get("draft_tokens"):
             out["acceptance_rate"] = self.acceptance_rate
         return out
@@ -3489,8 +3582,8 @@ class Engine:
                 ms.slot_nodes = [dict() for _ in range(self.num_slots)]
                 self.stats["prefix_flushes"] += 1
             else:
-                ms.cache = KVCache.zeros(ms.config, self.num_slots,
-                                         self.max_len)
+                ms.cache = slot_arena(ms.config, self.num_slots,
+                                      self.max_len)
             # A rebuilt arena invalidates the published blocks
             # wholesale: the failed call may have been a block copy
             # with either buffer donated, and after an arbitrary device
@@ -3590,6 +3683,8 @@ class Engine:
             tok, self._keys, ms.cache = self._device(
                 "prefill", ms.prefill_step, ms.cache, buf, meta,
                 self._keys)
+            if self._retention:
+                self.stats["retention_prefill_tokens"] += end - start
         r._nfill = end
         self._len[s] = end
         self.stats["prefill_chunks"] += 1
@@ -3625,6 +3720,16 @@ class Engine:
             for key, val in zip(SERVE_MOE_COUNTERS, vals):
                 self.stats[key] += int(val)
 
+    def _count_retention(self, active) -> None:
+        """A power-retention decode run's work, on the host (no fetch):
+        slot-layers whose state it read, tail rows it attended (the fed
+        token's included), slot-layers whose tail it folded."""
+        layers, c = self.config.num_layers, self.config.fold_tokens
+        at = self._len[active] % c
+        self.stats["retention_state_reads"] += layers * int(active.sum())
+        self.stats["retention_tail_rows"] += layers * int((at + 1).sum())
+        self.stats["retention_folds"] += layers * int((at == c - 1).sum())
+
     def _run_decode(self, ms: _ModelState, active, emitted) -> None:
         table = ms.table
         if ms.wtable is not None:
@@ -3643,6 +3748,8 @@ class Engine:
                 "decode", ms.decode_step,
                 ms.cache, self._last, self._len, active, self._temps,
                 self._topk, self._topp, self._keys)
+            if self._retention:
+                self._count_retention(active)
         span = self._enter("fetch")
         # tpudp: lint-ok(host-sync): the single-step path's per-token
         # fetch — Engine(decode_fuse=N) amortizes it to one fetch per
