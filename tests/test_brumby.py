@@ -302,10 +302,11 @@ def test_the_published_layer_has_its_parameter_count():
 # every serve program of the lockfile before the power-retention family
 # came, by the start of its fingerprint: the GPT-2, LLaMA, latent and
 # windowed families' programs trace as they did (the audit test holds the
-# tree to the lockfile)
+# tree to the lockfile); the two paged-decode kernel programs as they do
+# since the kernel's grid became its visit list
 PARENT_LOCK = {
     "serve.decode_paged@s2m32p6": "d2d61685bec1c5e8",
-    "serve.decode_paged_kernel@s2m32p6": "afff2aa3002a25d8",
+    "serve.decode_paged_kernel@s2m32p6": "a465f65edb911bf9",
     "serve.decode_paged_latent@s2m32p6": "6c5086a9a37c29b9",
     "serve.decode_paged_latent_kernel@s2m32p6": "6f67a2220118f3e4",
     "serve.decode_paged_windowed@s2m32p6": "67c337fd1254e9ea",
@@ -313,7 +314,7 @@ PARENT_LOCK = {
     "serve.draft_model@ctx16k3": "c6afc03209fb53b7",
     "serve.fused_decode@s2m32n4": "8dad0f5d988a4e21",
     "serve.fused_decode_paged@s2m32p6n4": "f3430b804c0d2658",
-    "serve.fused_decode_paged_kernel@s2m32p6n4": "46d93359c33b87f6",
+    "serve.fused_decode_paged_kernel@s2m32p6n4": "f6323f0f8608ed96",
     "serve.fused_decode_paged_stream@s2m32p6n4": "e9b36befc45ec9ab",
     "serve.fused_decode_stream@s2m32n4": "3ff1fab74a1a9be1",
     "serve.fused_spec_decode@s2m32k3n4": "a581c1c962a8229c",

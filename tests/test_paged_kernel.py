@@ -445,20 +445,91 @@ def _gather_oracle(pages, table, pos, q, dims):
     return out.reshape(S, cur, H, DH)
 
 
-def test_kernel_matches_gather_oracle_on_fragmented_tables():
+#: The paged-decode kernel walks only the entries a decoding slot reaches
+#: (its visit list): tables of every shape it must walk right.  Each case
+#: is ``(table, pos in tokens, active)`` over the fixture's 8 pool pages of
+#: T tokens; ``None`` keeps the fixture's own.
+def _visit_case(case, T):
+    full = (3, 2, 1, 0)
+    return {
+        "fragmented": (None, None, None),
+        # one slot at full depth, one with one page, one row all -1
+        "ragged": ([full, (5, -1, -1, -1), (-1, -1, -1, -1)],
+                   [4 * T - 1, T // 2, 2 * T], None),
+        # entries mapped past pos: a slot's later pages hold nothing yet
+        "past_pos": ([full, (4, 5, 6, 7), (6, 7, 0, 1)],
+                     [T + 2, 0, 2 * T], None),
+        # mapped slots left out of the decode run read nothing
+        "inactive": ([full, (4, 5, 6, 7), (6, 7, 0, 1)],
+                     [4 * T - 1, 3 * T + 1, 2 * T], [True, False, False]),
+        "int8": (None, None, None),
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["fragmented", "ragged", "past_pos",
+                                  "inactive", "int8"])
+def test_kernel_matches_gather_oracle_on_fragmented_tables(case):
     """Interpret-mode Pallas kernel vs the gather-based oracle across a
     fragmented table set (shared prefix pages, COW divergence pages,
-    -1 scratch tails): online softmax vs the XLA chain agree within fp
-    tolerance, and the exact einsum backend agrees BITWISE."""
-    pages, table, pos, q, dims = _fragmented_fixture()
+    -1 scratch tails), ragged depths, entries mapped past a slot's
+    depth, a decode run that leaves mapped slots out, and int8 pages:
+    online softmax vs the XLA chain agree within fp tolerance, and the
+    exact einsum backend agrees BITWISE.  A row whose slot reaches no
+    entry (all ``-1``, or not ``active``) comes out zero."""
+    pages, table, pos, q, dims = _fragmented_fixture(
+        kv_dtype="int8" if case == "int8" else None)
+    rows, depths, active = _visit_case(case, dims[2])
+    if rows is not None:
+        table = jnp.asarray(rows, jnp.int32)
+        pos = jnp.asarray(depths, jnp.int32)
     oracle = np.asarray(_gather_oracle(pages, table, pos, q, dims))
     einsum = np.asarray(paged_attention(
         q, pages, table, pos, dtype=jnp.float32, grouped=True))
     np.testing.assert_array_equal(oracle, einsum)  # bit-exact backend
     kernel = np.asarray(paged_attention(
         q, pages, table, pos, dtype=jnp.float32, grouped=True,
-        impl="kernel", interpret=True))
-    np.testing.assert_allclose(oracle, kernel, rtol=2e-6, atol=2e-6)
+        impl="kernel", interpret=True,
+        active=None if active is None else jnp.asarray(active)))
+    read = np.asarray(table).max(axis=1) >= 0
+    if active is not None:
+        read &= np.asarray(active)
+    np.testing.assert_allclose(oracle[read], kernel[read], rtol=2e-6,
+                               atol=2e-6)
+    np.testing.assert_array_equal(kernel[~read], 0)
+    assert read.any()
+
+
+@pytest.mark.parametrize("window, visits, pages", [
+    # slot 0 reaches entries 0-2 of 4; slot 1 none (an empty visit, which
+    # keeps the page fetched before it, 1, as -1 - 1); slot 2 is not in
+    # the run; slot 3 reaches its two mapped entries
+    (None, [(0, 0), (0, 1), (0, 2), (1, 0), (2, 0), (3, 0), (3, 2)],
+     [3, 2, 1, -2, -2, 5, 6]),
+    # a window of 8 also drops the entries wholly behind it
+    (8, [(0, 1), (0, 2), (1, 0), (2, 0), (3, 2)], [2, 1, -2, -2, 6])],
+    ids=["causal", "window"])
+def test_decode_visit_list_holds_the_reached_entries(window, visits, pages):
+    """The paged-decode kernel's grid, by hand: slot-major, increasing
+    entries, only those mapped, at or before the slot's depth, inside the
+    window and of a slot in the run; one empty visit for a slot with
+    none, on the page already resident (the scratch page, 9, before any
+    other).  The list is the table's size; past its length nothing is
+    read."""
+    from tpudp.ops.paged_attention import _decode_visits
+
+    table = jnp.asarray([[3, 2, 1, 0], [-1, -1, -1, -1], [4, 5, 6, 7],
+                         [5, -1, 6, -1]], jnp.int32)
+    pos = jnp.asarray([17, 5, 3, 30], jnp.int32)
+    active = jnp.asarray([True, True, False, True])
+    visit, page, n = _decode_visits(table, pos, 8, window, active, 9, 2)
+    assert visit.shape == page.shape == (16,)
+    assert int(n) == len(visits)
+    assert [(int(c) >> 2, int(c) & 3) for c in visit[:n]] == visits
+    assert [int(p) for p in page[:n]] == pages
+    first = jnp.asarray([1, 0, 2, 3])  # the empty slot first
+    _, page, _ = _decode_visits(table[first], pos[first], 8, window,
+                                active[first], 9, 2)
+    assert int(page[0]) == -1 - 9  # an empty first visit: the scratch page
 
 
 def test_kernel_int8_in_kernel_dequant_tolerance():
@@ -635,6 +706,50 @@ def test_kernel_engine_decode_end_to_end(model_and_params):
         np.testing.assert_array_equal(_reference(model, params, p, 5),
                                       np.asarray(h.tokens))
     eng.check_paged()
+
+
+@pytest.mark.parametrize("fuse", [1, 4])
+def test_engine_counts_the_decode_kernels_visits(model_and_params, fuse):
+    """``metrics()["stats"]``' ``decode_page_visits`` is what the decode
+    programs' visit lists hold, over every layer of every run: here the
+    sum of the lists the kernel builds from each dispatch's own table,
+    depths and ``active``; and ``decode_rows_read`` the cached tokens the
+    decoding slots read: a request of ``p`` prompt and ``n`` new tokens
+    feeds its ``n - 1`` decode tokens at ``p .. p + n - 2``, whatever the
+    schedule (single steps, or fused windows)."""
+    from tpudp.ops.paged_attention import _decode_visits
+
+    model, params = model_and_params
+    rng = np.random.default_rng(5)
+    lens, new = (3, 12, 20, 9), (6, 9, 4, 7)
+    prompts = [rng.integers(0, 61, size=n).astype(np.int32) for n in lens]
+    eng = Engine(model, params, num_slots=3, max_len=48, prefill_chunk=8,
+                 kv_pages=16, paged_attn="kernel", decode_fuse=fuse)
+    ms = eng._mstates[None]
+    calls, decode = [], ms.decode_paged
+
+    def record(pool, table, last, lengths, active, *rest):
+        calls.append((np.array(table), np.array(lengths), np.array(active)))
+        return decode(pool, table, last, lengths, active, *rest)
+
+    ms.decode_paged = record
+    handles = [eng.submit(p, n) for p, n in zip(prompts, new)]
+    eng.run_until_complete()
+    assert all(h.ok for h in handles)
+    stats = eng.metrics()["stats"]
+    assert stats["decode_rows_read"] == sum(
+        (p + 1 + p + n - 1) * (n - 1) // 2 for p, n in zip(lens, new))
+    if fuse == 1:
+        assert calls
+        layers = model.config.num_layers
+        # (8-token pages, the scratch page 16, 6 entries: 3 entry bits)
+        assert stats["decode_page_visits"] == sum(
+            layers * int(_decode_visits(jnp.asarray(t), jnp.asarray(pos), 8,
+                                        None, jnp.asarray(act), 16, 3)[2])
+            for t, pos, act in calls)
+    # an idle or prefilling slot is one visit, a decoding one its pages
+    assert stats["decode_page_visits"] >= model.config.num_layers * (
+        stats["decode_steps"] + stats.get("fused_steps", 0)) * 3
 
 
 def _run_traffic(model, params, paged_attn, **engine_kw):

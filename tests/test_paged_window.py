@@ -32,14 +32,18 @@ def _dense_window_attention(q, k, v, q_pos, window):
     return np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), v)
 
 
-@pytest.mark.parametrize("freed", [False, True], ids=["mapped", "freed"])
+@pytest.mark.parametrize("freed", [False, True, "ragged"],
+                         ids=["mapped", "freed", "ragged"])
 @pytest.mark.parametrize("impl", ["einsum", "kernel"])
 @pytest.mark.parametrize("shape", ["decode", "chunk", "verify"])
 def test_windowed_paged_attention_is_a_dense_masked_softmax(shape, impl,
                                                             freed):
     """Across page boundaries, pages in scattered order, with the table
     entries behind the window still mapped or freed (``-1``): the mask
-    alone decides what a sliding layer sees."""
+    alone decides what a sliding layer sees.  ``ragged``: one slot's
+    leading entries freed and another's still mapped, and in a decode run
+    a third slot, mapped, that is not in it (the decode kernel reads no
+    page for it: its row is zero)."""
     t_page, window, kv, h, dh = 8, 12, 2, 8, 16
     rng = np.random.default_rng(0)
     k = rng.normal(size=(48, kv, dh)).astype(np.float32)
@@ -57,17 +61,26 @@ def test_windowed_paged_attention_is_a_dense_masked_softmax(shape, impl,
         pos, cur = np.int32(32), t_page
     else:  # a window of three positions a slot
         pos, cur = np.array([21, 38], np.int32), 3
+    active = None
+    if freed == "ragged" and shape == "decode":
+        pos, active = np.append(pos, 30).astype(np.int32), [True] * 2 + [False]
     starts = np.atleast_1d(pos)
     table = np.tile(np.asarray(order, np.int32), (starts.size, 1))
     if freed:
-        for row, start in zip(table, starts):
-            row[:max(start - window + 1, 0) // t_page] = -1
+        for i, (row, start) in enumerate(zip(table, starts)):
+            if freed is True or i == 0:
+                row[:max(start - window + 1, 0) // t_page] = -1
     q = rng.normal(size=(starts.size, cur, h, dh)).astype(np.float32)
     got = paged_attention(
         jnp.asarray(q), (jnp.asarray(pool_k), jnp.asarray(pool_v)),
         jnp.asarray(table), jnp.asarray(pos), dtype=jnp.float32,
-        grouped=True, impl=impl, window=window)
+        grouped=True, impl=impl, window=window,
+        active=None if active is None else jnp.asarray(active))
     for b, start in enumerate(starts):
+        if active is not None and not active[b]:
+            if impl == "kernel":
+                np.testing.assert_array_equal(got[b], 0)
+            continue
         want = _dense_window_attention(q[b], k, v, start + np.arange(cur),
                                        window)
         np.testing.assert_allclose(got[b], want, atol=2e-5)
@@ -83,8 +96,8 @@ PARENT_TRACES = {
         "d9791d1b63aed26b79dee6af5f08cbb7c763f28b4cc6199238ea70557804556b",
     "gpt2.prefill.einsum":
         "38fdaf23711091aa90c83e01a78f1abcd901dd5d44d9e15da7b9a8ee905bf2d9",
-    "gpt2.decode.kernel":
-        "5ea69c93380ca0bb2b0d3c361c2c1007953594751ad55ef9fb96dfe96ab6d30e",
+    "gpt2.decode.kernel":  # the decode kernel's grid is its visit list
+        "7630acaf226133f8ef76e6a0b7306277d170fe311712d56dbfbf56d2e2189ca5",
     "gpt2.prefill.kernel":
         "93ca3ecadd6d32c5e86fe93dbc873c882a6186d0fa14ef4654ce783ba9d58236",
     "llama.decode.einsum":
@@ -92,7 +105,7 @@ PARENT_TRACES = {
     "llama.prefill.einsum":
         "6dd9c311c846f377336b32271d6fd006f690b4f08902e9ea596e153db0464c1c",
     "llama.decode.kernel":
-        "a9aa2ab98641bf251ef726e6a881ca4cb69313d977044ce16d9af4598fc83b65",
+        "f9bf1306188d5e841fe2669a5a3fc36ad71ba423fe9fbd27bf0eb8b96599c420",
     "llama.prefill.kernel":
         "b29b05b5fdc440fa08c23f610a02a0d2f148e2822b4936de6d780798b81ec0f2"}
 
@@ -131,15 +144,16 @@ def test_window_none_traces_the_parents_program(case):
 def test_the_other_families_lock_entries_did_not_move():
     """The engine's registered programs of GPT-2 and the latent family
     keep the fingerprints the lockfile had when the window family came,
-    but for the programs' counter accumulator, since removed, and the
-    prefill programs' first-token tail, since added (the audit test holds
-    the tree to the lockfile)."""
+    but for the programs' counter accumulator, since removed, the
+    prefill programs' first-token tail, since added, and the decode
+    kernel's grid, since a visit list (the audit test holds the tree to
+    the lockfile)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "tools", "trace_lock.json")) as f:
         programs = json.load(f)["programs"]
     parent = {
         "serve.decode_paged@s2m32p6": "d2d61685bec1c5e8",
-        "serve.decode_paged_kernel@s2m32p6": "afff2aa3002a25d8",
+        "serve.decode_paged_kernel@s2m32p6": "a465f65edb911bf9",
         "serve.prefill_paged@s2m32p6c8": "74dc2f514dfb93bc",
         "serve.prefill_paged_kernel@s2m32p6c8": "e7e554c0883b4c62",
         "serve.verify_paged_kernel@s2m32p6k3": "175e06f932497d8e",

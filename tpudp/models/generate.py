@@ -517,7 +517,7 @@ class _PagedKV:
         return paged_attention(q, self.pages, self.table, self.pos,
                                dtype=self.cfg.dtype, grouped=self.grouped,
                                impl=self.impl, layer=self.layer,
-                               window=self.window)
+                               window=self.window, active=self.active)
 
 
 def _row_major(x: jnp.ndarray) -> jnp.ndarray:

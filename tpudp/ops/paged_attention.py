@@ -35,18 +35,21 @@ Two backends behind one op:
     preserves the PR 13 parity oracle (paged ≡ dense ≡ ``generate()``)
     while the dense view and its scatter are gone (the committed budget
     ledger pins the peak-live drop).
-  * ``impl='kernel'`` — a Pallas paged-decode kernel: grid over
-    ``(slot, kv_pages)``, online-softmax carry (running max /
-    denominator / output accumulator) in VMEM scratch exactly like the
-    flash kernel, the block table and per-slot positions ride as
-    SCALAR PREFETCH so each grid step's page is DMA'd straight from the
-    pool by table value, ``-1`` (unmapped) entries skip their compute
-    via ``pl.when``, and int8 pages dequantize in-kernel.  Tolerance-
-    bounded like flash (online softmax rounds differently from the XLA
-    chain), so the engine treats it as an explicit opt-in
-    (``Engine(paged_attn='kernel')``).  Runs in interpret mode on the
-    CPU platform (and only there unless ``interpret=True`` is passed) so
-    the same code is unit-testable on the CPU host.
+  * ``impl='kernel'`` — a Pallas paged-decode kernel: its grid is a
+    VISIT LIST, one step for each table entry a decoding slot's query
+    reaches (mapped, at or before ``pos``, inside a sliding layer's
+    window) in slot-major, increasing-entry order, and ONE step with
+    nothing to do for a slot that reaches none (an idle slot: its row
+    comes out zero).  The list is built in the step program from the
+    table, ``pos``, ``window`` and ``active`` (:func:`decode_reach`) and
+    rides with the per-slot positions as SCALAR PREFETCH, so each grid
+    step's page is DMA'd straight from the pool by table value;
+    online-softmax carry (running max / denominator / output
+    accumulator) in VMEM scratch exactly like the flash kernel, int8
+    pages dequantize in-kernel.  Tolerance-bounded like flash (online
+    softmax rounds differently from the XLA chain).  Runs in interpret
+    mode on the CPU platform (and only there unless ``interpret=True``
+    is passed) so the same code is unit-testable on the CPU host.
 
 The op covers both attention families the decode twins use: the GPT-2
 MHA einsum forms and LLaMA's grouped (GQA) forms — selected by
@@ -447,18 +450,78 @@ def _head(blk, scales, ki: int, dh: int):
     return x if scales is None else x * scales[:, ki:ki + 1]
 
 
-def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   kv: int, groups: int, page_tokens: int, n_pages: int,
-                   scale: float, int8: bool, window: int | None = None):
-    """One ``(slot, page)`` grid step of the paged-decode kernel.
+def decode_reach(table, pos, page_tokens: int, window: int | None = None,
+                 active=None, xp=jnp):
+    """Which table entries a one-token decode query must read: ``(b,
+    max_pages)`` bool, entry ``m`` of slot ``s`` where it is mapped
+    (``>= 0``), its page holds a key the query at ``pos[s]`` may see
+    (``m * page_tokens <= pos[s]``; on a sliding layer also a key inside
+    the window, ``(m + 1) * page_tokens - 1 > pos[s] - window``) and the
+    slot is decoding (``active[s]``, where given).  ``xp`` is ``jnp`` in
+    the step program (:func:`_decode_visits`) and ``numpy`` where the
+    engine counts the same entries on the host, so the two use ONE
+    rule."""
+    first = xp.arange(table.shape[1]) * page_tokens  # an entry's first key
+    q_pos = pos[:, None]
+    reach = (table >= 0) & (first <= q_pos)
+    if window is not None:
+        reach = reach & (first + page_tokens - 1 > q_pos - window)
+    if active is not None:
+        reach = reach & active[:, None]
+    return reach
 
-    The block specs already fetched THIS slot's page ``m`` by table
-    value (the index maps read the scalar-prefetched table), so the
-    kernel body only runs the online-softmax recurrence over the page's
-    ``page_tokens`` keys — running max / denominator / accumulator
-    carried in VMEM scratch across the page axis, exactly the flash
-    kernel's recurrence with the K-block stream replaced by a
-    table-indirected page stream."""
+
+def _decode_visits(table, pos, page_tokens: int, window, active,
+                   scratch_page: int, shift: int):
+    """The paged-decode kernel's grid, built in the step program: XLA
+    integer operations over the ``(b, max_pages)`` table, identical in
+    every layer of one kind, so one program computes them once.
+
+    Returns ``(visit, page, n)``, both lists ``b * max_pages`` long (the
+    table's size) and slot-major, of which the first ``n`` are the grid:
+    ``visit[v] = (slot << shift) | entry`` and ``page[v]`` the pool page
+    it reads, for each entry :func:`decode_reach` keeps, in increasing
+    entry order.  A slot that reaches no entry gets ONE visit, at entry
+    0, whose page is stored as ``-1 - p`` (negative: the kernel skips its
+    body and writes the slot's row as zeros), ``p`` being the page the
+    visit before it fetched, already resident, so the step moves no
+    bytes (the scratch page if none came before)."""
+    b, n_pages = table.shape
+    n = b * n_pages
+    reach = decode_reach(table, pos, page_tokens, window, active)
+    keep = reach | ((jnp.arange(n_pages) == 0)
+                    & ~jnp.any(reach, axis=1, keepdims=True))
+    reach, keep = reach.reshape(-1), keep.reshape(-1)
+    flat = jnp.arange(n, dtype=jnp.int32)
+    code = ((flat // n_pages) << shift) | (flat % n_pages)
+    pages = table.reshape(-1).astype(jnp.int32)
+    last = jax.lax.cummax(jnp.where(reach, flat, -1))  # reached so far
+    resident = jnp.where(last >= 0, pages[jnp.maximum(last, 0)],
+                         scratch_page)
+    pages = jnp.where(reach, pages, -1 - resident)
+    # the kept entries in order to the front; the rest is never read
+    to = jnp.where(keep, jnp.cumsum(keep) - 1, n)
+    out = jnp.zeros((n, 2), jnp.int32).at[to].set(
+        jnp.stack([code, pages], axis=1), mode="drop")
+    return out[:, 0], out[:, 1], jnp.sum(keep, dtype=jnp.int32)
+
+
+def _decode_kernel(visit_ref, page_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+                   kv: int, groups: int, page_tokens: int, shift: int,
+                   n_list: int, scale: float, int8: bool,
+                   window: int | None = None):
+    """One visit of the paged-decode kernel: table entry ``m`` of slot
+    ``s``, both read from the scalar-prefetched visit list.
+
+    The block specs already fetched the entry's page by table value
+    (the index maps read the list), so the kernel body only runs the
+    online-softmax recurrence over the page's ``page_tokens`` keys —
+    running max / denominator / accumulator carried in VMEM scratch
+    across a slot's consecutive visits, exactly the flash kernel's
+    recurrence with the K-block stream replaced by a table-indirected
+    page stream.  The carry starts at a slot's first visit and is
+    written out at its last; a visit whose page is negative (a slot that
+    reaches no entry) does nothing in between, so that row is zero."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
@@ -466,19 +529,18 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
-    s = pl.program_id(0)
-    m = pl.program_id(1)
+    v = pl.program_id(0)
+    s = visit_ref[v] >> shift
+    m = visit_ref[v] & ((1 << shift) - 1)
     h = kv * groups
 
-    @pl.when(m == 0)
+    @pl.when((v == 0) | (visit_ref[jnp.maximum(v - 1, 0)] >> shift != s))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    mapped = tbl_ref[s * n_pages + m] >= 0
-
-    @pl.when(mapped)  # -1 (unmapped) pages: skip — nothing to attend
+    @pl.when(page_ref[v] >= 0)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale  # (h, dh)
         dh = q.shape[-1]
@@ -497,8 +559,6 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         s_blk = jnp.concatenate(rows, axis=0)  # (h, T)
         k_pos = m * page_tokens + lax.broadcasted_iota(
             jnp.int32, (h, page_tokens), 1)
-        # (a mapped page wholly behind a sliding layer's window weighs
-        # one a key until the first visible key's alpha wipes it: zero)
         s_blk = jnp.where(_seen(k_pos, pos_ref[s], window), s_blk, _NEG_INF)
         m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)  # (h, 1)
         l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
@@ -516,7 +576,9 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(pv, axis=0)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(m == n_pages - 1)
+    nxt = visit_ref[jnp.minimum(v + 1, n_list - 1)] >> shift
+
+    @pl.when((v == pl.num_programs(0) - 1) | (nxt != s))
     def _finalize():
         l_safe = jnp.maximum(jnp.max(l_ref[...], axis=-1, keepdims=True),
                              1e-30)
@@ -525,13 +587,18 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
 # tpudp: kernel-program(serve.decode_paged_kernel)
 def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None,
-                  window=None):
+                  window=None, active=None):
     """Dispatch one decode step (``cur == 1``) through the Pallas
-    paged-decode kernel.  ``q``: ``(b, 1, h, dh)``; the grid is
-    ``(b, M)`` with the online-softmax carry persisting across the
-    inner (page) axis; the table row and per-slot positions are scalar
-    prefetch, so each page block is DMA'd by TABLE VALUE — the gather
-    never exists even as a transient.
+    paged-decode kernel.  ``q``: ``(b, 1, h, dh)``; the grid is the
+    visit list of :func:`_decode_visits` — only the table entries a
+    decoding slot's query reaches, a slot's visits consecutive, plus one
+    empty visit for a slot that reaches none — a traced length, with the
+    online-softmax carry persisting across a slot's visits.  The list
+    and the per-slot positions are scalar prefetch, so each page block
+    is DMA'd by TABLE VALUE — the gather never exists even as a
+    transient.  A table full to the brim walks every ``(slot, entry)``
+    pair, as a dense grid would; rows of slots that are not ``active``
+    read no page and come out zero.
 
     With ``layer`` (the engine's whole-pool mode) ``pages`` carry the
     FULL stacked pool ``(layers, ...)`` and the BlockSpec picks the
@@ -546,29 +613,35 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None,
     int8 = len(pages) == 4
     lx = () if layer is None else (layer,)
     pb = (None,) * len(lx)  # layer block axis, squeezed out of the refs
-    k_pages, v_pages = pages[0], pages[1]
-    n_real = k_pages.shape[len(lx)] - 1  # trailing page is write scratch
+    k_pages = pages[0]
+    scratch_page = k_pages.shape[len(lx)] - 1  # trailing write scratch
     page_tokens = k_pages.shape[1 + len(lx)]
     row = k_pages.shape[2 + len(lx)]  # kv * dh: a token's stored row
     kv = row // dh
     n_pages = table.shape[1]
     groups = h // kv
     scale = dh ** -0.5
-    scratch_page = n_real
+    shift = max(n_pages - 1, 1).bit_length()  # entry bits of a visit
 
-    tbl = jnp.asarray(table, jnp.int32).reshape(-1)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    visit, page, n_visits = _decode_visits(
+        jnp.asarray(table, jnp.int32), pos, page_tokens, window, active,
+        scratch_page, shift)
 
-    def page_map(s, m, tbl_ref, pos_ref):  # payload and scale blocks
-        t = tbl_ref[s * n_pages + m]
-        return (*lx, jnp.where(t >= 0, t, scratch_page), 0, 0)
+    def slot_map(v, visit_ref, page_ref, pos_ref):  # the q and out blocks
+        return (visit_ref[v] >> shift, 0, 0)
+
+    def page_map(v, visit_ref, page_ref, pos_ref):  # payload and scales
+        pg = page_ref[v]
+        return (*lx, jnp.where(pg >= 0, pg, -1 - pg), 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, kv=kv, groups=groups, page_tokens=page_tokens,
-        n_pages=n_pages, scale=scale, int8=int8, window=window)
+        shift=shift, n_list=b * n_pages, scale=scale, int8=int8,
+        window=window)
     ins = (pages[0], pages[1]) + ((pages[2], pages[3]) if int8 else ())
     in_specs = [
-        pl.BlockSpec((1, h, dh), lambda s, m, t, p: (s, 0, 0)),
+        pl.BlockSpec((1, h, dh), slot_map),
         pl.BlockSpec((*pb, 1, page_tokens, row), page_map),
         pl.BlockSpec((*pb, 1, page_tokens, row), page_map),
     ]
@@ -576,10 +649,10 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None,
         in_specs += [pl.BlockSpec((*pb, 1, page_tokens, kv), page_map),
                      pl.BlockSpec((*pb, 1, page_tokens, kv), page_map)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_pages),
+        num_scalar_prefetch=3,
+        grid=(n_visits,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, dh), lambda s, m, t, p: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, dh), slot_map),
         scratch_shapes=[
             pltpu.VMEM((h, dh), jnp.float32),
             pltpu.VMEM((h, _LANES), jnp.float32),
@@ -594,7 +667,7 @@ def _kernel_paged(q, pages, table, pos, *, dtype, interpret, layer=None,
         # a sliding layer's calls under a name of their own: the trace
         # tells the two layer kinds apart
         name="paged_decode" if window is None else "paged_decode_window",
-    )(tbl, pos, q[:, 0], *ins)
+    )(visit, page, pos, q[:, 0], *ins)
     return out[:, None]
 
 
@@ -931,7 +1004,7 @@ def _tree_paged(q, pages, table, pos0, wk, wv, anc, *, dtype, interpret):
 def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
                     impl: str = "einsum", interpret: bool | None = None,
                     layer: int | None = None,
-                    window: int | None = None) -> jnp.ndarray:
+                    window: int | None = None, active=None) -> jnp.ndarray:
     """Attention for already-projected queries over table-indirected
     K/V pages — the ONE paged-attention op behind the serve engine's
     gather-free step programs.
@@ -972,7 +1045,11 @@ def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
     skip it, the einsum path masks the scratch page it reads), or still
     mapped.  Its Mosaic calls are named ``paged_decode_window`` /
     ``paged_prefill_window``.  ``None`` traces exactly the causal
-    program."""
+    program.
+
+    ``active`` ``(b,)`` bool (optional; the single-token decode kernel
+    alone reads it): the slots in the decode run.  The kernel reads no
+    page for the others, and their rows come out zero."""
     if impl not in ("einsum", "kernel"):
         raise ValueError(
             f"unknown paged-attention impl {impl!r}; choose from "
@@ -986,7 +1063,7 @@ def paged_attention(q, pages, table, pos, *, dtype, grouped: bool = False,
         if pos.ndim and q.shape[1] == 1:
             return _kernel_paged(q, pages, table, pos, dtype=dtype,
                                  interpret=interpret, layer=layer,
-                                 window=window)
+                                 window=window, active=active)
         return _window_paged(q, pages, table, pos, dtype=dtype,
                              interpret=interpret, layer=layer,
                              window=window)

@@ -3730,6 +3730,35 @@ class Engine:
         self.stats["retention_tail_rows"] += layers * int((at + 1).sum())
         self.stats["retention_folds"] += layers * int((at == c - 1).sum())
 
+    def _count_decode_visits(self, ms: _ModelState, table, lengths,
+                             active) -> None:
+        """A decode run's paged-decode kernel work, on the host (no
+        fetch), from the arguments of its dispatch, into the stats:
+        ``decode_page_visits``, the grid steps of each layer kind's calls
+        (``ops.paged_attention.decode_reach``'s entries, and one visit
+        for a slot that reaches none) times the layers of that kind; and
+        ``decode_rows_read``, ``pos + 1`` over the decoding slots (the
+        cached tokens a full-attention layer reads).  Nothing unless the
+        decode program runs the kernel."""
+        if self.paged_attn_dispatch.get("decode_paged") != "kernel" or (
+                ms.wtable is None and page_layout(ms.config) != "heads"):
+            return
+        from tpudp.ops.paged_attention import decode_reach
+
+        if ms.wtable is None:
+            kinds = ((table, None, ms.config.num_layers),)
+        else:
+            pages = ms.pool.pages
+            kinds = ((table[0], None, pages.full.k.shape[0]),
+                     (table[1], ms.config.sliding_window,
+                      pages.window.k.shape[0]))
+        for tbl, window, layers in kinds:
+            reach = decode_reach(tbl, lengths, ms.pool.page_tokens, window,
+                                 active, xp=np)
+            visits = int(reach.sum()) + int((~reach.any(axis=1)).sum())
+            self.stats["decode_page_visits"] += layers * visits
+        self.stats["decode_rows_read"] += int((lengths[active] + 1).sum())
+
     def _run_decode(self, ms: _ModelState, active, emitted) -> None:
         table = ms.table
         if ms.wtable is not None:
@@ -3743,6 +3772,9 @@ class Engine:
                 ms.pool.pages, table, self._last, self._len, active,
                 self._temps, self._topk, self._topp, self._keys)
             self._moe_pending += routed
+            span = self._enter("batch")
+            self._count_decode_visits(ms, table, self._len, active)
+            self._leave(span)
         else:
             ms.cache, toks, self._keys = self._device(
                 "decode", ms.decode_step,
@@ -3833,6 +3865,10 @@ class Engine:
         # occupancy consumers keep working with fusing on
         # (active / (decode_steps + fused_steps) x num_slots).
         self.stats["active_slot_steps"] += int(n_emit.sum())
+        # the window's iteration i ran the rows that emitted more than i
+        for i in range(int(iters)):  # tpudp: lint-ok(host-sync): same fetch
+            self._count_decode_visits(ms, ms.table, self._len + i,
+                                      n_emit > i)
         for s in np.nonzero(active)[0]:
             r = self._slots[s]
             # Take the window-final key carry PER SLOT, just before that
